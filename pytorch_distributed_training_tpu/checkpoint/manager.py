@@ -116,6 +116,9 @@ class CheckpointManager:
         self._bad_steps: set[int] = set()
         self._mgr = ocp.CheckpointManager(
             self.directory,
+            # Named up front so a process that never saved (the serving
+            # restore) can read a step's metadata.
+            item_handlers=ocp.StandardCheckpointHandler(),
             options=ocp.CheckpointManagerOptions(
                 max_to_keep=max_to_keep,
                 enable_async_checkpointing=async_save,
@@ -364,36 +367,58 @@ class CheckpointManager:
         return deleted
 
     def restore_params(self):
-        """Restore only the ``params`` tree of the newest checkpoint (None
-        when the directory holds no committed step).
+        """Restore only the ``params`` tree of the newest verified
+        checkpoint, as host numpy arrays (None when the directory holds no
+        committed step).
 
         The serving path (cli --serve / serve.ServingEngine) wants the
         trained weights and nothing else — restoring through a TrainState
         template would force the caller to reconstruct the exact optimizer
         (and LR-schedule state shape) the training run used just to throw
-        it away.  Raw restore sidesteps that: arrays come back with default
-        placement and the engine re-shards/casts as it needs.  Corrupt
-        newer steps fall back like :meth:`restore_latest` (params-leaf
-        checksums only — the manifest's other sections cover state the
-        serving path never touches).
+        it away.  Every leaf is read as ``np.ndarray``, named so
+        explicitly: with no restore type given orbax rebuilds each array
+        on the SAVING topology's sharding, which puts a four-chip save
+        back on four chips (and fails on a machine that has one).  Host
+        arrays land wherever the engine then places them.  Only the
+        ``params`` subtree is read; the optimizer state stays on disk.
+
+        Corrupt newer steps fall back like :meth:`restore_latest`
+        (params-leaf checksums only — the manifest's other sections cover
+        state the serving path never touches), and like it this raises
+        when committed steps exist but none restores: serving other
+        weights than the ones asked for is not a fallback.
         """
-        for step in sorted(self._mgr.all_steps(), reverse=True):
+        steps = sorted(self._mgr.all_steps(), reverse=True)
+        errors: list[str] = []
+        for step in steps:
             try:
-                # Template-free StandardRestore: arrays come back as saved.
-                # The bare ``restore(step)`` form works only in the process
-                # that just SAVED (the save registers the handler); a fresh
-                # serving process must name the handler through args.
-                restored = self._mgr.restore(
-                    step, args=ocp.args.StandardRestore()
-                )
-                self._verify_params(step, restored["params"])
+                item = {"params": self._mgr.item_metadata(step).tree["params"]}
+                params = ocp.PyTreeCheckpointer().restore(
+                    # The manager's layout: one "default" item per step.
+                    os.path.join(self.directory, str(step), "default"),
+                    args=ocp.args.PyTreeRestore(
+                        item=item,
+                        restore_args=jax.tree_util.tree_map(
+                            lambda _: ocp.RestoreArgs(restore_type=np.ndarray),
+                            item,
+                        ),
+                        partial_restore=True,
+                    ),
+                )["params"]
+                self._verify_params(step, params)
             except Exception as e:
+                errors.append(f"step {step}: {type(e).__name__}: {e}")
                 self._anomaly(
                     "checkpoint_restore_failed", step=int(step),
                     error=f"{type(e).__name__}: {e}",
                 )
                 continue
-            return restored["params"]
+            return params
+        if steps:
+            raise RuntimeError(
+                f"no committed checkpoint under {self.directory} could be "
+                f"restored ({len(steps)} candidates): " + "; ".join(errors)
+            )
         return None
 
     def _verify_params(self, step: int, params: Any) -> None:

@@ -10,8 +10,10 @@ device without the reversed-modulo crash of src/main.py:52.
 TPU semantics of the flags:
   --distributed  → multi-host: ``jax.distributed.initialize`` (replaces
                    ``dist.init_process_group``, src/main.py:39-41).
-  --use-cpu      → force the CPU backend (the reference's CUDA-else-CPU
-                   selection at src/main.py:56-57 becomes TPU-else-CPU).
+  --use-cpu      → run on the CPU backend.  The reference's CUDA-else-CPU
+                   selection (src/main.py:56-57) is NOT reproduced: without
+                   this flag a run that finds no accelerator is a usage
+                   error, never a silent CPU run.
   --num-workers  → decode worker processes, as in DataLoader(num_workers=2).
 """
 
@@ -501,6 +503,9 @@ _STRING_OVERRIDE_KEYS = frozenset({"moe_dispatch"})
                    "mesh, scale grad accumulation to preserve the global "
                    "batch, and grow back when the slice returns.")
 def main(**opts):
+    from ..utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if opts.pop("elastic", False):
         _run_elastic(
             opts,
@@ -517,42 +522,31 @@ def main(**opts):
     run(**opts)
 
 
-# Option names whose CLI flag differs from the parameter name, the
-# boolean flags (emitted bare, only when set), and the on/off toggles
-# (emitted as their explicit on/off form either way).
-_FLAG_NAMES = {"do_eval": "--eval"}
-_BOOL_OPTS = {
-    "distributed", "use_cpu", "synthetic_data", "do_eval", "resume", "serve",
-    "serve_autoscale", "serve_paged", "serve_spec", "skip_bad_steps", "trace",
-    "goodput",
-}
-_TOGGLE_OPTS = {
-    "serve_affinity": ("--serve-affinity", "--no-serve-affinity"),
-    "serve_failover": ("--serve-failover", "--no-serve-failover"),
-}
-
-
 def _opts_to_argv(opts: dict) -> list[str]:
     """Serialize parsed options back to an argv for the supervised child.
 
     Built from the *parsed* options (not sys.argv) so programmatic
     invocations (tests, notebooks) supervise the intended command rather
-    than the host process's argv.
+    than the host process's argv.  Flag spellings and kinds come from the
+    click command itself: a hand-kept table of which options are boolean
+    went stale three flags ago and every --elastic child died on
+    ``unexpected extra arguments (False False False)``.
     """
+    params = {p.name: p for p in main.params}
     argv: list[str] = []
     for key, value in opts.items():
-        flag = _FLAG_NAMES.get(key, "--" + key.replace("_", "-"))
-        if key in _BOOL_OPTS:
-            if value:
-                argv.append(flag)
-            continue
-        if key in _TOGGLE_OPTS:
-            on, off = _TOGGLE_OPTS[key]
-            argv.append(on if value else off)
-            continue
-        if value is None:
-            continue
-        argv.extend([flag, str(value)])
+        param = params[key]
+        if param.is_flag:
+            # --x/--no-x toggles are spelled out either way; a plain flag
+            # is emitted bare, only when set.
+            if param.secondary_opts:
+                argv.append(
+                    param.opts[0] if value else param.secondary_opts[0]
+                )
+            elif value:
+                argv.append(param.opts[0])
+        elif value is not None:
+            argv.extend([param.opts[0], str(value)])
     return argv
 
 
@@ -597,6 +591,50 @@ def _run_elastic(opts: dict, *, max_restarts, heartbeat_timeout):
     sys.exit(128 + abs(code) if code < 0 else code)
 
 
+def _select_backend(use_cpu, cpu_devices, *, distributed=False):
+    """Apply --use-cpu/--cpu-devices.  Must run before anything touches
+    devices; itself it touches them only to verify --cpu-devices, and not
+    under --distributed, where ``jax.distributed.initialize`` has to come
+    first (the start line shows the count there)."""
+    import jax
+
+    if not use_cpu:
+        if cpu_devices:
+            raise click.UsageError("--cpu-devices requires --use-cpu")
+        return
+    jax.config.update("jax_platforms", "cpu")
+    if not cpu_devices:
+        return
+    from ..compat import set_cpu_device_count
+
+    try:
+        set_cpu_device_count(int(cpu_devices))
+    except RuntimeError as e:  # backend already initialized
+        raise click.UsageError(
+            f"--cpu-devices must be set before JAX initializes its "
+            f"backends; this process already touched devices ({e})"
+        )
+    if not distributed and jax.local_device_count() != int(cpu_devices):
+        raise click.UsageError(
+            f"--cpu-devices {cpu_devices} did not take effect "
+            f"({jax.local_device_count()} devices visible); the "
+            "backend was initialized before this flag was applied"
+        )
+
+
+def _require_accelerator(use_cpu):
+    """Without --use-cpu the run is for an accelerator: a CPU default
+    backend means JAX found none (or was held to the CPU), and carrying on
+    would report CPU work under a device's name."""
+    import jax
+
+    if not use_cpu and jax.default_backend() == "cpu":
+        raise click.UsageError(
+            "JAX found no accelerator (the default backend is 'cpu'); "
+            "pass --use-cpu to run on the CPU on purpose"
+        )
+
+
 def _run_elastic_resize(spec: str, opts: dict):
     """One scripted elastic episode on the simulated multi-slice mesh.
 
@@ -608,27 +646,8 @@ def _run_elastic_resize(spec: str, opts: dict):
     import json
     import os
 
-    # Backend selection must precede any jax import that touches devices,
-    # exactly as in run() — this branch returns before run() ever sees
-    # --use-cpu/--cpu-devices.
-    import jax
-
-    if opts.get("use_cpu"):
-        jax.config.update("jax_platforms", "cpu")
-        cpu_devices = opts.get("cpu_devices")
-        if cpu_devices:
-            from ..compat import set_cpu_device_count
-
-            try:
-                set_cpu_device_count(int(cpu_devices))
-            except RuntimeError as e:  # backend already initialized
-                raise click.UsageError(
-                    f"--cpu-devices must be set before JAX initializes "
-                    f"its backends; this process already touched devices "
-                    f"({e})"
-                )
-    elif opts.get("cpu_devices"):
-        raise click.UsageError("--cpu-devices requires --use-cpu")
+    _select_backend(opts.get("use_cpu"), opts.get("cpu_devices"))
+    _require_accelerator(opts.get("use_cpu"))
 
     from ..obs import MetricsEmitter
     from ..resilience.elastic import ElasticConfig, run_elastic_episode
@@ -708,35 +727,9 @@ def run(
     rollback_after=8, max_rollbacks=2, snapshot_every_steps=200,
     inject_faults=None,
 ):
-    # Backend selection must precede any jax import that touches devices
-    # (the --use-cpu analogue of src/main.py:56-57).
+    _select_backend(use_cpu, cpu_devices, distributed=distributed)
+
     import jax
-
-    if use_cpu:
-        jax.config.update("jax_platforms", "cpu")
-        if cpu_devices:
-            from ..compat import set_cpu_device_count
-
-            try:
-                set_cpu_device_count(int(cpu_devices))
-            except RuntimeError as e:  # backend already initialized
-                raise click.UsageError(
-                    f"--cpu-devices must be set before JAX initializes its "
-                    f"backends; this process already touched devices ({e})"
-                )
-            # Verify the count took — but NOT under --distributed, where
-            # local_device_count() would initialize the backend before
-            # jax.distributed.initialize() runs (comm.initialize below
-            # must come first).  The post-init print covers that path.
-            if not distributed and jax.local_device_count() != int(cpu_devices):
-                raise click.UsageError(
-                    f"--cpu-devices {cpu_devices} did not take effect "
-                    f"({jax.local_device_count()} devices visible); the "
-                    "backend was initialized before this flag was applied"
-                )
-    elif cpu_devices:
-        raise click.UsageError("--cpu-devices requires --use-cpu")
-
     import jax.numpy as jnp
     import optax
 
@@ -752,9 +745,12 @@ def run(
         # Replaces the reference's assert-guarded init_process_group block
         # (src/main.py:35-42); rank/world size are discovered, not env asserts.
         comm.initialize()
+    _require_accelerator(use_cpu)
     print(
         f"process {comm.process_index()}/{comm.process_count()} | "
-        f"backend={jax.default_backend()} | devices={jax.local_device_count()}"
+        f"platform={jax.devices()[0].platform} | "
+        f"device_kind={jax.devices()[0].device_kind} | "
+        f"devices={jax.local_device_count()}"
     )
 
     # Cheap flag validations FIRST — a typo'd compression flag must fail
@@ -849,6 +845,8 @@ def run(
             "dataset": dataset, "precision": precision,
             "batch_size": batch_size, "accum_steps": accum_steps,
             "grad_sync": grad_sync, "backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
+            "device_count": jax.device_count(),
         },
     )
     # Span spine (--trace): spans ride the same event log, so tracing
@@ -1870,6 +1868,18 @@ def run(
                 f"{snap['wall_s']:.2f}s wall "
                 f"(identity {'ok' if snap['identity_ok'] else 'BROKEN'})"
             )
+        if emitter.enabled:
+            # What each device holds at the end (None where the backend
+            # keeps no memory statistics — the CPU).
+            stats = {d.id: d.memory_stats() or {} for d in jax.local_devices()}
+            emitter.emit("record", {
+                "record": "device_memory",
+                "devices": [
+                    {"id": i, "bytes_in_use": s.get("bytes_in_use"),
+                     "peak_bytes_in_use": s.get("peak_bytes_in_use")}
+                    for i, s in stats.items()
+                ],
+            })
         emitter.summary()
         emitter.close()
     elapsed = time.perf_counter() - t0
@@ -1942,22 +1952,29 @@ def _run_serve(
             f"--serve-max-new {max_new} leaves no room for a prompt in the "
             f"model's {net.cfg.max_seq_len}-position cache"
         )
-    params = None
     if checkpoint_dir:
         from ..checkpoint import CheckpointManager
 
+        # A --checkpoint-dir that cannot be served FAILS the run: random
+        # weights in place of the ones asked for is not a fallback
+        # (restore_params raises when committed steps exist but none
+        # restores).
         params = CheckpointManager(checkpoint_dir).restore_params()
-        if params is not None:
-            print(f"serving params restored from {checkpoint_dir}")
-    if params is None:
-        if checkpoint_dir:
-            print(f"warning: no committed checkpoint in {checkpoint_dir}")
+        if params is None:
+            raise click.UsageError(
+                f"--checkpoint-dir {checkpoint_dir} holds no committed "
+                "checkpoint (drop the flag to serve fresh-init weights)"
+            )
+        print(f"serving params restored from {checkpoint_dir}")
+    else:
         print("warning: serving FRESH-INIT weights (pass --checkpoint-dir "
               "with a trained run for real outputs)")
         params = net.init(
             jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32),
             train=False,
         )["params"]
+    if emitter is not None:
+        emitter.phase("serve_params_ready")
     # Serving reads every weight once per tick; compute-dtype params halve
     # the per-tick weight traffic vs the train-state fp32 tree (same trade
     # as bench.py --generate).
@@ -2033,6 +2050,25 @@ def _run_serve(
             for k in range(replicas)
         ]
     engine = engines[0]
+    if replicas > 1:
+        # Where each replica's weights actually sit (not where
+        # replica_mesh meant to put them).
+        print("serving replicas on devices: " + " ".join(
+            f"{k}:{sorted(d.id for d in leaf.devices())}"
+            for k, leaf in enumerate(
+                jax.tree_util.tree_leaves(e.params)[0] for e in engines
+            )
+        ))
+    # What the kernel dispatch actually lowered (every replica compiles
+    # the same programs): Mosaic custom calls per compiled program.
+    programs = engine.mosaic_custom_calls
+    print("serving programs: mosaic_custom_calls " + " ".join(
+        f"{name}={n}" for name, n in programs.items()
+    ))
+    if emitter is not None:
+        for name, n in programs.items():
+            emitter.gauge(f"mosaic_custom_calls[program={name}]", n)
+        emitter.phase("serve_engines_built")
     rng = np.random.default_rng(seed)
     p_hi = max(min(seq_len, max_len - max_new) // 2, 2)
     prompts = [
@@ -2349,12 +2385,25 @@ def _probe_compiled_cost(trainer, batches, mesh, sequence_parallel, emitter):
         sharded = shard_batch(
             first, mesh, sequence_sharded=sequence_parallel > 1
         )
+        # Where the batch actually landed: which devices hold a shard, and
+        # of what shape (the multi-chip check reads this, not the mesh).
+        leaf = next(iter(sharded.values()))
+        emitter.emit("record", {
+            "record": "batch_placement",
+            "devices": sorted(s.device.id for s in leaf.addressable_shards),
+            "shard_shape": list(leaf.addressable_shards[0].data.shape),
+            "global_shape": list(leaf.shape),
+        })
         try:
             compiled = trainer.train_step.lower(
                 trainer.state, sharded
             ).compile()
             report = step_cost_report(compiled)
             emitter.emit("compiled_cost", report)
+            emitter.gauge(
+                "mosaic_custom_calls[program=train_step]",
+                report.get("mosaic_custom_calls", 0),
+            )
             # Feed the live MFU gauge: the probe's compiled FLOPs + peak
             # over the trainer's rolling step-time window (obs/live.py).
             trainer.step_flops = report.get("flops")

@@ -23,8 +23,6 @@ import os
 
 import jax
 
-from ..compat import distributed_is_initialized
-
 logger = logging.getLogger(__name__)
 
 _initialized = False
@@ -61,7 +59,7 @@ def initialize(
     no env contract is a no-op outside a multi-host environment.
     """
     global _initialized
-    if _initialized or distributed_is_initialized():
+    if _initialized or jax.distributed.is_initialized():
         _initialized = True
         return
 
@@ -109,7 +107,7 @@ def initialize(
 
 
 def is_initialized() -> bool:
-    return _initialized or distributed_is_initialized()
+    return _initialized or jax.distributed.is_initialized()
 
 
 def process_count() -> int:

@@ -1,159 +1,48 @@
-"""Version shims over the installed JAX.
+"""The JAX surface the codebase is written against, in one place.
 
-The codebase is written against the current JAX surface — top-level
-``jax.shard_map`` with ``check_vma``, varying-axis typing via
-``jax.typeof``/``lax.pcast``, and the ``jax_num_cpu_devices`` config — but
-the baked toolchain may pin an older release (0.4.x exposes shard_map only
-under ``jax.experimental`` with ``check_rep``, has no vma typing, and sizes
-the simulated CPU backend through XLA_FLAGS).  Every divergence is routed
-through this module so call sites stay written against the new API and the
-shims disappear file-by-file when the pin moves.
+jax 0.9.0 is the one installation there is (``pyproject.toml`` pins it):
+top-level ``jax.shard_map`` with ``check_vma``, varying-axis typing via
+``jax.typeof``/``lax.pcast``, and the ``jax_num_cpu_devices`` config.  The
+names below are what the call sites import; there is no branch for another
+release.
 """
 
 from __future__ import annotations
 
 import contextlib
-import os
-from typing import Any, Sequence
 
 import jax
 from jax import lax
 
 __all__ = [
-    "HAS_VMA", "shard_map", "typeof", "pcast", "psum_completed",
-    "pbroadcast_varying", "set_cpu_device_count",
-    "distributed_is_initialized", "bound_axis_names",
+    "shard_map", "typeof", "pcast", "set_cpu_device_count", "ambient_mesh",
     "trace_annotation", "step_trace_annotation", "named_scope",
 ]
 
-# Whether avals carry varying-axes typing (``typeof(x).vma``).  Code that
-# READS vma to decide which collectives to emit must branch on this: on a
-# pre-vma JAX the attribute is simply absent, which reads as "varies over
-# nothing" and silently drops reductions.
-HAS_VMA = hasattr(lax, "pcast")
+shard_map = jax.shard_map
+typeof = jax.typeof
+pcast = lax.pcast
 
 
-if hasattr(jax, "shard_map"):
+def ambient_mesh():
+    """``(mesh, manual)``: the mesh of the enclosing ``with mesh:`` block
+    (None outside one) and whether the current trace is already inside a
+    ``shard_map`` body.  Valid at trace time, which the public
+    ``jax.sharding.get_mesh`` is not; the codebase enters meshes through
+    the ``with mesh:`` form, whose state only this private module holds."""
+    from jax._src import mesh as mesh_lib
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-        # Old shard_map's replication checker (``check_rep``) predates the
-        # vma typing this codebase marks its carries with (``pcast`` below
-        # is a no-op here), so bodies that are correctly typed for the new
-        # checker trip the old one on manual-collective outputs.  Disable
-        # it: it is a static lint, not a semantics change.
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
-
-
-if hasattr(jax, "typeof"):
-    typeof = jax.typeof
-else:
-
-    def typeof(x: Any):
-        """Aval of ``x``; pre-vma avals simply lack the ``vma`` attribute
-        (callers read it with ``getattr(..., "vma", ())``)."""
-        return jax.core.get_aval(x)
-
-
-if hasattr(lax, "pcast"):
-    pcast = lax.pcast
-else:
-
-    def pcast(x: Any, axes: Sequence[str], *, to: str = "varying") -> Any:
-        """No-op: pre-vma shard_map has no varying-axes typing to satisfy
-        (and the old ``check_rep`` checker is disabled above)."""
-        return x
-
-
-if HAS_VMA:
-    # vma-typed AD inserts the invariant↔varying conversions itself:
-    # psum's transpose on a varying→invariant reduction is the identity
-    # (pbroadcast), and the implicit pbroadcast where an invariant value
-    # enters varying compute transposes to a psum.  The plain collective
-    # (resp. nothing) is the right spelling.
-    def psum_completed(x: Any, axis_name):
-        return lax.psum(x, axis_name)
-
-    def pbroadcast_varying(x: Any, axis_name):
-        return x
-
-else:
-    # Pre-vma AD has one untyped rule — transpose(psum) = psum ("psum as
-    # psum + pbroadcast") — which is wrong on both ends of the Megatron
-    # pattern when the vjp runs inside the shard_map body (the manual
-    # pipeline engines): the completion psum re-sums an already-replicated
-    # cotangent (×axis_size on every tensor-sharded grad), and the entry
-    # edge never sums the per-shard partial cotangents at all.  The pair
-    # below writes the typed discipline out by hand; together they keep
-    # every cotangent replicated outside the sharded region.
-    import functools
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-    def psum_completed(x: Any, axis_name):
-        return lax.psum(x, axis_name)
-
-    def _psum_completed_fwd(x, axis_name):
-        return lax.psum(x, axis_name), None
-
-    def _psum_completed_bwd(axis_name, _, g):
-        # Varying partials → replicated sum; the incoming cotangent is
-        # replicated, so the transpose is the identity.
-        return (g,)
-
-    psum_completed.defvjp(_psum_completed_fwd, _psum_completed_bwd)
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-    def pbroadcast_varying(x: Any, axis_name):
-        return x
-
-    def _pbroadcast_varying_fwd(x, axis_name):
-        return x, None
-
-    def _pbroadcast_varying_bwd(axis_name, _, g):
-        # Replicated value entering per-shard compute: each shard's
-        # cotangent is a partial derivative through its own shard, so the
-        # transpose is the completing psum.
-        return (lax.psum(g, axis_name),)
-
-    pbroadcast_varying.defvjp(_pbroadcast_varying_fwd, _pbroadcast_varying_bwd)
-
-
-def bound_axis_names() -> tuple:
-    """Mesh axis names bound by an enclosing shard_map body trace, () when
-    not inside one (or when the interpreter offers no way to ask).
-
-    Old JAX validates ``with_sharding_constraint`` against the manual axes
-    only at LOWERING time, after any trace-time try/except has already
-    returned — so sharding hints that must degrade to no-ops inside
-    shard_map (models/moe._constrain_for_ep) need this trace-time probe
-    instead.  New JAX raises at trace time, where attempting the
-    constraint is itself the reliable probe."""
-    try:
-        from jax._src import core as _src_core
-
-        return tuple(_src_core.get_axis_env().axis_names())
-    except Exception:
-        return ()
+    mesh = mesh_lib.thread_resources.env.physical_mesh
+    manual = bool(jax.sharding.get_abstract_mesh().manual_axes)
+    return (None if mesh.empty else mesh), manual
 
 
 # ---- profiler / tracing shims (obs/) ----------------------------------
 #
 # The telemetry subsystem (obs/trace.py) threads semantic phase names into
-# xprof timelines.  The profiler surface has been stable since well before
-# the 0.4.37 pin, but it is optional in some builds (stripped profiler) —
-# every entry point degrades to a no-op context rather than an ImportError,
-# so annotation call sites never need their own guards.
+# xprof timelines.  The profiler is optional in some builds (stripped
+# profiler) — every entry point degrades to a no-op context rather than an
+# ImportError, so annotation call sites never need their own guards.
 
 
 def trace_annotation(name: str, **kwargs):
@@ -184,32 +73,7 @@ def named_scope(name: str):
         return contextlib.nullcontext()
 
 
-def distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()``; older releases never exposed
-    the query, but the same fact lives on the module's global state (the
-    client only exists after a successful ``initialize``)."""
-    if hasattr(jax.distributed, "is_initialized"):
-        return bool(jax.distributed.is_initialized())
-    state = getattr(jax.distributed, "global_state", None)
-    return getattr(state, "client", None) is not None
-
-
 def set_cpu_device_count(n: int) -> None:
-    """Simulate ``n`` CPU devices; must run before the backend initializes.
-
-    New JAX has a config option; old JAX only honors the XLA flag, which is
-    read once at backend init — callers that may race backend creation
-    should verify ``len(jax.devices())`` afterwards.
-    """
-    try:
-        jax.config.update("jax_num_cpu_devices", int(n))
-    except AttributeError:
-        # Replace (don't just append) any inherited device-count flag: a
-        # spawned worker gets the parent's XLA_FLAGS in its env and must
-        # still be able to size its own backend differently.
-        flags = [
-            f for f in os.environ.get("XLA_FLAGS", "").split()
-            if "xla_force_host_platform_device_count" not in f
-        ]
-        flags.append(f"--xla_force_host_platform_device_count={int(n)}")
-        os.environ["XLA_FLAGS"] = " ".join(flags)
+    """Simulate ``n`` CPU devices; must run before the backend initializes
+    (JAX raises ``RuntimeError`` afterwards)."""
+    jax.config.update("jax_num_cpu_devices", int(n))

@@ -97,7 +97,8 @@ class ShapeImages:
     scale, rotation, edge softness, pixel noise, and up to two distractor
     dots.  Color carries zero class signal by construction, so a classifier
     must learn spatial features; a pixel-space linear probe plateaus far
-    below a convnet (measured in CONVERGENCE.json), which makes train→val
+    below a convnet (measured in CONVERGENCE (deleted: not measured on the
+    current machine)), which makes train→val
     generalization here a meaningful end-to-end test of the training stack.
 
     Samples are deterministic functions of ``(seed, split, index)`` via

@@ -11,8 +11,8 @@ on-chip: ``jax.random.randint`` start offsets, a vmapped
 jitted step.  Steady-state input cost is microseconds and zero host bytes.
 
 ``make_train_fn`` goes one step further and runs N optimizer steps per jit
-call (``lax.scan``), so remote/tunneled runtimes pay one host round trip per
-N steps — the same superstep trick ``DeviceCachedImages.make_epoch_fn``
+call (``lax.scan``), so the host is touched once per N steps — the same
+superstep trick ``DeviceCachedImages.make_epoch_fn``
 uses, sized by steps instead of epochs because LM training samples windows
 IID (the nanoGPT convention) rather than visiting examples exactly once.
 """

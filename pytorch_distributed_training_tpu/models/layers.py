@@ -188,7 +188,8 @@ class SelfAttention(nn.Module):
     # score/combine einsums run canonically with zero internal relayouts,
     # and the output projection consumes (B, H, L, Dh) directly by
     # contracting (h, d) against the reshaped proj kernel — the
-    # model-layer-contract experiment VIT_ROOFLINE.json names (~10 GB/step
+    # model-layer-contract experiment VIT_ROOFLINE (deleted: not measured on
+    # the current machine) names (~10 GB/step
     # of dot-canonicalization relayout traffic at ViT batch 128).  XLA
     # non-causal path only (ViT); param tree is identical to "auto".
     attn_layout: str = "auto"
@@ -450,7 +451,8 @@ class SelfAttention(nn.Module):
             # all heads of a batch row in ONE Pallas program
             # (ops.pallas_attention.decode_attention).  The small-batch
             # decode tick is kernel-launch-count-bound, not
-            # bandwidth-bound (GEN_ROOFLINE.json), so collapsing the
+            # bandwidth-bound (GEN_ROOFLINE (deleted: not measured on the
+            # current machine)), so collapsing the
             # ~6-8 XLA fusions this math otherwise lowers to is what
             # moves end-to-end throughput: measured 10.2k → 12.4k tok/s
             # at batch 32 (+22%), 11.8k → 14.5k at 64.  Dispatch rule

@@ -41,19 +41,8 @@ def _constrain_for_ep(x: jax.Array, spec: P) -> jax.Array:
     attempt it (``get_abstract_mesh`` does not reflect the legacy context
     manager).
     """
-    from ..compat import bound_axis_names
-
     # Inside a shard_map body (e.g. the MoE block as a pipeline stage) the
-    # mesh axes are manual and the constraint must not name them.  Old JAX
-    # only validates this at lowering time — after the except below has
-    # already returned — so probe the trace's bound axes up front.
-    manual = set(bound_axis_names())
-    if manual and any(
-        a in manual
-        for entry in spec if entry is not None
-        for a in (entry if isinstance(entry, tuple) else (entry,))
-    ):
-        return x
+    # mesh axes are manual and naming them raises at trace time too.
     try:
         return lax.with_sharding_constraint(x, spec)
     except (RuntimeError, ValueError, KeyError):

@@ -77,7 +77,8 @@ class VisionTransformer(nn.Module):
     remat: bool = False
     # Attention activation-layout contract (models/layers.SelfAttention
     # .attn_layout) — the (B,H,L,Dh)-between-projections experiment
-    # VIT_ROOFLINE.json's analysis named.  "bhld2" (head-major q/k/v
+    # VIT_ROOFLINE (deleted: not measured on the current machine)'s analysis
+    # named.  "bhld2" (head-major q/k/v
     # straight from the projection GEMMs, canonical bh-leading einsums,
     # head-consuming output projection) measured BEST at the batch-44
     # residency optimum: 1070.5 vs 1014-1039 img/s auto (MFU 0.556 vs

@@ -24,12 +24,14 @@ from __future__ import annotations
 import re
 from typing import Any
 
-# bf16 peaks for MFU accounting, keyed by device_kind substrings (what
+# The one table of device peaks, keyed by device_kind substrings (what
 # jax.devices()[0].device_kind actually reports — v5e shows up as
-# "TPU v5 lite").  bench.py uses the same 197e12 v5e reference.
-PEAK_FLOPS = (
-    (("v5 lite", "v5e", "v5litepod"), 197e12),
-)
+# "TPU v5 lite").  Source: Google Cloud documentation, "TPU v5e" — 197
+# TFLOP/s bf16 and 819 GB/s of HBM per chip.  A device that is not here has
+# no peak: ``peak_flops_for`` says None, ``require_peaks`` raises.
+_V5E = ("v5 lite", "v5e", "v5litepod")
+PEAK_FLOPS = ((_V5E, 197e12),)
+PEAK_HBM_BYTES_PER_S = ((_V5E, 819e9),)
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2,
@@ -121,9 +123,16 @@ def collective_census(hlo_text: str) -> dict[str, dict[str, Any]]:
     return census
 
 
-def peak_flops_for(device_kind: str | None = None) -> float | None:
-    """Peak FLOP/s for MFU accounting, None when unknown (CPU — callers
-    pass an explicit override or report raw FLOP/s instead)."""
+def mosaic_custom_calls(hlo_text: str) -> int:
+    """Pallas TPU (Mosaic) kernels in a compiled program's text.  Kernel
+    choice is a predicate on the backend at trace time; this is what was
+    actually lowered — 0 on a TPU program that should carry a fused kernel
+    means the dispatch routed around it (and always 0 off-TPU, where the
+    kernels run interpreted as plain HLO)."""
+    return hlo_text.count('custom_call_target="tpu_custom_call"')
+
+
+def _lookup(table, device_kind: str | None) -> float | None:
     if not device_kind:
         try:
             import jax
@@ -132,10 +141,34 @@ def peak_flops_for(device_kind: str | None = None) -> float | None:
         except Exception:
             return None
     kind = device_kind.lower()
-    for patterns, peak in PEAK_FLOPS:
+    for patterns, peak in table:
         if any(p in kind for p in patterns):
             return peak
     return None
+
+
+def peak_flops_for(device_kind: str | None = None) -> float | None:
+    """Peak FLOP/s for MFU accounting, None when unknown (CPU — callers
+    pass an explicit override or report raw FLOP/s instead)."""
+    return _lookup(PEAK_FLOPS, device_kind)
+
+
+def require_peaks(device_kind: str | None = None) -> tuple[float, float]:
+    """(bf16 FLOP/s, HBM bytes/s) of the device (default: the first one
+    JAX reports).  Raises on a kind the tables do not hold: an MFU or a
+    roofline against a guessed peak is a wrong number, not a rough one."""
+    if not device_kind:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    flops = _lookup(PEAK_FLOPS, device_kind)
+    hbm = _lookup(PEAK_HBM_BYTES_PER_S, device_kind)
+    if flops is None or hbm is None:
+        raise ValueError(
+            f"no peak FLOP/s / HBM bandwidth on record for device kind "
+            f"{device_kind!r} (obs/cost.py PEAK_FLOPS, PEAK_HBM_BYTES_PER_S)"
+        )
+    return flops, hbm
 
 
 def mfu(flops_per_step: float, step_time_s: float,
@@ -158,7 +191,9 @@ def step_cost_report(
         report["memory"] = mem
     if with_census:
         try:
-            report["collectives"] = collective_census(compiled.as_text())
+            text = compiled.as_text()
+            report["collectives"] = collective_census(text)
+            report["mosaic_custom_calls"] = mosaic_custom_calls(text)
         except Exception:
             pass
     report["peak_flops"] = (

@@ -90,6 +90,13 @@ METRICS: dict[str, dict] = {
         "type": GAUGE, "labeled": False,
         "help": "goodput ledger: unattributed (setup/teardown/eval) seconds",
     },
+    # ---- what was lowered (obs/cost.py) --------------------------------
+    "mosaic_custom_calls": {
+        "type": GAUGE, "labeled": True,
+        "help": "Pallas TPU (Mosaic) custom calls in a compiled program's "
+                "text, per program (train_step, prefill, decode, verify); "
+                "also a field of the compiled_cost event",
+    },
     # ---- SLO / alerting plane (obs/slo.py) ------------------------------
     "slo_alert_transitions": {
         "type": COUNTER, "labeled": False,

@@ -14,6 +14,9 @@ fused-softmax XLA implementation that the compiler maps onto MXU matmuls.
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -82,7 +85,8 @@ def _xla_attention(
         # materialized copy next to the (B,H,L,D) q/k/v transposes.
         # Measured on ViT-B/16 (the L=197 consumer of this path):
         # compiled bytes 100.3 -> 93.6 GB/step and 831 -> 909 img/s at
-        # batch 128; +1.8% at the batch-44 headline (VIT_ROOFLINE.json).
+        # batch 128; +1.8% at the batch-44 headline (VIT_ROOFLINE (deleted: not
+        # measured on the current machine)).
         # Causal keeps the (b,h,q,k) form — its mask broadcasts over
         # (None, None, q, k) and GPT-2's flash threshold routes L>=1024
         # away from this path anyway.
@@ -124,8 +128,6 @@ def _xla_attention_remat(q, k, v, *, causal=False, scale=None):
     this removes the step's largest saved tensors for a rounding error of
     extra FLOPs (attention is ~1.4% of ViT-B's total) — flash-attention's
     memory behavior without the Pallas kernel's tile-padding waste."""
-    import functools
-
     fn = jax.checkpoint(
         functools.partial(_xla_attention, causal=causal, scale=scale)
     )
@@ -176,10 +178,52 @@ def flash_attention(
     )
     if not backend_ok:
         return _xla_attention(q, k, v, causal=causal, scale=scale)
-    return pallas_attention.flash_attention(
-        q, k, v, causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+    kernel = functools.partial(
+        pallas_attention.flash_attention, causal=causal, scale=scale,
+        block_q=block_q, block_k=block_k, interpret=interpret,
     )
+    partition = _kernel_partition(q.shape[0], q.shape[2])
+    if partition is None:
+        return kernel(q, k, v)
+    mesh, spec = partition
+    from ..compat import shard_map
+
+    return shard_map(
+        kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
+
+
+def _kernel_partition(batch: int, heads: int):
+    """How a ``pallas_call`` over (B, L, H, D) operands must be split when
+    it is traced under a multi-device mesh: ``(mesh, spec)``, or None when
+    it can be called as it is (no mesh, one device, or already inside a
+    ``shard_map`` body, where every axis is manual).
+
+    A compiled Mosaic kernel is opaque to the partitioner ("Mosaic kernels
+    cannot be automatically partitioned"), so under GSPMD the kernel runs
+    per shard inside a ``shard_map``: attention is independent per batch
+    row and per head, so the batch splits over the batch axes and the
+    heads over ``tensor``, each only where it divides — what does not
+    divide stays whole on every device (gathered: correct, never wrong).
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from ..comm.mesh import AXIS_TENSOR, BATCH_AXES
+    from ..compat import ambient_mesh
+
+    mesh, manual = ambient_mesh()
+    if mesh is None or mesh.size == 1 or manual:
+        return None
+    # .get: an ambient mesh need not be one of ours with all six axes.
+    batch_axes = tuple(a for a in BATCH_AXES if mesh.shape.get(a, 1) > 1)
+    if batch % math.prod(mesh.shape[a] for a in batch_axes):
+        batch_axes = ()
+    tensor_ways = mesh.shape.get(AXIS_TENSOR, 1)
+    tensor = AXIS_TENSOR if (
+        tensor_ways > 1 and heads % tensor_ways == 0
+    ) else None
+    return mesh, P(batch_axes or None, None, tensor, None)
 
 
 def flash_preferred(
@@ -292,7 +336,8 @@ def dot_product_attention(
         # kernel pays pad-to-tile waste XLA does not.  Above ~2k the XLA
         # path's (B, H, L, L) materialization also stops fitting, so
         # flash is the only option on memory.  Only full-model A/Bs are
-        # trusted for this threshold; ATTN_MICRO.json's slope protocol
+        # trusted for this threshold; ATTN_MICRO (deleted: not measured on the
+        # current machine)'s slope protocol
         # catches kernel-level regressions cheaply.
         use_flash = flash_preferred(q.shape[1], k.shape[1], q.shape[3])
     if use_flash:

@@ -258,7 +258,8 @@ class FusedBNAddRelu(_FusedBNBase):
 # flax's nn.LayerNorm under reverse-mode AD leaves XLA to choose residuals;
 # on the bf16 GPT-2/ViT steps the compiled graphs materialize a (B, L, D)
 # f32 normalized intermediate per LN (12-25 MB each, observed as relayout
-# copies in GPT2_ROOFLINE/VIT_ROOFLINE analyses).  This custom-vjp LN saves
+# copies in GPT2_ROOFLINE/VIT_ROOFLINE (deleted: not measured on the current
+# machine) analyses).  This custom-vjp LN saves
 # only the low-precision INPUT plus the (B, L, 1) stat columns and
 # recomputes xhat in the backward — the standard LN gradient:
 #   dxhat = dy * scale

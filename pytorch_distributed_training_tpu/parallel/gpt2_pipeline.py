@@ -40,7 +40,6 @@ from ..comm.compress import PP_COMPRESS_MODES
 from ..comm.mesh import (
     AXIS_FSDP, AXIS_PIPELINE, AXIS_SEQUENCE, AXIS_TENSOR,
 )
-from ..compat import pbroadcast_varying, psum_completed
 from ..models.gpt2 import Block, GPT2, GPT2Config
 from .pipeline import (
     fsdp_gather_leaves, pipeline_forward, pipeline_train_1f1b,
@@ -316,7 +315,7 @@ def _manual_dropout(y, key, rate):
     return jnp.where(keep, y / (1.0 - rate), jnp.zeros_like(y))
 
 
-def _tp_block(p, x, key, *, cfg, dtype, tp, axis_name, sp=1, manual_ad=False):
+def _tp_block(p, x, key, *, cfg, dtype, tp, axis_name, sp=1):
     """One transformer block with tensor- and/or sequence-parallel shards.
 
     Same math as ``models.gpt2.Block`` on the permuted-qkv layout: the
@@ -355,15 +354,6 @@ def _tp_block(p, x, key, *, cfg, dtype, tp, axis_name, sp=1, manual_ad=False):
         )
 
     h = _manual_layer_norm(x, p["ln1"], dtype)
-    if manual_ad:
-        # Replicated activations enter tensor-sharded compute: the marker
-        # is an identity whose transpose completes the per-shard cotangent
-        # partials.  Needed only where jax.vjp runs INSIDE the shard_map
-        # body (the manual engines) on pre-vma JAX — autodiff THROUGH
-        # shard_map (the GPipe path) has its own consistent handling of
-        # the plain psum, and vma-typed AD needs no markers at all
-        # (compat.pbroadcast_varying/psum_completed).
-        h = pbroadcast_varying(h, axis_name)
     qkv = (
         h @ p["attn"]["qkv"]["kernel"].astype(dtype)
         + p["attn"]["qkv"]["bias"].astype(dtype)
@@ -379,8 +369,7 @@ def _tp_block(p, x, key, *, cfg, dtype, tp, axis_name, sp=1, manual_ad=False):
         att = dot_product_attention(q, k, v, causal=True)
     att = att.reshape(b, l, local_heads * dh)
     partial = att @ p["attn"]["proj"]["kernel"].astype(dtype)
-    _complete = psum_completed if manual_ad else lax.psum
-    y = _complete(partial, axis_name) + p["attn"]["proj"]["bias"].astype(dtype)
+    y = lax.psum(partial, axis_name) + p["attn"]["proj"]["bias"].astype(dtype)
     y = _manual_dropout(
         y, None if key is None else jax.random.fold_in(key, 0),
         cfg.dropout_rate,
@@ -388,15 +377,13 @@ def _tp_block(p, x, key, *, cfg, dtype, tp, axis_name, sp=1, manual_ad=False):
     x = x + y
 
     h = _manual_layer_norm(x, p["ln2"], dtype)
-    if manual_ad:
-        h = pbroadcast_varying(h, axis_name)
     h = (
         h @ p["mlp_up"]["kernel"].astype(dtype)
         + p["mlp_up"]["bias"].astype(dtype)
     )
     h = jax.nn.gelu(h)
     partial = h @ p["mlp_down"]["kernel"].astype(dtype)
-    y = _complete(partial, axis_name) + p["mlp_down"]["bias"].astype(dtype)
+    y = lax.psum(partial, axis_name) + p["mlp_down"]["bias"].astype(dtype)
     y = _manual_dropout(
         y, None if key is None else jax.random.fold_in(key, 1),
         cfg.dropout_rate,
@@ -679,7 +666,6 @@ class PipelinedGPT2:
                 return xmb
         else:
             cfg, dtype, tp, sp = self.cfg, self.dtype, self.tp, self.sp
-            manual_ad = self.schedule != "gpipe"
 
             def inner(stage_params, xmb, key=None):
                 for j in range(per):
@@ -687,7 +673,7 @@ class PipelinedGPT2:
                         stage_params[f"layer_{j}"], xmb,
                         None if key is None else jax.random.fold_in(key, j),
                         cfg=cfg, dtype=dtype, tp=tp, sp=sp,
-                        axis_name=AXIS_TENSOR, manual_ad=manual_ad,
+                        axis_name=AXIS_TENSOR,
                     )
                 return xmb
 

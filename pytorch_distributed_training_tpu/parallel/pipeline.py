@@ -58,7 +58,7 @@ from ..comm.compress import (
     PP_COMPRESS_MODES, boundary_has_residual, boundary_permute,
 )
 from ..comm.mesh import AXIS_PIPELINE, AXIS_SEQUENCE, BATCH_AXES
-from ..compat import HAS_VMA, pcast, shard_map, typeof
+from ..compat import pcast, shard_map, typeof
 from ..obs.trace import scope
 
 
@@ -81,11 +81,11 @@ def _vma_markers(reference: jax.Array, axis_name: str):
     fsdp — a params union would mis-type PP x TP carries as
     tensor-varying and break their replicated out_specs.
     """
-    ref_vma = tuple(getattr(typeof(reference), "vma", ()) or ())
+    ref_vma = tuple(typeof(reference).vma)
     want = (axis_name,) + tuple(a for a in ref_vma if a != axis_name)
 
     def mark_varying(v):
-        have = set(getattr(typeof(v), "vma", ()) or ())
+        have = typeof(v).vma
         missing = tuple(a for a in want if a not in have)
         return pcast(v, missing, to="varying") if missing else v
 
@@ -305,7 +305,6 @@ def _finalize_fsdp_grads(
 
 def _combine_accumulators(
     gacc, facc, lacc, loss_acc, *, inputs, axis_name, gather_specs, fsdp_size,
-    batch_axes=(),
 ):
     """Post-scan cross-batch-shard combine shared by both manual engines.
 
@@ -315,17 +314,9 @@ def _combine_accumulators(
     serve (CE), mean-of-shard-means == the global mean, and grads scale
     identically.  With ``gather_specs`` the stage grads instead take the
     psum-scatter path (``_finalize_fsdp_grads``)."""
-    if HAS_VMA:
-        # The microbatches' own varying-axes type says exactly which mesh
-        # axes they were sharded over.
-        batch_used = tuple(
-            a for a in (getattr(typeof(inputs), "vma", ()) or ())
-            if a != axis_name
-        )
-    else:
-        # Pre-vma JAX: no type to read — the launcher passes the axes it
-        # actually put in the microbatch in_specs (``batch_axes``).
-        batch_used = tuple(a for a in batch_axes if a != axis_name)
+    # The microbatches' own varying-axes type says exactly which mesh
+    # axes they were sharded over.
+    batch_used = tuple(a for a in typeof(inputs).vma if a != axis_name)
     if gather_specs is not None:
         gacc = _finalize_fsdp_grads(gacc, gather_specs, fsdp_size, batch_used)
         if batch_used:
@@ -354,7 +345,6 @@ def _1f1b_local(
     num_stages: int,
     gather_specs: Any = None,
     fsdp_size: int = 1,
-    batch_axes: tuple = (),
     boundary_compress: str = "none",
     boundary_stripe: int = 1,
 ):
@@ -573,7 +563,6 @@ def _1f1b_local(
     gacc, facc, lacc, loss_acc = _combine_accumulators(
         gacc, facc, lacc, loss_acc, inputs=inputs, axis_name=axis_name,
         gather_specs=gather_specs, fsdp_size=fsdp_size,
-        batch_axes=batch_axes,
     )
     # Stage grads stay per-stage (leading axis restored); everything else
     # is nonzero on exactly one stage — psum replicates it.
@@ -690,7 +679,6 @@ def _interleaved_local(
     sched: Any,
     gather_specs: Any = None,
     fsdp_size: int = 1,
-    batch_axes: tuple = (),
     boundary_compress: str = "none",
     boundary_stripe: int = 1,
 ):
@@ -920,7 +908,6 @@ def _interleaved_local(
     gacc, facc, lacc, loss_acc = _combine_accumulators(
         gacc, facc, lacc, loss_acc, inputs=inputs, axis_name=axis_name,
         gather_specs=gather_specs, fsdp_size=fsdp_size,
-        batch_axes=batch_axes,
     )
     stacked = jax.tree_util.tree_map(lambda g: g[None], gacc)
     loss = lax.psum(loss_acc, axis_name)
@@ -1014,16 +1001,6 @@ def _launch_schedule_local(
             lambda _: P(axis_name), stacked_params
         )
     micro_spec = _micro_spec_for(mesh, inputs, sequence_sharded, param_specs)
-    # The axes the microbatches are actually sharded over, for the post-scan
-    # combine on JAX versions whose avals carry no vma typing to read
-    # (_combine_accumulators; compat.HAS_VMA).
-    used_axes = tuple(
-        a
-        for entry in micro_spec if entry is not None
-        for a in (entry if isinstance(entry, tuple) else (entry,))
-        if a is not None and mesh.shape.get(a, 1) > 1
-    )
-    local = functools.partial(local, batch_axes=used_axes)
     replicated = P()
     if rng is None:
         fn = shard_map(

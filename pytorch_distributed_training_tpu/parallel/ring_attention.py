@@ -111,7 +111,7 @@ def ring_attention(
     l0 = jnp.zeros((b, h, l_loc), jnp.float32)
     # Constant inits are device-invariant; the scan carry becomes varying the
     # moment it mixes with q/k/v, so pre-mark them (shard_map vma typing).
-    vma = getattr(typeof(q), "vma", None)
+    vma = typeof(q).vma
     if vma:
         o0, m0, l0 = (pcast(x, tuple(vma), to="varying") for x in (o0, m0, l0))
     # checkpoint: rematerialize each hop's (B,H,Lq,Lk) probability block in

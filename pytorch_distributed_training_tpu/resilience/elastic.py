@@ -444,32 +444,7 @@ def oracle_batch_digests(n_steps: int, *, seed: int = 0, rows: int = 16,
     ]
 
 
-def run_elastic_episode(**kwargs) -> dict[str, Any]:
-    """One deterministic elastic episode — see :func:`_episode`.
-
-    Runs with the persistent compilation cache disabled for the
-    episode's lifetime: re-lowering the full-world step after a
-    grow-back is a byte-identical cache hit, and EXECUTING the
-    deserialized executable on the simulated CPU mesh after the
-    survivor-mesh interlude corrupts the jaxlib heap (observed as a
-    segfault/double-free a step or two later).  The episode's compile
-    cost is virtual-clocked, so a cold compile changes nothing the
-    ledger sees.
-    """
-    import jax
-
-    try:
-        cache_was = jax.config.jax_enable_compilation_cache
-    except AttributeError:  # older jax: no toggle, no persistent cache
-        return _episode(**kwargs)
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        return _episode(**kwargs)
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-
-
-def _episode(
+def run_elastic_episode(
     *,
     faults: list[Fault] | str,
     n_steps: int = 10,

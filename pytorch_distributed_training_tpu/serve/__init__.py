@@ -3,7 +3,8 @@ training stack).
 
 The static path (``models/generate.py``) is a fixed-batch, run-to-completion
 scan: every request shares one ``max_new_tokens`` budget and finished rows
-burn compute until the longest row ends.  GEN_ROOFLINE.json shows decode
+burn compute until the longest row ends.  GEN_ROOFLINE (deleted: not measured
+on the current machine) shows decode
 throughput scales with batch toward the byte bound — so the serving win is
 keeping decode slots FULL under a live request stream.  This package is the
 Orca/vLLM-class iteration-level answer, built on the same trained-checkpoint

@@ -200,6 +200,20 @@ class DisaggServingEngine:
         return self.decode_engine.drafter
 
     @property
+    def params(self):
+        """The weights both role engines were built with and placed."""
+        return self.decode_engine.params
+
+    @property
+    def mosaic_custom_calls(self) -> dict[str, int]:
+        """Per-program kernel counts of both roles (each role compiles
+        only its own programs, so the names do not collide)."""
+        return {
+            **self.prefill_engine.mosaic_custom_calls,
+            **self.decode_engine.mosaic_custom_calls,
+        }
+
+    @property
     def stream_cb(self):
         return self.prefill_engine.stream_cb
 

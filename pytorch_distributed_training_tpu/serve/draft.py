@@ -1,6 +1,7 @@
 """Model-free draft-token proposal for speculative decoding.
 
-GEN_ROOFLINE.json pins decode at a fraction of the HBM bound: every tick
+GEN_ROOFLINE (deleted: not measured on the current machine) pins decode at a
+fraction of the HBM bound: every tick
 reads all params and the live KV cache to emit ONE token per slot.  The
 only way past that floor is to amortize the read over k tokens — verify k
 drafted tokens in one forward pass (serve/engine.py's third compiled

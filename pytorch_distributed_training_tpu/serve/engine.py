@@ -17,7 +17,8 @@ full slot array so shapes never change:
   forward pass with greedy chain matching (or rejection-style acceptance
   under sampling), so accepted tokens cost one param/KV-cache read per
   tick instead of one each — the only way past the one-token-per-tick
-  floor GEN_ROOFLINE.json pins decode at.  Greedy speculative output is
+  floor GEN_ROOFLINE (deleted: not measured on the current machine) pins decode
+  at.  Greedy speculative output is
   TOKEN-EXACT vs the plain decode path; a rejected draft costs wasted
   compute, never a wrong token.  Rejected K/V writes are rolled back by
   length accounting (contiguous pool: stale bytes are unreachable by the
@@ -50,6 +51,7 @@ import numpy as np
 from ..analysis.signature import PROGRAM_REGISTRY, abstract_signature
 from ..compat import named_scope
 from ..models.generate import eos_cut_length, filter_logits, sample_logits
+from ..obs.cost import mosaic_custom_calls
 from ..obs.trace import phase_span
 from .draft import NgramIndex, PromptLookupDrafter
 from .kv_pool import KVCachePool, PagedKVCachePool, SlotExport
@@ -566,6 +568,20 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
     # slot admission / retirement
     # ------------------------------------------------------------------ #
+
+    @property
+    def mosaic_custom_calls(self) -> dict[str, int]:
+        """Pallas TPU kernels per compiled program (obs/cost.py): what the
+        kernel dispatch actually lowered.  Read from the program text on
+        demand — one caller (the CLI's start-up line) wants it."""
+        programs = {
+            "prefill": self._prefill_fn, "decode": self._decode_fn,
+            "verify": self._verify_fn,
+        }
+        return {
+            name: mosaic_custom_calls(fn.as_text())
+            for name, fn in programs.items() if fn is not None
+        }
 
     @property
     def effective_slots(self) -> int:

@@ -7,7 +7,8 @@ The scheduler is the host-side control loop around ``ServingEngine``:
   instead of silently building unbounded latency), and every ``tick``
   drains the queue head into freed slots BEFORE stepping the engine — a
   request admitted the same tick a slot frees is what keeps decode slots
-  full (the whole point: GEN_ROOFLINE.json shows throughput scales with
+  full (the whole point: GEN_ROOFLINE (deleted: not measured on the current
+  machine) shows throughput scales with
   live batch).
 - **One engine tick per scheduler tick**: a prefill chunk for loading
   slots interleaved with a decode token for generating slots.

@@ -214,6 +214,22 @@ class Trainer:
         local_batch = 0
         metrics: dict | None = None
         last_logged_step = -1
+        # (global step, device scalar) of every step since the last host
+        # sync: fetched in one go WHERE the host syncs anyway (log points,
+        # epoch end) and written as a ``step_losses`` record, so the log
+        # holds every step's loss without a per-step device wait.
+        pending_losses: list = []
+
+        def flush_losses():
+            if self.emitter is not None and self.emitter.enabled \
+                    and pending_losses:
+                steps, vals = zip(*pending_losses)
+                self.emitter.emit("record", {
+                    "record": "step_losses", "first_step": steps[0],
+                    "losses": [float(v) for v in jax.device_get(list(vals))],
+                })
+            pending_losses.clear()
+
         # Liveness for the elastic supervisor (utils/supervisor.py): beat at
         # epoch start (covers compile + first-batch load) and at every log
         # point, so a hung collective is detectable by wall clock without
@@ -263,6 +279,9 @@ class Trainer:
                         self.state, metrics = self.train_step(self.state, batch)
                     local_batch = int(next(iter(batch.values())).shape[0])
                     examples += local_batch
+                    pending_losses.append(
+                        (self._global_step, metrics["loss"])
+                    )
                     timer.tick()  # dispatch-rate rolling window (no device sync)
                     now = time.perf_counter()
                     if self.ledger is not None:
@@ -294,6 +313,7 @@ class Trainer:
                         loss = float(metrics["loss"])
                         if self.spans is not None:
                             self.spans.end_span(hspan)
+                        flush_losses()
                         step_fields["loss"] = loss
                         step_fields["steps_per_sec"] = timer.steps_per_sec
                         skipped_delta = None
@@ -442,8 +462,7 @@ class Trainer:
                 self.spans.flush()
         # Fetch the final step's loss to close the timing window: the donated
         # state chains every step, so this read completes only after all
-        # device work has.  (block_until_ready without a value fetch does not
-        # reliably wait on all transports.)
+        # device work has.
         if examples:
             final_loss = float(metrics["loss"])
             # Dedupe: when the epoch length lands exactly on a log point the
@@ -451,6 +470,7 @@ class Trainer:
             # again would double-count it in the record.
             if last_logged_step != step_idx:
                 losses.append(final_loss)
+            flush_losses()
         if heartbeat is not None:
             heartbeat.beat()  # cover the epoch-end checkpoint/eval window
         elapsed = time.perf_counter() - t0

@@ -156,6 +156,11 @@ def supervise(
         # on open, so only the final attempt's ledger survives and it
         # must carry the whole run's backoff.
         env[BACKOFF_ENV] = repr(cum_backoff_s)
+        # One process per chip: the child takes the accelerator, so this
+        # parent must never initialize a JAX backend (it would hold the
+        # chip and every child would fail or hang at start-up).  This
+        # module and its callers on the --elastic path import nothing
+        # that touches devices — keep it so.
         proc = subprocess.Popen(attempt_argv, env=env)
         code = None
         while code is None:

@@ -46,6 +46,9 @@ def launch_workers(
                 os.environ, MASTER_ADDR="localhost", MASTER_PORT=str(port),
                 WORLD_SIZE=str(n_procs), RANK=str(rank),
                 DEVICES_PER_PROC=str(devices_per_proc),
+                # Concurrent workers cannot share a chip: held to the CPU
+                # from their first import.
+                JAX_PLATFORMS="cpu",
             )
             procs.append(subprocess.Popen(
                 [sys.executable, worker], env=env,

@@ -1,5 +1,6 @@
 """Convergence-evidence stack: the ShapeImages learnable dataset, the
-token-cache epoch iterator, and the CLI paths the CONVERGENCE.json runs use
+token-cache epoch iterator, and the CLI paths the CONVERGENCE (deleted: not
+measured on the current machine) runs use
 (token-file + sibling val.bin, --device-cache for LM).
 
 The reference's entire purpose is the training epoch

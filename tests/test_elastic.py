@@ -15,10 +15,6 @@ refusals, the ``/slo`` ``elastic`` block, and run-twice determinism.
 """
 
 import json
-import os
-import subprocess
-import sys
-import textwrap
 import urllib.request
 
 import numpy as np
@@ -44,56 +40,26 @@ NS = 1_000_000_000
 EPISODE_FAULTS = "slice_lost@4:1,slice_return@9"
 EPISODE_STEPS = 12
 
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 def _run_episode(faults, n_steps, metrics_dir=None):
-    """Run one scripted episode in a PRISTINE subprocess and return its
-    report (JSON round-tripped — every pin below is ints/strs/bools).
-
-    Not an in-process call: executing the episode's survivor-mesh dance
-    in a process that has already run hundreds of other compiled
-    programs trips a jaxlib heap corruption (glibc abort inside the
-    step dispatch) that no standalone repro reproduces — the same bug
-    family that forces run_elastic_episode to disable the persistent
-    compilation cache for its own lifetime.  A fresh process is exactly
-    how the CLI (`--elastic-resize`) and bench drive the episode, the
-    clock is virtual, and the report is the whole contract, so the
-    isolation loses no coverage — and run-twice determinism across
-    processes is the stronger form of the pin."""
-    driver = textwrap.dedent(f"""
-        import json, sys
-        sys.path.insert(0, {_REPO!r})
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-        from pytorch_distributed_training_tpu.compat import (
-            set_cpu_device_count,
-        )
-        set_cpu_device_count(8)
-        from pytorch_distributed_training_tpu.obs import MetricsEmitter
-        from pytorch_distributed_training_tpu.resilience import (
-            run_elastic_episode,
-        )
-        emitter = None
-        metrics_dir = {metrics_dir!r}
-        if metrics_dir:
-            emitter = MetricsEmitter(metrics_dir, rank=0, world=1)
-        report = run_elastic_episode(
-            faults={faults!r}, n_steps={n_steps}, emitter=emitter,
-        )
-        if emitter is not None:
-            emitter.summary()
-            emitter.close()
-        print("REPORT " + json.dumps(report))
-    """)
-    proc = subprocess.run(
-        [sys.executable, "-c", driver], capture_output=True, text=True,
-        timeout=600, env={**os.environ, "PYTHONPATH": ""},
+    """Run one scripted episode and return its report, JSON round-tripped
+    (every pin below is ints/strs/bools) — the form the run-twice
+    determinism pin compares."""
+    from pytorch_distributed_training_tpu.obs import MetricsEmitter
+    from pytorch_distributed_training_tpu.resilience import (
+        run_elastic_episode,
     )
-    assert proc.returncode == 0, proc.stderr[-4000:]
-    line = [l for l in proc.stdout.splitlines() if l.startswith("REPORT ")]
-    assert line, proc.stdout[-4000:]
-    return json.loads(line[-1][len("REPORT "):])
+
+    emitter = (
+        MetricsEmitter(metrics_dir, rank=0, world=1) if metrics_dir else None
+    )
+    report = run_elastic_episode(
+        faults=faults, n_steps=n_steps, emitter=emitter,
+    )
+    if emitter is not None:
+        emitter.summary()
+        emitter.close()
+    return json.loads(json.dumps(report))
 
 
 @pytest.fixture(scope="module")

@@ -537,10 +537,6 @@ def test_chaos_crash_restart_exact_badput_attribution(tmp_path, monkeypatch):
 
     from pytorch_distributed_training_tpu.utils.supervisor import supervise
 
-    monkeypatch.setenv(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.expanduser("~/.cache/jax_test_comp_cache"),
-    )
     ckpt = tmp_path / "ckpt"
     metrics = tmp_path / "metrics"
     argv = [

@@ -223,7 +223,8 @@ def test_vit_attn_layout_variants_parity():
     """The three attention layout contracts (auto / bhld / bhld2 —
     models/layers.SelfAttention.attn_layout) must share one param tree and
     produce matching outputs and gradients; bhld2 is the measured TPU
-    default (VIT_ROOFLINE.json r5 experiments)."""
+    default (VIT_ROOFLINE (deleted: not measured on the current machine) r5
+    experiments)."""
     from pytorch_distributed_training_tpu.models.vit import vit_b16
 
     x = jnp.asarray(

@@ -8,21 +8,8 @@ The reference's analogue is the torchrun launch contract at
 /root/reference/src/main.py:35-42."""
 
 import numpy as np
-import pytest
 
 from tests.multiproc_worker import launch_workers
-
-# The CPU backend only learned cross-process collectives alongside the
-# transfer-server work (jax >= 0.5); on the older pins the worker dies with
-# "Multiprocess computations aren't implemented on the CPU backend".
-_CPU_MULTIPROCESS = tuple(
-    int(x) for x in __import__("jax").__version__.split(".")[:2]
-) >= (0, 5)
-
-pytestmark = pytest.mark.skipif(
-    not _CPU_MULTIPROCESS,
-    reason="this jaxlib's CPU backend has no multi-process collectives",
-)
 
 
 def test_two_process_dp_train():

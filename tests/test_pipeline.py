@@ -822,8 +822,6 @@ def test_collective_stage_needs_gpipe(devices8):
     pipeline-varying lax.cond gating — revisit the ban."""
     from jax import lax
 
-    from pytorch_distributed_training_tpu.compat import HAS_VMA
-
     from pytorch_distributed_training_tpu.comm.mesh import AXIS_SEQUENCE
     from pytorch_distributed_training_tpu.models.gpt2 import GPT2Config
     from pytorch_distributed_training_tpu.parallel.gpt2_pipeline import (
@@ -841,15 +839,6 @@ def test_collective_stage_needs_gpipe(devices8):
     for schedule in ("1f1b", "interleaved"):
         with pytest.raises(ValueError, match="gpipe"):
             PipelinedGPT2(cfg, mesh, schedule=schedule)
-
-    if not HAS_VMA:
-        # The canary distinguishes "diverges" from "became exact" — but on
-        # pre-vma JAX the CPU backend DEADLOCKS instead: a collective under
-        # a device-varying lax.cond is entered by only the active stage's
-        # devices and the ppermute never completes.  There is no divergence
-        # to measure, only a hang; the constructor ban in (a) still holds.
-        pytest.skip("cond-gated collective deadlocks (not diverges) on "
-                    "pre-vma JAX's CPU backend; canary needs vma typing")
 
     # (b) the minimal repro: ring-mix stage under the 1F1B engine.
     S, M, mb, L, d, n_seq = 2, 2, 2, 8, 4, 2

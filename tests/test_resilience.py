@@ -844,12 +844,8 @@ def test_chaos_supervised_run_recovers_from_all_fault_classes(
     fallback) — and the run still reaches its final epoch."""
     from pytorch_distributed_training_tpu.utils.supervisor import supervise
 
-    # Children compile from scratch per relaunch; share the test compile
-    # cache so the heartbeat timeout prices the STALL, not XLA.
-    monkeypatch.setenv(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.expanduser("~/.cache/jax_test_comp_cache"),
-    )
+    # Relaunched children share the CLI's own persistent compile cache,
+    # so the heartbeat timeout prices the STALL, not XLA.
     ckpt = tmp_path / "ckpt"
     faults = "nan_batch@1,crash@3,stall@5:600,sigterm@8,ckpt_truncate@9"
     result = supervise(

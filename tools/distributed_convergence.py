@@ -60,8 +60,7 @@ def _parse_metrics(path: str):
 
 def run_single(epochs: int) -> tuple[list, list, str]:
     path = os.path.join(tempfile.mkdtemp(), "single.jsonl")
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     subprocess.run(
         _cli_args(path, epochs, distributed=False),
         check=True, cwd=REPO, env=env, capture_output=True, timeout=3000,
@@ -82,8 +81,11 @@ def run_distributed(epochs: int, n_procs: int = 2) -> tuple[list, list, str]:
             env = dict(
                 os.environ, MASTER_ADDR="localhost", MASTER_PORT=str(port),
                 WORLD_SIZE=str(n_procs), RANK=str(rank),
+                # N children at once cannot share one chip: they run
+                # --use-cpu, and the environment holds them there from
+                # the first import.
+                JAX_PLATFORMS="cpu",
             )
-            env.pop("JAX_PLATFORMS", None)
             # Rank 0's logger owns the committed stream (rank-0 JSONL
             # contract, utils/metrics.py); other ranks write to a scratch
             # path that is simply ignored.
@@ -163,9 +165,13 @@ def main():
         with open(d_path) as f, open(dst, "w") as g:
             g.write(f.read())
         conv_path = os.path.join(REPO, "CONVERGENCE.json")
-        conv = json.load(open(conv_path))
+        conv = {}
+        if os.path.exists(conv_path):  # else tools/convergence_report.py
+            with open(conv_path) as f:
+                conv = json.load(f)
         conv["distributed"] = out
-        json.dump(conv, open(conv_path, "w"), indent=1)
+        with open(conv_path, "w") as f:
+            json.dump(conv, f, indent=1)
         print(f"saved {dst} + CONVERGENCE.json entry")
 
 

@@ -1,7 +1,8 @@
 """GPT-2 124M step diagnosis: compiled cost analysis + roofline placement.
 
 The ViT and ResNet headlines carry committed roofline evidence
-(VIT_ROOFLINE.json, RESNET_ROOFLINE.json); this closes the set for the
+(VIT_ROOFLINE (deleted: not measured on the current machine), RESNET_ROOFLINE
+(deleted: not measured on the current machine)); this closes the set for the
 GPT-2 flagship.  Reports the accumulation microbatch's own XLA FLOP and
 bytes-accessed counts (cost analysis counts a while-loop body ONCE, so
 multiply by accum for per-step totals), roofline bounds from the public
@@ -20,8 +21,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-V5E_BF16_PEAK = 197e12
-V5E_HBM_GBPS = 819e9
 
 
 def main():
@@ -79,6 +78,9 @@ def main():
 
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(state.params))
     model_flops = 6 * n_params * batch * seq
+    from pytorch_distributed_training_tpu.obs.cost import require_peaks
+
+    peak_flops, peak_hbm = require_peaks()
     out = {
         "metric": "gpt2_124m_step_diagnosis",
         "batch": batch,
@@ -87,12 +89,12 @@ def main():
         "compiled_flops_per_step": flops_step,
         "compiled_bytes_accessed_per_step": bytes_step,
         "model_flops_6NT_per_step": model_flops,
-        "roofline_ms_flops": round(flops_step / V5E_BF16_PEAK * 1e3, 1),
-        "roofline_ms_bytes": round(bytes_step / V5E_HBM_GBPS * 1e3, 1),
+        "roofline_ms_flops": round(flops_step / peak_flops * 1e3, 1),
+        "roofline_ms_bytes": round(bytes_step / peak_hbm * 1e3, 1),
         "measured_ms_full_step": round(best * 1e3, 1),
         "tokens_per_sec": round(batch * seq / best, 1),
         "mfu_vs_v5e_bf16_peak": round(
-            model_flops / best / V5E_BF16_PEAK, 4
+            model_flops / best / peak_flops, 4
         ),
     }
     print(json.dumps(out))

@@ -24,8 +24,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-V5E_BF16_PEAK = 197e12
-V5E_HBM_GBPS = 819e9
 
 
 def _measure(mode: str, batch: int, seq: int, accum: int):
@@ -79,6 +77,9 @@ def _measure(mode: str, batch: int, seq: int, accum: int):
     )
     activated = n_params - expert_params + expert_params // e
     router_flops_per_tok = 6 * model.cfg.hidden_dim * e * (model.cfg.num_layers // 2)
+    from pytorch_distributed_training_tpu.obs.cost import require_peaks
+
+    peak_flops, peak_hbm = require_peaks()
     routed_flops_per_step = (6 * activated + router_flops_per_tok) * batch * seq
     tok_s = batch * seq / best
     return {
@@ -87,11 +88,11 @@ def _measure(mode: str, batch: int, seq: int, accum: int):
         "compiled_bytes_accessed_per_step": bytes_step,
         "routed_model_flops_per_step": routed_flops_per_step,
         "compiled_over_routed_flops": round(flops_step / routed_flops_per_step, 3),
-        "roofline_ms_flops": round(flops_step / V5E_BF16_PEAK * 1e3, 1),
-        "roofline_ms_bytes": round(bytes_step / V5E_HBM_GBPS * 1e3, 1),
+        "roofline_ms_flops": round(flops_step / peak_flops * 1e3, 1),
+        "roofline_ms_bytes": round(bytes_step / peak_hbm * 1e3, 1),
         "measured_ms_full_step": round(best * 1e3, 1),
         "tokens_per_sec": round(tok_s, 1),
-        "mfu_routed_flops": round(routed_flops_per_step / best / V5E_BF16_PEAK, 4),
+        "mfu_routed_flops": round(routed_flops_per_step / best / peak_flops, 4),
         "token_drop_rate_at_init": round(drop, 4) if drop == drop else None,
     }, model.cfg, n_params
 

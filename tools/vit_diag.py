@@ -18,8 +18,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-V5E_BF16_PEAK = 197e12
-V5E_HBM_GBPS = 819e9
 
 
 def timed(fn, *args, rounds=3, inner=8):
@@ -115,13 +113,16 @@ def main():
 
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(params))
     model_flops_step = 6 * n_params * 197 * batch
+    from pytorch_distributed_training_tpu.obs.cost import require_peaks
+
+    peak_flops, peak_hbm = require_peaks()
     out = {
         "metric": "vit_b16_step_diagnosis",
         "batch": batch,
         "compiled_flops_per_step": flops,
         "compiled_bytes_accessed_per_step": bytes_acc,
-        "roofline_ms_flops": round(flops / V5E_BF16_PEAK * 1e3, 2),
-        "roofline_ms_bytes": round(bytes_acc / V5E_HBM_GBPS * 1e3, 2),
+        "roofline_ms_flops": round(flops / peak_flops * 1e3, 2),
+        "roofline_ms_bytes": round(bytes_acc / peak_hbm * 1e3, 2),
         "model_flops_6NT_per_step": model_flops_step,
         "measured_ms_forward": round(t_fwd * 1e3, 2),
         "measured_ms_fwd_bwd": round(t_fwdbwd * 1e3, 2),
