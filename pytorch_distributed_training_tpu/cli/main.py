@@ -24,6 +24,8 @@ import time
 
 import click
 
+from ..utils.compile_cache import compile_events, compile_phase, compile_totals
+
 # Model-config fields whose --model-overrides values are strings; all other
 # keys take int/float/bool only (value typos must fail at parse time).
 _STRING_OVERRIDE_KEYS = frozenset({"moe_dispatch"})
@@ -1357,11 +1359,12 @@ def run(
         from ..parallel.sharding import ZERO1_OPT_RULES
 
         opt_rules = ZERO1_OPT_RULES
-    state = create_train_state(
-        net, jax.random.PRNGKey(seed), sample, tx,
-        mesh=mesh, rules=rules, opt_rules=opt_rules,
-        init_kwargs={"train": False},
-    )
+    with compile_phase("startup/state"):
+        state = create_train_state(
+            net, jax.random.PRNGKey(seed), sample, tx,
+            mesh=mesh, rules=rules, opt_rules=opt_rules,
+            init_kwargs={"train": False},
+        )
 
     grad_sync_obj = None
     if grad_sync != "flat":
@@ -1560,7 +1563,7 @@ def run(
             with (
                 ledger.bracket("ckpt_restore") if ledger is not None
                 else contextlib.nullcontext()
-            ):
+            ), compile_phase("startup/restore"):
                 restored = ckpt_mgr.restore_latest(state)
             if ledger is not None:
                 # Restart rework: the interrupted attempt completed steps
@@ -1872,6 +1875,11 @@ def run(
             # What each device holds at the end (None where the backend
             # keeps no memory statistics — the CPU).
             stats = {d.id: d.memory_stats() or {} for d in jax.local_devices()}
+            # Why this start took as long as it did: what JAX traced,
+            # lowered, compiled and found in its cache, in which phase.
+            emitter.emit("record", {
+                "record": "compile_events", **_compile_summary(),
+            })
             emitter.emit("record", {
                 "record": "device_memory",
                 "devices": [
@@ -2060,14 +2068,17 @@ def _run_serve(
             )
         ))
     # What the kernel dispatch actually lowered (every replica compiles
-    # the same programs): Mosaic custom calls per compiled program.
-    programs = engine.mosaic_custom_calls
+    # the same programs): Mosaic custom calls per compiled program, and
+    # which kernels they are (ops.pallas_attention.KERNEL_NAMES).
+    programs = engine.mosaic_kernels
     print("serving programs: mosaic_custom_calls " + " ".join(
-        f"{name}={n}" for name, n in programs.items()
+        f"{name}={sum(kernels.values())}" + "".join(
+            f" ({kernel} x{n})" for kernel, n in kernels.items()
+        ) for name, kernels in programs.items()
     ))
     if emitter is not None:
-        for name, n in programs.items():
-            emitter.gauge(f"mosaic_custom_calls[program={name}]", n)
+        for name, kernels in programs.items():
+            _gauge_mosaic_kernels(emitter, name, kernels)
         emitter.phase("serve_engines_built")
     rng = np.random.default_rng(seed)
     p_hi = max(min(seq_len, max_len - max_new) // 2, 2)
@@ -2362,6 +2373,39 @@ def _run_serve(
     return summary
 
 
+def _compile_summary() -> dict:
+    """The process's compile events (utils/compile_cache.py) as totals,
+    totals per phase, and the five longest single events."""
+    events = compile_events()
+    phases: dict = {}
+    for e in events:
+        key = e["phase"] or "none"
+        if "epoch" in e:
+            key += f":{e['epoch']}"
+        phases.setdefault(key, []).append(e)
+    longest = sorted(events, key=lambda e: -e["seconds"])[:5]
+    return {
+        **compile_totals(events),
+        "by_phase": {k: compile_totals(v) for k, v in phases.items()},
+        "longest": [
+            {k: e[k] for k in ("what", "fun_name", "seconds", "phase")}
+            for e in longest
+        ],
+    }
+
+
+def _gauge_mosaic_kernels(emitter, program: str, kernels: dict) -> None:
+    """``mosaic_custom_calls[program=..]`` (all kernels of one compiled
+    program) and one ``[program=..,kernel=..]`` gauge per kernel name."""
+    emitter.gauge(
+        f"mosaic_custom_calls[program={program}]", sum(kernels.values())
+    )
+    for kernel, n in kernels.items():
+        emitter.gauge(
+            f"mosaic_custom_calls[program={program},kernel={kernel}]", n
+        )
+
+
 def _probe_compiled_cost(trainer, batches, mesh, sequence_parallel, emitter):
     """AOT-lower the train step on the first batch and emit one
     ``compiled_cost`` event (FLOPs / bytes accessed / collective census
@@ -2400,9 +2444,8 @@ def _probe_compiled_cost(trainer, batches, mesh, sequence_parallel, emitter):
             ).compile()
             report = step_cost_report(compiled)
             emitter.emit("compiled_cost", report)
-            emitter.gauge(
-                "mosaic_custom_calls[program=train_step]",
-                report.get("mosaic_custom_calls", 0),
+            _gauge_mosaic_kernels(
+                emitter, "train_step", report.get("mosaic_kernels", {})
             )
             # Feed the live MFU gauge: the probe's compiled FLOPs + peak
             # over the trainer's rolling step-time window (obs/live.py).
@@ -2441,7 +2484,7 @@ def _run_epochs(
             with (
                 ledger.bracket("compile") if ledger is not None
                 else contextlib.nullcontext()
-            ):
+            ), compile_phase("startup/step"):
                 batches = _probe_compiled_cost(
                     trainer, batches, mesh, sequence_parallel, emitter
                 )

@@ -93,7 +93,7 @@ from .ledger import (
     fleet_ledger,
 )
 from .schema import METRICS as METRIC_SCHEMA, check_metric_name
-from .trace import PHASES, annotate, phase_span, scope, step_annotation
+from .trace import PHASES, phase_span, scope, step_annotation
 
 __all__ = [
     "ALERT_STATES",
@@ -118,7 +118,6 @@ __all__ = [
     "SUPPORTED_SCHEMA_VERSIONS",
     "Span",
     "SpanRecorder",
-    "annotate",
     "bucket_counts_of",
     "bucket_index",
     "bucket_upper",

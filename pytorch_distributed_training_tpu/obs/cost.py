@@ -123,12 +123,30 @@ def collective_census(hlo_text: str) -> dict[str, dict[str, Any]]:
     return census
 
 
+_MOSAIC_CALL = re.compile(
+    r'^\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = .*custom_call_target="tpu_custom_call"',
+    re.M,
+)
+
+
+def mosaic_kernels(hlo_text: str) -> dict[str, int]:
+    """Pallas TPU (Mosaic) kernels in a compiled program's text, counted by
+    the name their instruction carries — the ``name`` the ``pallas_call``
+    was given (``ops.pallas_attention.KERNEL_NAMES``: ``flash_fwd``,
+    ``paged_decode_attn``, ...).  Kernel choice is a predicate on the
+    backend at trace time; this is what was actually lowered — nothing on
+    a TPU program that should carry a fused kernel means the dispatch
+    routed around it (and always nothing off-TPU, where the kernels run
+    interpreted as plain HLO)."""
+    counts: dict[str, int] = {}
+    for name in _MOSAIC_CALL.findall(hlo_text):
+        counts[name] = counts.get(name, 0) + 1
+    return counts
+
+
 def mosaic_custom_calls(hlo_text: str) -> int:
-    """Pallas TPU (Mosaic) kernels in a compiled program's text.  Kernel
-    choice is a predicate on the backend at trace time; this is what was
-    actually lowered — 0 on a TPU program that should carry a fused kernel
-    means the dispatch routed around it (and always 0 off-TPU, where the
-    kernels run interpreted as plain HLO)."""
+    """How many Mosaic kernels the program's text holds, whatever their
+    names (see :func:`mosaic_kernels`)."""
     return hlo_text.count('custom_call_target="tpu_custom_call"')
 
 
@@ -194,6 +212,7 @@ def step_cost_report(
             text = compiled.as_text()
             report["collectives"] = collective_census(text)
             report["mosaic_custom_calls"] = mosaic_custom_calls(text)
+            report["mosaic_kernels"] = mosaic_kernels(text)
         except Exception:
             pass
     report["peak_flops"] = (

@@ -22,8 +22,7 @@ recorder's whole point is per-rank evidence (which host stalled), merged
 after the fact by ``tools/telemetry_report.py``.
 
 The emitter is also constructible disabled (``metrics_dir=None``): every
-method short-circuits, so call sites thread one object unconditionally and
-``bench.py --telemetry-overhead`` can price the enabled path honestly.
+method short-circuits, so call sites thread one object unconditionally.
 """
 
 from __future__ import annotations

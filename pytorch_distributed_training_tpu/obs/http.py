@@ -31,8 +31,7 @@ one process's :class:`~.live.LiveAggregator` / :class:`~.slo.SLOPolicy`:
 The handler thread only READS (the aggregator's lock guards the
 snapshot); all mutation stays on the host control loop.  Nothing here
 ever touches a device — the endpoint is host-thread-only by
-construction, and its cost under scrape-during-load is priced in
-TELEMETRY_BENCH.json's ``live`` leg.
+construction.
 """
 
 from __future__ import annotations

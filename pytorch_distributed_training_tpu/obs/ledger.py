@@ -18,7 +18,8 @@ on every platform.
 How the trainer feeds it (train/trainer.py; every hook is None-guarded
 so a run without ``--goodput`` pays nothing):
 
-- :meth:`wrap_batches` brackets the iterator pull: the pull interval is
+- :meth:`begin_pull` / :meth:`end_pull` bracket the iterator pull (the
+  trainer's ``train/input_wait`` boundary): the pull interval is
   ``data_wait``; the interval from batch-ready through dispatch (where
   the host blocks on XLA's async queue — i.e. on device compute, at
   steady state) plus the post-dispatch host tail belongs to the step.
@@ -63,6 +64,8 @@ from typing import Any, Iterable, Iterator
 # which must stay importable without the obs package); re-exported here
 # so ledger consumers need only one import.
 from ..utils.supervisor import BACKOFF_ENV
+
+_EXHAUSTED = object()   # wrap_batches: the pull found the iterator empty
 
 # Mutually exclusive wall-clock categories; ``sum == wall`` is pinned.
 CATEGORIES = (
@@ -183,24 +186,32 @@ class GoodputLedger:
 
     # ---- trainer hooks --------------------------------------------------
 
+    def begin_pull(self) -> None:
+        """The trainer is about to pull its next batch: close the previous
+        step's host tail, open ``data_wait``."""
+        self._switch("data_wait")
+
+    def end_pull(self, exhausted: bool) -> None:
+        """The pull returned.  What follows a batch (fault hooks, dispatch)
+        is the step's own interval — :meth:`begin_step` classifies it; an
+        exhausted pull was still input-side wall time, and the epoch tail
+        after it (eval, epoch-end bookkeeping) is ``other``."""
+        if exhausted:
+            self._switch("other")
+        else:
+            self._switch("step", step=None, cls="step_compute")
+
     def wrap_batches(self, it: Iterable) -> Iterator:
-        """Bracket the iterator pull: pull time is ``data_wait``; the
-        interval from batch-ready to :meth:`begin_step` (dispatch, which
-        blocks on the device at steady state) joins the step's charge."""
+        """:meth:`begin_pull` / :meth:`end_pull` around every pull of an
+        iterator, for loops that are not the trainer's (the trainer calls
+        the pair inside its own ``train/input_wait`` boundary)."""
         it = iter(it)
         while True:
-            # Close the previous step's host tail, open the pull.
-            self._switch("data_wait")
-            try:
-                batch = next(it)
-            except StopIteration:
-                # The exhausted pull was still input-side wall time; the
-                # epoch tail (eval, epoch-end bookkeeping) is "other".
-                self._switch("other")
+            self.begin_pull()
+            batch = next(it, _EXHAUSTED)
+            self.end_pull(batch is _EXHAUSTED)
+            if batch is _EXHAUSTED:
                 return
-            # Pull done: what follows (fault hooks, shard, dispatch) is
-            # the step's own interval — begin_step classifies it.
-            self._switch("step", step=None, cls="step_compute")
             yield batch
 
     def begin_step(self, step: int) -> None:

@@ -35,8 +35,7 @@ side is the host control loop (scheduler tick / trainer step), the
 reading side is the ops HTTP thread (obs/http.py) serving ``/metrics``,
 ``/healthz``, ``/slo``.  Nothing here touches a device or runs inside
 ``jit`` — the whole plane is host-thread-only (graftcheck's
-``host-clock-in-trace`` discipline), priced by ``bench.py
---telemetry-overhead`` (TELEMETRY_BENCH.json ``live`` leg).
+``host-clock-in-trace`` discipline).
 """
 
 from __future__ import annotations

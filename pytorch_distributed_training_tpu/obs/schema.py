@@ -36,11 +36,14 @@ METRICS: dict[str, dict] = {
     # ---- training loop (train/trainer.py, obs/ledger.py) ----------------
     "mfu_live": {
         "type": GAUGE, "labeled": False,
-        "help": "rolling live MFU: compiled FLOPs / median recent step time",
+        "help": "live MFU: compiled FLOPs / the step time the last loss "
+                "fetch closed (host time between two fetches / the steps "
+                "between them); absent before the second fetch",
     },
     "step_time_s": {
         "type": HISTOGRAM, "labeled": False,
-        "help": "host wall time per optimizer step",
+        "help": "host dispatch interval per optimizer step (step.dt); "
+                "equals device step time only once the queue is full",
     },
     "goodput_fraction": {
         "type": GAUGE, "labeled": False,
@@ -94,8 +97,10 @@ METRICS: dict[str, dict] = {
     "mosaic_custom_calls": {
         "type": GAUGE, "labeled": True,
         "help": "Pallas TPU (Mosaic) custom calls in a compiled program's "
-                "text, per program (train_step, prefill, decode, verify); "
-                "also a field of the compiled_cost event",
+                "text, per program (train_step, prefill, decode, verify) "
+                "and, with a kernel label, per kernel name (flash_fwd, "
+                "paged_decode_attn, ...); also fields of the compiled_cost "
+                "event (mosaic_custom_calls, mosaic_kernels)",
     },
     # ---- SLO / alerting plane (obs/slo.py) ------------------------------
     "slo_alert_transitions": {

@@ -19,7 +19,7 @@ this module is the low-overhead recorder that emits them as schema-v3
 - **deferred serialization**: the hot path appends a :class:`Span` to a
   list; JSON encoding and the file write happen at :meth:`flush`
   (tick/step boundaries and close), so recording a span costs an object
-  append, not a syscall — priced by ``bench.py --telemetry-overhead``;
+  append, not a syscall;
 - **sampling** (``--trace-sample-rate``): per-CORRELATION-ID and
   deterministic (a hash of the id, not a coin flip), so either *every*
   span of a request records or none do — a sampled trace always holds
@@ -58,14 +58,18 @@ from .emitter import MetricsEmitter, percentiles
 #                        tiers, pipeline ticks — measured per-tier times
 #                        live in the xprof capture, not here: the tiers
 #                        run inside ONE compiled program)
-#   train/host_sync      the log-point loss fetch (device wait)
+#   train/input_wait     the loop's batch pull: the loader's next, the
+#                        shard and the host-to-device enqueue
+#   train/host_sync      a loss fetch (device wait): every log point, and
+#                        the fetch that closes the epoch
 #   train/snapshot       recovery snapshot staging
 #   train/checkpoint     step-checkpoint save call
 SPAN_NAMES = (
     "serve/request", "request/queued", "request/prefill", "request/decode",
     "router/route",
     "serve/prefill", "serve/decode", "serve/verify",
-    "train/step", "train/host_sync", "train/snapshot", "train/checkpoint",
+    "train/step", "train/input_wait", "train/host_sync", "train/snapshot",
+    "train/checkpoint",
 )
 
 def _jsonable(value: Any) -> Any:
@@ -183,11 +187,15 @@ class SpanRecorder:
 
     # ---- recording ------------------------------------------------------
 
-    def span(self, name: str, *, corr: Any = None, **attrs):
+    def span(self, name: str, *, corr: Any = None,
+             parent: Span | int | None = None, **attrs):
         """Context manager: bracket host work lexically.  Nested ``span``
         calls parent to the enclosing one automatically (the implicit
-        stack); yields the :class:`Span` (or None when not recording)."""
-        return _SpanContext(self, self.start_span(name, corr=corr, **attrs))
+        stack) unless ``parent`` names one; yields the :class:`Span` (or
+        None when not recording)."""
+        return _SpanContext(
+            self, self.start_span(name, corr=corr, parent=parent, **attrs)
+        )
 
     def start_span(
         self, name: str, *, corr: Any = None, parent: Span | int | None = None,

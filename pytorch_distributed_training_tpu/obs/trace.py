@@ -6,29 +6,33 @@ gradient sync, which engine program, which tick.  This module owns the
 canonical names (so the README table, the annotations, and any trace
 tooling agree) and re-exports the compat-shimmed entry points:
 
-- :func:`annotate` — host-side span (``TraceAnnotation``): brackets
-  dispatch + wait of host code.  Used around the serve engine's compiled
-  calls and the trainer's step dispatch.
+- :func:`phase_span` — host-side span (``TraceAnnotation``, plus a
+  recorded span when a ``SpanRecorder`` is passed): brackets dispatch +
+  wait of host code.  Used around the serve engine's compiled calls and
+  the trainer's input pull and host syncs.
 - :func:`step_annotation` — ``StepTraceAnnotation``: xprof's step marker,
   giving the per-step row grouping in the trace viewer.
 - :func:`scope` — trace-time ``named_scope``: ops traced under it carry
   the phase in HLO metadata, so *compiled* timelines (and HLO dumps) show
-  grad-sync tiers and pipeline ticks by name.
+  the step's phases, grad-sync tiers and pipeline ticks by name.
 
-All three are no-ops outside an active capture; the overhead with no
-profiler attached is priced by ``bench.py --telemetry-overhead``.
+The host-side two land in the capture's ``/host:CPU`` plane, on the clock
+the device planes use, so an idle gap on the device can be laid against
+what the host was doing.  All three do nothing outside an active capture;
+what they cost inside one is measured on the chip (PERF.md section 6).
 
 The span layer (obs/spans.py) is the *recorded* counterpart of the same
 vocabulary: :func:`phase_span` brackets host-side phases with BOTH an
 xprof annotation and a ``SpanRecorder`` span, so the exported timeline
 (``tools/trace_export.py``) and a live xprof capture name the same work
 the same way.  Only the HOST-side phases promote — trace-time
-:func:`scope` names (grad-sync tiers, grad-accum microbatches, pipeline
-ticks) live inside ONE compiled program, where a host clock would record
-trace time once and bake it in; graftcheck's ``host-clock-in-trace``
-rule makes that class a lint finding, and their measured timelines stay
-xprof's job.  The host span for such a step instead carries the anatomy
-as attributes (microbatch count, sync tiers, pipeline ticks).
+:func:`scope` names (the loss, the optimizer, grad-sync tiers, grad-accum
+microbatches, pipeline ticks) live inside ONE compiled program, where a
+host clock would record trace time once and bake it in; graftcheck's
+``host-clock-in-trace`` rule makes that class a lint finding, and their
+measured timelines stay xprof's job.  The host span for such a step
+instead carries the anatomy as attributes (microbatch count, sync tiers,
+pipeline ticks).
 """
 
 from __future__ import annotations
@@ -41,7 +45,10 @@ from ..compat import named_scope, step_trace_annotation, trace_annotation
 # tests pin membership so renames are deliberate).
 PHASES = (
     "train/step",            # one optimizer step (host span + step marker)
-    "train/eval",            # eval pass batches
+    "train/input_wait",      # the loop's batch pull: loader next, shard, H2D enqueue
+    "train/host_sync",       # a loss fetch: the host waits for the device
+    "train/loss",            # forward + loss of one microbatch (in the program)
+    "train/optimizer",       # the update gate: optimizer + apply (in the program)
     "grad_accum/microbatch",  # fwd+bwd of one accumulation microbatch
     "grad_sync/rs_ici",      # tier 1: reduce-scatter over ICI
     "grad_sync/ar_dcn",      # tier 2: cross-slice all-reduce over DCN
@@ -52,11 +59,6 @@ PHASES = (
     "serve/decode",          # engine decode program
     "serve/verify",          # engine speculative multi-token verify program
 )
-
-
-def annotate(name: str, **kwargs):
-    """Host-side xprof span named ``name`` (see :data:`PHASES`)."""
-    return trace_annotation(name, **kwargs)
 
 
 def step_annotation(step_num: int, name: str = "train"):
@@ -70,15 +72,17 @@ def scope(name: str):
 
 
 @contextmanager
-def phase_span(spans, name: str, *, corr=None, **attrs):
+def phase_span(spans, name: str, *, corr=None, parent=None, **attrs):
     """One host-side phase, visible to BOTH timelines: an xprof
     annotation (live captures) and a recorded span on ``spans`` (a
-    :class:`~.spans.SpanRecorder`, or None — then this is just
-    :func:`annotate`).  Use at dispatch boundaries only; inside compiled
-    code it is a ``host-clock-in-trace`` lint finding."""
+    :class:`~.spans.SpanRecorder`, or None — then only the annotation).
+    ``parent`` is the recorded span's parent where the lexical nesting of
+    ``spans.span`` does not give it.  Use at dispatch boundaries only;
+    inside compiled code it is a ``host-clock-in-trace`` lint finding."""
     if spans is None:
         with trace_annotation(name):
             yield None
         return
-    with trace_annotation(name), spans.span(name, corr=corr, **attrs) as s:
+    with trace_annotation(name), \
+            spans.span(name, corr=corr, parent=parent, **attrs) as s:
         yield s

@@ -35,6 +35,23 @@ from jax.experimental.pallas import tpu as pltpu
 _LANES = 128
 _NEG_INF = -1e30
 
+# Every ``pl.pallas_call`` below passes one of these as ``name``: the HLO
+# instruction (and so the device trace's event) is then ``%<name>.<n>``.
+# By role, not by variant (single-tile, native-layout, grouped, ...), so a
+# metric on a name keeps meaning the same work when an implementation is
+# swapped; a test walks this file and refuses a call without one.
+KERNEL_NAMES = (
+    "flash_fwd",          # every forward variant
+    "flash_bwd",          # a fused backward (dq, dk, dv from one call)
+    "flash_bwd_dq",       # the split backward's two halves
+    "flash_bwd_dkv",
+    "decode_attn",        # contiguous cache, one token a row
+    "decode_multi_attn",  # contiguous cache, a C-token chunk (verify)
+    "paged_decode_attn",  # paged pool, one token a row
+    "paged_verify_attn",  # paged pool, the speculative k+1 chunk
+    "paged_prefill_attn",  # paged pool, a prefill chunk
+)
+
 
 def _live_block(qi, ki, *, causal, causal_offset, kv_len, block_q, block_k):
     """Predicate for kv/q tile pairs with any unmasked entry, or None when
@@ -250,6 +267,7 @@ def _flash_fwd_single(q, k, v, causal, scale, block_q, interpret,
             jax.ShapeDtypeStruct((b, h, q_len, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, q_len, 8), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse[..., 0]
@@ -332,6 +350,7 @@ def _flash_fwd_single_nlhd(q, k, v, causal, scale, block_q, interpret,
             jax.ShapeDtypeStruct((b, q_len, hd), q.dtype),
             jax.ShapeDtypeStruct((b, q_len, num_heads), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse
@@ -410,6 +429,7 @@ def _flash_bwd_nlhd(q, k, v, out, lse, do, causal, scale, interpret,
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
+        name="flash_bwd",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -624,6 +644,7 @@ def _flash_fwd_grouped(q, k, v, causal, scale, interpret, causal_offset,
             jax.ShapeDtypeStruct((b, q_len, hd_all), q.dtype),
             jax.ShapeDtypeStruct((b, ng, q_len, hg), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse
@@ -671,6 +692,7 @@ def _flash_bwd_grouped(q, k, v, out, lse, do, causal, scale, interpret,
             pltpu.VMEM((k_len, hd), jnp.float32),
             pltpu.VMEM((k_len, hd), jnp.float32),
         ],
+        name="flash_bwd",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -760,6 +782,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse[..., 0]
@@ -941,6 +964,7 @@ def _flash_bwd_single(q, k, v, lse, delta, do, causal, scale, interpret,
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
+        name="flash_bwd",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -992,6 +1016,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, q_len, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        name="flash_bwd_dq",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -1015,6 +1040,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        name="flash_bwd_dkv",
         interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
@@ -1258,6 +1284,7 @@ def decode_attention(
         functools.partial(_decode_kernel, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
+        name="decode_attn",
         interpret=interpret,
     )(index, q, k_cache, v_cache)
 
@@ -1337,6 +1364,7 @@ def decode_attention_multi(
         functools.partial(_decode_kernel_multi, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, c, dh), q.dtype),
+        name="decode_multi_attn",
         interpret=interpret,
     )(index, jnp.swapaxes(q, 1, 2), k_cache, v_cache)
     return jnp.swapaxes(out, 1, 2)
@@ -1512,7 +1540,7 @@ def paged_decode_attention(
         return _paged_multi_call(
             q[:, None], k_blocks, v_blocks, block_table, index, scale=scale,
             interpret=interpret, k_scale=k_scale, v_scale=v_scale,
-            quant=quant,
+            quant=quant, name="paged_decode_attn",
         )[:, 0]
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
@@ -1543,6 +1571,7 @@ def paged_decode_attention(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
+        name="paged_decode_attn",
         interpret=interpret,
     )(index, block_table, q, k_blocks, v_blocks)
 
@@ -1654,11 +1683,12 @@ def _paged_decode_kernel_multi(i_ref, tbl_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def _paged_multi_call(q, k_blocks, v_blocks, block_table, index, *,
-                      scale, interpret, k_scale, v_scale, quant):
+                      scale, interpret, k_scale, v_scale, quant, name):
     """Shared launcher for the multi-query paged kernel: the speculative-
     verify chunk (``paged_decode_attention_multi``), the fused chunked
     prefill (``paged_prefill_attention``) and quantized single-token
-    decode run the SAME kernel body on the same (B, nb) grid."""
+    decode run the SAME kernel body on the same (B, nb) grid, each under
+    its own role ``name`` (one of :data:`KERNEL_NAMES`)."""
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     n_blocks, h, block_size, dh_stored = k_blocks.shape
@@ -1700,6 +1730,7 @@ def _paged_multi_call(q, k_blocks, v_blocks, block_table, index, *,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q_planes.shape, q.dtype),
+        name=name,
         interpret=interpret,
     )(index, block_table, *operands)
     return jnp.transpose(out, (0, 3, 1, 4, 2)).reshape(b, c, h, dh)
@@ -1736,6 +1767,7 @@ def paged_decode_attention_multi(
     return _paged_multi_call(
         q, k_blocks, v_blocks, block_table, index, scale=scale,
         interpret=interpret, k_scale=k_scale, v_scale=v_scale, quant=quant,
+        name="paged_verify_attn",
     )
 
 
@@ -1792,6 +1824,7 @@ def paged_prefill_attention(
     return _paged_multi_call(
         q, k_blocks, v_blocks, block_table, index, scale=scale,
         interpret=interpret, k_scale=k_scale, v_scale=v_scale, quant=quant,
+        name="paged_prefill_attn",
     )
 
 

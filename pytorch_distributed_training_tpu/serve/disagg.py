@@ -205,12 +205,13 @@ class DisaggServingEngine:
         return self.decode_engine.params
 
     @property
-    def mosaic_custom_calls(self) -> dict[str, int]:
-        """Per-program kernel counts of both roles (each role compiles
-        only its own programs, so the names do not collide)."""
+    def mosaic_kernels(self) -> dict[str, dict[str, int]]:
+        """Per-program kernel counts of both roles, by kernel name (each
+        role compiles only its own programs, so the names do not
+        collide)."""
         return {
-            **self.prefill_engine.mosaic_custom_calls,
-            **self.decode_engine.mosaic_custom_calls,
+            **self.prefill_engine.mosaic_kernels,
+            **self.decode_engine.mosaic_kernels,
         }
 
     @property

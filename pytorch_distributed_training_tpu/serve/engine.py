@@ -51,7 +51,7 @@ import numpy as np
 from ..analysis.signature import PROGRAM_REGISTRY, abstract_signature
 from ..compat import named_scope
 from ..models.generate import eos_cut_length, filter_logits, sample_logits
-from ..obs.cost import mosaic_custom_calls
+from ..obs.cost import mosaic_kernels
 from ..obs.trace import phase_span
 from .draft import NgramIndex, PromptLookupDrafter
 from .kv_pool import KVCachePool, PagedKVCachePool, SlotExport
@@ -570,17 +570,26 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
 
     @property
-    def mosaic_custom_calls(self) -> dict[str, int]:
-        """Pallas TPU kernels per compiled program (obs/cost.py): what the
-        kernel dispatch actually lowered.  Read from the program text on
-        demand — one caller (the CLI's start-up line) wants it."""
+    def mosaic_kernels(self) -> dict[str, dict[str, int]]:
+        """Pallas TPU kernels per compiled program, by kernel name
+        (obs/cost.py; the names are ``ops.pallas_attention.KERNEL_NAMES``):
+        what the kernel dispatch actually lowered.  Read from the program
+        text on demand — one caller (the CLI's start-up line) wants it."""
         programs = {
             "prefill": self._prefill_fn, "decode": self._decode_fn,
             "verify": self._verify_fn,
         }
         return {
-            name: mosaic_custom_calls(fn.as_text())
+            name: mosaic_kernels(fn.as_text())
             for name, fn in programs.items() if fn is not None
+        }
+
+    @property
+    def mosaic_custom_calls(self) -> dict[str, int]:
+        """:attr:`mosaic_kernels` summed per program."""
+        return {
+            name: sum(kernels.values())
+            for name, kernels in self.mosaic_kernels.items()
         }
 
     @property

@@ -15,6 +15,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import scope
 from ..ops.losses import chunked_lm_cross_entropy, cross_entropy_loss
 from ..parallel.grad_accum import accumulate_gradients
 from ..resilience.anomaly import guarded_apply
@@ -153,10 +154,17 @@ def make_train_step(
     policy = policy or Policy()
 
     def apply_update(state, loss, grads, **replace_kwargs):
-        """The one update gate all three backward paths exit through."""
-        if anomaly_policy is None:
-            return state.apply_gradients(grads, **replace_kwargs), {}
-        return guarded_apply(state, loss, grads, anomaly_policy, **replace_kwargs)
+        """The one update gate all three backward paths exit through.
+        ``train/optimizer`` and ``train/loss`` below are trace-time scopes:
+        with ``grad_accum/microbatch`` they put the step's phase into every
+        instruction's ``op_name`` (forward + loss, its backward as the
+        transpose of the same scope, the update)."""
+        with scope("train/optimizer"):
+            if anomaly_policy is None:
+                return state.apply_gradients(grads, **replace_kwargs), {}
+            return guarded_apply(
+                state, loss, grads, anomaly_policy, **replace_kwargs
+            )
 
     def compute_loss(state, params, batch, rng):
         if kind == "image_classifier":
@@ -229,7 +237,8 @@ def make_train_step(
                 if step_rng is not None
                 else None
             )
-            return compute_loss(state, p, b, rng)
+            with scope("train/loss"):
+                return compute_loss(state, p, b, rng)
 
         if grad_sync is not None:
             (loss, aux), grads, residual = grad_sync.accumulate_and_sync(

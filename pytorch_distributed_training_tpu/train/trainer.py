@@ -7,11 +7,14 @@ what the reference computes but never surfaces (loss logging, SURVEY.md §5)
 and per-epoch throughput in the BASELINE.json metric (examples/sec).
 
 Telemetry rides the loop through one spine (obs/): an optional
-``MetricsEmitter`` gets a per-step structured event (host-side step wall
-time + the configured per-step counters; the loss joins at log points, where
-the host syncs anyway), anomalies route through the flight recorder, and
-every step dispatch carries an xprof step annotation so captured traces
-group device activity by optimizer step.  Profiling can bracket a step
+``MetricsEmitter`` gets a per-step structured event (host-side dispatch
+interval + the configured per-step counters; the loss joins at log points,
+where the host syncs anyway, and so does the step time the device closed),
+anomalies route through the flight recorder, and the loop's host boundaries
+are on the profiler's clock: every step dispatch carries an xprof step
+annotation (``train``), the batch pull is ``train/input_wait`` and every
+loss fetch ``train/host_sync``, so a capture lays the device's idle gaps
+against what the host was doing.  Profiling can bracket a step
 window (``TrainerConfig.profile_steps``) instead of a whole epoch — the
 steady-state capture — with the supervisor heartbeat beaten every captured
 step so a long capture is never mistaken for a hang.
@@ -21,8 +24,8 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import time
-from collections import deque
 from typing import Any, Callable, Iterable
 
 import jax
@@ -30,9 +33,9 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ..obs.cost import mfu
-from ..obs.trace import step_annotation
+from ..obs.trace import phase_span, step_annotation
 from ..parallel.sharding import shard_batch
-from ..utils.profiling import StepTimer
+from ..utils.compile_cache import compile_events, compile_phase, compile_totals
 from .state import TrainState
 
 
@@ -92,16 +95,16 @@ class Trainer:
         # at every step boundary — the trainer is the host control loop
         # a training run has, the way the scheduler tick is for serving.
         # step_flops/peak_flops (set by the CLI's compiled-cost probe)
-        # turn the rolling step-time window into a live MFU gauge.
+        # turn the step time each host sync closes into a live MFU gauge.
         self.slo = slo
         self.step_flops: float | None = None
         self.peak_flops: float | None = None
-        self._recent_dts: deque = deque(maxlen=32)
         # Span recorder (obs/spans.py): every optimizer step records a
         # ``train/step`` host span (corr = global step, sampled per step)
-        # bracketing dispatch through the step's host bookkeeping, with
-        # ``train/host_sync`` / ``train/snapshot`` / ``train/checkpoint``
-        # children at the boundaries where the host actually waits.
+        # bracketing the batch pull through the step's host bookkeeping,
+        # with ``train/input_wait`` / ``train/host_sync`` /
+        # ``train/snapshot`` / ``train/checkpoint`` children at the
+        # boundaries where the host actually waits.
         # ``anatomy`` attrs ride every step span: what ONE compiled step
         # contains (grad-accum microbatches, grad-sync tiers, pipeline
         # ticks) — those phases run inside a single program, so their
@@ -120,8 +123,9 @@ class Trainer:
         self.checkpoint_fn = checkpoint_fn
         # Goodput ledger (obs/ledger.py, --goodput): exhaustive wall-clock
         # attribution.  The loop feeds it at the boundaries it already
-        # crosses — iterator pull, step dispatch, checkpoint calls — so
-        # the hooks add clock reads, not synchronization.
+        # crosses — the ``train/input_wait`` pull, step dispatch,
+        # checkpoint calls — so the hooks add clock reads, not
+        # synchronization.
         self.ledger = ledger
         self.recorder = None
         if emitter is not None and emitter.enabled:
@@ -173,7 +177,10 @@ class Trainer:
             # loss so the traced window contains the steps it brackets,
             # not just their dispatch.
             if metrics is not None:
-                float(metrics["loss"])
+                with phase_span(
+                    self.spans, "train/host_sync", corr=self._global_step,
+                ):
+                    float(metrics["loss"])
             jax.profiler.stop_trace()
             self._profiling = False
             self._profile_done = True
@@ -210,10 +217,32 @@ class Trainer:
         examples = 0
         losses = []
         last_metrics: dict = {}
-        timer = StepTimer()
-        local_batch = 0
         metrics: dict | None = None
         last_logged_step = -1
+        # (host time, step index) of the last loss fetch.  Between two
+        # fetches the device finished exactly the steps between them, so
+        # their quotient is a step time the DEVICE closed — unlike the
+        # dispatch interval ``dt``, which is the host's pace and runs
+        # ahead of the device until the queue is full.
+        last_sync: tuple[float, int] | None = None
+
+        def close_on_sync(step_idx: int) -> float | None:
+            """Call right after a loss fetch: seconds per step since the
+            fetch before it (None at the epoch's first), and the live MFU
+            gauge from it."""
+            nonlocal last_sync
+            now = time.perf_counter()
+            step_s = None
+            if last_sync is not None and step_idx > last_sync[1]:
+                step_s = (now - last_sync[0]) / (step_idx - last_sync[1])
+                if self.emitter is not None and self.step_flops \
+                        and self.peak_flops:
+                    live = mfu(self.step_flops, step_s, self.peak_flops)
+                    if live is not None:
+                        self.emitter.gauge("mfu_live", live)
+            last_sync = (now, step_idx)
+            return step_s
+
         # (global step, device scalar) of every step since the last host
         # sync: fetched in one go WHERE the host syncs anyway (log points,
         # epoch end) and written as a ``step_losses`` record, so the log
@@ -243,8 +272,9 @@ class Trainer:
             self.emitter.phase("epoch_start", epoch=epoch)
         t0 = time.perf_counter()
         prev_tick = t0
+        compiled_before = len(compile_events())
         try:
-            with self.mesh:
+            with self.mesh, compile_phase("train/epoch", epoch=epoch):
                 if cfg.prefetch > 0:
                     # Keep N sharded batches in flight so the next batch's
                     # H2D transfer rides under the current step's compute.
@@ -254,35 +284,57 @@ class Trainer:
                         it, self.mesh, size=cfg.prefetch,
                         sequence_sharded=cfg.sequence_sharded,
                     )
-                if self.ledger is not None:
-                    # Outside the prefetch wrap: a pull that blocks here
-                    # means the input pipeline (even prefetched) could not
-                    # hide the load — exactly what data_wait should charge.
-                    it = self.ledger.wrap_batches(it)
-                for step_idx, batch in enumerate(it):
-                    self._profile_tick(heartbeat)
-                    if self.faults is not None:
-                        # Deterministic fault plane: may corrupt the batch,
-                        # stall without beating, SIGTERM self, or kill the
-                        # process outright (resilience/faults.py).
-                        batch = self.faults.on_step(self._global_step, batch)
-                    batch = shard_batch(  # idempotent if already placed
-                        batch, self.mesh, sequence_sharded=cfg.sequence_sharded
-                    )
+                it = iter(it)
+                for step_idx in itertools.count():
                     sspan = (
                         self.spans.start_span(
                             "train/step", corr=self._global_step,
                             **self.anatomy,
                         ) if self.spans is not None else None
                     )
+                    # What the host does for input, timed once: the
+                    # loader's next, the shard and the host-to-device
+                    # enqueue (of the batch AFTER this one when the
+                    # prefetch wrap is on).  Outside the prefetch wrap, so
+                    # a pull that blocks here means the input pipeline
+                    # (even prefetched) could not hide the load — exactly
+                    # what the ledger's data_wait should charge too.
+                    with phase_span(
+                        self.spans, "train/input_wait",
+                        corr=self._global_step, parent=sspan,
+                    ) as wspan:
+                        if self.ledger is not None:
+                            self.ledger.begin_pull()
+                        batch = next(it, None)
+                        if batch is not None:
+                            batch = shard_batch(  # idempotent if placed
+                                batch, self.mesh,
+                                sequence_sharded=cfg.sequence_sharded,
+                            )
+                        elif wspan is not None:
+                            # The pull that finds the loader empty is input
+                            # time all the same, but no step follows it:
+                            # the step span opened above is never recorded.
+                            wspan.parent = None
+                        if self.ledger is not None:
+                            self.ledger.end_pull(exhausted=batch is None)
+                    if batch is None:
+                        break
+                    self._profile_tick(heartbeat)
+                    if self.faults is not None:
+                        # Deterministic fault plane: may corrupt the batch,
+                        # stall without beating, SIGTERM self, or kill the
+                        # process outright (resilience/faults.py).
+                        batch = shard_batch(
+                            self.faults.on_step(self._global_step, batch),
+                            self.mesh, sequence_sharded=cfg.sequence_sharded,
+                        )
                     with step_annotation(self._global_step):
                         self.state, metrics = self.train_step(self.state, batch)
-                    local_batch = int(next(iter(batch.values())).shape[0])
-                    examples += local_batch
+                    examples += int(next(iter(batch.values())).shape[0])
                     pending_losses.append(
                         (self._global_step, metrics["loss"])
                     )
-                    timer.tick()  # dispatch-rate rolling window (no device sync)
                     now = time.perf_counter()
                     if self.ledger is not None:
                         # Classify the batch-ready..dispatch interval (the
@@ -294,7 +346,6 @@ class Trainer:
                         self.ledger.begin_step(self._global_step)
                     step_fields: dict = {"dt": now - prev_tick}
                     prev_tick = now
-                    self._recent_dts.append(step_fields["dt"])
                     if cfg.check_nan or step_idx % cfg.log_every == 0:
                         if heartbeat is not None:
                             heartbeat.beat()
@@ -304,18 +355,16 @@ class Trainer:
                         # shows fat host_sync bars at log points and thin
                         # dispatch bars between them is HEALTHY async
                         # dispatch, not a slow step.
-                        hspan = (
-                            self.spans.start_span(
-                                "train/host_sync",
-                                corr=self._global_step, parent=sspan,
-                            ) if self.spans is not None else None
-                        )
-                        loss = float(metrics["loss"])
-                        if self.spans is not None:
-                            self.spans.end_span(hspan)
+                        with phase_span(
+                            self.spans, "train/host_sync",
+                            corr=self._global_step, parent=sspan,
+                        ):
+                            loss = float(metrics["loss"])
+                        step_s = close_on_sync(step_idx)
                         flush_losses()
                         step_fields["loss"] = loss
-                        step_fields["steps_per_sec"] = timer.steps_per_sec
+                        if step_s is not None:
+                            step_fields["steps_per_sec"] = 1.0 / step_s
                         skipped_delta = None
                         if "skipped_total" in metrics:
                             total_skips = int(metrics["skipped_total"])
@@ -333,20 +382,6 @@ class Trainer:
                                 # host/link hiccup worth an alert).
                                 "dt": step_fields["dt"],
                             })
-                        if (
-                            self.emitter is not None
-                            and self.step_flops and self.peak_flops
-                        ):
-                            # Rolling live MFU: compiled FLOPs over the
-                            # median of recent host step times — the
-                            # same numerator/denominator shape as
-                            # telemetry_report's post-hoc MFU, gauged so
-                            # /metrics can scrape it mid-run.
-                            med = float(np.median(self._recent_dts))
-                            live = mfu(self.step_flops, med,
-                                       self.peak_flops)
-                            if live is not None:
-                                self.emitter.gauge("mfu_live", live)
                         if self.ledger is not None \
                                 and self.emitter is not None:
                             # Live goodput gauges at log cadence (the
@@ -374,8 +409,9 @@ class Trainer:
                             k: float(v) for k, v in metrics.items()
                         }
                     if self.emitter is not None:
-                        # Rolling step-time histogram: the live plane's
-                        # step_time_p* objectives window these samples.
+                        # Rolling histogram of the dispatch interval: the
+                        # live plane's step_time_p* objectives window
+                        # these samples.
                         self.emitter.observe(
                             "step_time_s", step_fields["dt"]
                         )
@@ -456,24 +492,32 @@ class Trainer:
                             heartbeat.beat()
                     if self.spans is not None:
                         self.spans.end_span(sspan)
+                # Fetch the final step's loss to close the timing window:
+                # the donated state chains every step, so this read
+                # completes only after all device work has — which also
+                # lets a capture still open hold the steps it brackets.
+                if examples:
+                    with phase_span(
+                        self.spans, "train/host_sync",
+                        corr=self._global_step - 1,
+                    ):
+                        final_loss = float(metrics["loss"])
+                    close_on_sync(step_idx - 1)
+                    # Dedupe: when the epoch length lands exactly on a log
+                    # point the final loss is already the last logged value
+                    # — appending it again would double-count it in the
+                    # record.
+                    if last_logged_step != step_idx - 1:
+                        losses.append(final_loss)
+                    flush_losses()
         finally:
             self._finalize_profile()
             if self.spans is not None:
                 self.spans.flush()
-        # Fetch the final step's loss to close the timing window: the donated
-        # state chains every step, so this read completes only after all
-        # device work has.
-        if examples:
-            final_loss = float(metrics["loss"])
-            # Dedupe: when the epoch length lands exactly on a log point the
-            # final loss is already the last logged value — appending it
-            # again would double-count it in the record.
-            if last_logged_step != step_idx:
-                losses.append(final_loss)
-            flush_losses()
         if heartbeat is not None:
             heartbeat.beat()  # cover the epoch-end checkpoint/eval window
         elapsed = time.perf_counter() - t0
+        compiled = compile_totals(compile_events(compiled_before))
 
         summary = {
             "epoch": epoch,
@@ -484,9 +528,11 @@ class Trainer:
             "elapsed_s": elapsed,
             "examples": examples,
             "examples_per_sec": examples / elapsed if elapsed > 0 else 0.0,
-            # Rolling dispatch rate over the epoch tail; approaches the
-            # device rate once the async queue saturates (steady state).
-            "rolling_examples_per_sec": timer.examples_per_sec(local_batch),
+            # What JAX compiled (or loaded from its cache) inside this
+            # epoch: > 0 for the first epoch of a new step, 0 after it —
+            # anything else is a recompile (utils/compile_cache.py).
+            "compiles": compiled["compiles"],
+            "compile_s": compiled["compile_s"],
             "loss": losses[-1] if losses else float("nan"),
             **{k: v for k, v in last_metrics.items() if k != "loss"},
         }
@@ -498,7 +544,8 @@ class Trainer:
         if self.emitter is not None:
             self.emitter.phase(
                 "epoch_end", epoch=epoch, examples=examples,
-                elapsed_s=elapsed,
+                elapsed_s=elapsed, compiles=compiled["compiles"],
+                compile_s=compiled["compile_s"],
             )
         return summary
 

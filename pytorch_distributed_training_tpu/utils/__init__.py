@@ -10,14 +10,14 @@ no data races to detect).
 
 from .backoff import BackoffPolicy
 from .metrics import MetricsLogger, RequestLogger
-from .profiling import StepTimer, trace
+from .profiling import trace
 from .seeding import seed_everything
 from .supervisor import (
     BACKOFF_ENV, PREEMPTED_EXIT_CODE, Heartbeat, SupervisorResult, supervise,
 )
 
 __all__ = [
-    "BackoffPolicy", "MetricsLogger", "RequestLogger", "StepTimer", "trace",
+    "BackoffPolicy", "MetricsLogger", "RequestLogger", "trace",
     "seed_everything", "Heartbeat", "SupervisorResult", "supervise",
     "BACKOFF_ENV", "PREEMPTED_EXIT_CODE",
 ]

@@ -1,16 +1,15 @@
-"""Profiling: trace capture and step timing.
+"""Profiling: trace capture.
 
 Upgrades the reference's single ``perf_counter`` pair around the epoch
-(src/main.py:65, 81, 84) to (a) ``jax.profiler`` trace capture — the XLA
+(src/main.py:65, 81, 84) to a ``jax.profiler`` trace capture — the XLA
 timeline showing MXU occupancy and collective overlap, the tool for chasing
-the BASELINE ≥90 % scaling bar — and (b) a rolling per-step timer that
-reports steps/sec and examples/sec without forcing a device sync per step.
+the BASELINE ≥90 % scaling bar.  Step timing is the trainer's: the step
+time each loss fetch closes (train/trainer.py).
 """
 
 from __future__ import annotations
 
 import contextlib
-import time
 
 
 @contextlib.contextmanager
@@ -23,26 +22,3 @@ def trace(log_dir: str):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-class StepTimer:
-    """Rolling wall-clock over the last ``window`` steps."""
-
-    def __init__(self, window: int = 50):
-        self.window = window
-        self._times: list[float] = []
-
-    def tick(self) -> None:
-        self._times.append(time.perf_counter())
-        if len(self._times) > self.window + 1:
-            self._times.pop(0)
-
-    @property
-    def steps_per_sec(self) -> float:
-        if len(self._times) < 2:
-            return 0.0
-        span = self._times[-1] - self._times[0]
-        return (len(self._times) - 1) / span if span > 0 else 0.0
-
-    def examples_per_sec(self, batch_size: int) -> float:
-        return self.steps_per_sec * batch_size

@@ -11,7 +11,7 @@ from click.testing import CliRunner
 from pytorch_distributed_training_tpu.cli.main import main as cli_main
 from pytorch_distributed_training_tpu.models import resnet18
 from pytorch_distributed_training_tpu.train import create_train_state, make_train_step
-from pytorch_distributed_training_tpu.utils import MetricsLogger, StepTimer, seed_everything
+from pytorch_distributed_training_tpu.utils import MetricsLogger, seed_everything
 
 
 def test_cli_smoke_config0(tmp_path):
@@ -183,14 +183,6 @@ def test_metrics_logger_jsonl(tmp_path, capsys):
 
     rec = json.loads(path.read_text().strip())
     assert rec["epoch"] == 0
-
-
-def test_step_timer():
-    t = StepTimer(window=10)
-    for _ in range(5):
-        t.tick()
-    assert t.steps_per_sec > 0
-    assert t.examples_per_sec(32) == t.steps_per_sec * 32
 
 
 def test_seed_everything_returns_key():

@@ -492,7 +492,8 @@ def test_exporter_validator_rejects_unbound_flow():
 # --------------------------------------------------------------------- #
 
 
-def test_trainer_step_spans_and_anatomy(tmp_path):
+def _tiny_trainer(emitter=None, spans=None, *, log_every=2, microbatches=1,
+                  train_step=None, anatomy=None):
     import optax
 
     from pytorch_distributed_training_tpu.comm import MeshConfig, make_mesh
@@ -514,6 +515,19 @@ def test_trainer_step_spans_and_anatomy(tmp_path):
         optax.adam(1e-3), mesh=mesh, rules=DDP_RULES,
         init_kwargs={"train": False},
     )
+    trainer = Trainer(
+        state,
+        train_step or make_train_step(kind="lm", num_microbatches=microbatches),
+        mesh, TrainerConfig(progress=False, log_every=log_every, prefetch=0),
+        emitter=emitter, spans=spans, anatomy=anatomy,
+    )
+    batch = {"tokens": np.random.default_rng(0).integers(
+        0, 64, (8, 8), np.int32
+    )}
+    return trainer, batch
+
+
+def test_trainer_step_spans_and_anatomy(tmp_path):
     emitter = MetricsEmitter(str(tmp_path), rank=0, world=1)
     spans = SpanRecorder(emitter)
     anatomy = {
@@ -521,14 +535,8 @@ def test_trainer_step_spans_and_anatomy(tmp_path):
         "sync_tiers": ["grad_sync/rs_ici", "grad_sync/ar_dcn",
                        "grad_sync/ag_ici"],
     }
-    trainer = Trainer(
-        state, make_train_step(kind="lm"), mesh,
-        TrainerConfig(progress=False, log_every=1, prefetch=0),
-        emitter=emitter, spans=spans, anatomy=anatomy,
-    )
-    batch = {"tokens": np.random.default_rng(0).integers(
-        0, 64, (8, 8), np.int32
-    )}
+    trainer, batch = _tiny_trainer(emitter, spans, log_every=1,
+                                   anatomy=anatomy)
     trainer.run_epoch([batch] * 3, epoch=0)
     spans.close()
     emitter.close()
@@ -545,11 +553,21 @@ def test_trainer_step_spans_and_anatomy(tmp_path):
         assert ev["attrs"]["microbatches"] == 2
         assert ev["attrs"]["sync_tiers"] == anatomy["sync_tiers"]
     # log_every=1: every step's loss fetch is a host_sync child of its
-    # own step span.
-    syncs = spans_by_name["train/host_sync"]
-    assert len(syncs) == 3
+    # own step span, and so is the pull of its batch; the fetch that
+    # closes the epoch comes after the last step span and has no parent.
     step_sids = {e["corr"]: e["sid"] for e in steps}
+    *syncs, closing = spans_by_name["train/host_sync"]
+    assert len(syncs) == 3
     assert all(e["parent"] == step_sids[e["corr"]] for e in syncs)
+    assert "parent" not in closing and closing["corr"] == 2
+    *waits, exhausted = spans_by_name["train/input_wait"]
+    assert [e["corr"] for e in waits] == [0, 1, 2]
+    assert all(e["parent"] == step_sids[e["corr"]] for e in waits)
+    # the pull that found the loader empty: input time, but no step followed
+    assert exhausted["corr"] == 3 and "parent" not in exhausted
+    by_sid = {e["sid"]: e for e in steps}
+    assert all(by_sid[e["parent"]]["t0"] <= e["t0"] and e["t1"] <= by_sid[e["parent"]]["t1"]
+               for e in syncs + waits)
 
 
 # --------------------------------------------------------------------- #
@@ -610,3 +628,219 @@ def test_validate_events_rejects_malformed_spans(tmp_path):
               **bad}
         with pytest.raises(ValueError, match=msg):
             validate_events(meta + [ev])
+
+
+# --------------------------------------------------------------------- #
+# the program's own instruments (ISSUE 26): compile events, kernel names,
+# the loop's host boundaries on the profiler's clock, the step's scopes,
+# the step time a host sync closes
+# --------------------------------------------------------------------- #
+
+
+def test_compile_events_name_the_function_and_the_open_phase():
+    from pytorch_distributed_training_tpu.utils import compile_cache as cc
+
+    cc.enable_compile_cache()       # the session did already: registers once
+
+    @jax.jit
+    def issue26_probe(x):
+        return (x @ x).sum()
+
+    x = jnp.ones((16, 16))          # made outside the phase: its own compiles are not the probe's
+    before = len(cc.compile_events())
+    with cc.compile_phase("train/epoch", epoch=7):
+        issue26_probe(x).block_until_ready()
+    mine = [e for e in cc.compile_events(before)
+            if e["fun_name"] in ("issue26_probe", "jit(issue26_probe)")]
+    assert [e["what"] for e in mine] == ["trace", "lower", "backend_compile"]
+    assert all(e["phase"] == "train/epoch" and e["epoch"] == 7 for e in mine)
+    assert all(e["seconds"] > 0 and e["t_end"] > 0 for e in mine)
+    totals = cc.compile_totals(mine)
+    assert totals["compiles"] == 1
+    # the three spans follow one another, so their union is their sum
+    assert totals["compile_s"] == pytest.approx(sum(e["seconds"] for e in mine), rel=1e-6)
+    # a second call compiles nothing, and a record made outside any phase says so
+    after = len(cc.compile_events())
+    issue26_probe(x).block_until_ready()
+    assert cc.compile_events(after) == []
+    with pytest.raises(ValueError):
+        with cc.compile_phase("train/typo"):
+            pass
+
+
+def test_compile_totals_take_the_union_of_nested_spans():
+    from pytorch_distributed_training_tpu.utils.compile_cache import (
+        compile_totals,
+    )
+
+    def ev(what, t0, t1):
+        return {"what": what, "fun_name": None, "seconds": t1 - t0,
+                "t_end": t1, "phase": None}
+
+    events = [ev("trace", 0.0, 10.0), ev("trace", 2.0, 5.0),     # an inner function's trace
+              ev("lower", 10.0, 11.0), ev("backend_compile", 12.0, 15.0),
+              ev("cache_retrieval", 12.5, 13.0), ev("cache_hit", 13.0, 13.0),
+              ev("cache_miss", 14.0, 14.0), ev("cache_miss", 14.0, 14.0)]
+    assert compile_totals(events) == {
+        "compiles": 1, "compile_s": 14.0, "cache_hits": 1, "cache_misses": 2,
+    }
+
+
+def test_run_epoch_counts_its_own_compiles():
+    trainer, batch = _tiny_trainer()
+    first = trainer.run_epoch([batch] * 2, epoch=0)
+    second = trainer.run_epoch([batch] * 2, epoch=1)
+    assert first["compiles"] > 0 and first["compile_s"] > 0
+    assert second["compiles"] == 0 and second["compile_s"] == 0.0
+    assert "rolling_examples_per_sec" not in first
+
+
+def _pallas_call_names(tree):
+    """``(lineno, name node)`` of every ``pl.pallas_call(...)`` and of every
+    call of a launcher that takes the kernel's name as an argument."""
+    import ast
+
+    for node in ast.walk(tree):
+        if not isinstance(node, ast.Call):
+            continue
+        f = node.func
+        is_pallas = isinstance(f, ast.Attribute) and f.attr == "pallas_call"
+        is_launcher = isinstance(f, ast.Name) and f.id == "_paged_multi_call"
+        if is_pallas or is_launcher:
+            named = [k.value for k in node.keywords if k.arg == "name"]
+            yield node.lineno, is_pallas, (named[0] if named else None)
+
+
+def test_every_pallas_call_has_a_role_name():
+    import ast
+    import inspect
+
+    from pytorch_distributed_training_tpu.ops import pallas_attention as pa
+
+    tree = ast.parse(inspect.getsource(pa))
+    sites = list(_pallas_call_names(tree))
+    assert sum(is_pallas for _, is_pallas, _ in sites) == 13
+    seen = set()
+    for lineno, is_pallas, name in sites:
+        assert name is not None, f"pallas_attention.py:{lineno}: no name="
+        if isinstance(name, ast.Constant):
+            assert name.value in pa.KERNEL_NAMES, (lineno, name.value)
+            seen.add(name.value)
+        else:
+            # only the shared paged launcher forwards its caller's name
+            assert is_pallas and isinstance(name, ast.Name) and name.id == "name", lineno
+    assert seen == set(pa.KERNEL_NAMES)      # no name in the tuple that no call uses
+
+
+def test_flash_kernels_lower_under_their_names():
+    from pytorch_distributed_training_tpu.ops import pallas_attention as pa
+
+    q = jnp.ones((1, 256, 2, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return jnp.sum(pa.flash_attention(q, k, v, causal=True) ** 2)
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, q, q).as_text(debug_info=True)
+    assert "flash_fwd" in text and "flash_bwd" in text
+
+
+def test_traced_epoch_puts_the_loops_boundaries_on_the_profilers_clock(tmp_path):
+    """A capture of a tiny epoch on the CPU: the host plane holds the step
+    marker, the batch pulls and the loss fetches under their names."""
+    from benchmark import tracered
+
+    trainer, batch = _tiny_trainer(log_every=1)
+    trainer.run_epoch([batch] * 2, epoch=0)          # compile outside the capture
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        trainer.run_epoch([batch] * 3, epoch=1)
+    finally:
+        jax.profiler.stop_trace()
+    host = tracered.host_events(tracered.load_events(str(tmp_path)))
+    count = {n: sum(e[0] == n for e in host)
+             for n in ("train", "train/input_wait", "train/host_sync")}
+    assert count["train"] == 3
+    assert count["train/input_wait"] == 4        # three batches and the pull that found none
+    assert count["train/host_sync"] == 4         # three log points and the closing fetch
+
+
+def test_compiled_step_names_its_phases():
+    trainer, batch = _tiny_trainer(microbatches=2)
+    with trainer.mesh:
+        text = trainer.train_step.lower(trainer.state, batch).compile().as_text()
+    import re
+
+    op_names = re.findall(r'op_name="([^"]+)"', text)
+    for phase in ("train/loss", "train/optimizer", "grad_accum/microbatch"):
+        assert any(phase in name for name in op_names), phase
+    # the loss runs inside a microbatch; the optimizer after all of them
+    assert any("grad_accum/microbatch" in n and "train/loss" in n for n in op_names)
+    assert not any("grad_accum/microbatch" in n and "train/optimizer" in n for n in op_names)
+
+
+def test_mfu_live_is_the_step_time_a_sync_closes(tmp_path):
+    """A stub step that takes 30 ms on the host: dispatch and "device" are
+    one here, so the sync-closed step time is 30 ms; before the second
+    sync there is no value."""
+    sleep_s, flops, peak = 0.03, 3.0e9, 1.0e12
+
+    def stub_step(state, batch):
+        time.sleep(sleep_s)
+        return state, {"loss": np.float32(1.0)}
+
+    emitter = MetricsEmitter(str(tmp_path), rank=0, world=1)
+    trainer, batch = _tiny_trainer(emitter, log_every=2, train_step=stub_step)
+    trainer.step_flops, trainer.peak_flops = flops, peak
+    trainer.run_epoch([batch], epoch=0)              # one sync at step 0, the closing one on the same step
+    assert "mfu_live" not in emitter._gauges
+    trainer.run_epoch([batch] * 5, epoch=1)          # syncs at 0, 2, 4: two closed intervals of two steps
+    live = emitter._gauges["mfu_live"]
+    assert live == pytest.approx(flops / sleep_s / peak, rel=0.25)
+    assert live < flops / sleep_s / peak             # a step cannot be closed faster than it ran
+    emitter.close()
+    steps = [e for e in read_events(emitter.path) if e["kind"] == "step"]
+    assert [("steps_per_sec" in e) for e in steps] == [False, False, False, True, False, True]
+    assert all(e["steps_per_sec"] < 1 / sleep_s for e in steps if "steps_per_sec" in e)
+    assert all("dt" in e for e in steps)             # the dispatch interval stays what it was
+
+
+def _vocabulary(name):
+    from pytorch_distributed_training_tpu.obs import trace as obs_trace, spans as obs_spans
+    from pytorch_distributed_training_tpu.ops import pallas_attention
+    from pytorch_distributed_training_tpu.utils import compile_cache
+
+    return {"PHASES": obs_trace.PHASES, "SPAN_NAMES": obs_spans.SPAN_NAMES,
+            "COMPILE_PHASES": compile_cache.COMPILE_PHASES,
+            "KERNEL_NAMES": pallas_attention.KERNEL_NAMES}[name]
+
+
+@pytest.mark.parametrize("name, members", [
+    # Renaming an xprof phase, a span, a compile phase or a kernel
+    # invalidates saved traces, the README tables and the benchmark's
+    # readers; make it a deliberate act.
+    ("PHASES", {
+        "train/step", "train/input_wait", "train/host_sync", "train/loss",
+        "train/optimizer", "grad_accum/microbatch",
+        "grad_sync/rs_ici", "grad_sync/ar_dcn", "grad_sync/ag_ici",
+        "grad_sync/stripe",
+        "pipeline/tick", "serve/prefill", "serve/decode", "serve/verify",
+    }),
+    ("SPAN_NAMES", {
+        "serve/request", "request/queued", "request/prefill",
+        "request/decode", "router/route",
+        "serve/prefill", "serve/decode", "serve/verify",
+        "train/step", "train/input_wait", "train/host_sync",
+        "train/snapshot", "train/checkpoint",
+    }),
+    ("COMPILE_PHASES", {
+        "startup/state", "startup/restore", "startup/step", "train/epoch",
+    }),
+    ("KERNEL_NAMES", {
+        "flash_fwd", "flash_bwd", "flash_bwd_dq", "flash_bwd_dkv",
+        "decode_attn", "decode_multi_attn", "paged_decode_attn",
+        "paged_verify_attn", "paged_prefill_attn",
+    }),
+])
+def test_vocabulary_is_stable(name, members):
+    vocabulary = _vocabulary(name)
+    assert set(vocabulary) == members and len(vocabulary) == len(members)

@@ -156,7 +156,7 @@ def main():
             "bits_per_byte": round(bits_per_byte, 4),
             "bytes_per_token": round(bytes_per_token, 3),
             "tokens_per_sec_during_run": round(
-                g_train[-1]["rolling_examples_per_sec"] * 1024, 0
+                g_train[-1]["examples_per_sec"] * 1024, 0
             ),
             "val_loss_curve": [round(r["eval_loss"], 4) for r in g_eval],
             "metrics_jsonl": "convergence/gpt2.jsonl",

@@ -5,8 +5,11 @@ dispatch to, on the backend this process gets, at GPT-2 124M width
 
 The interpret-mode tests pin the kernels' arithmetic; only a compiled
 run says whether Mosaic accepts them.  One JSON line per case:
-``{"case", "ok", "max_err" | "error"}``; the full report (with whole
-error texts) goes to ``--out``.  Exit code 1 if any case failed.
+``{"case", "ok", "kernels", "max_err" | "error"}`` — ``kernels`` counts the
+Mosaic calls of the case's compiled program by the role name the
+``pallas_call`` carries (``ops.pallas_attention.KERNEL_NAMES``; empty under
+the interpreter) — and the full report (with whole error texts) goes to
+``--out``.  Exit code 1 if any case failed.
 
     python tools/serve_kernel_check.py --out chiprun_out/kernels.json
     JAX_PLATFORMS=cpu python tools/serve_kernel_check.py --tiny   # interpreter
@@ -31,8 +34,15 @@ def _cases(tiny: bool):
     from pytorch_distributed_training_tpu.comm.compress import (
         dequantize_kv, quantize_kv,
     )
+    from pytorch_distributed_training_tpu.obs.cost import mosaic_kernels
     from pytorch_distributed_training_tpu.ops import pallas_attention as pa
     from pytorch_distributed_training_tpu.ops.attention import _xla_attention
+
+    def compiled_run(fn, *args):
+        """``fn(*args)`` through one compile, and what Mosaic kernels it
+        holds, by name."""
+        compiled = jax.jit(fn).lower(*args).compile()
+        return compiled(*args), mosaic_kernels(compiled.as_text())
 
     if tiny:
         b, h, dh, bs, nb, seq = 3, 2, 8, 4, 32, 128
@@ -90,15 +100,15 @@ def _cases(tiny: bool):
                 k_ref = dequantize_kv(k_in, ks, quant)
                 v_ref = dequantize_kv(v_in, vs, quant)
             q_in = q[:, 0] if fn is pa.paged_decode_attention else q
-            out = jax.jit(lambda *a: fn(*a, **kw))(
-                q_in, k_in, v_in, table, index
+            out, kernels = compiled_run(
+                lambda *a: fn(*a, **kw), q_in, k_in, v_in, table, index
             )
             if out.ndim == 3:
                 out = out[:, None]
             ref = reference(
                 q, through_table(k_ref), through_table(v_ref), index
             )
-            return out, ref
+            return out, ref, kernels
         return run
 
     def contiguous(fn, c):
@@ -107,10 +117,10 @@ def _cases(tiny: bool):
             q = rand(b, c, h, dh)
             kk, vv = rand(b, h, max_len, dh), rand(b, h, max_len, dh)
             q_in = q[:, 0] if fn is pa.decode_attention else q
-            out = jax.jit(fn)(q_in, kk, vv, index)
+            out, kernels = compiled_run(fn, q_in, kk, vv, index)
             if out.ndim == 3:
                 out = out[:, None]
-            return out, reference(q, kk, vv, index)
+            return out, reference(q, kk, vv, index), kernels
         return run
 
     def flash(grad):
@@ -123,20 +133,21 @@ def _cases(tiny: bool):
                 )
 
             if grad:
-                out = jax.jit(jax.grad(loss(pa.flash_attention), (0, 1, 2)))(
-                    q, k, v
+                out, kernels = compiled_run(
+                    jax.grad(loss(pa.flash_attention), (0, 1, 2)), q, k, v
                 )
                 ref = jax.jit(jax.grad(loss(_xla_attention), (0, 1, 2)))(
                     *(x.astype(jnp.float32) for x in (q, k, v))
                 )
-                return jnp.concatenate(out), jnp.concatenate(ref)
-            out = jax.jit(
-                lambda q, k, v: pa.flash_attention(q, k, v, causal=True)
-            )(q, k, v)
+                return jnp.concatenate(out), jnp.concatenate(ref), kernels
+            out, kernels = compiled_run(
+                lambda q, k, v: pa.flash_attention(q, k, v, causal=True),
+                q, k, v,
+            )
             ref = _xla_attention(
                 *(x.astype(jnp.float32) for x in (q, k, v)), causal=True
             )
-            return out, ref
+            return out, ref, kernels
         return run
 
     spec_c = 5  # --serve-spec-k 4 verifies k+1 positions per slot
@@ -190,7 +201,7 @@ def main() -> int:
             continue
         entry = {"case": name}
         try:
-            out, ref = run()
+            out, ref, entry["kernels"] = run()
             out = np.asarray(out, np.float32)
             ref = np.asarray(ref, np.float32)
             err = float(np.max(np.abs(out - ref)) / np.max(np.abs(ref)))
