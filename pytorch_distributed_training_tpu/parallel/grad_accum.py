@@ -4,29 +4,68 @@ Absent from the reference (its loop at src/main.py:68-79 steps the optimizer
 every batch) but required by BASELINE.json configs[3] (GPT-2 + gradient
 accumulation).  The torch idiom — N forward/backwards before one
 ``optimizer.step()`` — becomes a single jitted scan: the microbatch loop is
-*inside* the compiled step, so XLA keeps gradients in registers/VMEM between
-microbatches and the optimizer update fuses onto the final accumulation.
+*inside* the compiled step, the gradient sums are f32 arrays in HBM that
+the loop carries from one microbatch to the next, and the optimizer update
+follows the last microbatch in the same program.
+
+Which rows make a microbatch: under a mesh the batch is sharded on its
+leading axis over ``BATCH_AXES`` (``parallel/sharding.py::batch_sharding``),
+each chip holding one contiguous block of rows.  Microbatch *i* is then the
+*i*-th slice of EVERY chip's own block — DDP's ``no_sync`` accumulation,
+every rank over microbatches of its own rows — so the split moves nothing
+between chips and the scanned microbatch keeps the batch sharding.
+(Contiguous microbatches ``i*m … (i+1)*m - 1`` would each live on a few of
+the chips, and GSPMD re-gathers activations inside the loop to spread
+them.)  With no mesh, one batch shard, or inside a ``shard_map`` body (the
+batch is one device's rows already) microbatches are contiguous.  The
+step's mean is over the same samples either way.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 
+from ..comm.mesh import BATCH_AXES
+from ..compat import ambient_mesh
 from ..obs.trace import scope
 
 
+def _batch_shards() -> int:
+    """How many contiguous blocks of rows the batch's leading axis is cut
+    into where this is traced: the ambient mesh's ``BATCH_AXES`` sizes,
+    or 1 with no mesh and inside a ``shard_map`` body."""
+    mesh, manual = ambient_mesh()
+    if mesh is None or manual:
+        return 1
+    # .get: an ambient mesh need not be one of ours with all six axes.
+    return math.prod(mesh.shape.get(a, 1) for a in BATCH_AXES)
+
+
 def _split_microbatches(batch: Any, num_microbatches: int) -> Any:
-    """(N*m, ...) leaves → (num_microbatches, m, ...) leaves."""
+    """(N*m, ...) leaves → (num_microbatches, m, ...) leaves.
+
+    Microbatch *i* takes the *i*-th ``m / ways`` rows of each of the
+    ``ways`` batch shards (module docstring); where the shards do not
+    divide ``m``, or ``ways`` is 1, rows ``i*m … (i+1)*m - 1``."""
+    ways = _batch_shards()
+
     def split(x):
         if x.shape[0] % num_microbatches != 0:
             raise ValueError(
                 f"batch dim {x.shape[0]} not divisible by "
                 f"num_microbatches={num_microbatches}"
             )
-        return x.reshape(num_microbatches, x.shape[0] // num_microbatches, *x.shape[1:])
+        m, rest = x.shape[0] // num_microbatches, x.shape[1:]
+        if ways > 1 and m % ways == 0:
+            # Every step keeps the leading axis' ways-fold sharding, so
+            # each chip only re-indexes its own rows.
+            x = x.reshape(ways, num_microbatches, m // ways, *rest)
+            x = x.swapaxes(0, 1)
+        return x.reshape(num_microbatches, m, *rest)
     return jax.tree_util.tree_map(split, batch)
 
 

@@ -135,6 +135,146 @@ def test_grad_accum_with_aux():
     np.testing.assert_allclose(grads["w"], grads_ref["w"], rtol=1e-6)
 
 
+def _microbatch_rows(batch, num_microbatches):
+    """``rows[i]`` = the row ids microbatch *i* held: the aux puts them in
+    slot *i*, scaled so that ``accumulate_gradients``' mean over
+    microbatches returns them as they are."""
+    def loss_fn(p, b, i):
+        slot = jax.nn.one_hot(i, num_microbatches)[:, None]
+        return p["w"] * jnp.mean(b["id"]), slot * b["id"][None, :] * num_microbatches
+
+    (_, rows), _ = accumulate_gradients(
+        loss_fn, {"w": jnp.ones(())}, batch, num_microbatches,
+        has_aux=True, pass_microbatch_index=True,
+    )
+    return rows
+
+
+def test_grad_accum_microbatch_is_a_slice_of_every_shards_rows(devices8):
+    """Under a mesh microbatch i takes the i-th 8 rows of each chip's 16
+    (DDP's no_sync accumulation: every rank over its own rows), so the
+    split moves nothing between chips."""
+    mesh = make_mesh(MeshConfig(data=4), devices=devices8[:4])
+    ids = np.arange(64, dtype=np.float32)
+    with mesh:
+        rows = jax.jit(_microbatch_rows, static_argnums=1)(
+            shard_batch({"id": ids}, mesh), 2
+        )
+    expect = np.stack([
+        np.concatenate([16 * d + 8 * i + np.arange(8) for d in range(4)])
+        for i in range(2)
+    ])
+    np.testing.assert_array_equal(np.asarray(rows), expect)
+
+
+def test_grad_accum_microbatch_is_contiguous_without_batch_shards(devices8):
+    """No mesh: rows i*m … (i+1)*m - 1.  Inside a shard_map the batch is
+    one device's rows already, and the same holds of those."""
+    ids = np.arange(64, dtype=np.float32)
+    rows = _microbatch_rows({"id": jnp.asarray(ids)}, 2)
+    np.testing.assert_array_equal(np.asarray(rows), ids.reshape(2, 32))
+
+    mesh = make_mesh(MeshConfig(data=4), devices=devices8[:4])
+    batch_spec = P(("data", "fsdp"))
+    with mesh:
+        local = jax.jit(jax.shard_map(
+            lambda b: _microbatch_rows(b, 2), mesh=mesh,
+            in_specs=({"id": batch_spec},), out_specs=batch_spec,
+            check_vma=False,
+        ))(shard_batch({"id": ids}, mesh))
+    # device d returns (2, 8): its rows 16d + 8i … 16d + 8i + 7
+    np.testing.assert_array_equal(np.asarray(local), ids.reshape(8, 8))
+
+
+@pytest.mark.parametrize(
+    "has_aux,pass_index", [(False, False), (True, False), (True, True)]
+)
+def test_grad_accum_under_mesh_matches_full_batch(devices8, has_aux, pass_index):
+    """Which rows share a microbatch changes under a mesh; the step's mean
+    over the batch does not."""
+    mesh = make_mesh(MeshConfig(data=4), devices=devices8[:4])
+    rng = np.random.default_rng(0)
+    params = {"w": jnp.asarray(rng.normal(size=(5,)), jnp.float32)}
+    batch = {"x": rng.normal(size=(64, 5)).astype(np.float32),
+             "y": rng.normal(size=(64,)).astype(np.float32)}
+
+    def loss_fn(p, b, *index):
+        pred = b["x"] @ p["w"]
+        loss = jnp.mean((pred - b["y"]) ** 2)
+        return (loss, {"pred_mean": jnp.mean(pred)}) if has_aux else loss
+
+    ref, grads_ref = jax.value_and_grad(loss_fn, has_aux=has_aux)(params, batch)
+    with mesh:
+        out, grads = jax.jit(
+            lambda p, b: accumulate_gradients(
+                loss_fn, p, b, 4, has_aux=has_aux,
+                pass_microbatch_index=pass_index,
+            )
+        )(params, shard_batch(batch, mesh))
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-6),
+        (out, grads), (ref, grads_ref),
+    )
+
+
+def _tiny_gpt2_step(mesh, rows):
+    """The flat ``make_train_step`` for a small GPT-2 with 2 microbatches,
+    compiled under ``mesh`` for a ``(rows, 128)`` batch: ``(compiled,
+    state, batch)``."""
+    import optax
+
+    from pytorch_distributed_training_tpu.models.gpt2 import GPT2, GPT2Config
+    from pytorch_distributed_training_tpu.train import (
+        create_train_state, make_train_step,
+    )
+
+    cfg = GPT2Config(vocab_size=256, max_seq_len=128, num_layers=2,
+                     num_heads=2, hidden_dim=64)
+    state = create_train_state(
+        GPT2(cfg=cfg), jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32),
+        optax.adam(1e-3), mesh=mesh, rules=DDP_RULES,
+        init_kwargs={"train": False},
+    )
+    tokens = np.random.default_rng(0).integers(0, 256, (rows, 128), np.int32)
+    batch = shard_batch({"tokens": tokens}, mesh)
+    step = make_train_step(kind="lm", num_microbatches=2)
+    with mesh:
+        return step.lower(state, batch).compile(), state, batch
+
+
+@pytest.mark.parametrize("mesh_cfg,rows,shard_local", [
+    pytest.param(dict(data=4), 16, True, id="data4"),
+    pytest.param(dict(data=2, fsdp=2), 16, True, id="data2-fsdp2"),
+    # 6 rows a microbatch over 4 shards: the contiguous split, as ever
+    pytest.param(dict(data=4), 12, False, id="microbatch-not-divided"),
+])
+def test_accumulating_step_computes_on_each_chips_own_rows(
+    devices8, mesh_cfg, rows, shard_local
+):
+    """Reads the compiled step, not only its result: a split that cuts
+    across the batch sharding still trains, on twice the FLOPs and with
+    the microbatch gathered inside the loop (params replicated here, so
+    any all-gather would be of the batch or of activations)."""
+    from pytorch_distributed_training_tpu.obs.cost import (
+        collective_census, compiled_cost,
+    )
+
+    mesh = make_mesh(MeshConfig(**mesh_cfg), devices=devices8[:4])
+    compiled, state, batch = _tiny_gpt2_step(mesh, rows)
+    if not shard_local:
+        with mesh:
+            _, metrics = compiled(state, batch)
+        assert np.isfinite(float(metrics["loss"]))
+        return
+    census = collective_census(compiled.as_text())
+    assert not {"all-gather", "collective-permute", "all-to-all"} & set(census), census
+    one = make_mesh(MeshConfig(data=1), devices=devices8[:1])
+    quarter, _, _ = _tiny_gpt2_step(one, rows // 4)
+    assert compiled_cost(compiled)["flops"] == pytest.approx(
+        compiled_cost(quarter)["flops"], rel=0.05
+    )
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_matches_full(devices8, causal):
     mesh = make_mesh(MeshConfig(data=1, sequence=8))
