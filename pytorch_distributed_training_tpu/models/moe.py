@@ -1,16 +1,24 @@
-"""Mixture-of-Experts layers with expert parallelism over the ``expert`` axis.
+"""Mixture-of-Experts layers: two routers, one file.
 
 Absent from the reference (SURVEY.md §2c "EP" row) — provided because the
 mesh reserves an ``expert`` axis and a complete framework fills it.
-Switch-Transformer-style top-1 routing (Fedus et al. 2021) in the
-GShard einsum formulation: tokens are one-hot dispatched into per-expert
-capacity-bounded buffers, experts run as one batched einsum over a leading
-expert axis (shardable over the mesh — GSPMD turns the dispatch/combine
-einsums into all-to-alls when experts are distributed), and outputs combine
-weighted by the router probability.
 
-Everything is static-shaped (capacity bounds, one-hot masks) — no
-data-dependent gathers, so the whole layer jits cleanly on TPU.
+- :class:`MoeMlp` / :class:`MoeBlock` (``gpt2_moe``): Switch-Transformer
+  top-1 routing (Fedus et al. 2021) into capacity-bounded buffers, tokens
+  over capacity DROPPED.  Two formulations of the same selection: GShard
+  one-hot einsums (experts on a leading axis that shards over the mesh's
+  ``expert`` axis — GSPMD turns dispatch/combine into all-to-alls) and a
+  row scatter/gather for experts that are not mesh-sharded.  Static shapes
+  throughout.
+- :class:`TopKMoe` (``sdar_moe``): top-k routing over the published router
+  width with normalised weights and NO capacity: every assignment to an
+  expert this chip holds is computed.  The layer is told which contiguous
+  range of experts it holds (``experts_held``), routes over all of them,
+  and returns its own experts' part of the result — what expert
+  parallelism asks of a chip before the exchange, and nothing standing in
+  for the absent chips.  Assignments are sorted by expert and multiplied
+  as grouped matrix products (``lax.ragged_dot``: on a TPU XLA's own
+  Mosaic grouped matmul, which runs only the row tiles that hold rows).
 """
 
 from __future__ import annotations
@@ -22,6 +30,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 from jax.sharding import PartitionSpec as P
+
+from ..obs.trace import scope
 
 
 def _constrain_for_ep(x: jax.Array, spec: P) -> jax.Array:
@@ -253,3 +263,104 @@ class MoeBlock(nn.Module):
         )(y)
         y = nn.Dropout(self.dropout_rate)(y, deterministic=deterministic)
         return x + y
+
+
+def topk_route(logits: jax.Array, k: int, norm_topk_prob: bool = True):
+    """Router math of the top-k layer.  logits: (T, E) → (weights (T, k)
+    f32, expert ids (T, k) int32): softmax in f32 over ALL E outputs, the k
+    largest, renormalised to sum to one when ``norm_topk_prob``."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, experts = lax.top_k(probs, k)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts.astype(jnp.int32)
+
+
+def group_held_assignments(experts: jax.Array, first: int, held: int, rows: int):
+    """Sort the (T, k) assignments by expert and keep those on the held
+    range ``first .. first + held - 1``.
+
+    Returns ``(slot, group_sizes, n_held)``: ``slot`` (rows,) indexes the
+    flattened (T·k) assignments, held ones first, grouped by expert in
+    ascending order (stable: by token inside a group); ``group_sizes``
+    (held,) int32 rows per held expert, clipped so they sum to at most
+    ``rows``; ``n_held`` the unclipped number of held assignments (so
+    ``max(n_held - rows, 0)`` assignments did not fit the static bound)."""
+    local = experts.reshape(-1) - first
+    is_held = (local >= 0) & (local < held)
+    group = jnp.where(is_held, local, held)       # absent experts sort last
+    slot = jnp.argsort(group, stable=True)[:rows]
+    counts = jnp.sum(
+        group[:, None] == jnp.arange(held, dtype=group.dtype)[None, :],
+        axis=0, dtype=jnp.int32,
+    )
+    ends = jnp.minimum(jnp.cumsum(counts), rows)
+    sizes = jnp.diff(ends, prepend=jnp.zeros((1,), ends.dtype))
+    return slot, sizes.astype(jnp.int32), jnp.sum(counts)
+
+
+class TopKMoe(nn.Module):
+    """SiLU-gated experts under a dropless top-k router: (B, L, D) →
+    (B, L, D), ``Σ_{e ∈ top-k ∩ held} w_e · (silu(x·Wgate_e) ⊙ (x·Wup_e))·Wdown_e``.
+
+    ``experts_held = (first, count)`` is this chip's share of the
+    ``num_experts`` the router scores (None: all of them).  Only the held
+    experts have weights here.  ``rows_factor`` bounds the rows of the
+    grouped products at that multiple of the expected ``T·k·count/E``
+    (None: ``T·k``, every assignment could land here and nothing can
+    overflow); held assignments past the bound are NOT computed and are
+    counted in ``moe_overflow``, which a run must read 0 to be right.
+
+    Sown into ``moe_counters`` (one scalar a layer, f32): held assignments,
+    the busiest held expert's rows, the overflow.
+    """
+
+    num_experts: int
+    num_experts_per_tok: int
+    mlp_dim: int
+    experts_held: tuple | None = None
+    norm_topk_prob: bool = True
+    rows_factor: float | None = None
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        b, l, d = x.shape
+        t, k, e = b * l, self.num_experts_per_tok, self.num_experts
+        first, held = self.experts_held or (0, e)
+        if first < 0 or held < 1 or first + held > e:
+            raise ValueError(f"experts_held {self.experts_held} outside 0..{e}")
+        rows = t * k
+        if self.rows_factor is not None and held < e:
+            rows = min(rows, -(-int(self.rows_factor * t * k * held / e) // 8) * 8)
+        tokens = x.reshape(t, d)
+
+        init = nn.initializers.normal(stddev=0.02)
+        router = self.param("router", init, (d, e), jnp.float32)
+        w_gate = self.param("w_gate", init, (held, d, self.mlp_dim), jnp.float32)
+        w_up = self.param("w_up", init, (held, d, self.mlp_dim), jnp.float32)
+        w_down = self.param("w_down", init, (held, self.mlp_dim, d), jnp.float32)
+
+        with scope("moe/route"):
+            logits = jnp.dot(
+                tokens.astype(self.dtype), router.astype(self.dtype),
+                preferred_element_type=jnp.float32,
+            )
+            weights, experts = topk_route(logits, k, self.norm_topk_prob)
+            slot, sizes, n_held = group_held_assignments(experts, first, held, rows)
+            live = jnp.arange(rows) < n_held          # rows past it hold no assignment
+            token_of = slot // k
+        self.sow("moe_counters", "moe_held_assignments", n_held.astype(jnp.float32))
+        self.sow("moe_counters", "moe_load_max", jnp.max(sizes).astype(jnp.float32))
+        self.sow("moe_counters", "moe_overflow",
+                 jnp.maximum(n_held - rows, 0).astype(jnp.float32))
+
+        with scope("moe/experts"):
+            grouped = lambda lhs, w: lax.ragged_dot(lhs, w.astype(self.dtype), sizes)
+            x_rows = tokens.astype(self.dtype)[token_of]
+            h = nn.silu(grouped(x_rows, w_gate)) * grouped(x_rows, w_up)
+            y_rows = grouped(h, w_down)
+            y_rows = y_rows * weights.reshape(-1)[slot].astype(self.dtype)[:, None]
+            y_rows = jnp.where(live[:, None], y_rows, 0)
+            out = jnp.zeros((t, d), self.dtype).at[token_of].add(y_rows)
+        return out.reshape(b, l, d).astype(x.dtype)

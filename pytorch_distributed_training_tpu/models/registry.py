@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from .resnet import resnet18, resnet34, resnet50, resnet101, resnet152
 from .vit import vit_b16, vit_l16, vit_s16
 from .gpt2 import gpt2_124m, gpt2_large, gpt2_medium, gpt2_xl
+from .sdar import sdar_30b_a3b
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,6 +45,10 @@ MODEL_REGISTRY: dict[str, ModelEntry] = {
     "gpt2_large": ModelEntry(gpt2_large, "lm"),
     "gpt2_xl": ModelEntry(gpt2_xl, "lm"),
     "gpt2_moe": ModelEntry(_gpt2_moe, "lm"),
+    # An "lm" whose module says it trains by block diffusion
+    # (``SdarMoe.lm_objective``, read by train/step.py): same batches, same
+    # step, another loss.
+    "sdar_30b_a3b": ModelEntry(sdar_30b_a3b, "lm"),
 }
 
 

@@ -121,6 +121,41 @@ def _xla_attention(
     return out
 
 
+def block_diffusion_mask(seq_len: int, block_len: int) -> jax.Array:
+    """The dense (2L, 2L) boolean block-diffusion mask, from its definition:
+    positions 0 .. L-1 are the noised copy, L .. 2L-1 the clean copy, and
+    position j of either half lies in block j // B.  A noisy query sees the
+    noisy keys of its own block and the clean keys of earlier blocks; a
+    clean query sees the clean keys of its own and earlier blocks."""
+    pos = jnp.arange(2 * seq_len)
+    noisy = pos < seq_len
+    blk = (pos % seq_len) // block_len
+    qn, kn = noisy[:, None], noisy[None, :]
+    qb, kb = blk[:, None], blk[None, :]
+    return jnp.where(
+        qn, jnp.where(kn, qb == kb, kb < qb), ~kn & (kb <= qb)
+    )
+
+
+def _xla_masked_attention(q, k, v, mask, *, scale=None):
+    """Pure-XLA attention under a dense (Lq, Lk) boolean ``mask`` with
+    grouped K/V heads: q (B, Lq, H, D), k/v (B, Lk, Hkv, D), H = G * Hkv.
+    Scores accumulate and the softmax runs in f32; K and V are indexed per
+    group, never repeated.  The CPU and short-length path of the mask kinds
+    the causal path above does not know."""
+    b, q_len, h, d = q.shape
+    hkv = k.shape[2]
+    scale = scale if scale is not None else d**-0.5
+    qg = q.reshape(b, q_len, hkv, h // hkv, d)
+    logits = jnp.einsum(
+        "bqngd,bknd->bngqk", qg, k, preferred_element_type=jnp.float32
+    ) * scale
+    logits = jnp.where(mask[None, None, None], logits, jnp.finfo(jnp.float32).min)
+    weights = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bngqk,bknd->bqngd", weights.astype(v.dtype), v)
+    return out.reshape(b, q_len, h, d)
+
+
 def _xla_attention_remat(q, k, v, *, causal=False, scale=None):
     """XLA attention with rematerialized internals: only q/k/v are saved
     for the backward, which recomputes the (B, H, L, L) logits/softmax
@@ -144,6 +179,7 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,
+    block_diffusion: tuple[int, int] | None = None,
 ) -> jax.Array:
     """Blockwise (flash) attention via the Pallas TPU kernel.
 
@@ -177,12 +213,18 @@ def flash_attention(
         or bool(interpret)
     )
     if not backend_ok:
+        if block_diffusion is not None or k.shape[2] != q.shape[2]:
+            return _xla_masked_attention(
+                q, k, v, _dense_mask(q, k, causal, block_diffusion), scale=scale
+            )
         return _xla_attention(q, k, v, causal=causal, scale=scale)
     kernel = functools.partial(
         pallas_attention.flash_attention, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
+        **({} if block_diffusion is None else {"block_diffusion": block_diffusion}),
     )
-    partition = _kernel_partition(q.shape[0], q.shape[2])
+    # heads split over ``tensor`` only where the K/V heads divide too
+    partition = _kernel_partition(q.shape[0], math.gcd(q.shape[2], k.shape[2]))
     if partition is None:
         return kernel(q, k, v)
     mesh, spec = partition
@@ -286,6 +328,16 @@ def flash_preferred(
     return size_ok
 
 
+def _dense_mask(q, k, causal, block_diffusion):
+    """The (Lq, Lk) boolean mask of the XLA path for a mask kind."""
+    if block_diffusion is not None:
+        return block_diffusion_mask(*block_diffusion)
+    q_len, k_len = q.shape[1], k.shape[1]
+    if causal:
+        return jnp.tril(jnp.ones((q_len, k_len), dtype=bool), k=k_len - q_len)
+    return jnp.ones((q_len, k_len), dtype=bool)
+
+
 def dot_product_attention(
     q: jax.Array,
     k: jax.Array,
@@ -294,12 +346,57 @@ def dot_product_attention(
     causal: bool = False,
     scale: float | None = None,
     use_flash: bool | None = None,
+    num_kv_heads: int | None = None,
+    mask: str | None = None,
+    block_diffusion: tuple[int, int] | None = None,
 ) -> jax.Array:
-    """Public attention entry point. q/k/v: (B, L, H, D) → (B, L, H, D).
+    """Public attention entry point. q: (B, L, H, D), k/v: (B, L, Hkv, D)
+    → (B, L, H, D).
 
     ``use_flash=None`` auto-selects: Pallas flash kernel on TPU backends for
     tile-aligned shapes, XLA everywhere else.
+
+    ``num_kv_heads`` (default H): grouped-query attention — k and v carry
+    that many heads and query head h reads K/V head h // (H / Hkv); neither
+    path repeats K/V to H heads.  ``mask`` names the mask kind: None (the
+    ``causal`` flag decides, as before), "causal", or "block_diffusion",
+    which needs ``block_diffusion=(L, B)`` — q and k hold 2L positions, a
+    noised copy then the clean copy, in blocks of B
+    (:func:`block_diffusion_mask`).  With neither argument the call is the
+    one it always was, kernel for kernel.
     """
+    if mask not in (None, "causal", "block_diffusion"):
+        raise ValueError(f"unknown mask kind {mask!r}")
+    causal = causal or mask == "causal"
+    if (mask == "block_diffusion") != (block_diffusion is not None):
+        raise ValueError('mask="block_diffusion" goes with block_diffusion=(L, B)')
+    if block_diffusion is not None and (
+        causal or q.shape[1] != 2 * block_diffusion[0] or k.shape[1] != q.shape[1]
+    ):
+        raise ValueError(
+            f"block diffusion over L={block_diffusion[0]} takes 2L positions "
+            f"and no causal flag; got q {q.shape}, k {k.shape}"
+        )
+    num_kv_heads = k.shape[2] if num_kv_heads is None else num_kv_heads
+    if k.shape[2] != num_kv_heads or v.shape[2] != num_kv_heads or q.shape[2] % num_kv_heads:
+        raise ValueError(
+            f"num_kv_heads={num_kv_heads}: k/v carry {k.shape[2]}/{v.shape[2]} "
+            f"heads, q {q.shape[2]}"
+        )
+    if block_diffusion is not None or num_kv_heads != q.shape[2]:
+        # The masked / grouped kinds: the multi-tile flash kernels on a TPU
+        # from the same size rule, the dense-mask XLA path elsewhere and
+        # for short lengths.
+        if use_flash is None:
+            use_flash = flash_preferred(q.shape[1], k.shape[1], q.shape[3])
+        if use_flash:
+            return flash_attention(
+                q, k, v, causal=causal, scale=scale,
+                block_diffusion=block_diffusion,
+            )
+        return _xla_masked_attention(
+            q, k, v, _dense_mask(q, k, causal, block_diffusion), scale=scale
+        )
     if use_flash is None:
         import os
 

@@ -29,6 +29,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -45,6 +46,9 @@ KERNEL_NAMES = (
     "flash_bwd",          # a fused backward (dq, dk, dv from one call)
     "flash_bwd_dq",       # the split backward's two halves
     "flash_bwd_dkv",
+    "flash_bd_fwd",       # the block-diffusion mask (and grouped K/V heads):
+    "flash_bd_bwd_dq",    # the multi-tile kernels under names of their own,
+    "flash_bd_bwd_dkv",   # so a causal kernel's metric never reads them
     "decode_attn",        # contiguous cache, one token a row
     "decode_multi_attn",  # contiguous cache, a C-token chunk (verify)
     "paged_decode_attn",  # paged pool, one token a row
@@ -53,17 +57,104 @@ KERNEL_NAMES = (
 )
 
 
-def _live_block(qi, ki, *, causal, causal_offset, kv_len, block_q, block_k):
+# ``checkpoint_name``s of the multi-tile forward's output and log-sum-exp.
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
+
+
+def _live_block(qi, ki, *, causal, causal_offset, kv_len, block_q, block_k,
+                bd=None):
     """Predicate for kv/q tile pairs with any unmasked entry, or None when
     every tile is live.  Shared by the forward and both backward kernels so
     mask variants stay in lockstep."""
     live = None
     if causal:
         live = ki * block_k <= qi * block_q + block_q - 1 + causal_offset
+    if bd is not None:
+        live = _bd_live_block(qi, ki, block_q, block_k, *bd)
     if kv_len is not None:
         key_live = ki * block_k < kv_len
         live = key_live if live is None else live & key_live
     return live
+
+
+# The block-diffusion mask (BD3-LM's training mask, Arriola et al. 2025):
+# positions 0 .. L-1 hold the noised copy of a sequence, L .. 2L-1 the clean
+# copy, and position j of either half lies in block j // B.  A noisy query
+# sees the noisy keys of its own block and the clean keys of EARLIER blocks;
+# a clean query sees the clean keys of its own and earlier blocks, and never
+# a noisy key.  ``bd`` is the static pair (L, B) everywhere below.
+
+
+def _bd_live_block(qi, ki, block_q, block_k, seq_len, block_len):
+    """Whether tile (qi, ki) holds any live (query, key) pair of the
+    block-diffusion mask.  A tile may straddle the noisy/clean boundary
+    (short padded sequences), so each half of each side is tested."""
+    q0, k0 = qi * block_q, ki * block_k
+    q1, k1 = q0 + block_q - 1, k0 + block_k - 1
+    blk = lambda pos: jnp.minimum(pos, seq_len - 1) // block_len
+    q_noisy, k_noisy = q0 < seq_len, k0 < seq_len
+    q_clean, k_clean = q1 >= seq_len, k1 >= seq_len
+    # block ranges of each side's halves (meaningful only where the half exists)
+    qn_lo, qn_hi = q0 // block_len, blk(q1)
+    kn_lo, kn_hi = k0 // block_len, blk(k1)
+    qc_hi = blk(q1 - seq_len)
+    kc_lo = jnp.maximum(k0 - seq_len, 0) // block_len
+    same = q_noisy & k_noisy & (kn_lo <= qn_hi) & (qn_lo <= kn_hi)
+    earlier = q_noisy & k_clean & (kc_lo < qn_hi)
+    clean = q_clean & k_clean & (kc_lo <= qc_hi)
+    return same | earlier | clean
+
+
+def _when_live(live, compute, qi, ki, block_q, block_k, bd):
+    """Run a tile's ``compute`` where the tile is live.  Under the
+    block-diffusion mask a live tile whose every pair is live (half of them
+    at L = 4096) takes ``compute(masked=False)``: no mask is built and none
+    applied."""
+    if bd is not None:
+        full = _bd_full_block(qi, ki, block_q, block_k, *bd)
+        pl.when(live & full)(functools.partial(compute, masked=False))
+        pl.when(live & jnp.logical_not(full))(compute)
+    elif live is not None:
+        pl.when(live)(compute)
+    else:
+        compute()
+
+
+def _bd_full_block(qi, ki, block_q, block_k, seq_len, block_len):
+    """Whether EVERY pair of tile (qi, ki) is live: clean keys only, all of
+    them in blocks the tile's first query row already sees (no padded key:
+    a padded sequence's last clean tile is never full, its ``kv_len`` mask
+    stays on).  Such a tile runs without building the mask."""
+    q0, k0 = qi * block_q, ki * block_k
+    q1, k1 = q0 + block_q - 1, k0 + block_k - 1
+    kc_hi = (k1 - seq_len) // block_len
+    k_clean = (k0 >= seq_len) & (k1 < 2 * seq_len)
+    noisy_q = (q1 < seq_len) & (kc_hi < q0 // block_len)
+    clean_q = (q0 >= seq_len) & (kc_hi <= (q0 - seq_len) // block_len)
+    return k_clean & (noisy_q | clean_q)
+
+
+def _bd_mask(q0, k0, block_q, block_k, seq_len, block_len):
+    """(block_q, block_k) boolean tile of the block-diffusion mask whose
+    first row is position ``q0`` and first column position ``k0``.  Block
+    ids are taken on a column and a row vector, so the full tile costs two
+    compares and an or."""
+    q = q0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    k = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    q_noisy, k_noisy = q < seq_len, k < seq_len
+    if block_len & (block_len - 1) == 0:
+        shift = block_len.bit_length() - 1
+        blk = lambda pos: pos >> shift      # a vector shift, not a division
+    else:
+        blk = lambda pos: pos // block_len
+    qb = blk(jnp.where(q_noisy, q, q - seq_len))
+    kb = blk(jnp.where(k_noisy, k, k - seq_len))
+    big = jnp.int32(2**30)
+    # clean keys up to the query's last visible clean block
+    earlier = jnp.where(k_noisy, big, kb) <= jnp.where(q_noisy, qb - 1, qb)
+    # noisy keys of a noisy query's own block
+    same = jnp.where(k_noisy, kb, -1) == jnp.where(q_noisy, qb, -2)
+    return earlier | same
 
 
 def _fwd_kernel(
@@ -82,6 +173,7 @@ def _fwd_kernel(
     block_q: int,
     block_k: int,
     kv_len: int | None,
+    bd: tuple | None = None,
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -93,7 +185,7 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _compute():
+    def _compute(masked=True):
         q = q_ref[0, 0]  # (block_q, d)
         k = k_ref[0, 0]  # (block_k, d)
         v = v_ref[0, 0]  # (block_k, d)
@@ -103,7 +195,8 @@ def _fwd_kernel(
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        s = s * scale  # (block_q, block_k)
+        if bd is None or scale != 1.0:   # the masked kinds hand in a scaled q
+            s = s * scale  # (block_q, block_k)
 
         mask = None
         if causal:
@@ -112,7 +205,9 @@ def _fwd_kernel(
             q_ids = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             k_ids = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             mask = q_ids + causal_offset >= k_ids
-        if kv_len is not None:
+        if bd is not None and masked:
+            mask = _bd_mask(qi * block_q, ki * block_k, block_q, block_k, *bd)
+        if kv_len is not None and masked:
             # Pad-and-mask support (ViT's L=197 and friends): keys at or past
             # the original kv length are padding and must not contribute.
             k_ids = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -145,12 +240,9 @@ def _fwd_kernel(
 
     block_live = _live_block(
         qi, ki, causal=causal, causal_offset=causal_offset, kv_len=kv_len,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, bd=bd,
     )
-    if block_live is not None:
-        pl.when(block_live)(_compute)
-    else:
-        _compute()
+    _when_live(block_live, _compute, qi, ki, block_q, block_k, bd)
 
     @pl.when(ki == num_k - 1)
     def _finalize():
@@ -731,7 +823,12 @@ _flash_nlhd_grouped.defvjp(_flash_nlhd_grouped_vjp_fwd,
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-               causal_offset=None, kv_len=None):
+               causal_offset=None, kv_len=None, bd=None):
+    if bd is not None or k.shape[1] != q.shape[1]:
+        return _flash_tabled_fwd(
+            q, k, v, causal, scale, block_q, block_k, interpret,
+            causal_offset, kv_len, bd,
+        )
     b, h, q_len, d = q.shape
     k_len = k.shape[2]
     block_q = min(block_q, q_len)
@@ -789,7 +886,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
 
 
 def _bwd_block(q, k, v, do, lse, delta, qi, ki, *, causal, causal_offset,
-               scale, block_q, block_k, kv_len=None):
+               scale, block_q, block_k, kv_len=None, bd=None, masked=True):
     """Recompute p and ds for one (q_block, kv_block) tile.
 
     q/do: (bq, d); k/v: (bk, d) — in their INPUT dtype (bf16 on the AMP
@@ -805,7 +902,10 @@ def _bwd_block(q, k, v, do, lse, delta, qi, ki, *, causal, causal_offset,
     s = jax.lax.dot_general(
         q, k, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * scale
+    )
+    rescale = bd is None or scale != 1.0    # the masked kinds hand in a scaled q
+    if rescale:
+        s = s * scale
     p = jnp.exp(s - lse)
     if causal:
         q_ids = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -813,20 +913,26 @@ def _bwd_block(q, k, v, do, lse, delta, qi, ki, *, causal, causal_offset,
         # Explicit zero (not -inf then exp): a fully-masked row has lse ≈
         # _NEG_INF and exp(s - lse) would be 1 there, leaking gradient.
         p = jnp.where(q_ids + causal_offset >= k_ids, p, 0.0)
-    if kv_len is not None:
+    if bd is not None and masked:
+        p = jnp.where(
+            _bd_mask(qi * block_q, ki * block_k, block_q, block_k, *bd), p, 0.0
+        )
+    if kv_len is not None and masked:
         k_ids = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         p = jnp.where(k_ids < kv_len, p, 0.0)
     dp = jax.lax.dot_general(
         do, v, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    ds = p * (dp - delta) * scale
+    ds = p * (dp - delta)
+    if rescale:
+        ds = ds * scale
     return p, ds
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_scr, *, causal, causal_offset, scale, block_q, block_k,
-                   kv_len=None):
+                   kv_len=None, bd=None):
     """Accumulates dq over kv blocks (grid: b, h, q_blocks, kv_blocks)."""
     qi, ki = pl.program_id(2), pl.program_id(3)
     num_k = pl.num_programs(3)
@@ -835,12 +941,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _compute():
+    def _compute(masked=True):
         _, ds = _bwd_block(
             q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
             lse_ref[0, 0], delta_ref[0, 0], qi, ki,
             causal=causal, causal_offset=causal_offset, scale=scale,
-            block_q=block_q, block_k=block_k, kv_len=kv_len,
+            block_q=block_q, block_k=block_k, kv_len=kv_len, bd=bd,
+            masked=masked,
         )
         dq_scr[:] += jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[0, 0],
@@ -850,12 +957,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     live = _live_block(
         qi, ki, causal=causal, causal_offset=causal_offset, kv_len=kv_len,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, bd=bd,
     )
-    if live is not None:
-        pl.when(live)(_compute)
-    else:
-        _compute()
+    _when_live(live, _compute, qi, ki, block_q, block_k, bd)
 
     @pl.when(ki == num_k - 1)
     def _finalize():
@@ -864,22 +968,33 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, causal, causal_offset,
-                    scale, block_q, block_k, kv_len=None):
-    """Accumulates dk/dv over q blocks (grid: b, h, kv_blocks, q_blocks)."""
+                    scale, block_q, block_k, kv_len=None, bd=None,
+                    num_q_blocks=None):
+    """Accumulates dk/dv over q blocks (grid: b, h, kv_blocks, q_blocks).
+
+    With grouped K/V heads the grid's second axis is the K/V head and the
+    last runs over every q block of every query head of its group
+    (``num_q_blocks`` q blocks a head): one K/V tile stays in VMEM while
+    the whole group's queries pass, and dk/dv come out at the K/V head
+    count with no per-query-head copy in HBM."""
     ki, qi = pl.program_id(2), pl.program_id(3)
     num_q = pl.num_programs(3)
+    first, last = qi == 0, qi == num_q - 1
+    if num_q_blocks is not None:
+        qi = qi % num_q_blocks
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _compute():
+    def _compute(masked=True):
         p, ds = _bwd_block(
             q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
             lse_ref[0, 0], delta_ref[0, 0], qi, ki,
             causal=causal, causal_offset=causal_offset, scale=scale,
-            block_q=block_q, block_k=block_k, kv_len=kv_len,
+            block_q=block_q, block_k=block_k, kv_len=kv_len, bd=bd,
+            masked=masked,
         )
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do_ref.dtype), do_ref[0, 0],
@@ -894,14 +1009,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     live = _live_block(
         qi, ki, causal=causal, causal_offset=causal_offset, kv_len=kv_len,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, bd=bd,
     )
-    if live is not None:
-        pl.when(live)(_compute)
-    else:
-        _compute()
+    _when_live(live, _compute, qi, ki, block_q, block_k, bd)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
@@ -970,8 +1082,162 @@ def _flash_bwd_single(q, k, v, lse, delta, do, causal, scale, interpret,
     return dq, dk, dv
 
 
+def _nearest_live(live):
+    """(rows, cols) bool → int32 block table: a live tile's own column; a
+    dead tile's nearest live column at or after it, else the row's last
+    live one (a row with none keeps its own columns)."""
+    import numpy as np
+
+    table = np.tile(np.arange(live.shape[1], dtype=np.int32), (live.shape[0], 1))
+    for r, row in enumerate(live):
+        cols = np.flatnonzero(row)
+        if cols.size:
+            at = np.searchsorted(cols, np.arange(row.size))
+            table[r] = cols[np.minimum(at, cols.size - 1)]
+    return table
+
+
+def _live_tables(nq, nk, **mask):
+    """Block tables ``(kv_of[qi, ki], q_of[ki, qi])`` for the index maps of
+    the masked multi-tile kernels: a dead tile's block index repeats a live
+    neighbour's, so the pipeline issues no copy for a tile the kernel skips
+    (three quarters of the tiles under the block-diffusion mask).  The
+    tile predicate is the kernels' own (``_live_block``), evaluated here on
+    the whole grid at trace time."""
+    import numpy as np
+
+    with jax.ensure_compile_time_eval():
+        live = _live_block(
+            np.arange(nq, dtype=np.int32)[:, None],
+            np.arange(nk, dtype=np.int32)[None, :], **mask,
+        )
+    live = (np.ones((nq, nk), bool) if live is None
+            else np.broadcast_to(np.asarray(live), (nq, nk)))
+    return _nearest_live(live), _nearest_live(live.T)
+
+
+def _flash_tabled_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                      causal_offset, kv_len, bd):
+    """The multi-tile forward for the mask kinds whose dead tiles are many
+    (block diffusion) and for grouped K/V heads.  q: (B, H, Lq, D); k, v:
+    (B, Hkv, Lk, D) with H a multiple of Hkv: query head h reads K/V head
+    h // (H / Hkv) through the block index, never a repeated copy in HBM."""
+    b, h, q_len, d = q.shape
+    k_len = k.shape[2]
+    group = h // k.shape[1]
+    block_q, block_k = min(block_q, q_len), min(block_k, k_len)
+    if q_len % block_q or k_len % block_k:
+        raise ValueError(f"seq lens ({q_len},{k_len}) not divisible by blocks ({block_q},{block_k})")
+    mask = dict(
+        causal=causal,
+        causal_offset=k_len - q_len if causal_offset is None else causal_offset,
+        kv_len=kv_len, block_q=block_q, block_k=block_k, bd=bd,
+    )
+    nq, nk = q_len // block_q, k_len // block_k
+    kv_of, _ = _live_tables(nq, nk, **mask)
+    q_index = lambda b_, h_, qi, ki, tbl: (b_, h_, qi, 0)
+    kv_index = lambda b_, h_, qi, ki, tbl: (b_, h_ // group, tbl[qi, ki], 0)
+    kernel = functools.partial(_fwd_kernel, scale=scale, **mask)
+    out, lse = pl.pallas_call(
+        lambda tbl, *refs: kernel(*refs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, d), q_index),
+                pl.BlockSpec((1, 1, block_k, d), kv_index),
+                pl.BlockSpec((1, 1, block_k, d), kv_index),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_q, d), q_index),
+                pl.BlockSpec((1, 1, block_q, 8), q_index),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, d), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, q_len, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, q_len, 8), jnp.float32),
+        ],
+        name="flash_bd_fwd" if bd is not None else "flash_fwd",
+        interpret=interpret,
+    )(jnp.asarray(kv_of), q, k, v)
+    return out, lse[..., 0]
+
+
+def _flash_tabled_bwd(q, k, v, lse, delta, do, causal, scale, block_q,
+                      block_k, interpret, causal_offset, kv_len, bd):
+    """The split backward behind ``_flash_tabled_fwd``.  dq reads its K/V
+    head through the block index; the dk/dv kernel runs once per K/V head
+    over the q blocks of all its query heads (``num_q_blocks``), so dk and
+    dv come out at the K/V head count."""
+    b, h, q_len, d = q.shape
+    k_len = k.shape[2]
+    group = h // k.shape[1]
+    mask = dict(
+        causal=causal,
+        causal_offset=k_len - q_len if causal_offset is None else causal_offset,
+        kv_len=kv_len, block_q=block_q, block_k=block_k, bd=bd,
+    )
+    nq, nk = q_len // block_q, k_len // block_k
+    kv_of, q_of = _live_tables(nq, nk, **mask)
+
+    q_index = lambda b_, h_, qi, ki, tbl: (b_, h_, qi, 0)
+    kv_index = lambda b_, h_, qi, ki, tbl: (b_, h_ // group, tbl[qi, ki], 0)
+    q_spec = pl.BlockSpec((1, 1, block_q, d), q_index)
+    k_spec = pl.BlockSpec((1, 1, block_k, d), kv_index)
+    row_spec = pl.BlockSpec((1, 1, block_q, 1), q_index)
+    dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, **mask)
+    dq = pl.pallas_call(
+        lambda tbl, *refs: dq_kernel(*refs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h, nq, nk),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, q_len, d), q.dtype),
+        name="flash_bd_bwd_dq" if bd is not None else "flash_bwd_dq",
+        interpret=interpret,
+    )(jnp.asarray(kv_of), q, k, v, do, lse, delta)
+
+    # grid (b, K/V head, ki, j): j runs over the group's heads x q blocks
+    q_index2 = lambda b_, h_, ki, j, tbl: (
+        b_, h_ * group + j // nq, tbl[ki, j % nq], 0)
+    kv_index2 = lambda b_, h_, ki, j, tbl: (b_, h_, ki, 0)
+    q_spec2 = pl.BlockSpec((1, 1, block_q, d), q_index2)
+    k_spec2 = pl.BlockSpec((1, 1, block_k, d), kv_index2)
+    row_spec2 = pl.BlockSpec((1, 1, block_q, 1), q_index2)
+    dkv_kernel = functools.partial(
+        _bwd_dkv_kernel, scale=scale, num_q_blocks=nq, **mask)
+    dk, dv = pl.pallas_call(
+        lambda tbl, *refs: dkv_kernel(*refs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, h // group, nk, group * nq),
+            in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2, row_spec2],
+            out_specs=[k_spec2, k_spec2],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        name="flash_bd_bwd_dkv" if bd is not None else "flash_bwd_dkv",
+        interpret=interpret,
+    )(jnp.asarray(q_of), q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
 def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret,
-               causal_offset=None, kv_len=None):
+               causal_offset=None, kv_len=None, bd=None):
     """Blockwise backward: never materializes the (L, L) score matrix.
 
     Two kernels (the standard flash-attention backward split): dq accumulates
@@ -989,6 +1255,11 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret
     )
     lse = lse[..., None]
 
+    if bd is not None or k.shape[1] != h:
+        return _flash_tabled_bwd(
+            q, k, v, lse, delta, do, causal, scale, block_q, block_k,
+            interpret, causal_offset, kv_len, bd,
+        )
     if q_len <= block_q and k_len <= block_k:
         # One-tile case: the fused kernel recomputes (s, p, dp) once for
         # all three grads instead of once per split kernel.
@@ -1046,29 +1317,36 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret,
-           causal_offset=None, kv_len=None):
+           causal_offset=None, kv_len=None, bd=None):
     out, _ = _flash_fwd(
-        q, k, v, causal, scale, block_q, block_k, interpret, causal_offset, kv_len
+        q, k, v, causal, scale, block_q, block_k, interpret, causal_offset,
+        kv_len, bd,
     )
     return out
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                   causal_offset=None, kv_len=None):
+                   causal_offset=None, kv_len=None, bd=None):
     out, lse = _flash_fwd(
-        q, k, v, causal, scale, block_q, block_k, interpret, causal_offset, kv_len
+        q, k, v, causal, scale, block_q, block_k, interpret, causal_offset,
+        kv_len, bd,
     )
+    # Named so that a rematerialized block can keep them (``FLASH_RESIDUALS``
+    # in a ``save_only_these_names`` policy): the backward then reads o and
+    # the log-sum-exp instead of running the forward kernel a second time.
+    out = checkpoint_name(out, FLASH_RESIDUALS[0])
+    lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
     return out, (q, k, v, out, lse)
 
 
 def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, causal_offset,
-                   kv_len, res, do):
+                   kv_len, bd, res, do):
     q, k, v, out, lse = res
     return _flash_bwd(
         q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret,
-        causal_offset, kv_len,
+        causal_offset, kv_len, bd,
     )
 
 
@@ -1124,8 +1402,16 @@ def flash_attention(
     block_q: int = 1024,
     block_k: int = 1024,
     interpret: bool | None = None,
+    block_diffusion: tuple[int, int] | None = None,
 ) -> jax.Array:
     """Flash attention. q/k/v: (B, L, H, D) → (B, L, H, D).
+
+    ``k``/``v`` may carry fewer heads than ``q`` (grouped-query attention:
+    H a multiple of their head count); ``block_diffusion=(L, B)`` applies
+    the block-diffusion training mask over 2L positions (noised copy, then
+    clean copy, blocks of B; see ``_bd_mask``).  Either takes the
+    transposed multi-tile kernels, whose tile predicate skips the dead
+    tiles and whose block index reads each K/V head in place.
 
     Sequence lengths need not be lane-aligned: non-multiples of 128 (e.g.
     ViT-B/16's L = 197) are zero-padded to the next multiple, padded keys
@@ -1159,6 +1445,24 @@ def flash_attention(
     causal_offset = k_len - q_len
     kv_len = k_len if pad_k else None
     b, ql, h, d = q.shape
+    if block_diffusion is not None or k.shape[2] != h:
+        if causal and block_diffusion is not None:
+            raise ValueError("block_diffusion replaces the causal mask")
+        if h % k.shape[2]:
+            raise ValueError(
+                f"{h} query heads are not a multiple of {k.shape[2]} K/V heads"
+            )
+        if block_diffusion is not None:
+            # The scale goes onto q once (a (P, d) pass XLA fuses into q's
+            # producer), not onto every (block_q, block_k) score tile.
+            q, scale = q * jnp.asarray(scale, q.dtype), 1.0
+        qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+        out = _flash(
+            qt, kt, vt, causal, scale, block_q, block_k, interpret,
+            causal_offset, kv_len, block_diffusion,
+        )
+        out = jnp.swapaxes(out, 1, 2)
+        return out[:, :q_len] if pad_q else out
     if (
         k.shape[1] <= min(block_k, 512)
         and ql <= 512

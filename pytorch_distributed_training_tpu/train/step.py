@@ -19,6 +19,7 @@ from ..obs.trace import scope
 from ..ops.losses import chunked_lm_cross_entropy, cross_entropy_loss
 from ..parallel.grad_accum import accumulate_gradients
 from ..resilience.anomaly import guarded_apply
+from . import block_diffusion
 from .policy import Policy
 from .state import TrainState
 
@@ -80,7 +81,7 @@ def _forward(
         variables["batch_stats"] = state.batch_stats
     rngs = {"dropout": rng} if rng is not None else None
     if train:
-        mutable = ["losses", "moe_stats"] + (
+        mutable = ["losses", "moe_stats", "moe_counters"] + (
             ["batch_stats"] if has_stats else []
         )
         logits, updates = state.apply_fn(
@@ -95,9 +96,27 @@ def _forward(
             {"moe_drop_rate": sum(jnp.sum(d) for d in drops) / len(drops)}
             if drops else {}
         )
+        # The dropless layer's counters (models/moe.TopKMoe): summed over
+        # the layers by name, device scalars like the loss.
+        for path, c in jax.tree_util.tree_leaves_with_path(
+            updates.get("moe_counters", {})
+        ):
+            name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+            stats[name] = stats.get(name, 0.0) + jnp.sum(c)
         return logits, new_stats, aux, stats
     logits = state.apply_fn(variables, x, train=train, rngs=rngs, **apply_kwargs)
     return logits, state.batch_stats, jnp.zeros((), jnp.float32), {}
+
+
+def lm_objective(state: TrainState):
+    """How the model behind ``state.apply_fn`` trains as an LM:
+    ``("next_token", None)``, or ``("block_diffusion", cfg)`` for a module
+    that says so (``lm_objective`` on the module ``apply_fn`` is bound to,
+    e.g. ``models/sdar.SdarMoe``).  Read from the model, so no caller
+    passes it."""
+    model = getattr(state.apply_fn, "__self__", None)
+    kind = getattr(model, "lm_objective", "next_token")
+    return kind, (model.cfg if kind != "next_token" else None)
 
 
 def make_train_step(
@@ -178,6 +197,26 @@ def make_train_step(
             acc = jnp.mean(jnp.argmax(logits, -1) == batch["label"])
             return loss + aux_loss_weight * aux_l, {
                 "accuracy": acc, "batch_stats": new_stats, **stats,
+            }
+        if kind == "lm" and lm_objective(state)[0] == "block_diffusion":
+            # Block diffusion (train/block_diffusion.py): noise from this
+            # microbatch's key, the noised copy then the clean copy through
+            # the model under its mask, weighted CE where the noise fell.
+            if rng is None:
+                raise ValueError("the block-diffusion objective draws its noise "
+                                 "from the step's key: pass base_rng")
+            tokens, cfg = batch["tokens"], lm_objective(state)[1]
+            with scope("train/noise"):
+                noisy, masked, p = block_diffusion.noise(tokens, rng, cfg)
+                both = jnp.concatenate([noisy, tokens], axis=1)
+            logits, new_stats, aux_l, stats = _forward(
+                state, params, both, train=True, rng=None, policy=policy,
+                block_diffusion=True,
+            )
+            loss = block_diffusion.weighted_masked_ce(logits, tokens, masked, p)
+            return loss + aux_loss_weight * aux_l, {
+                "batch_stats": new_stats, **stats,
+                "masked_tokens": jnp.sum(masked).astype(jnp.float32),
             }
         if kind == "lm":
             tokens = batch["tokens"]
