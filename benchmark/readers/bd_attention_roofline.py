@@ -1,17 +1,16 @@
 """Share of its roofline a block-diffusion attention kernel reaches, by the
-name the program gave it (``%flash_bd_fwd.<n>``, ``%flash_bd_bwd_dq.<n>``,
-``%flash_bd_bwd_dkv.<n>``): the least time the chip could take for the calls
+name the program gave it (``%flash_bd_fwd.<n>``, ``%flash_bd_bwd.<n>``): the least time the chip could take for the calls
 (per call the larger of FLOPs / peak and bytes / peak bandwidth, from
 ``benchmark/flops/block_diffusion_attention.py``: live pairs of the mask
 only, K/V at their own head count) over the device time the trace gives
 every kernel the pattern matches.
 
 ``kernel_roofline.py`` cannot read these: it knows one head count and a mask
-that is causal or absent.  As there, a backward split over two kernels needs
-its operations once: the least time is counted for the calls ``counted``
-matches, the device time for all the pattern matches.  The result the
-program's kernels lead with is ``bf16[batch, heads, 2L, dim]`` (the forward's
-o, the dq kernel's dq); the block length is the configuration's.  A trace
+that is causal or absent.  As there, a backward split over several kernels
+would need its operations once: the least time is counted for the calls
+``counted`` matches, the device time for all the pattern matches.  The result
+the program's kernels lead with is ``bf16[batch, heads, 2L, dim]`` (the
+forward's o, the backward's dq); the block length is the configuration's.  A trace
 with no such kernel (the parent's, another cell's) gives nothing to read.
 """
 

@@ -29,6 +29,9 @@ from ..utils.compile_cache import compile_events, compile_phase, compile_totals
 # Model-config fields whose --model-overrides values are strings; all other
 # keys take int/float/bool only (value typos must fail at parse time).
 _STRING_OVERRIDE_KEYS = frozenset({"moe_dispatch"})
+# --model-overrides keys whose value is a range "first:count" (a chip's
+# contiguous share of a layer's experts, models/sdar.SdarConfig).
+_RANGE_OVERRIDE_KEYS = frozenset({"experts_held"})
 
 
 @click.command()
@@ -981,6 +984,15 @@ def run(
             if v.lower() in ("true", "false"):
                 overrides[k] = v.lower() == "true"
                 continue
+            if k in _RANGE_OVERRIDE_KEYS:
+                try:
+                    first, count = (int(x) for x in v.split(":"))
+                except ValueError:
+                    raise click.BadParameter(
+                        f"--model-overrides {k} takes first:count, got {v!r}"
+                    )
+                overrides[k] = (first, count)
+                continue
             try:
                 overrides[k] = int(v)
             except ValueError:
@@ -1183,7 +1195,9 @@ def run(
     # scatter formulation (no (T,E,C) one-hots — models/moe.py, measured
     # +15% tok/s in MOE_BENCH.json) is always sound here; an explicit
     # --model-overrides moe_dispatch=einsum wins.
-    is_moe = model == "gpt2_moe" or int(overrides.get("num_experts", 0) or 0) > 0
+    is_moe = model == "gpt2_moe" or (
+        model.startswith("gpt2") and int(overrides.get("num_experts", 0) or 0) > 0
+    )
     if is_moe and dict(mesh.shape).get("expert", 1) == 1:
         overrides.setdefault("moe_dispatch", "scatter")
     model_kw = {"cfg_overrides": overrides} if overrides else {}

@@ -33,13 +33,16 @@ class GPT2Config:
     dropout_rate: float = 0.0
     tie_embeddings: bool = True
     # MoE variant: >0 swaps every odd block's MLP for a Switch-style top-1
-    # MoE with this many experts (models/moe.py), expert-parallel over the
-    # mesh's `expert` axis.
+    # MoE with this many experts (models/moe.MoeMlp: capacity-bounded, tokens
+    # over capacity dropped), expert-parallel over the mesh's `expert` axis.
+    # It is one of the file's two routers: the dropless top-k layer
+    # (models/moe.TopKMoe) belongs to models/sdar.py and takes none of these.
     num_experts: int = 0
     moe_capacity_factor: float = 1.25
-    # Token → expert-buffer formulation (models/moe.MoeMlp.dispatch_mode):
-    # "einsum" = GShard (T,E,C) one-hots, the EP-shardable path; "scatter"
-    # = row scatter/gather, the fast path when experts are NOT mesh-sharded
+    # Token → expert-buffer formulation of the top-1 layer
+    # (models/moe.MoeMlp.dispatch_mode): "einsum" = GShard (T,E,C) one-hots,
+    # the form of THAT layer GSPMD shards over `expert`; "scatter" = row
+    # scatter/gather, the fast form when its experts are NOT mesh-sharded
     # (identical selection — parity-tested).
     moe_dispatch: str = "einsum"
     # Rematerialize each block in the backward (jax.checkpoint): activation

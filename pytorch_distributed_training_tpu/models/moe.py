@@ -18,11 +18,13 @@ mesh reserves an ``expert`` axis and a complete framework fills it.
   parallelism asks of a chip before the exchange, and nothing standing in
   for the absent chips.  Assignments are sorted by expert and multiplied
   as grouped matrix products (``lax.ragged_dot``: on a TPU XLA's own
-  Mosaic grouped matmul, which runs only the row tiles that hold rows).
+  Mosaic grouped matmul) in chunks of rows under a loop whose trip count
+  follows the assignments there are: no static bound, nothing to overflow.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -276,27 +278,119 @@ def topk_route(logits: jax.Array, k: int, norm_topk_prob: bool = True):
     return weights, experts.astype(jnp.int32)
 
 
-def group_held_assignments(experts: jax.Array, first: int, held: int, rows: int):
-    """Sort the (T, k) assignments by expert and keep those on the held
-    range ``first .. first + held - 1``.
+def group_held_assignments(experts: jax.Array, first: int, held: int):
+    """Sort the (T, k) assignments by expert, those on the held range
+    ``first .. first + held - 1`` first.
 
-    Returns ``(slot, group_sizes, n_held)``: ``slot`` (rows,) indexes the
-    flattened (T·k) assignments, held ones first, grouped by expert in
-    ascending order (stable: by token inside a group); ``group_sizes``
-    (held,) int32 rows per held expert, clipped so they sum to at most
-    ``rows``; ``n_held`` the unclipped number of held assignments (so
-    ``max(n_held - rows, 0)`` assignments did not fit the static bound)."""
+    Returns ``(order, counts)``: ``order`` (T·k,) indexes the flattened
+    assignments, held ones first, grouped by expert in ascending order
+    (stable: by token inside a group); ``counts`` (held,) int32 is each
+    held expert's number of assignments, so the first ``sum(counts)``
+    entries of ``order`` are the held assignments."""
     local = experts.reshape(-1) - first
     is_held = (local >= 0) & (local < held)
     group = jnp.where(is_held, local, held)       # absent experts sort last
-    slot = jnp.argsort(group, stable=True)[:rows]
+    order = jnp.argsort(group, stable=True)
+    # (held, T·k): the long axis on the lanes
     counts = jnp.sum(
-        group[:, None] == jnp.arange(held, dtype=group.dtype)[None, :],
-        axis=0, dtype=jnp.int32,
+        jnp.arange(held, dtype=group.dtype)[:, None] == group[None, :],
+        axis=1, dtype=jnp.int32,
     )
-    ends = jnp.minimum(jnp.cumsum(counts), rows)
-    sizes = jnp.diff(ends, prepend=jnp.zeros((1,), ends.dtype))
-    return slot, sizes.astype(jnp.int32), jnp.sum(counts)
+    return order, counts
+
+
+def _expert_chunk(x, w_rows, sizes, w_gate, w_up, w_down):
+    """One chunk of sorted rows through its experts: x (C, d), the rows'
+    router weights (C,), rows per held expert inside the chunk (held,)."""
+    grouped = lambda lhs, w: lax.ragged_dot(lhs, w, sizes)
+    h = nn.silu(grouped(x, w_gate)) * grouped(x, w_up)
+    return grouped(h, w_down) * w_rows[:, None].astype(x.dtype)
+
+
+def _chunk_plan(order, counts, weights, chunk):
+    """What every chunk of the sorted held assignments needs: the token of
+    each sorted assignment, its router weight, the group boundaries, the
+    number of held assignments and of chunks that hold any."""
+    k = weights.shape[1]
+    pad = (-order.shape[0]) % chunk          # whole chunks to slice from
+    order = jnp.pad(order, (0, pad))
+    ends = jnp.cumsum(counts)
+    n_held = ends[-1]
+    return (order // k, weights.reshape(-1)[order], ends - counts, ends, n_held,
+            (n_held + chunk - 1) // chunk)
+
+
+def _chunk_inputs(c, chunk, token_of, w_sorted, starts, ends, n_held):
+    lo = c * chunk
+    idx = lax.dynamic_slice(token_of, (lo,), (chunk,))
+    w_rows = lax.dynamic_slice(w_sorted, (lo,), (chunk,))
+    live = lo + jnp.arange(chunk) < n_held       # the last chunk's tail holds no assignment
+    sizes = jnp.clip(ends, lo, lo + chunk) - jnp.clip(starts, lo, lo + chunk)
+    return lo, idx, w_rows, live, sizes.astype(jnp.int32)
+
+
+# The held experts' part of the layer, as a custom-VJP function whose two
+# passes walk the sorted held assignments in chunks of ``chunk`` rows under a
+# loop with a DYNAMIC trip count: the work follows the assignments that are
+# there (none is ever dropped: there is no bound to overflow, whatever the
+# routing does), and the memory is a chunk's.  Each chunk gathers its rows,
+# runs the grouped products (``lax.ragged_dot``; the rows past the last group
+# are left unwritten by it and masked here) and scatter-adds into the tokens.
+# Its residuals are its inputs and the routing, so a rematerialized block's
+# second forward computes no expert product at all (the backward below
+# recomputes a chunk's products where it needs them).
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def held_experts(tokens, weights, order, counts, w_gate_up_down, zero, chunk):
+    """tokens (T, d), weights (T, k) f32, the sorted assignments of
+    :func:`group_held_assignments`, the three expert weight stacks → (T, d):
+    ``out[t] = Σ_{j: expert(t, j) held} weights[t, j] · expert(tokens[t])``.
+    ``zero`` is a (T, d) zeros array the result accumulates into."""
+    token_of, w_sorted, starts, ends, n_held, n_chunks = _chunk_plan(order, counts, weights, chunk)
+
+    def body(c, out):
+        _, idx, w_rows, live, sizes = _chunk_inputs(c, chunk, token_of, w_sorted, starts, ends, n_held)
+        y = _expert_chunk(tokens[idx], w_rows, sizes, *w_gate_up_down)
+        return out.at[idx].add(jnp.where(live[:, None], y, 0).astype(out.dtype))
+
+    return lax.fori_loop(0, n_chunks, body, zero)
+
+
+def _held_experts_fwd(tokens, weights, order, counts, w_gate_up_down, zero, chunk):
+    out = held_experts(tokens, weights, order, counts, w_gate_up_down, zero, chunk)
+    return out, (tokens, weights, order, counts, w_gate_up_down)
+
+
+def _held_experts_bwd(chunk, res, d_out):
+    tokens, weights, order, counts, w_gate_up_down = res
+    token_of, w_sorted, starts, ends, n_held, n_chunks = _chunk_plan(order, counts, weights, chunk)
+
+    def body(c, carry):
+        d_tokens, d_w_sorted, d_stacks = carry
+        lo, idx, w_rows, live, sizes = _chunk_inputs(c, chunk, token_of, w_sorted, starts, ends, n_held)
+        _, pull = jax.vjp(
+            lambda x, w, *stacks: _expert_chunk(x, w, sizes, *stacks),
+            tokens[idx], w_rows, *w_gate_up_down)
+        d_x, d_w, *d_chunk = pull(jnp.where(live[:, None], d_out[idx], 0).astype(tokens.dtype))
+        return (
+            d_tokens.at[idx].add(jnp.where(live[:, None], d_x, 0)),
+            lax.dynamic_update_slice(d_w_sorted, jnp.where(live, d_w, 0.0), (lo,)),
+            tuple(a + g.astype(a.dtype) for a, g in zip(d_stacks, d_chunk)),
+        )
+
+    d_tokens, d_w_sorted, d_stacks = lax.fori_loop(0, n_chunks, body, (
+        jnp.zeros_like(tokens), jnp.zeros_like(w_sorted),
+        tuple(jnp.zeros(w.shape, jnp.float32) for w in w_gate_up_down),
+    ))
+    # back from sorted order: assignment a sits at place[a]
+    place = jnp.argsort(order)
+    d_weights = jnp.where(place < n_held, d_w_sorted[place], 0.0).reshape(weights.shape)
+    d_stacks = tuple(g.astype(w.dtype) for g, w in zip(d_stacks, w_gate_up_down))
+    return d_tokens, d_weights.astype(weights.dtype), None, None, d_stacks, None
+
+
+held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 class TopKMoe(nn.Module):
@@ -305,14 +399,12 @@ class TopKMoe(nn.Module):
 
     ``experts_held = (first, count)`` is this chip's share of the
     ``num_experts`` the router scores (None: all of them).  Only the held
-    experts have weights here.  ``rows_factor`` bounds the rows of the
-    grouped products at that multiple of the expected ``T·k·count/E``
-    (None: ``T·k``, every assignment could land here and nothing can
-    overflow); held assignments past the bound are NOT computed and are
-    counted in ``moe_overflow``, which a run must read 0 to be right.
+    experts have weights here.  Every held assignment is computed
+    (:func:`held_experts`); ``rows_chunk`` is how many sorted rows one pass
+    of its loop takes.
 
-    Sown into ``moe_counters`` (one scalar a layer, f32): held assignments,
-    the busiest held expert's rows, the overflow.
+    Sown into ``moe_counters`` (one scalar a layer, f32): the held
+    assignments and the busiest held expert's rows.
     """
 
     num_experts: int
@@ -320,7 +412,7 @@ class TopKMoe(nn.Module):
     mlp_dim: int
     experts_held: tuple | None = None
     norm_topk_prob: bool = True
-    rows_factor: float | None = None
+    rows_chunk: int = 4096
     dtype: Any = jnp.float32
 
     @nn.compact
@@ -330,37 +422,26 @@ class TopKMoe(nn.Module):
         first, held = self.experts_held or (0, e)
         if first < 0 or held < 1 or first + held > e:
             raise ValueError(f"experts_held {self.experts_held} outside 0..{e}")
-        rows = t * k
-        if self.rows_factor is not None and held < e:
-            rows = min(rows, -(-int(self.rows_factor * t * k * held / e) // 8) * 8)
-        tokens = x.reshape(t, d)
+        tokens = x.reshape(t, d).astype(self.dtype)
 
         init = nn.initializers.normal(stddev=0.02)
         router = self.param("router", init, (d, e), jnp.float32)
-        w_gate = self.param("w_gate", init, (held, d, self.mlp_dim), jnp.float32)
-        w_up = self.param("w_up", init, (held, d, self.mlp_dim), jnp.float32)
-        w_down = self.param("w_down", init, (held, self.mlp_dim, d), jnp.float32)
+        stacks = tuple(
+            self.param(name, init, shape, jnp.float32).astype(self.dtype)
+            for name, shape in (("w_gate", (held, d, self.mlp_dim)),
+                                ("w_up", (held, d, self.mlp_dim)),
+                                ("w_down", (held, self.mlp_dim, d)))
+        )
 
         with scope("moe/route"):
-            logits = jnp.dot(
-                tokens.astype(self.dtype), router.astype(self.dtype),
-                preferred_element_type=jnp.float32,
-            )
+            logits = jnp.dot(tokens, router.astype(self.dtype),
+                             preferred_element_type=jnp.float32)
             weights, experts = topk_route(logits, k, self.norm_topk_prob)
-            slot, sizes, n_held = group_held_assignments(experts, first, held, rows)
-            live = jnp.arange(rows) < n_held          # rows past it hold no assignment
-            token_of = slot // k
-        self.sow("moe_counters", "moe_held_assignments", n_held.astype(jnp.float32))
-        self.sow("moe_counters", "moe_load_max", jnp.max(sizes).astype(jnp.float32))
-        self.sow("moe_counters", "moe_overflow",
-                 jnp.maximum(n_held - rows, 0).astype(jnp.float32))
+            order, counts = group_held_assignments(experts, first, held)
+        self.sow("moe_counters", "moe_held_assignments", jnp.sum(counts).astype(jnp.float32))
+        self.sow("moe_counters", "moe_load_max", jnp.max(counts).astype(jnp.float32))
 
         with scope("moe/experts"):
-            grouped = lambda lhs, w: lax.ragged_dot(lhs, w.astype(self.dtype), sizes)
-            x_rows = tokens.astype(self.dtype)[token_of]
-            h = nn.silu(grouped(x_rows, w_gate)) * grouped(x_rows, w_up)
-            y_rows = grouped(h, w_down)
-            y_rows = y_rows * weights.reshape(-1)[slot].astype(self.dtype)[:, None]
-            y_rows = jnp.where(live[:, None], y_rows, 0)
-            out = jnp.zeros((t, d), self.dtype).at[token_of].add(y_rows)
+            out = held_experts(tokens, weights, order, counts, stacks,
+                               jnp.zeros_like(tokens), min(self.rows_chunk, t * k))
         return out.reshape(b, l, d).astype(x.dtype)

@@ -31,6 +31,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.trace import scope
 from ..ops.attention import dot_product_attention
@@ -56,21 +57,37 @@ class SdarConfig:
     # num_experts; None holds all.  The layer computes its own experts'
     # part of the result (models/moe.TopKMoe).
     experts_held: tuple | None = None
-    # Static bound on the rows of the grouped expert products, as a multiple
-    # of the expected share; None leaves room for every assignment.
-    moe_rows_factor: float | None = None
+    # Sorted rows one pass of the expert loop takes (models/moe.held_experts).
+    moe_rows_chunk: int = 4096
     # Block diffusion: tokens a block, the id a noised position takes, the
     # floor of the masking probability (train/block_diffusion.py).
     # ``mask_token_id`` None is the vocabulary's last row.
     block_length: int = 4
     mask_token_id: int | None = None
     noise_eps: float = 1e-3
-    # Rematerialize each block in the backward (jax.checkpoint).
+    # Rematerialize each block in the backward (jax.checkpoint), keeping
+    # the ``remat_save`` names (REMAT_NAMES below; the flash kernels' output
+    # and log-sum-exp by default: the forward kernel then runs once).
     remat: bool = False
+    remat_save: tuple = ("flash_out", "flash_lse")
 
     def __post_init__(self):
         if self.experts_held is not None:      # JSON hands a list
             object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        object.__setattr__(self, "remat_save", tuple(self.remat_save))
+        unknown = set(self.remat_save) - set(REMAT_NAMES)
+        if unknown:
+            raise ValueError(f"remat_save {sorted(unknown)} not in {REMAT_NAMES}")
+
+
+# What a rematerialized block may keep for its backward, by
+# ``checkpoint_name``: bytes a block at P positions (bf16), and what keeping
+# it saves the backward from running again.
+REMAT_NAMES = (
+    "flash_out", "flash_lse",   # P*H*dh*2 + P*H*4: the flash forward kernel
+    "attn_qkv",                 # P*(H+2*Hkv)*dh*2: three projections, q/k norm, RoPE
+    "attn_proj",                # P*d*2: the output projection
+)
 
 
 class RMSNorm(nn.Module):
@@ -112,6 +129,7 @@ class SdarAttention(nn.Module):
         v = dense(hkv * dh, "wv")(x).reshape(b, p, hkv, dh)
         q = rope(RMSNorm(cfg.rms_norm_eps, self.dtype, name="q_norm")(q), positions, cfg.rope_theta)
         k = rope(RMSNorm(cfg.rms_norm_eps, self.dtype, name="k_norm")(k), positions, cfg.rope_theta)
+        q, k, v = (checkpoint_name(t, "attn_qkv") for t in (q, k, v))
         if block_diffusion:
             with scope("attn/block_diffusion"):
                 o = dot_product_attention(
@@ -123,7 +141,7 @@ class SdarAttention(nn.Module):
 
             blk = jnp.arange(p) // cfg.block_length
             o = _xla_masked_attention(q, k, v, blk[None, :] <= blk[:, None])
-        return dense(cfg.hidden_size, "wo")(o.reshape(b, p, h * dh))
+        return checkpoint_name(dense(cfg.hidden_size, "wo")(o.reshape(b, p, h * dh)), "attn_proj")
 
 
 class SdarBlock(nn.Module):
@@ -139,7 +157,7 @@ class SdarBlock(nn.Module):
         return x + TopKMoe(
             cfg.num_experts, cfg.num_experts_per_tok, cfg.moe_intermediate_size,
             experts_held=cfg.experts_held, norm_topk_prob=cfg.norm_topk_prob,
-            rows_factor=cfg.moe_rows_factor, dtype=self.dtype, name="moe",
+            rows_chunk=cfg.moe_rows_chunk, dtype=self.dtype, name="moe",
         )(y)
 
 
@@ -174,14 +192,11 @@ class SdarMoe(nn.Module):
         x = embed.astype(self.dtype)[tokens]
         block_cls = SdarBlock
         if cfg.remat:
-            # A block is recomputed in the backward except its attention's
-            # output and log-sum-exp (64 MB a block at 8192 positions): the
-            # flash forward, a quarter of a block's time, runs once.
-            from ..ops.pallas_attention import FLASH_RESIDUALS
-
+            # A block is recomputed in the backward except what
+            # ``remat_save`` names (REMAT_NAMES).
             block_cls = nn.remat(
                 SdarBlock, static_argnums=(3,),
-                policy=jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS),
+                policy=jax.checkpoint_policies.save_only_these_names(*cfg.remat_save),
             )
         for i in range(cfg.num_hidden_layers):
             x = block_cls(cfg, self.dtype, name=f"block_{i}")(x, positions, block_diffusion)

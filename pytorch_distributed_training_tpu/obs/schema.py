@@ -93,6 +93,23 @@ METRICS: dict[str, dict] = {
         "type": GAUGE, "labeled": False,
         "help": "goodput ledger: unattributed (setup/teardown/eval) seconds",
     },
+    # ---- a step's own counters (train/step.py::STEP_COUNTERS) ----------
+    "moe_held_assignments": {
+        "type": GAUGE, "labeled": False,
+        "help": "dropless top-k MoE: (token, expert) assignments on the "
+                "experts this chip holds, summed over the layers, a "
+                "microbatch's mean over the step",
+    },
+    "moe_load_max": {
+        "type": GAUGE, "labeled": False,
+        "help": "dropless top-k MoE: rows of the busiest held expert, "
+                "summed over the layers",
+    },
+    "masked_tokens": {
+        "type": GAUGE, "labeled": False,
+        "help": "block-diffusion objective: positions the step's noise "
+                "masked, a microbatch's mean over the step",
+    },
     # ---- what was lowered (obs/cost.py) --------------------------------
     "mosaic_custom_calls": {
         "type": GAUGE, "labeled": True,

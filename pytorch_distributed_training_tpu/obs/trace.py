@@ -49,6 +49,10 @@ PHASES = (
     "train/host_sync",       # a loss fetch: the host waits for the device
     "train/loss",            # forward + loss of one microbatch (in the program)
     "train/optimizer",       # the update gate: optimizer + apply (in the program)
+    "train/noise",           # block diffusion: the step's noise and its 2L input
+    "attn/block_diffusion",  # attention under the block-diffusion mask
+    "moe/route",             # top-k router: scores, top-k, grouping by expert
+    "moe/experts",           # the held experts: row gather, grouped products, combine
     "grad_accum/microbatch",  # fwd+bwd of one accumulation microbatch
     "grad_sync/rs_ici",      # tier 1: reduce-scatter over ICI
     "grad_sync/ar_dcn",      # tier 2: cross-slice all-reduce over DCN

@@ -46,9 +46,8 @@ KERNEL_NAMES = (
     "flash_bwd",          # a fused backward (dq, dk, dv from one call)
     "flash_bwd_dq",       # the split backward's two halves
     "flash_bwd_dkv",
-    "flash_bd_fwd",       # the block-diffusion mask (and grouped K/V heads):
-    "flash_bd_bwd_dq",    # the multi-tile kernels under names of their own,
-    "flash_bd_bwd_dkv",   # so a causal kernel's metric never reads them
+    "flash_bd_fwd",       # under the block-diffusion mask: names of their own,
+    "flash_bd_bwd",       # so a causal kernel's metric never reads them
     "decode_attn",        # contiguous cache, one token a row
     "decode_multi_attn",  # contiguous cache, a C-token chunk (verify)
     "paged_decode_attn",  # paged pool, one token a row
@@ -932,7 +931,7 @@ def _bwd_block(q, k, v, do, lse, delta, qi, ki, *, causal, causal_offset,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    dq_scr, *, causal, causal_offset, scale, block_q, block_k,
-                   kv_len=None, bd=None):
+                   kv_len=None):
     """Accumulates dq over kv blocks (grid: b, h, q_blocks, kv_blocks)."""
     qi, ki = pl.program_id(2), pl.program_id(3)
     num_k = pl.num_programs(3)
@@ -941,13 +940,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _compute(masked=True):
+    def _compute():
         _, ds = _bwd_block(
             q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
             lse_ref[0, 0], delta_ref[0, 0], qi, ki,
             causal=causal, causal_offset=causal_offset, scale=scale,
-            block_q=block_q, block_k=block_k, kv_len=kv_len, bd=bd,
-            masked=masked,
+            block_q=block_q, block_k=block_k, kv_len=kv_len,
         )
         dq_scr[:] += jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[0, 0],
@@ -957,9 +955,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     live = _live_block(
         qi, ki, causal=causal, causal_offset=causal_offset, kv_len=kv_len,
-        block_q=block_q, block_k=block_k, bd=bd,
+        block_q=block_q, block_k=block_k,
     )
-    _when_live(live, _compute, qi, ki, block_q, block_k, bd)
+    if live is not None:
+        pl.when(live)(_compute)
+    else:
+        _compute()
 
     @pl.when(ki == num_k - 1)
     def _finalize():
@@ -968,33 +969,22 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, causal, causal_offset,
-                    scale, block_q, block_k, kv_len=None, bd=None,
-                    num_q_blocks=None):
-    """Accumulates dk/dv over q blocks (grid: b, h, kv_blocks, q_blocks).
-
-    With grouped K/V heads the grid's second axis is the K/V head and the
-    last runs over every q block of every query head of its group
-    (``num_q_blocks`` q blocks a head): one K/V tile stays in VMEM while
-    the whole group's queries pass, and dk/dv come out at the K/V head
-    count with no per-query-head copy in HBM."""
+                    scale, block_q, block_k, kv_len=None):
+    """Accumulates dk/dv over q blocks (grid: b, h, kv_blocks, q_blocks)."""
     ki, qi = pl.program_id(2), pl.program_id(3)
     num_q = pl.num_programs(3)
-    first, last = qi == 0, qi == num_q - 1
-    if num_q_blocks is not None:
-        qi = qi % num_q_blocks
 
-    @pl.when(first)
+    @pl.when(qi == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _compute(masked=True):
+    def _compute():
         p, ds = _bwd_block(
             q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
             lse_ref[0, 0], delta_ref[0, 0], qi, ki,
             causal=causal, causal_offset=causal_offset, scale=scale,
-            block_q=block_q, block_k=block_k, kv_len=kv_len, bd=bd,
-            masked=masked,
+            block_q=block_q, block_k=block_k, kv_len=kv_len,
         )
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do_ref.dtype), do_ref[0, 0],
@@ -1009,11 +999,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     live = _live_block(
         qi, ki, causal=causal, causal_offset=causal_offset, kv_len=kv_len,
-        block_q=block_q, block_k=block_k, bd=bd,
+        block_q=block_q, block_k=block_k,
     )
-    _when_live(live, _compute, qi, ki, block_q, block_k, bd)
+    if live is not None:
+        pl.when(live)(_compute)
+    else:
+        _compute()
 
-    @pl.when(last)
+    @pl.when(qi == num_q - 1)
     def _finalize():
         dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
@@ -1168,12 +1161,83 @@ def _flash_tabled_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     return out, lse[..., 0]
 
 
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
+                      causal, causal_offset, scale, block_q, block_k,
+                      kv_len=None, bd=None):
+    """dq, dk and dv from ONE recomputation of each live tile (grid: b, K/V
+    head, query head of its group, q_blocks, kv_blocks).
+
+    The split backward recomputes a tile's scores, probabilities and dp
+    twice (7 matmuls a tile); here they are computed once (5).  dq
+    accumulates over the kv blocks of one q block; dk and dv accumulate over
+    EVERYTHING that visits their K/V head — all its query heads' q blocks —
+    in float32 scratch that holds the head's whole key length, and leave
+    for HBM once, at the head's last tile."""
+    g, qi, ki = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    num_g, num_q, num_k = pl.num_programs(2), pl.num_programs(3), pl.num_programs(4)
+
+    @pl.when((g == 0) & (qi == 0) & (ki == 0))
+    def _init_head():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(ki == 0)
+    def _init_rows():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    def _compute(masked=True):
+        p, ds = _bwd_block(
+            q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0],
+            lse_ref[0, 0], delta_ref[0, 0], qi, ki,
+            causal=causal, causal_offset=causal_offset, scale=scale,
+            block_q=block_q, block_k=block_k, kv_len=kv_len, bd=bd,
+            masked=masked,
+        )
+        ds_c = ds.astype(k_ref.dtype)
+        dq_scr[:] += jax.lax.dot_general(
+            ds_c, k_ref[0, 0], dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        keys = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+        dv_scr[keys, :] += jax.lax.dot_general(
+            p.astype(do_ref.dtype), do_ref[0, 0],
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dk_scr[keys, :] += jax.lax.dot_general(
+            ds_c, q_ref[0, 0], dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    live = _live_block(
+        qi, ki, causal=causal, causal_offset=causal_offset, kv_len=kv_len,
+        block_q=block_q, block_k=block_k, bd=bd,
+    )
+    _when_live(live, _compute, qi, ki, block_q, block_k, bd)
+
+    @pl.when(ki == num_k - 1)
+    def _finalize_rows():
+        dq_ref[0, 0] = dq_scr[:].astype(dq_ref.dtype)
+
+    @pl.when((g == num_g - 1) & (qi == num_q - 1) & (ki == num_k - 1))
+    def _finalize_head():
+        dk_ref[0, 0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+# Scoped VMEM the fused backward may take: two float32 (Lk, d) accumulators,
+# the (Lk, d) dk / dv output blocks and four (block_q, block_k) float32
+# tiles are past the 16 MB default at 8192 keys.
+_FUSED_BWD_VMEM = 64 * 2**20
+
+
 def _flash_tabled_bwd(q, k, v, lse, delta, do, causal, scale, block_q,
                       block_k, interpret, causal_offset, kv_len, bd):
-    """The split backward behind ``_flash_tabled_fwd``.  dq reads its K/V
-    head through the block index; the dk/dv kernel runs once per K/V head
-    over the q blocks of all its query heads (``num_q_blocks``), so dk and
-    dv come out at the K/V head count."""
+    """The backward behind ``_flash_tabled_fwd``: one fused kernel
+    (``_bwd_fused_kernel``) over grid (b, K/V head, its query heads,
+    q blocks, kv blocks).  K/V are read through the block index of their own
+    head, and dk / dv come out at the K/V head count."""
     b, h, q_len, d = q.shape
     k_len = k.shape[2]
     group = h // k.shape[1]
@@ -1183,57 +1247,38 @@ def _flash_tabled_bwd(q, k, v, lse, delta, do, causal, scale, block_q,
         kv_len=kv_len, block_q=block_q, block_k=block_k, bd=bd,
     )
     nq, nk = q_len // block_q, k_len // block_k
-    kv_of, q_of = _live_tables(nq, nk, **mask)
+    kv_of, _ = _live_tables(nq, nk, **mask)
 
-    q_index = lambda b_, h_, qi, ki, tbl: (b_, h_, qi, 0)
-    kv_index = lambda b_, h_, qi, ki, tbl: (b_, h_ // group, tbl[qi, ki], 0)
+    q_index = lambda b_, n_, g_, qi, ki, tbl: (b_, n_ * group + g_, qi, 0)
+    kv_index = lambda b_, n_, g_, qi, ki, tbl: (b_, n_, tbl[qi, ki], 0)
+    head_index = lambda b_, n_, g_, qi, ki, tbl: (b_, n_, 0, 0)
     q_spec = pl.BlockSpec((1, 1, block_q, d), q_index)
     k_spec = pl.BlockSpec((1, 1, block_k, d), kv_index)
     row_spec = pl.BlockSpec((1, 1, block_q, 1), q_index)
-    dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, **mask)
-    dq = pl.pallas_call(
-        lambda tbl, *refs: dq_kernel(*refs),
+    head_spec = pl.BlockSpec((1, 1, k_len, d), head_index)
+    kernel = functools.partial(_bwd_fused_kernel, scale=scale, **mask)
+    return pl.pallas_call(
+        lambda tbl, *refs: kernel(*refs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b, h, nq, nk),
+            grid=(b, h // group, group, nq, nk),
             in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, q_len, d), q.dtype),
-        name="flash_bd_bwd_dq" if bd is not None else "flash_bwd_dq",
-        interpret=interpret,
-    )(jnp.asarray(kv_of), q, k, v, do, lse, delta)
-
-    # grid (b, K/V head, ki, j): j runs over the group's heads x q blocks
-    q_index2 = lambda b_, h_, ki, j, tbl: (
-        b_, h_ * group + j // nq, tbl[ki, j % nq], 0)
-    kv_index2 = lambda b_, h_, ki, j, tbl: (b_, h_, ki, 0)
-    q_spec2 = pl.BlockSpec((1, 1, block_q, d), q_index2)
-    k_spec2 = pl.BlockSpec((1, 1, block_k, d), kv_index2)
-    row_spec2 = pl.BlockSpec((1, 1, block_q, 1), q_index2)
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, scale=scale, num_q_blocks=nq, **mask)
-    dk, dv = pl.pallas_call(
-        lambda tbl, *refs: dkv_kernel(*refs),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, h // group, nk, group * nq),
-            in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2, row_spec2],
-            out_specs=[k_spec2, k_spec2],
+            out_specs=[q_spec, head_spec, head_spec],
             scratch_shapes=[
-                pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((k_len, d), jnp.float32),
+                pltpu.VMEM((k_len, d), jnp.float32),
             ],
         ),
         out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        name="flash_bd_bwd_dkv" if bd is not None else "flash_bwd_dkv",
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_FUSED_BWD_VMEM),
+        name="flash_bd_bwd" if bd is not None else "flash_bwd",
         interpret=interpret,
-    )(jnp.asarray(q_of), q, k, v, do, lse, delta)
-    return dq, dk, dv
+    )(jnp.asarray(kv_of), q, k, v, do, lse, delta)
 
 
 def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret,

@@ -24,6 +24,14 @@ from .policy import Policy
 from .state import TrainState
 
 
+# Per-step counters a model or an objective may return beside the loss
+# (device scalars; sums over the layers, means over the microbatches):
+# the dropless top-k layer's (models/moe.TopKMoe) and the block-diffusion
+# objective's.  Declared in obs/schema.py::METRICS; the trainer publishes
+# them at its log points.
+STEP_COUNTERS = ("moe_held_assignments", "moe_load_max", "masked_tokens")
+
+
 def prepare_image_input(
     x: jax.Array, policy: Policy, normalize: tuple | None
 ) -> jax.Array:

@@ -1,0 +1,259 @@
+"""SDAR-MoE on the normal path at a toy size (float32, CPU): the
+block-diffusion mask of the flash kernels against its dense definition, the
+dropless top-k experts and their shares, the noising function, and the step
+that finds the objective on the model."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from pytorch_distributed_training_tpu import models, train
+from pytorch_distributed_training_tpu.models import moe
+from pytorch_distributed_training_tpu.ops import attention, pallas_attention as pa
+from pytorch_distributed_training_tpu.train import block_diffusion
+
+TOY = dict(vocab_size=512, hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
+           num_key_value_heads=2, head_dim=16, num_experts=8, num_experts_per_tok=2,
+           moe_intermediate_size=32, block_length=4)
+
+
+def dense_definition(seq_len, block):
+    """The mask by its words: loops, no array trick shared with the code."""
+    m = np.zeros((2 * seq_len, 2 * seq_len), bool)
+    for i in range(2 * seq_len):
+        for j in range(2 * seq_len):
+            qi, kj = i % seq_len // block, j % seq_len // block
+            if i < seq_len and j < seq_len:
+                m[i, j] = qi == kj
+            elif i < seq_len:
+                m[i, j] = kj < qi
+            elif j >= seq_len:
+                m[i, j] = kj <= qi
+    return m
+
+
+@pytest.mark.parametrize("seq_len, block, tile", [(32, 4, 16), (48, 8, 32), (256, 4, 128)])
+def test_tile_predicate_and_in_tile_mask_equal_the_dense_definition(seq_len, block, tile):
+    want = dense_definition(seq_len, block)
+    np.testing.assert_array_equal(attention.block_diffusion_mask(seq_len, block), want)
+    n = 2 * seq_len // tile
+    for qi in range(n):
+        for ki in range(n):
+            cut = want[qi * tile:(qi + 1) * tile, ki * tile:(ki + 1) * tile]
+            live = bool(pa._bd_live_block(qi, ki, tile, tile, seq_len, block))
+            full = bool(pa._bd_full_block(qi, ki, tile, tile, seq_len, block))
+            assert live == cut.any() and full == cut.all(), (qi, ki)
+            if live:
+                got = pa._bd_mask(qi * tile, ki * tile, tile, tile, seq_len, block)
+                np.testing.assert_array_equal(got, cut)
+    kv_of, q_of = pa._live_tables(n, n, causal=False, causal_offset=0, kv_len=None,
+                                  block_q=tile, block_k=tile, bd=(seq_len, block))
+    tiles = want.reshape(n, tile, n, tile).any(axis=(1, 3))
+    assert all(tiles[q, kv_of[q, k]] and (not tiles[q, k] or kv_of[q, k] == k)
+               for q in range(n) for k in range(n))
+    assert all(tiles[q_of[k, q], k] and (not tiles[q, k] or q_of[k, q] == q)
+               for q in range(n) for k in range(n))
+
+
+@pytest.mark.parametrize("seq_len, block, tile", [(32, 4, 1024), (48, 8, 1024), (256, 4, 128)])
+def test_flash_kernels_under_the_mask_match_the_xla_path(seq_len, block, tile):
+    """The forward and the fused backward kernel through the Pallas interpreter,
+    grouped K/V heads, against the dense-mask XLA path."""
+    keys = jax.random.split(jax.random.PRNGKey(seq_len), 3)
+    q = jax.random.normal(keys[0], (2, 2 * seq_len, 4, 16))
+    k, v = (jax.random.normal(x, (2, 2 * seq_len, 2, 16)) for x in keys[1:])
+
+    def run(flash):
+        def loss(q, k, v):
+            if flash:
+                o = pa.flash_attention(q, k, v, block_q=tile, block_k=tile,
+                                       block_diffusion=(seq_len, block))
+            else:
+                o = attention.dot_product_attention(
+                    q, k, v, use_flash=False, num_kv_heads=2, mask="block_diffusion",
+                    block_diffusion=(seq_len, block))
+            return jnp.sum(o * jnp.cos(o)), o
+        (_, o), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return (o, *grads)
+
+    for got, want in zip(run(True), run(False)):
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=3e-5)
+
+
+def test_xla_path_equals_attention_with_repeated_heads():
+    """Grouped K/V: head h reads K/V head h // group, on the definition."""
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(keys[0], (1, 64, 4, 16))
+    k, v = (jax.random.normal(x, (1, 64, 2, 16)) for x in keys[1:])
+    got = attention.dot_product_attention(q, k, v, num_kv_heads=2, mask="block_diffusion",
+                                          block_diffusion=(32, 4), use_flash=False)
+    mask = dense_definition(32, 4)
+    kr, vr = jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kr) / 4.0
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1), vr)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_attention_arguments_are_checked():
+    x = jnp.zeros((1, 64, 4, 16))
+    kv = jnp.zeros((1, 64, 2, 16))
+    with pytest.raises(ValueError):
+        attention.dot_product_attention(x, kv, kv, mask="block_diffusion")       # no (L, B)
+    with pytest.raises(ValueError):
+        attention.dot_product_attention(x, kv, kv, mask="sliding")
+    with pytest.raises(ValueError):
+        attention.dot_product_attention(x, kv, kv, num_kv_heads=4)
+    with pytest.raises(ValueError):
+        attention.dot_product_attention(x, kv, kv, mask="block_diffusion",
+                                        block_diffusion=(48, 4))                  # 2L != 64
+
+
+def layer(held=None, rows_chunk=64, e=8, k=2):
+    return moe.TopKMoe(num_experts=e, num_experts_per_tok=k, mlp_dim=32, experts_held=held,
+                       rows_chunk=rows_chunk)
+
+
+def test_topk_routing_drops_nothing_under_a_biased_router():
+    """A router that sends every token to the same two experts: all 2·T
+    assignments are computed (the old top-1 layer would drop past capacity)."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 64))
+    net = layer()
+    params = net.init(jax.random.PRNGKey(1), x)["params"]
+    x = jnp.abs(x)                               # every logit of experts 3 and 6 is large
+    params = {**params, "router": jnp.zeros((64, 8)).at[:, 3].set(5.0).at[:, 6].set(4.0)}
+    out, sown = net.apply({"params": params}, x, mutable=["moe_counters"])
+    counters = {k: float(v[0]) for k, v in sown["moe_counters"].items()}
+    assert counters == {"moe_held_assignments": 256.0, "moe_load_max": 128.0}
+    tokens = x.reshape(-1, 64)
+    w, idx = moe.topk_route(tokens @ params["router"], 2)
+    assert set(np.unique(idx)) == {3, 6}
+    want = sum(
+        w[:, j:j + 1] * ((jax.nn.silu(tokens @ params["w_gate"][e]) * (tokens @ params["w_up"][e]))
+                         @ params["w_down"][e])
+        for j, e in ((0, 3), (1, 6)))
+    np.testing.assert_allclose(out.reshape(-1, 64), want, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("rows_chunk", [8, 48, 4096])
+def test_a_share_under_a_biased_router_needs_no_room(rows_chunk):
+    """Expert 3 is the only one of the share (2, 3) any token picks, and every
+    token picks it: four times the share's expected rows, all computed,
+    whatever the chunk (smaller than, unaligned with, larger than the rows)."""
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(0), (1, 64, 64)))
+    net = layer(held=(2, 2), rows_chunk=rows_chunk)
+    params = net.init(jax.random.PRNGKey(1), x)["params"]
+    params = {**params, "router": jnp.zeros((64, 8)).at[:, 3].set(5.0).at[:, 6].set(4.0)}
+    out, sown = net.apply({"params": params}, x, mutable=["moe_counters"])
+    assert float(sown["moe_counters"]["moe_held_assignments"][0]) == 64.0
+    assert float(sown["moe_counters"]["moe_load_max"][0]) == 64.0
+    tokens = x[0]
+    w, idx = moe.topk_route(tokens @ params["router"], 2)
+    want = w[:, :1] * ((jax.nn.silu(tokens @ params["w_gate"][1]) * (tokens @ params["w_up"][1]))
+                       @ params["w_down"][1])
+    assert set(np.unique(idx[:, 0])) == {3}
+    np.testing.assert_allclose(out[0], want, rtol=1e-4, atol=1e-6)
+
+
+def test_gradients_flow_through_the_grouped_products():
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 32, 64))
+    net = layer(held=(0, 4), rows_chunk=16)
+    params = net.init(jax.random.PRNGKey(1), x)["params"]
+    grads = jax.grad(lambda p: jnp.sum(jnp.square(net.apply({"params": p}, x))))(params)
+    assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree_util.tree_leaves(grads))
+
+
+def test_noise_masks_the_drawn_share_and_follows_its_key():
+    cfg = models.sdar.SdarConfig(**TOY)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (64, 256), 0, 511)
+    noisy, masked, p = block_diffusion.noise(tokens, jax.random.PRNGKey(1), cfg)
+    again = block_diffusion.noise(tokens, jax.random.PRNGKey(1), cfg)
+    other = block_diffusion.noise(tokens, jax.random.PRNGKey(2), cfg)
+    assert all(bool(jnp.array_equal(a, b)) for a, b in zip((noisy, masked, p), again))
+    assert not bool(jnp.array_equal(masked, other[1]))
+    assert bool(jnp.all((p >= cfg.noise_eps) & (p <= 1.0)))
+    assert bool(jnp.all(jnp.where(masked, noisy == 511, noisy == tokens)))     # the last row is the mask id
+    # each sequence's masked fraction is its own p (256 draws: 4 sigma of a Bernoulli mean)
+    frac = jnp.mean(masked, axis=1)
+    assert float(jnp.max(jnp.abs(frac - p))) < 4 * 0.5 / 16
+    assert abs(float(jnp.mean(p)) - 0.5) < 0.15            # t is uniform
+
+
+def test_the_lm_step_reads_the_objective_from_the_model():
+    net = models.create_model("sdar_30b_a3b", cfg_overrides=dict(TOY, remat=True))
+    assert models.MODEL_REGISTRY["sdar_30b_a3b"].kind == "lm"
+    sample = jnp.zeros((2, 32), jnp.int32)
+    state = train.create_train_state(net, jax.random.PRNGKey(0), sample, optax.adamw(1e-3),
+                                     init_kwargs={"train": False})
+    from pytorch_distributed_training_tpu.train.step import lm_objective
+
+    assert lm_objective(state)[0] == "block_diffusion"
+    gpt2 = models.create_model("gpt2", cfg_overrides=dict(num_layers=1, hidden_dim=32, num_heads=2,
+                                                          vocab_size=64, max_seq_len=16))
+    gpt2_state = train.create_train_state(gpt2, jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32),
+                                          optax.sgd(0.1), init_kwargs={"train": False})
+    assert lm_objective(gpt2_state) == ("next_token", None)
+
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(2), (4, 32), 0, 511)}
+    step = train.make_train_step(kind="lm", num_microbatches=2, base_rng=jax.random.PRNGKey(1))
+    first = None
+    for _ in range(8):
+        state, metrics = step(state, batch)
+        first = float(metrics["loss"]) if first is None else first
+    assert set(metrics) >= {"loss", "masked_tokens", "moe_held_assignments", "moe_load_max"}
+    assert float(metrics["moe_held_assignments"]) == 2 * 2 * 64 * 2     # layers x positions x k, all held
+    assert np.isfinite(float(metrics["loss"]))
+    with pytest.raises(ValueError, match="base_rng"):
+        train.make_train_step(kind="lm")(state, batch)
+
+
+def test_cli_trains_the_toy_size(tmp_path):
+    import os
+    import subprocess
+    import sys
+
+    overrides = ",".join(f"{k}={v}" for k, v in TOY.items())
+    out = subprocess.run(
+        [sys.executable, "-m", "pytorch_distributed_training_tpu.cli.main", "--use-cpu",
+         "--model", "sdar_30b_a3b", "--dataset", "synthetic-tokens", "--seq-len", "32",
+         "--model-overrides", overrides, "--batch-size", "4", "--accum-steps", "2",
+         "--num-workers", "0", "--steps-per-epoch", "3", "--learning-rate", "1e-3"],
+        capture_output=True, text=True, timeout=600,
+        # one CPU device: the suite's 8-device XLA_FLAGS would want a batch of 8
+        env={k: v for k, v in {**os.environ, "JAX_PLATFORMS": "cpu"}.items() if k != "XLA_FLAGS"},
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.returncode == 0, out.stderr[-3000:] + out.stdout[-2000:]
+    assert "training started" in out.stdout
+
+
+def test_masked_kernels_lower_under_their_names():
+    q = jnp.ones((1, 256, 4, 16), jnp.float32)
+    kv = jnp.ones((1, 256, 2, 16), jnp.float32)
+
+    def loss(q, k, v):
+        return jnp.sum(pa.flash_attention(q, k, v, block_diffusion=(128, 4)) ** 2)
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).as_text(debug_info=True)
+    for name in ("flash_bd_fwd", "flash_bd_bwd"):
+        assert name in text and name in pa.KERNEL_NAMES
+    assert not __import__("re").search(r"flash_fwd[_.]", text)       # the causal kernels' metrics stay blind
+
+
+def test_compiled_step_names_the_new_phases():
+    import re
+
+    from pytorch_distributed_training_tpu.obs.schema import METRICS
+    from pytorch_distributed_training_tpu.obs.trace import PHASES
+    from pytorch_distributed_training_tpu.train.step import STEP_COUNTERS
+
+    net = models.create_model("sdar_30b_a3b", cfg_overrides=TOY)
+    state = train.create_train_state(net, jax.random.PRNGKey(0), jnp.zeros((1, 32), jnp.int32),
+                                     optax.sgd(0.1), init_kwargs={"train": False})
+    step = train.make_train_step(kind="lm", num_microbatches=2, base_rng=jax.random.PRNGKey(1))
+    batch = {"tokens": jnp.zeros((2, 32), jnp.int32)}
+    op_names = re.findall(r'op_name="([^"]+)"', step.lower(state, batch).compile().as_text())
+    for phase in ("train/noise", "attn/block_diffusion", "moe/route", "moe/experts"):
+        assert phase in PHASES and any(phase in name for name in op_names), phase
+    assert set(STEP_COUNTERS) <= set(METRICS)

@@ -719,13 +719,19 @@ def test_every_pallas_call_has_a_role_name():
 
     tree = ast.parse(inspect.getsource(pa))
     sites = list(_pallas_call_names(tree))
-    assert sum(is_pallas for _, is_pallas, _ in sites) == 13
+    assert sum(is_pallas for _, is_pallas, _ in sites) == 15
     seen = set()
     for lineno, is_pallas, name in sites:
         assert name is not None, f"pallas_attention.py:{lineno}: no name="
         if isinstance(name, ast.Constant):
             assert name.value in pa.KERNEL_NAMES, (lineno, name.value)
             seen.add(name.value)
+        elif isinstance(name, ast.IfExp):
+            # the tabled multi-tile launchers: one name under the
+            # block-diffusion mask, the causal kernels' own otherwise
+            for branch in (name.body, name.orelse):
+                assert isinstance(branch, ast.Constant) and branch.value in pa.KERNEL_NAMES, lineno
+                seen.add(branch.value)
         else:
             # only the shared paged launcher forwards its caller's name
             assert is_pallas and isinstance(name, ast.Name) and name.id == "name", lineno
@@ -820,7 +826,8 @@ def _vocabulary(name):
     # readers; make it a deliberate act.
     ("PHASES", {
         "train/step", "train/input_wait", "train/host_sync", "train/loss",
-        "train/optimizer", "grad_accum/microbatch",
+        "train/optimizer", "train/noise", "attn/block_diffusion",
+        "moe/route", "moe/experts", "grad_accum/microbatch",
         "grad_sync/rs_ici", "grad_sync/ar_dcn", "grad_sync/ag_ici",
         "grad_sync/stripe",
         "pipeline/tick", "serve/prefill", "serve/decode", "serve/verify",
@@ -837,6 +844,7 @@ def _vocabulary(name):
     }),
     ("KERNEL_NAMES", {
         "flash_fwd", "flash_bwd", "flash_bwd_dq", "flash_bwd_dkv",
+        "flash_bd_fwd", "flash_bd_bwd",
         "decode_attn", "decode_multi_attn", "paged_decode_attn",
         "paged_verify_attn", "paged_prefill_attn",
     }),
