@@ -7,7 +7,11 @@ sequence of L clean tokens, which the model runs as P = 2L positions (a
 noised copy and the clean copy).  Per position and layer: the q/k/v/o
 projections, the router, and the HELD experts at their expected share of
 the routed assignments (k * held / router width passes through one gated
-expert: three matmuls).  Attention's QK^T and PV are counted on the LIVE
+expert: three matmuls) — or, given the share of the routed assignments that
+landed on held experts in the measured window (``held_share``, from the
+step's own counter), at what the dropless layer really multiplied: routing
+at random weights is far from even, and a layer that drops nothing does
+more or less than the expected share of the work.  Attention's QK^T and PV are counted on the LIVE
 (query, key) pairs of the block-diffusion mask, exactly (``live_pairs``),
 never P^2 / 2.  The head is counted on the L noisy positions.  Not counted:
 embedding look-ups, norms, RoPE, softmax, routing and sorting, the optimizer.
@@ -46,20 +50,21 @@ def live_pairs(seq_len: int, block: int) -> int:
     return total
 
 
-def forward_flops_per_sequence(cfg: dict, seq_len: int) -> float:
+def forward_flops_per_sequence(cfg: dict, seq_len: int, held_share: float | None = None) -> float:
     d, layers, vocab = cfg["hidden_size"], cfg["layers"], cfg["vocab_size"]
     heads, kv_heads, dh = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
     overrides = cfg["system"]["overrides"]
     router, held, k = overrides["num_experts"], cfg["num_experts"], cfg["num_experts_per_tok"]
     dense = 2 * (2 * d * heads * dh) + 2 * (2 * d * kv_heads * dh) + 2 * d * router
-    experts = k * held / router * 3 * 2 * d * cfg["moe_intermediate_size"]
+    share = held / router if held_share is None else held_share
+    experts = k * share * 3 * 2 * d * cfg["moe_intermediate_size"]
     attention = 4 * dh * heads * live_pairs(seq_len, overrides["block_length"])
     return float(layers * (2 * seq_len * (dense + experts) + attention) + 2 * d * vocab * seq_len)
 
 
-def train_flops_per_sample(cfg: dict, shape: dict) -> float:
+def train_flops_per_sample(cfg: dict, shape: dict, held_share: float | None = None) -> float:
     """One sample is one sequence of ``shape["seq_len"]`` clean tokens."""
-    return 3.0 * forward_flops_per_sequence(cfg, int(shape["seq_len"]))
+    return 3.0 * forward_flops_per_sequence(cfg, int(shape["seq_len"]), held_share)
 
 
 def units_per_sample(cfg: dict, shape: dict) -> tuple[str, float]:

@@ -14,7 +14,9 @@ loss and gradient norm on those masks are compared.  The optimizer's slots
 are built after the check: both do not fit beside the reference's gradients.
 
 (b) The step's MoE counters (device scalars, fetched after the window like
-the losses) go into ``facts["counters"]`` for the per-layer readers.
+the losses) go into ``facts["counters"]`` for the per-layer readers, and the
+share of the routed assignments that ran on held experts goes into the
+FLOPs count: a dropless layer's work follows the routing.
 
 A later ``benchmark`` issue folds the two kinds into one (PERF.md section 7).
 """
@@ -169,7 +171,11 @@ def run(ctx) -> dict:
     counters["moe_experts_held_per_layer"] = float(overrides["experts_held"][1])
 
     flops_mod = importlib.import_module(f"benchmark.flops.{system['flops']}")
-    per_sample = flops_mod.train_flops_per_sample(config, step_spec)
+    # The held experts' FLOPs at the share of the assignments that ran: the
+    # layer drops nothing, so its work follows the routing (flops/sdar_moe.py).
+    per_sample = flops_mod.train_flops_per_sample(
+        config, step_spec,
+        held_share=counters["moe_held_assignments"] / counters["moe_routed_assignments"])
     unit, per = flops_mod.units_per_sample(config, step_spec)
     rate = summary["examples"] / summary["elapsed_s"]
     chips = len(ctx.devices)

@@ -333,7 +333,7 @@ def _chunk_inputs(c, chunk, token_of, w_sorted, starts, ends, n_held):
 # passes walk the sorted held assignments in chunks of ``chunk`` rows under a
 # loop with a DYNAMIC trip count: the work follows the assignments that are
 # there (none is ever dropped: there is no bound to overflow, whatever the
-# routing does), and the memory is a chunk's.  Each chunk gathers its rows,
+# routing does), as the expert FLOPs do, and the memory is a chunk's.  Each chunk gathers its rows,
 # runs the grouped products (``lax.ragged_dot``; the rows past the last group
 # are left unwritten by it and masked here) and scatter-adds into the tokens.
 # Its residuals are its inputs and the routing, so a rematerialized block's
@@ -341,12 +341,11 @@ def _chunk_inputs(c, chunk, token_of, w_sorted, starts, ends, n_held):
 # recomputes a chunk's products where it needs them).
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def held_experts(tokens, weights, order, counts, w_gate_up_down, zero, chunk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def held_experts(tokens, weights, order, counts, w_gate_up_down, chunk):
     """tokens (T, d), weights (T, k) f32, the sorted assignments of
     :func:`group_held_assignments`, the three expert weight stacks → (T, d):
-    ``out[t] = Σ_{j: expert(t, j) held} weights[t, j] · expert(tokens[t])``.
-    ``zero`` is a (T, d) zeros array the result accumulates into."""
+    ``out[t] = Σ_{j: expert(t, j) held} weights[t, j] · expert(tokens[t])``."""
     token_of, w_sorted, starts, ends, n_held, n_chunks = _chunk_plan(order, counts, weights, chunk)
 
     def body(c, out):
@@ -354,11 +353,11 @@ def held_experts(tokens, weights, order, counts, w_gate_up_down, zero, chunk):
         y = _expert_chunk(tokens[idx], w_rows, sizes, *w_gate_up_down)
         return out.at[idx].add(jnp.where(live[:, None], y, 0).astype(out.dtype))
 
-    return lax.fori_loop(0, n_chunks, body, zero)
+    return lax.fori_loop(0, n_chunks, body, jnp.zeros_like(tokens))
 
 
-def _held_experts_fwd(tokens, weights, order, counts, w_gate_up_down, zero, chunk):
-    out = held_experts(tokens, weights, order, counts, w_gate_up_down, zero, chunk)
+def _held_experts_fwd(tokens, weights, order, counts, w_gate_up_down, chunk):
+    out = held_experts(tokens, weights, order, counts, w_gate_up_down, chunk)
     return out, (tokens, weights, order, counts, w_gate_up_down)
 
 
@@ -387,7 +386,7 @@ def _held_experts_bwd(chunk, res, d_out):
     place = jnp.argsort(order)
     d_weights = jnp.where(place < n_held, d_w_sorted[place], 0.0).reshape(weights.shape)
     d_stacks = tuple(g.astype(w.dtype) for g, w in zip(d_stacks, w_gate_up_down))
-    return d_tokens, d_weights.astype(weights.dtype), None, None, d_stacks, None
+    return d_tokens, d_weights.astype(weights.dtype), None, None, d_stacks
 
 
 held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
@@ -412,7 +411,7 @@ class TopKMoe(nn.Module):
     mlp_dim: int
     experts_held: tuple | None = None
     norm_topk_prob: bool = True
-    rows_chunk: int = 4096
+    rows_chunk: int = 16384
     dtype: Any = jnp.float32
 
     @nn.compact
@@ -443,5 +442,5 @@ class TopKMoe(nn.Module):
 
         with scope("moe/experts"):
             out = held_experts(tokens, weights, order, counts, stacks,
-                               jnp.zeros_like(tokens), min(self.rows_chunk, t * k))
+                               min(self.rows_chunk, t * k))
         return out.reshape(b, l, d).astype(x.dtype)

@@ -34,7 +34,8 @@ from flax import linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.trace import scope
-from ..ops.attention import dot_product_attention
+from ..ops.attention import _xla_masked_attention, dot_product_attention
+from ..ops.pallas_attention import FLASH_RESIDUALS
 from .moe import TopKMoe
 
 
@@ -58,7 +59,7 @@ class SdarConfig:
     # part of the result (models/moe.TopKMoe).
     experts_held: tuple | None = None
     # Sorted rows one pass of the expert loop takes (models/moe.held_experts).
-    moe_rows_chunk: int = 4096
+    moe_rows_chunk: int = 16384
     # Block diffusion: tokens a block, the id a noised position takes, the
     # floor of the masking probability (train/block_diffusion.py).
     # ``mask_token_id`` None is the vocabulary's last row.
@@ -69,7 +70,7 @@ class SdarConfig:
     # the ``remat_save`` names (REMAT_NAMES below; the flash kernels' output
     # and log-sum-exp by default: the forward kernel then runs once).
     remat: bool = False
-    remat_save: tuple = ("flash_out", "flash_lse")
+    remat_save: tuple = FLASH_RESIDUALS
 
     def __post_init__(self):
         if self.experts_held is not None:      # JSON hands a list
@@ -84,7 +85,7 @@ class SdarConfig:
 # ``checkpoint_name``: bytes a block at P positions (bf16), and what keeping
 # it saves the backward from running again.
 REMAT_NAMES = (
-    "flash_out", "flash_lse",   # P*H*dh*2 + P*H*4: the flash forward kernel
+    *FLASH_RESIDUALS,           # P*H*dh*2 + P*H*4: the flash forward kernel
     "attn_qkv",                 # P*(H+2*Hkv)*dh*2: three projections, q/k norm, RoPE
     "attn_proj",                # P*d*2: the output projection
 )
@@ -137,8 +138,6 @@ class SdarAttention(nn.Module):
                     block_diffusion=(p // 2, cfg.block_length),
                 )
         else:
-            from ..ops.attention import _xla_masked_attention
-
             blk = jnp.arange(p) // cfg.block_length
             o = _xla_masked_attention(q, k, v, blk[None, :] <= blk[:, None])
         return checkpoint_name(dense(cfg.hidden_size, "wo")(o.reshape(b, p, h * dh)), "attn_proj")

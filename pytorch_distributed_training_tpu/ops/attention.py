@@ -221,7 +221,7 @@ def flash_attention(
     kernel = functools.partial(
         pallas_attention.flash_attention, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
-        **({} if block_diffusion is None else {"block_diffusion": block_diffusion}),
+        block_diffusion=block_diffusion,
     )
     # heads split over ``tensor`` only where the K/V heads divide too
     partition = _kernel_partition(q.shape[0], math.gcd(q.shape[2], k.shape[2]))

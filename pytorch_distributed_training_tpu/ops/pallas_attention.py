@@ -1075,24 +1075,9 @@ def _flash_bwd_single(q, k, v, lse, delta, do, causal, scale, interpret,
     return dq, dk, dv
 
 
-def _nearest_live(live):
-    """(rows, cols) bool → int32 block table: a live tile's own column; a
-    dead tile's nearest live column at or after it, else the row's last
-    live one (a row with none keeps its own columns)."""
-    import numpy as np
-
-    table = np.tile(np.arange(live.shape[1], dtype=np.int32), (live.shape[0], 1))
-    for r, row in enumerate(live):
-        cols = np.flatnonzero(row)
-        if cols.size:
-            at = np.searchsorted(cols, np.arange(row.size))
-            table[r] = cols[np.minimum(at, cols.size - 1)]
-    return table
-
-
-def _live_tables(nq, nk, **mask):
-    """Block tables ``(kv_of[qi, ki], q_of[ki, qi])`` for the index maps of
-    the masked multi-tile kernels: a dead tile's block index repeats a live
+def _live_table(nq, nk, **mask):
+    """Block table ``kv_of[qi, ki]`` for the K/V index maps of the masked
+    multi-tile kernels: a dead tile's block index repeats a live
     neighbour's, so the pipeline issues no copy for a tile the kernel skips
     (three quarters of the tiles under the block-diffusion mask).  The
     tile predicate is the kernels' own (``_live_block``), evaluated here on
@@ -1104,9 +1089,16 @@ def _live_tables(nq, nk, **mask):
             np.arange(nq, dtype=np.int32)[:, None],
             np.arange(nk, dtype=np.int32)[None, :], **mask,
         )
-    live = (np.ones((nq, nk), bool) if live is None
-            else np.broadcast_to(np.asarray(live), (nq, nk)))
-    return _nearest_live(live), _nearest_live(live.T)
+    table = np.tile(np.arange(nk, dtype=np.int32), (nq, 1))
+    if live is None:
+        return table
+    for qi, row in enumerate(np.broadcast_to(np.asarray(live), (nq, nk))):
+        cols = np.flatnonzero(row)
+        if cols.size:
+            # a dead tile: the nearest live one at or after it, else the last
+            at = np.searchsorted(cols, np.arange(nk))
+            table[qi] = cols[np.minimum(at, cols.size - 1)]
+    return table
 
 
 def _flash_tabled_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
@@ -1127,7 +1119,7 @@ def _flash_tabled_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
         kv_len=kv_len, block_q=block_q, block_k=block_k, bd=bd,
     )
     nq, nk = q_len // block_q, k_len // block_k
-    kv_of, _ = _live_tables(nq, nk, **mask)
+    kv_of = _live_table(nq, nk, **mask)
     q_index = lambda b_, h_, qi, ki, tbl: (b_, h_, qi, 0)
     kv_index = lambda b_, h_, qi, ki, tbl: (b_, h_ // group, tbl[qi, ki], 0)
     kernel = functools.partial(_fwd_kernel, scale=scale, **mask)
@@ -1247,7 +1239,7 @@ def _flash_tabled_bwd(q, k, v, lse, delta, do, causal, scale, block_q,
         kv_len=kv_len, block_q=block_q, block_k=block_k, bd=bd,
     )
     nq, nk = q_len // block_q, k_len // block_k
-    kv_of, _ = _live_tables(nq, nk, **mask)
+    kv_of = _live_table(nq, nk, **mask)
 
     q_index = lambda b_, n_, g_, qi, ki, tbl: (b_, n_ * group + g_, qi, 0)
     kv_index = lambda b_, n_, g_, qi, ki, tbl: (b_, n_, tbl[qi, ki], 0)

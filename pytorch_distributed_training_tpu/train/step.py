@@ -14,6 +14,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from flax.traverse_util import flatten_dict
 
 from ..obs.trace import scope
 from ..ops.losses import chunked_lm_cross_entropy, cross_entropy_loss
@@ -106,11 +107,10 @@ def _forward(
         )
         # The dropless layer's counters (models/moe.TopKMoe): summed over
         # the layers by name, device scalars like the loss.
-        for path, c in jax.tree_util.tree_leaves_with_path(
+        for path, sown_values in flatten_dict(
             updates.get("moe_counters", {})
-        ):
-            name = next(k.key for k in reversed(path) if hasattr(k, "key"))
-            stats[name] = stats.get(name, 0.0) + jnp.sum(c)
+        ).items():
+            stats[path[-1]] = stats.get(path[-1], 0.0) + sum(sown_values)
         return logits, new_stats, aux, stats
     logits = state.apply_fn(variables, x, train=train, rngs=rngs, **apply_kwargs)
     return logits, state.batch_stats, jnp.zeros((), jnp.float32), {}
@@ -206,16 +206,17 @@ def make_train_step(
             return loss + aux_loss_weight * aux_l, {
                 "accuracy": acc, "batch_stats": new_stats, **stats,
             }
-        if kind == "lm" and lm_objective(state)[0] == "block_diffusion":
+        objective, model_cfg = lm_objective(state) if kind == "lm" else (None, None)
+        if objective == "block_diffusion":
             # Block diffusion (train/block_diffusion.py): noise from this
             # microbatch's key, the noised copy then the clean copy through
             # the model under its mask, weighted CE where the noise fell.
             if rng is None:
                 raise ValueError("the block-diffusion objective draws its noise "
                                  "from the step's key: pass base_rng")
-            tokens, cfg = batch["tokens"], lm_objective(state)[1]
+            tokens = batch["tokens"]
             with scope("train/noise"):
-                noisy, masked, p = block_diffusion.noise(tokens, rng, cfg)
+                noisy, masked, p = block_diffusion.noise(tokens, rng, model_cfg)
                 both = jnp.concatenate([noisy, tokens], axis=1)
             logits, new_stats, aux_l, stats = _forward(
                 state, params, both, train=True, rng=None, policy=policy,

@@ -89,7 +89,7 @@ def test_the_shares_add_up_to_the_uncut_reference_layer():
     for first in range(0, 8, 2):
         share = {"router": params["router"],
                  **{k: params[k][first:first + 2] for k in ("w_gate", "w_up", "w_down")}}
-        part = moe.TopKMoe(8, 2, 32, experts_held=(first, 2), rows_chunk=32).apply({"params": share}, x)
+        part = moe.TopKMoe(8, 2, 32, experts_held=(first, 2), rows_chunk=48).apply({"params": share}, x)
         with jax.default_matmul_precision("highest"):
             held = copy.deepcopy(cfg)
             np.testing.assert_allclose(part[0], ref.experts(x[0], share, held, (first, 2)),
@@ -130,6 +130,11 @@ def test_flops_function_against_its_hand_worked_docstring():
     forward = flops.forward_flops_per_sequence(cfg, 4096)
     assert forward == 4_314_563_084_288 and int(forward) in numbers
     assert flops.train_flops_per_sample(cfg, shape) == 12_943_689_252_864
+    assert flops.train_flops_per_sample(cfg, shape, held_share=16 / 128) == 12_943_689_252_864
+    # the held experts' term follows the assignments that ran: 9,437,184 a pass, x 8192 x 6 x 3 at 1 pass
+    step = 9_437_184 * 8192 * 6 * 3
+    assert flops.train_flops_per_sample(cfg, shape, held_share=0.115) == pytest.approx(
+        12_943_689_252_864 - step * (1 - 0.115 * 8), rel=1e-12)
     assert 12_943_689_252_864 in numbers and 665_988_366_336 in numbers
     assert flops.units_per_sample(cfg, shape) == ("tokens", 4096.0)
 

@@ -48,12 +48,10 @@ def test_tile_predicate_and_in_tile_mask_equal_the_dense_definition(seq_len, blo
             if live:
                 got = pa._bd_mask(qi * tile, ki * tile, tile, tile, seq_len, block)
                 np.testing.assert_array_equal(got, cut)
-    kv_of, q_of = pa._live_tables(n, n, causal=False, causal_offset=0, kv_len=None,
-                                  block_q=tile, block_k=tile, bd=(seq_len, block))
+    kv_of = pa._live_table(n, n, causal=False, causal_offset=0, kv_len=None,
+                           block_q=tile, block_k=tile, bd=(seq_len, block))
     tiles = want.reshape(n, tile, n, tile).any(axis=(1, 3))
     assert all(tiles[q, kv_of[q, k]] and (not tiles[q, k] or kv_of[q, k] == k)
-               for q in range(n) for k in range(n))
-    assert all(tiles[q_of[k, q], k] and (not tiles[q, k] or q_of[k, q] == q)
                for q in range(n) for k in range(n))
 
 
@@ -238,7 +236,8 @@ def test_masked_kernels_lower_under_their_names():
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).as_text(debug_info=True)
     for name in ("flash_bd_fwd", "flash_bd_bwd"):
         assert name in text and name in pa.KERNEL_NAMES
-    assert not __import__("re").search(r"flash_fwd[_.]", text)       # the causal kernels' metrics stay blind
+    # (that the causal kernels' metric patterns do not match these names:
+    # tests/benchmark_tests/test_bench_sdar.py, on the trace's own spelling)
 
 
 def test_compiled_step_names_the_new_phases():
