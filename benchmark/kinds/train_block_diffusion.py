@@ -9,14 +9,21 @@ plain reference has to be handed the positions the step masked: one probe
 sequence at the TIMED length goes through the program's own step (SGD of
 rate 1: the gradient is old params minus new), the masks are rebuilt with
 the program's public noising function from the key the step used
-(``fold_in(fold_in(base_rng, step 0), microbatch 0)``), and the reference's
-loss and gradient norm on those masks are compared.  The optimizer's slots
-are built after the check: both do not fit beside the reference's gradients.
+(``fold_in(fold_in(base_rng, step), microbatch 0)``) and held against what
+the configuration assumes of them, and the reference's loss, the norm of
+EVERY parameter's gradient (the worst leaf decides) and its own count of the
+assignments on held experts are compared with the step's.  The optimizer's
+slots are built after the check: both do not fit beside the reference's
+gradients.
 
-(b) The step's MoE counters (device scalars, fetched after the window like
-the losses) go into ``facts["counters"]`` for the per-layer readers, and the
-share of the routed assignments that ran on held experts goes into the
-FLOPs count: a dropless layer's work follows the routing.
+(b) The step's counters (device scalars, fetched after the window like the
+losses) go into ``facts["counters"]`` for the per-layer readers, and two of
+them into the verdict and the FLOPs: the window's ``masked_tokens`` has to
+be what the noise schedule gives over every sequence of every step (half a
+batch left out of the timed program halves it), and the share of the routed
+assignments that ran on held experts goes into the FLOPs count, because a
+dropless layer's work follows the routing (the counter behind it is the one
+the reference check held against the reference's routing).
 
 A later ``benchmark`` issue folds the two kinds into one (PERF.md section 7).
 """
@@ -26,6 +33,7 @@ from __future__ import annotations
 import importlib
 import itertools
 import math
+import time
 
 from ..harness import model_overrides
 from .train import TimedBatches, _batch_source
@@ -33,47 +41,79 @@ from .train import TimedBatches, _batch_source
 COUNTERS = ("moe_held_assignments", "moe_load_max", "masked_tokens")
 
 
+def reference_fn(config):
+    """The reference's jitted entry: ``(params, tokens, masked, p)`` → loss,
+    per-leaf gradient norms, held assignments."""
+    import jax
+
+    ref = importlib.import_module(f"benchmark.reference.{config['system']['reference']}")
+    return jax.jit(lambda prm, t, m, q: ref.loss_and_grad_norms(prm, t, m, q, config))
+
+
+def readings(got, want) -> dict:
+    """Relative differences of ``(loss, {leaf: gradient norm}, held
+    assignments)`` (host numbers) between two computations of one probe."""
+    rel = lambda a, b: abs(a - b) / abs(b)
+    leaves = {k: rel(got[1][k], want[1][k]) for k in want[1]}
+    worst = max(leaves, key=leaves.get)
+    whole = lambda norms: math.sqrt(sum(v * v for v in norms.values()))
+    return {"loss": rel(got[0], want[0]), "grad_leaf": leaves[worst], "worst_leaf": worst,
+            "grad_norm": rel(whole(got[1]), whole(want[1])), "held_assignments": rel(got[2], want[2])}
+
+
+def within(read: dict, check: dict) -> bool:
+    return (read["loss"] <= float(check["loss_rtol"])
+            and read["grad_leaf"] <= float(check["grad_leaf_rtol"])
+            and read["held_assignments"] <= float(check["held_assignments_rtol"]))
+
+
+def host_norms(tree) -> dict:
+    import jax
+
+    return {jax.tree_util.keystr(path): float(x)
+            for path, x in jax.tree_util.tree_leaves_with_path(jax.device_get(tree))}
+
+
 def _reference_check(ctx, mesh, net, state, step_kw, probe):
     import jax
     import jax.numpy as jnp
+    import numpy as np
     import optax
 
     from pytorch_distributed_training_tpu import train
     from pytorch_distributed_training_tpu.parallel.sharding import shard_batch
     from pytorch_distributed_training_tpu.train import block_diffusion
 
-    from ..reference import global_norm
-
     check = ctx.cell["reference_check"]
     batch = probe(int(check["samples_per_device"]) * len(ctx.devices))
     sgd = optax.sgd(1.0)
-    params = jax.tree_util.tree_map(jnp.copy, state.params)   # the step donates its state
-    probe_state = state.replace(step=jnp.zeros((), jnp.int32), params=params,
-                                opt_state=sgd.init(params), tx=sgd)
+    params, at = jax.tree_util.tree_map(jnp.copy, (state.params, state.step))   # the step donates its state
+    probe_state = state.replace(step=at, params=params, opt_state=sgd.init(params), tx=sgd)
     step = train.make_train_step(num_microbatches=1, **step_kw)
-    key = jax.random.fold_in(jax.random.fold_in(step_kw["base_rng"], 0), 0)
+    key = jax.random.fold_in(jax.random.fold_in(step_kw["base_rng"], state.step), 0)
     ref = importlib.import_module(f"benchmark.reference.{ctx.config['system']['reference']}")
     with mesh:
         placed = shard_batch(batch, mesh)
         new_state, metrics = step(probe_state, placed)
-        sys_norm = float(jax.jit(
-            lambda old, new: global_norm(jax.tree_util.tree_map(jnp.subtract, old, new))
-        )(state.params, new_state.params))
-        sys_loss = float(metrics["loss"])
-        del new_state, probe_state, params
+        sys_norms = host_norms(jax.jit(lambda old, new: jax.tree_util.tree_map(
+            lambda a, b: jnp.sqrt(jnp.sum(jnp.square(a - b))), old, new))(state.params, new_state.params))
+        got = (float(metrics["loss"]), sys_norms, float(metrics["moe_held_assignments"]))
+        del new_state, probe_state, params, at
         _, masked, p = jax.jit(
             lambda t, k: block_diffusion.noise(t, k, net.cfg))(placed["tokens"], key)
-        ref_loss, ref_norm = (float(x) for x in jax.jit(
-            lambda prm, t, m, q: ref.loss_and_grad_norm(prm, t, m, q, ctx.config)
-        )(state.params, placed["tokens"], masked, p))
-    loss_err = abs(sys_loss - ref_loss) / abs(ref_loss)
-    norm_err = abs(sys_norm - ref_norm) / abs(ref_norm)
-    ok = loss_err <= float(check["loss_rtol"]) and norm_err <= float(check["grad_norm_rtol"])
-    print(f"reference check: {int(jax.numpy.sum(masked))} masked of {masked.size}; loss system "
-          f"{sys_loss:.6f} reference {ref_loss:.6f} (rel {loss_err:.2e}, tol {check['loss_rtol']}); "
-          f"grad norm system {sys_norm:.6f} reference {ref_norm:.6f} (rel {norm_err:.2e}, tol "
-          f"{check['grad_norm_rtol']}) -> {'ok' if ok else 'FAILED'}",
-          flush=True)
+        value, norms, held = reference_fn(ctx.config)(state.params, placed["tokens"], masked, p)
+        want = (float(value), host_norms(norms), float(held))
+    masked, p = np.asarray(masked), np.asarray(p)
+    noise_ok = ref.noise_is_the_assumed(masked, p)
+    read = readings(got, want)
+    ok = within(read, check) and noise_ok
+    print(f"reference check: {int(masked.sum())} masked of {masked.size} at p {p.round(4).tolist()} "
+          f"({'as' if noise_ok else 'NOT as'} assumed); loss system {got[0]:.6f} reference {want[0]:.6f} "
+          f"(rel {read['loss']:.2e}, tol {check['loss_rtol']}); gradient norm, worst of {len(want[1])} "
+          f"leaves {read['worst_leaf']} (rel {read['grad_leaf']:.2e}, tol {check['grad_leaf_rtol']}), whole "
+          f"tree rel {read['grad_norm']:.2e}; held assignments system {got[2]:.0f} reference {want[2]:.0f} "
+          f"(rel {read['held_assignments']:.2e}, tol {check['held_assignments_rtol']}) -> "
+          f"{'ok' if ok else 'FAILED'}", flush=True)
     return ok
 
 
@@ -105,13 +145,18 @@ def run(ctx) -> dict:
         lambda key: net.init(key, sample, train=False)["params"],
         out_shardings=jax.tree_util.tree_map(lambda x: x.sharding, state.params),
     )
+    # The step's key is a constant of the compiled step, so it is one key
+    # for every seed (one cached program each for the probe and the timed
+    # step); the seed's noise comes from the step counter the key is folded
+    # with, which is an argument: training starts at a step the seed gives.
     with mesh:
-        state = state.replace(params=seeded(jax.random.PRNGKey(ctx.seed32)))
+        state = state.replace(
+            params=seeded(jax.random.PRNGKey(ctx.seed32)),
+            step=jax.device_put(jax.numpy.asarray(ctx.seed32 // 2, jax.numpy.int32), state.step.sharding))
     ctx.mark("model and params built")
     take, _, probe = _batch_source(ctx, mesh, samples)
     ctx.mark("input ready")
-    step_kw = dict(kind="lm", policy=policy,
-                   base_rng=jax.random.PRNGKey((ctx.seed32 + 1) % 2147483629))
+    step_kw = dict(kind="lm", policy=policy, base_rng=jax.random.PRNGKey(0))
     reference_ok = _reference_check(ctx, mesh, net, state, step_kw, probe)
     ctx.mark("reference check done")
 
@@ -122,10 +167,12 @@ def run(ctx) -> dict:
     state = state.replace(tx=tx, opt_state=slots)
     jitted = train.make_train_step(num_microbatches=micro, **step_kw)
     seen: list = []              # every step's loss and counters, as device scalars
+    dispatched: list = []        # host clock at every dispatch's return
 
     def step_fn(s, batch):
         s, metrics = jitted(s, batch)
         seen.append({k: metrics[k] for k in ("loss",) + COUNTERS if k in metrics})
+        dispatched.append(time.perf_counter())
         return s, metrics
 
     trainer = train.Trainer(state, step_fn, mesh, train.TrainerConfig(progress=False, prefetch=2))
@@ -135,6 +182,7 @@ def run(ctx) -> dict:
     n_steps = max(int(math.floor(ctx.seconds / step_s)), 1)
     first_loss = float(seen[0]["loss"])
     seen.clear()
+    dispatched.clear()
     if ctx.measuring:
         print(f"warm-up done: {step_s * 1e3:.1f} ms a step, window = {n_steps} steps", flush=True)
 
@@ -160,15 +208,21 @@ def run(ctx) -> dict:
     window = {k: np.asarray(v, np.float64) for k, v in jax.device_get(
         {k: [s[k] for s in seen] for k in seen[0]}).items()}
     failed = int(np.sum(~np.isfinite(window["loss"])))
-    expect = math.log(float(config["vocab_size"]))
-    first_ok = abs(first_loss - expect) / expect <= float(cell["first_loss_rtol"])
-    # Counters are sums over the layers, averaged over a step's microbatches.
-    positions = 2 * int(step_spec["seq_len"]) * samples // micro
+    first = cell["first_loss"]
+    first_ok = abs(first_loss - float(first["expected"])) / float(first["expected"]) <= float(first["rtol"])
+    # Counters are a step's totals over its layers and microbatches.
+    seq_len, sequences = int(step_spec["seq_len"]), len(seen) * samples
     overrides = system["overrides"]
     counters = {k: float(window[k].sum()) for k in COUNTERS if k in window}
     counters["moe_routed_assignments"] = float(
-        len(seen) * int(config["layers"]) * positions * int(config["num_experts_per_tok"]))
+        sequences * int(config["layers"]) * 2 * seq_len * int(config["num_experts_per_tok"]))
     counters["moe_experts_held_per_layer"] = float(overrides["experts_held"][1])
+    # t ~ U(0, 1) a sequence: its masked count has mean L (1 + eps) / 2 and
+    # standard deviation L / sqrt(12) (the binomial's own part is small beside).
+    noise = cell["masked_tokens"]
+    masked_want = sequences * seq_len * (1.0 + float(noise["eps"])) / 2.0
+    masked_room = float(noise["sigmas"]) * seq_len * math.sqrt(sequences / 12.0)
+    masked_ok = abs(counters["masked_tokens"] - masked_want) <= masked_room
 
     flops_mod = importlib.import_module(f"benchmark.flops.{system['flops']}")
     # The held experts' FLOPs at the share of the assignments that ran: the
@@ -179,18 +233,21 @@ def run(ctx) -> dict:
     unit, per = flops_mod.units_per_sample(config, step_spec)
     rate = summary["examples"] / summary["elapsed_s"]
     chips = len(ctx.devices)
-    print(f"window: {steps} steps, first loss {first_loss:.4f} (ln = {expect:.4f}), "
-          f"last loss {window['loss'][-1]:.4f}; counters {counters}", flush=True)
+    print(f"window: {steps} steps, first loss {first_loss:.4f} (expected {first['expected']}, "
+          f"{'ok' if first_ok else 'FAILED'}), last loss {window['loss'][-1]:.4f}; masked tokens "
+          f"{counters['masked_tokens']:.0f} of {masked_want:.0f} +- {masked_room:.0f} expected over "
+          f"{sequences} sequences ({'ok' if masked_ok else 'FAILED'}); counters {counters}", flush=True)
     end_to_end = {}
     if ctx.measuring:
         print(f"window: {summary['elapsed_s']:.3f} s, {rate * per / chips:.1f} {unit}/s/chip, "
-              f"input wait {batches.wait_s:.3f} s", flush=True)
+              f"input wait {batches.wait_s:.3f} s; ms between dispatches "
+              f"{[round(1e3 * (b - a)) for a, b in zip(dispatched, dispatched[1:])]}", flush=True)
         end_to_end["train_mfu"] = 100.0 * rate * per_sample / chips / ctx.peaks["bf16_flops_per_s"]
     return {
-        "correct": bool(reference_ok and first_ok and failed == 0 and steps == n_steps),
+        "correct": bool(reference_ok and first_ok and masked_ok and failed == 0 and steps == n_steps),
         "attempted": n_steps,
         "failed": failed + (n_steps - steps),
         "end_to_end": end_to_end,
-        "facts": {"window_s": summary["elapsed_s"], "steps": steps,
+        "facts": {"window_s": summary["elapsed_s"], "steps": steps, "microbatches": micro,
                   "data_wait_s": batches.wait_s, "counters": counters},
     }

@@ -21,7 +21,9 @@ built densely from the definition above, the experts are a loop over the
 held range that runs every expert on every token, and nothing has a bound
 on rows, so a dropped assignment or a wrong mask row in the program shows.
 Attention runs in query blocks and each layer under ``jax.checkpoint`` so
-that 8192 positions fit beside the resident state.
+that 8192 positions fit beside the resident state; both loops are
+``lax.map`` / ``lax.scan`` and not unrolled, which compiles in a third of
+the time (71 s against 236 for a described v5e, PR 28).
 
 Departures from the published model, each under ``assumed`` or ``reduced``
 in the configuration's file: the experts summed are the held range only
@@ -37,9 +39,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from . import f32, global_norm
+from . import f32
 
 Q_BLOCK = 512          # query rows a block of the attention loop
+NOISE_EPS = 1e-3       # the floor of the masking probability the file assumes
 
 
 def settings(cfg: dict) -> dict:
@@ -95,61 +98,108 @@ def attention(a, p, cfg, pos, mask):
         return jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, axis=-1), v)
 
     step = min(Q_BLOCK, n)
-    o = jnp.concatenate([rows(q[i:i + step], mask[i:i + step]) for i in range(0, n, step)])
+    blocks = lambda x: x.reshape(n // step, step, *x.shape[1:])
+    o = jax.lax.map(lambda qm: rows(*qm), (blocks(q), blocks(mask)))
     return o.reshape(n, heads * dh) @ p["wo"]["kernel"]
+
+
+def route(b, router, cfg):
+    """b: (P, d) → each position's k experts and their weights, (P, k) each."""
+    g = jax.nn.softmax(b @ router, axis=-1)
+    top_w, top_e = jax.lax.top_k(g, int(cfg["num_experts_per_tok"]))
+    if cfg["norm_topk_prob"]:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    return top_w, top_e
+
+
+def held_assignments(b, p, cfg, held):
+    """How many (position, expert) assignments fall on the held range: what
+    the program's ``moe_held_assignments`` counts, by this file's routing."""
+    _, top_e = route(b, p["router"], cfg)
+    return jnp.sum((top_e >= held[0]) & (top_e < held[0] + held[1])).astype(jnp.float32)
 
 
 def experts(b, p, cfg, held):
     """b: (P, d) → the held experts' part of the layer's result."""
     first, count = held
-    g = jax.nn.softmax(b @ p["router"], axis=-1)
-    top_w, top_e = jax.lax.top_k(g, int(cfg["num_experts_per_tok"]))
-    if cfg["norm_topk_prob"]:
-        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
-    out = jnp.zeros_like(b)
-    for i in range(count):                            # every held expert on every token
+    top_w, top_e = route(b, p["router"], cfg)
+
+    def one(out, expert):                             # every held expert on every token
+        i, w_gate, w_up, w_down = expert
         w = jnp.sum(jnp.where(top_e == first + i, top_w, 0.0), axis=-1)
-        h = jax.nn.silu(b @ p["w_gate"][i]) * (b @ p["w_up"][i])
-        out = out + w[:, None] * (h @ p["w_down"][i])
-    return out
+        h = jax.nn.silu(b @ w_gate) * (b @ w_up)
+        return out + w[:, None] * (h @ w_down), None
+
+    return jax.lax.scan(one, jnp.zeros_like(b), (jnp.arange(count), p["w_gate"], p["w_up"], p["w_down"]))[0]
 
 
 def hidden(params, ids, pos, mask, cfg):
-    """ids: (P,) → (P, d) after the last block."""
+    """ids: (P,) → (P, d) after the last block, and the held assignments
+    summed over the layers."""
     eps, held = cfg["rms_norm_eps"], settings(cfg)["experts_held"]
 
     @jax.checkpoint
     def layer(x, blk):
         x = x + attention(rms_norm(x, blk["ln1"]["scale"], eps), blk["attn"], cfg, pos, mask)
-        return x + experts(rms_norm(x, blk["ln2"]["scale"], eps), blk["moe"], cfg, held)
+        b = rms_norm(x, blk["ln2"]["scale"], eps)
+        return x + experts(b, blk["moe"], cfg, held), held_assignments(b, blk["moe"], cfg, held)
 
-    x = params["embed"][ids]
+    x, count = params["embed"][ids], 0.0
     for i in range(int(cfg["layers"])):
-        x = layer(x, params[f"block_{i}"])
-    return x
+        x, n = layer(x, params[f"block_{i}"])
+        count = count + n
+    return x, count
 
 
-def noisy_logits(params, tokens, masked, cfg):
-    """tokens, masked: (L,) → (L, V) logits at the noisy positions."""
+def _noisy_logits(params, tokens, masked, cfg):
     s = settings(cfg)
     length = tokens.shape[0]
     ids = jnp.concatenate([jnp.where(masked, s["mask_token_id"], tokens), tokens])
     pos = jnp.concatenate([jnp.arange(length), jnp.arange(length)])
-    x = hidden(params, ids, pos, training_mask(length, s["block_length"]), cfg)[:length]
-    return rms_norm(x, params["ln_final"]["scale"], cfg["rms_norm_eps"]) @ params["lm_head"]["kernel"]
+    x, count = hidden(params, ids, pos, training_mask(length, s["block_length"]), cfg)
+    x = rms_norm(x[:length], params["ln_final"]["scale"], cfg["rms_norm_eps"])
+    return x @ params["lm_head"]["kernel"], count
+
+
+def noisy_logits(params, tokens, masked, cfg):
+    """tokens, masked: (L,) → (L, V) logits at the noisy positions."""
+    return _noisy_logits(params, tokens, masked, cfg)[0]
+
+
+def _loss(params, tokens, masked, p, cfg):
+    total = count = 0.0
+    for n in range(tokens.shape[0]):
+        logits, held = _noisy_logits(params, tokens[n], masked[n], cfg)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ce = -jnp.take_along_axis(logp, tokens[n][:, None], axis=-1)[:, 0]
+        total = total + jnp.sum(jnp.where(masked[n], ce, 0.0)) / p[n]
+        count = count + held
+    return total / tokens.size, count
 
 
 def loss(params, tokens, masked, p, cfg):
     """tokens, masked: (N, L); p: (N,) → the weighted masked cross entropy."""
-    total = 0.0
-    for n in range(tokens.shape[0]):
-        logp = jax.nn.log_softmax(noisy_logits(params, tokens[n], masked[n], cfg), axis=-1)
-        ce = -jnp.take_along_axis(logp, tokens[n][:, None], axis=-1)[:, 0]
-        total = total + jnp.sum(jnp.where(masked[n], ce, 0.0)) / p[n]
-    return total / tokens.size
+    return _loss(params, tokens, masked, p, cfg)[0]
 
 
-def loss_and_grad_norm(params, tokens, masked, p, cfg):
+def loss_and_grad_norms(params, tokens, masked, p, cfg):
+    """→ the loss, the norm of every parameter's gradient (a tree like
+    ``params``: one wrong leaf does not hide in a sum) and the held
+    assignments summed over layers and sequences."""
     with jax.default_matmul_precision("highest"):
-        value, grads = jax.value_and_grad(loss)(f32(params), tokens, masked, p, cfg)
-        return value, global_norm(grads)
+        (value, count), grads = jax.value_and_grad(_loss, has_aux=True)(f32(params), tokens, masked, p, cfg)
+        norms = jax.tree_util.tree_map(lambda g: jnp.sqrt(jnp.sum(jnp.square(g))), grads)
+        return value, norms, count
+
+
+def noise_is_the_assumed(masked, p, sigmas: float = 6.0) -> bool:
+    """Whether masks and probabilities that were handed in (host arrays:
+    masked (N, L) bool, p (N,)) can be what the file assumes: every p in
+    [NOISE_EPS, 1], and each sequence's masked count within ``sigmas``
+    standard deviations of a binomial (L, p).  What a run cannot see of
+    ``t ~ U(0, 1)`` in one sequence, the window's ``masked_tokens`` shows
+    over its hundred (``kinds/train_block_diffusion.py``)."""
+    length = masked.shape[1]
+    count = masked.sum(axis=1)
+    room = sigmas * (length * p * abs(1.0 - p)) ** 0.5 + 1.0
+    return bool(((p >= NOISE_EPS * (1 - 1e-6)) & (p <= 1.0)).all() and (abs(count - length * p) <= room).all())

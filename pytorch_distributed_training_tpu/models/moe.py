@@ -18,8 +18,9 @@ mesh reserves an ``expert`` axis and a complete framework fills it.
   parallelism asks of a chip before the exchange, and nothing standing in
   for the absent chips.  Assignments are sorted by expert and multiplied
   as grouped matrix products (``lax.ragged_dot``: on a TPU XLA's own
-  Mosaic grouped matmul) in chunks of rows under a loop whose trip count
-  follows the assignments there are: no static bound, nothing to overflow.
+  Mosaic grouped matmul) in chunks of ``ROWS_CHUNK`` rows under a loop
+  whose trip count follows the assignments there are: no static bound,
+  nothing to overflow.
 """
 
 from __future__ import annotations
@@ -299,6 +300,12 @@ def group_held_assignments(experts: jax.Array, first: int, held: int):
     return order, counts
 
 
+# Sorted rows one pass of ``held_experts``' loop takes in ``TopKMoe``: the
+# expected P·k·held/E of SDAR's chip share at 8192 positions (8192 rows) fits
+# one pass twice over; a layer the routing favours takes more passes.
+ROWS_CHUNK = 16384
+
+
 def _expert_chunk(x, w_rows, sizes, w_gate, w_up, w_down):
     """One chunk of sorted rows through its experts: x (C, d), the rows'
     router weights (C,), rows per held expert inside the chunk (held,)."""
@@ -399,8 +406,7 @@ class TopKMoe(nn.Module):
     ``experts_held = (first, count)`` is this chip's share of the
     ``num_experts`` the router scores (None: all of them).  Only the held
     experts have weights here.  Every held assignment is computed
-    (:func:`held_experts`); ``rows_chunk`` is how many sorted rows one pass
-    of its loop takes.
+    (:func:`held_experts`, ``ROWS_CHUNK`` sorted rows a pass of its loop).
 
     Sown into ``moe_counters`` (one scalar a layer, f32): the held
     assignments and the busiest held expert's rows.
@@ -411,7 +417,6 @@ class TopKMoe(nn.Module):
     mlp_dim: int
     experts_held: tuple | None = None
     norm_topk_prob: bool = True
-    rows_chunk: int = 16384
     dtype: Any = jnp.float32
 
     @nn.compact
@@ -442,5 +447,5 @@ class TopKMoe(nn.Module):
 
         with scope("moe/experts"):
             out = held_experts(tokens, weights, order, counts, stacks,
-                               min(self.rows_chunk, t * k))
+                               min(ROWS_CHUNK, t * k))
         return out.reshape(b, l, d).astype(x.dtype)

@@ -58,37 +58,26 @@ class SdarConfig:
     # num_experts; None holds all.  The layer computes its own experts'
     # part of the result (models/moe.TopKMoe).
     experts_held: tuple | None = None
-    # Sorted rows one pass of the expert loop takes (models/moe.held_experts).
-    moe_rows_chunk: int = 16384
-    # Block diffusion: tokens a block, the id a noised position takes, the
-    # floor of the masking probability (train/block_diffusion.py).
-    # ``mask_token_id`` None is the vocabulary's last row.
+    # Block diffusion: tokens a block and the id a noised position takes
+    # (train/block_diffusion.py); ``mask_token_id`` None is the vocabulary's
+    # last row.
     block_length: int = 4
     mask_token_id: int | None = None
-    noise_eps: float = 1e-3
     # Rematerialize each block in the backward (jax.checkpoint), keeping
-    # the ``remat_save`` names (REMAT_NAMES below; the flash kernels' output
-    # and log-sum-exp by default: the forward kernel then runs once).
+    # REMAT_SAVE below.
     remat: bool = False
-    remat_save: tuple = FLASH_RESIDUALS
 
     def __post_init__(self):
         if self.experts_held is not None:      # JSON hands a list
             object.__setattr__(self, "experts_held", tuple(self.experts_held))
-        object.__setattr__(self, "remat_save", tuple(self.remat_save))
-        unknown = set(self.remat_save) - set(REMAT_NAMES)
-        if unknown:
-            raise ValueError(f"remat_save {sorted(unknown)} not in {REMAT_NAMES}")
 
 
-# What a rematerialized block may keep for its backward, by
-# ``checkpoint_name``: bytes a block at P positions (bf16), and what keeping
-# it saves the backward from running again.
-REMAT_NAMES = (
-    *FLASH_RESIDUALS,           # P*H*dh*2 + P*H*4: the flash forward kernel
-    "attn_qkv",                 # P*(H+2*Hkv)*dh*2: three projections, q/k norm, RoPE
-    "attn_proj",                # P*d*2: the output projection
-)
+# What a rematerialized block keeps for its backward, by ``checkpoint_name``
+# (bytes a block at P positions, bf16): the flash kernels' output and
+# log-sum-exp (P*H*dh*2 + P*H*4: the forward kernel then runs once) and q, k,
+# v after their norm and RoPE (P*(H+2*Hkv)*dh*2: three projections and the
+# float32 passes over them).
+REMAT_SAVE = (*FLASH_RESIDUALS, "attn_qkv")
 
 
 class RMSNorm(nn.Module):
@@ -134,13 +123,12 @@ class SdarAttention(nn.Module):
         if block_diffusion:
             with scope("attn/block_diffusion"):
                 o = dot_product_attention(
-                    q, k, v, num_kv_heads=hkv, mask="block_diffusion",
-                    block_diffusion=(p // 2, cfg.block_length),
+                    q, k, v, block_diffusion=(p // 2, cfg.block_length),
                 )
         else:
             blk = jnp.arange(p) // cfg.block_length
             o = _xla_masked_attention(q, k, v, blk[None, :] <= blk[:, None])
-        return checkpoint_name(dense(cfg.hidden_size, "wo")(o.reshape(b, p, h * dh)), "attn_proj")
+        return dense(cfg.hidden_size, "wo")(o.reshape(b, p, h * dh))
 
 
 class SdarBlock(nn.Module):
@@ -156,7 +144,7 @@ class SdarBlock(nn.Module):
         return x + TopKMoe(
             cfg.num_experts, cfg.num_experts_per_tok, cfg.moe_intermediate_size,
             experts_held=cfg.experts_held, norm_topk_prob=cfg.norm_topk_prob,
-            rows_chunk=cfg.moe_rows_chunk, dtype=self.dtype, name="moe",
+            dtype=self.dtype, name="moe",
         )(y)
 
 
@@ -191,11 +179,9 @@ class SdarMoe(nn.Module):
         x = embed.astype(self.dtype)[tokens]
         block_cls = SdarBlock
         if cfg.remat:
-            # A block is recomputed in the backward except what
-            # ``remat_save`` names (REMAT_NAMES).
             block_cls = nn.remat(
                 SdarBlock, static_argnums=(3,),
-                policy=jax.checkpoint_policies.save_only_these_names(*cfg.remat_save),
+                policy=jax.checkpoint_policies.save_only_these_names(*REMAT_SAVE),
             )
         for i in range(cfg.num_hidden_layers):
             x = block_cls(cfg, self.dtype, name=f"block_{i}")(x, positions, block_diffusion)

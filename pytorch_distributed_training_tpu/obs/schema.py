@@ -97,18 +97,18 @@ METRICS: dict[str, dict] = {
     "moe_held_assignments": {
         "type": GAUGE, "labeled": False,
         "help": "dropless top-k MoE: (token, expert) assignments on the "
-                "experts this chip holds, summed over the layers, a "
-                "microbatch's mean over the step",
+                "experts this chip holds, a step's total over its layers "
+                "and microbatches",
     },
     "moe_load_max": {
         "type": GAUGE, "labeled": False,
         "help": "dropless top-k MoE: rows of the busiest held expert, "
-                "summed over the layers",
+                "summed over the step's layers and microbatches",
     },
     "masked_tokens": {
         "type": GAUGE, "labeled": False,
         "help": "block-diffusion objective: positions the step's noise "
-                "masked, a microbatch's mean over the step",
+                "masked, over all its microbatches",
     },
     # ---- what was lowered (obs/cost.py) --------------------------------
     "mosaic_custom_calls": {

@@ -213,9 +213,9 @@ def flash_attention(
         or bool(interpret)
     )
     if not backend_ok:
-        if block_diffusion is not None or k.shape[2] != q.shape[2]:
+        if block_diffusion is not None:
             return _xla_masked_attention(
-                q, k, v, _dense_mask(q, k, causal, block_diffusion), scale=scale
+                q, k, v, block_diffusion_mask(*block_diffusion), scale=scale
             )
         return _xla_attention(q, k, v, causal=causal, scale=scale)
     kernel = functools.partial(
@@ -328,16 +328,6 @@ def flash_preferred(
     return size_ok
 
 
-def _dense_mask(q, k, causal, block_diffusion):
-    """The (Lq, Lk) boolean mask of the XLA path for a mask kind."""
-    if block_diffusion is not None:
-        return block_diffusion_mask(*block_diffusion)
-    q_len, k_len = q.shape[1], k.shape[1]
-    if causal:
-        return jnp.tril(jnp.ones((q_len, k_len), dtype=bool), k=k_len - q_len)
-    return jnp.ones((q_len, k_len), dtype=bool)
-
-
 def dot_product_attention(
     q: jax.Array,
     k: jax.Array,
@@ -346,56 +336,47 @@ def dot_product_attention(
     causal: bool = False,
     scale: float | None = None,
     use_flash: bool | None = None,
-    num_kv_heads: int | None = None,
-    mask: str | None = None,
     block_diffusion: tuple[int, int] | None = None,
 ) -> jax.Array:
-    """Public attention entry point. q: (B, L, H, D), k/v: (B, L, Hkv, D)
-    → (B, L, H, D).
+    """Public attention entry point. q/k/v: (B, L, H, D) → (B, L, H, D).
 
     ``use_flash=None`` auto-selects: Pallas flash kernel on TPU backends for
     tile-aligned shapes, XLA everywhere else.
 
-    ``num_kv_heads`` (default H): grouped-query attention — k and v carry
-    that many heads and query head h reads K/V head h // (H / Hkv); neither
-    path repeats K/V to H heads.  ``mask`` names the mask kind: None (the
-    ``causal`` flag decides, as before), "causal", or "block_diffusion",
-    which needs ``block_diffusion=(L, B)`` — q and k hold 2L positions, a
-    noised copy then the clean copy, in blocks of B
-    (:func:`block_diffusion_mask`).  With neither argument the call is the
-    one it always was, kernel for kernel.
+    ``block_diffusion=(L, B)`` applies the block-diffusion training mask
+    (:func:`block_diffusion_mask`): q and k hold 2L positions, a noised copy
+    then the clean copy, in blocks of B.  Under it k and v may carry fewer
+    heads than q (grouped-query attention, read from ``k``'s shape: query
+    head h reads K/V head h // (H / Hkv), and neither path repeats K/V to H
+    heads).  Without it the call is the one it always was, kernel for kernel,
+    and takes equal head counts only: no model here runs grouped K/V under
+    the causal or the empty mask.
     """
-    if mask not in (None, "causal", "block_diffusion"):
-        raise ValueError(f"unknown mask kind {mask!r}")
-    causal = causal or mask == "causal"
-    if (mask == "block_diffusion") != (block_diffusion is not None):
-        raise ValueError('mask="block_diffusion" goes with block_diffusion=(L, B)')
-    if block_diffusion is not None and (
-        causal or q.shape[1] != 2 * block_diffusion[0] or k.shape[1] != q.shape[1]
-    ):
+    if block_diffusion is None and k.shape[2] != q.shape[2]:
         raise ValueError(
-            f"block diffusion over L={block_diffusion[0]} takes 2L positions "
-            f"and no causal flag; got q {q.shape}, k {k.shape}"
+            f"k carries {k.shape[2]} heads and q {q.shape[2]}: grouped K/V "
+            f"heads run under block_diffusion=(L, B) only"
         )
-    num_kv_heads = k.shape[2] if num_kv_heads is None else num_kv_heads
-    if k.shape[2] != num_kv_heads or v.shape[2] != num_kv_heads or q.shape[2] % num_kv_heads:
-        raise ValueError(
-            f"num_kv_heads={num_kv_heads}: k/v carry {k.shape[2]}/{v.shape[2]} "
-            f"heads, q {q.shape[2]}"
-        )
-    if block_diffusion is not None or num_kv_heads != q.shape[2]:
-        # The masked / grouped kinds: the multi-tile flash kernels on a TPU
-        # from the same size rule, the dense-mask XLA path elsewhere and
-        # for short lengths.
+    if block_diffusion is not None:
+        if causal or q.shape[1] != 2 * block_diffusion[0] or k.shape[1] != q.shape[1]:
+            raise ValueError(
+                f"block diffusion over L={block_diffusion[0]} takes 2L positions "
+                f"and no causal flag; got q {q.shape}, k {k.shape}"
+            )
+        if k.shape[2] != v.shape[2] or q.shape[2] % k.shape[2]:
+            raise ValueError(
+                f"k/v carry {k.shape[2]}/{v.shape[2]} heads, q {q.shape[2]}"
+            )
+        # The multi-tile flash kernels on a TPU from the same size rule, the
+        # dense-mask XLA path elsewhere and for short lengths.
         if use_flash is None:
             use_flash = flash_preferred(q.shape[1], k.shape[1], q.shape[3])
         if use_flash:
             return flash_attention(
-                q, k, v, causal=causal, scale=scale,
-                block_diffusion=block_diffusion,
+                q, k, v, scale=scale, block_diffusion=block_diffusion,
             )
         return _xla_masked_attention(
-            q, k, v, _dense_mask(q, k, causal, block_diffusion), scale=scale
+            q, k, v, block_diffusion_mask(*block_diffusion), scale=scale
         )
     if use_flash is None:
         import os

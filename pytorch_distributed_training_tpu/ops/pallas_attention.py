@@ -823,7 +823,7 @@ _flash_nlhd_grouped.defvjp(_flash_nlhd_grouped_vjp_fwd,
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                causal_offset=None, kv_len=None, bd=None):
-    if bd is not None or k.shape[1] != q.shape[1]:
+    if bd is not None:
         return _flash_tabled_fwd(
             q, k, v, causal, scale, block_q, block_k, interpret,
             causal_offset, kv_len, bd,
@@ -1103,10 +1103,10 @@ def _live_table(nq, nk, **mask):
 
 def _flash_tabled_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                       causal_offset, kv_len, bd):
-    """The multi-tile forward for the mask kinds whose dead tiles are many
-    (block diffusion) and for grouped K/V heads.  q: (B, H, Lq, D); k, v:
-    (B, Hkv, Lk, D) with H a multiple of Hkv: query head h reads K/V head
-    h // (H / Hkv) through the block index, never a repeated copy in HBM."""
+    """The multi-tile forward under the block-diffusion mask, whose dead
+    tiles are many.  q: (B, H, Lq, D); k, v: (B, Hkv, Lk, D) with H a
+    multiple of Hkv: query head h reads K/V head h // (H / Hkv) through the
+    block index, never a repeated copy in HBM."""
     b, h, q_len, d = q.shape
     k_len = k.shape[2]
     group = h // k.shape[1]
@@ -1147,7 +1147,7 @@ def _flash_tabled_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((b, h, q_len, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, q_len, 8), jnp.float32),
         ],
-        name="flash_bd_fwd" if bd is not None else "flash_fwd",
+        name="flash_bd_fwd",
         interpret=interpret,
     )(jnp.asarray(kv_of), q, k, v)
     return out, lse[..., 0]
@@ -1268,7 +1268,7 @@ def _flash_tabled_bwd(q, k, v, lse, delta, do, causal, scale, block_q,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_FUSED_BWD_VMEM),
-        name="flash_bd_bwd" if bd is not None else "flash_bwd",
+        name="flash_bd_bwd",
         interpret=interpret,
     )(jnp.asarray(kv_of), q, k, v, do, lse, delta)
 
@@ -1292,7 +1292,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret
     )
     lse = lse[..., None]
 
-    if bd is not None or k.shape[1] != h:
+    if bd is not None:
         return _flash_tabled_bwd(
             q, k, v, lse, delta, do, causal, scale, block_q, block_k,
             interpret, causal_offset, kv_len, bd,
@@ -1443,12 +1443,15 @@ def flash_attention(
 ) -> jax.Array:
     """Flash attention. q/k/v: (B, L, H, D) → (B, L, H, D).
 
-    ``k``/``v`` may carry fewer heads than ``q`` (grouped-query attention:
-    H a multiple of their head count); ``block_diffusion=(L, B)`` applies
-    the block-diffusion training mask over 2L positions (noised copy, then
-    clean copy, blocks of B; see ``_bd_mask``).  Either takes the
-    transposed multi-tile kernels, whose tile predicate skips the dead
-    tiles and whose block index reads each K/V head in place.
+    ``block_diffusion=(L, B)`` applies the block-diffusion training mask
+    over 2L positions (noised copy, then clean copy, blocks of B; see
+    ``_bd_mask``) and takes the transposed multi-tile kernels
+    ``flash_bd_fwd`` / ``flash_bd_bwd``, whose tile predicate skips the dead
+    tiles.  Under it ``k``/``v`` may carry fewer heads than ``q``
+    (grouped-query attention: H a multiple of their head count), read in
+    place through the block index.  Grouped K/V under the causal or the
+    empty mask is refused: no model runs it, and the kernels named
+    ``flash_fwd`` / ``flash_bwd*`` know one head count.
 
     Sequence lengths need not be lane-aligned: non-multiples of 128 (e.g.
     ViT-B/16's L = 197) are zero-padded to the next multiple, padded keys
@@ -1482,17 +1485,23 @@ def flash_attention(
     causal_offset = k_len - q_len
     kv_len = k_len if pad_k else None
     b, ql, h, d = q.shape
-    if block_diffusion is not None or k.shape[2] != h:
-        if causal and block_diffusion is not None:
+    if block_diffusion is None and k.shape[2] != h:
+        raise ValueError(
+            f"k carries {k.shape[2]} heads and q {h}: grouped K/V heads run "
+            f"under block_diffusion only"
+        )
+    if block_diffusion is not None:
+        if causal:
             raise ValueError("block_diffusion replaces the causal mask")
         if h % k.shape[2]:
             raise ValueError(
                 f"{h} query heads are not a multiple of {k.shape[2]} K/V heads"
             )
-        if block_diffusion is not None:
-            # The scale goes onto q once (a (P, d) pass XLA fuses into q's
-            # producer), not onto every (block_q, block_k) score tile.
-            q, scale = q * jnp.asarray(scale, q.dtype), 1.0
+        # The scale goes onto q once (a (P, d) pass XLA fuses into q's
+        # producer), not onto every (block_q, block_k) score tile.  q is
+        # rounded to its dtype a second time by this, which the causal
+        # kernels (scale on the float32 scores) are not.
+        q, scale = q * jnp.asarray(scale, q.dtype), 1.0
         qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
         out = _flash(
             qt, kt, vt, causal, scale, block_q, block_k, interpret,

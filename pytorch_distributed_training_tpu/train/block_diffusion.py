@@ -2,8 +2,8 @@
 masking schedule in LLaDA's form, Nie et al. 2025).
 
 A sequence of L clean tokens ``x0`` is noised per sequence: ``t ~ U(0, 1)``,
-``p = (1 - eps) t + eps``, and every position becomes the mask id with
-probability ``p``.  The model runs the noised copy followed by the clean
+``p = (1 - eps) t + eps`` with ``eps = NOISE_EPS``, and every position
+becomes the mask id with probability ``p``.  The model runs the noised copy followed by the clean
 copy (2L positions) under the block-diffusion mask and is scored where the
 noise fell, each masked position predicting its own token (no shift):
 
@@ -20,14 +20,16 @@ import jax
 import jax.numpy as jnp
 
 
+NOISE_EPS = 1e-3     # the floor of the masking probability (LLaDA's)
+
+
 def noise(tokens: jax.Array, key: jax.Array, cfg):
     """tokens (N, L) int → ``(noisy (N, L), masked (N, L) bool, p (N,))``.
-    ``cfg`` carries ``mask_token_id`` (None: the vocabulary's last row),
-    ``vocab_size`` and ``noise_eps``."""
+    ``cfg`` carries ``mask_token_id`` (None: the vocabulary's last row) and
+    ``vocab_size``."""
     n, length = tokens.shape
     t_key, m_key = jax.random.split(key)
-    eps = float(cfg.noise_eps)
-    p = (1.0 - eps) * jax.random.uniform(t_key, (n,), jnp.float32) + eps
+    p = (1.0 - NOISE_EPS) * jax.random.uniform(t_key, (n,), jnp.float32) + NOISE_EPS
     masked = jax.random.uniform(m_key, (n, length), jnp.float32) < p[:, None]
     mask_id = cfg.vocab_size - 1 if cfg.mask_token_id is None else cfg.mask_token_id
     return jnp.where(masked, jnp.asarray(mask_id, tokens.dtype), tokens), masked, p

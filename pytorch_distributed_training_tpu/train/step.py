@@ -26,8 +26,8 @@ from .state import TrainState
 
 
 # Per-step counters a model or an objective may return beside the loss
-# (device scalars; sums over the layers, means over the microbatches):
-# the dropless top-k layer's (models/moe.TopKMoe) and the block-diffusion
+# (device scalars; a step's totals over its layers and microbatches): the
+# dropless top-k layer's (models/moe.TopKMoe) and the block-diffusion
 # objective's.  Declared in obs/schema.py::METRICS; the trainer publishes
 # them at its log points.
 STEP_COUNTERS = ("moe_held_assignments", "moe_load_max", "masked_tokens")
@@ -305,6 +305,9 @@ def make_train_step(
             has_aux=True, pass_microbatch_index=True,
         )
         new_stats = aux.pop("batch_stats")
+        # The accumulation averaged what the microbatches returned, as the
+        # loss wants; a count is the step's total.
+        aux.update({k: aux[k] * num_microbatches for k in STEP_COUNTERS if k in aux})
         state, guard = apply_update(state, loss, grads, batch_stats=new_stats)
         metrics = {"loss": loss, **aux, **guard}
         return state, metrics

@@ -70,8 +70,7 @@ def test_flash_kernels_under_the_mask_match_the_xla_path(seq_len, block, tile):
                                        block_diffusion=(seq_len, block))
             else:
                 o = attention.dot_product_attention(
-                    q, k, v, use_flash=False, num_kv_heads=2, mask="block_diffusion",
-                    block_diffusion=(seq_len, block))
+                    q, k, v, use_flash=False, block_diffusion=(seq_len, block))
             return jnp.sum(o * jnp.cos(o)), o
         (_, o), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
         return (o, *grads)
@@ -85,8 +84,7 @@ def test_xla_path_equals_attention_with_repeated_heads():
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(keys[0], (1, 64, 4, 16))
     k, v = (jax.random.normal(x, (1, 64, 2, 16)) for x in keys[1:])
-    got = attention.dot_product_attention(q, k, v, num_kv_heads=2, mask="block_diffusion",
-                                          block_diffusion=(32, 4), use_flash=False)
+    got = attention.dot_product_attention(q, k, v, block_diffusion=(32, 4), use_flash=False)
     mask = dense_definition(32, 4)
     kr, vr = jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, kr) / 4.0
@@ -98,19 +96,28 @@ def test_attention_arguments_are_checked():
     x = jnp.zeros((1, 64, 4, 16))
     kv = jnp.zeros((1, 64, 2, 16))
     with pytest.raises(ValueError):
-        attention.dot_product_attention(x, kv, kv, mask="block_diffusion")       # no (L, B)
+        attention.dot_product_attention(x, kv, kv)                                # grouped K/V, no (L, B)
     with pytest.raises(ValueError):
-        attention.dot_product_attention(x, kv, kv, mask="sliding")
+        attention.dot_product_attention(x, kv, kv, causal=True)
     with pytest.raises(ValueError):
-        attention.dot_product_attention(x, kv, kv, num_kv_heads=4)
+        pa.flash_attention(x, kv, kv, causal=True)                                # nor in the kernels
     with pytest.raises(ValueError):
-        attention.dot_product_attention(x, kv, kv, mask="block_diffusion",
-                                        block_diffusion=(48, 4))                  # 2L != 64
+        attention.dot_product_attention(x, kv, kv, block_diffusion=(48, 4))       # 2L != 64
+    with pytest.raises(ValueError):
+        attention.dot_product_attention(x, kv, kv, causal=True, block_diffusion=(32, 4))
+    with pytest.raises(ValueError):
+        attention.dot_product_attention(x, kv[:, :, :1], kv, block_diffusion=(32, 4))   # k and v disagree
 
 
-def layer(held=None, rows_chunk=64, e=8, k=2):
-    return moe.TopKMoe(num_experts=e, num_experts_per_tok=k, mlp_dim=32, experts_held=held,
-                       rows_chunk=rows_chunk)
+def layer(held=None, e=8, k=2):
+    return moe.TopKMoe(num_experts=e, num_experts_per_tok=k, mlp_dim=32, experts_held=held)
+
+
+@pytest.fixture(autouse=True)
+def small_chunks(monkeypatch):
+    """The toy layers take several passes of the expert loop, as a layer the
+    routing favours does at full size."""
+    monkeypatch.setattr(moe, "ROWS_CHUNK", 64)
 
 
 def test_topk_routing_drops_nothing_under_a_biased_router():
@@ -135,12 +142,13 @@ def test_topk_routing_drops_nothing_under_a_biased_router():
 
 
 @pytest.mark.parametrize("rows_chunk", [8, 48, 4096])
-def test_a_share_under_a_biased_router_needs_no_room(rows_chunk):
+def test_a_share_under_a_biased_router_needs_no_room(rows_chunk, monkeypatch):
     """Expert 3 is the only one of the share (2, 3) any token picks, and every
     token picks it: four times the share's expected rows, all computed,
     whatever the chunk (smaller than, unaligned with, larger than the rows)."""
     x = jnp.abs(jax.random.normal(jax.random.PRNGKey(0), (1, 64, 64)))
-    net = layer(held=(2, 2), rows_chunk=rows_chunk)
+    monkeypatch.setattr(moe, "ROWS_CHUNK", rows_chunk)
+    net = layer(held=(2, 2))
     params = net.init(jax.random.PRNGKey(1), x)["params"]
     params = {**params, "router": jnp.zeros((64, 8)).at[:, 3].set(5.0).at[:, 6].set(4.0)}
     out, sown = net.apply({"params": params}, x, mutable=["moe_counters"])
@@ -154,9 +162,10 @@ def test_a_share_under_a_biased_router_needs_no_room(rows_chunk):
     np.testing.assert_allclose(out[0], want, rtol=1e-4, atol=1e-6)
 
 
-def test_gradients_flow_through_the_grouped_products():
+def test_gradients_flow_through_the_grouped_products(monkeypatch):
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 32, 64))
-    net = layer(held=(0, 4), rows_chunk=16)
+    monkeypatch.setattr(moe, "ROWS_CHUNK", 16)
+    net = layer(held=(0, 4))
     params = net.init(jax.random.PRNGKey(1), x)["params"]
     grads = jax.grad(lambda p: jnp.sum(jnp.square(net.apply({"params": p}, x))))(params)
     assert all(float(jnp.abs(g).max()) > 0 for g in jax.tree_util.tree_leaves(grads))
@@ -170,7 +179,7 @@ def test_noise_masks_the_drawn_share_and_follows_its_key():
     other = block_diffusion.noise(tokens, jax.random.PRNGKey(2), cfg)
     assert all(bool(jnp.array_equal(a, b)) for a, b in zip((noisy, masked, p), again))
     assert not bool(jnp.array_equal(masked, other[1]))
-    assert bool(jnp.all((p >= cfg.noise_eps) & (p <= 1.0)))
+    assert bool(jnp.all((p >= block_diffusion.NOISE_EPS) & (p <= 1.0)))
     assert bool(jnp.all(jnp.where(masked, noisy == 511, noisy == tokens)))     # the last row is the mask id
     # each sequence's masked fraction is its own p (256 draws: 4 sigma of a Bernoulli mean)
     frac = jnp.mean(masked, axis=1)
@@ -200,7 +209,9 @@ def test_the_lm_step_reads_the_objective_from_the_model():
         state, metrics = step(state, batch)
         first = float(metrics["loss"]) if first is None else first
     assert set(metrics) >= {"loss", "masked_tokens", "moe_held_assignments", "moe_load_max"}
-    assert float(metrics["moe_held_assignments"]) == 2 * 2 * 64 * 2     # layers x positions x k, all held
+    # a step's totals: 4 sequences as 2 microbatches x layers x positions x k, all held
+    assert float(metrics["moe_held_assignments"]) == 4 * 2 * 64 * 2
+    assert 0 < float(metrics["masked_tokens"]) <= 4 * 32 and float(metrics["masked_tokens"]).is_integer()
     assert np.isfinite(float(metrics["loss"]))
     with pytest.raises(ValueError, match="base_rng"):
         train.make_train_step(kind="lm")(state, batch)
