@@ -266,7 +266,7 @@ def test_cell_is_the_issues():
     assert cell["step"] == {"samples": 8, "microbatches": 8, "seq_len": 4096}
     assert cell["trace"]["annotations"] == ["train", "train/input_wait", "train/host_sync"]
     check = cell["reference_check"]
-    assert 0 < check["loss_rtol"] <= 1e-3 and 0 < check["grad_leaf_rtol"] <= 0.1
+    assert 0 < check["loss_rtol"] <= 1e-3 and 0 < check["grad_leaf_rtol"] <= 0.05 < check["router_grad_rtol"] < 1
     assert 0 < check["held_assignments_rtol"] <= 0.05 and len(check["reason"]) > 80
     # the loss a fresh model starts at: ln V and half the logits' variance at a 0.02 head
     first = cell["first_loss"]
@@ -283,6 +283,31 @@ def test_cell_is_the_issues():
             "moe.load_imbalance.train", "input.wait_share.train", "kernel.ragged_dot_roofline.train",
             "moe.grouped_matmul_share.train"} <= reported
     assert "kernel.flash_fwd_roofline.train" not in reported
+
+
+@pytest.mark.parametrize("wrong, passes", [
+    (None, True),
+    ("['block_0']['moe']['router']", True),        # 20 % off: inside the routers' own limit
+    ("['block_0']['moe']['w_down']", False),       # the same on any other leaf is one wrong leaf
+    ("['block_1']['moe']['w_down']", False),       # a gradient where the reference has exactly none
+    ("loss", False), ("held", False),
+])
+def test_kind_reads_the_routers_leaves_apart(wrong, passes):
+    from benchmark.kinds import train_block_diffusion as kind
+
+    check = load_json("workloads", "sdar-30b-a3b-chat.train.bd4k.json")["reference_check"]
+    # block_1's held experts drew no token: its expert stack's gradient is exactly 0
+    leaves = {"['block_0']['moe']['router']": 1.0, "['block_1']['moe']['router']": 0.5,
+              "['block_0']['moe']['w_down']": 5.0, "['block_1']['moe']['w_down']": 0.0, "['embed']": 300.0}
+    want = (10.0, leaves, 50000.0)
+    got = (10.0 * (1.01 if wrong == "loss" else 1.0),
+           {k: v * 1.001 + (0.2 * max(v, 1.0) if k == wrong else 0.0) for k, v in leaves.items()},
+           50000.0 * (1.2 if wrong == "held" else 1.0))
+    read = kind.readings(got, want)
+    assert kind.within(read, check) is passes
+    assert "router" in read["worst_router"] and "router" not in read["worst_leaf"]
+    assert read["grad_norm"] < 2e-3            # the whole tree's norm hides either leaf
+    assert set(kind.LIMITS.values()) <= set(check)
 
 
 def test_kind_rehearsal_counts_only():
