@@ -2429,6 +2429,7 @@ def _probe_compiled_cost(trainer, batches, mesh, sequence_parallel, emitter):
     import itertools
 
     from ..obs import step_cost_report
+    from ..ops.pallas_attention import flash_visited_pair_share
     from ..parallel.sharding import shard_batch
 
     # Bind the iterator ONCE and chain onto it — peeking via a fresh
@@ -2461,6 +2462,10 @@ def _probe_compiled_cost(trainer, batches, mesh, sequence_parallel, emitter):
             _gauge_mosaic_kernels(
                 emitter, "train_step", report.get("mosaic_kernels", {})
             )
+            for kernel, share in flash_visited_pair_share().items():
+                emitter.gauge(
+                    f"flash_visited_pair_share[kernel={kernel}]", share
+                )
             # Feed the live MFU gauge: the probe's compiled FLOPs + peak
             # over the trainer's rolling step-time window (obs/live.py).
             trainer.step_flops = report.get("flops")

@@ -119,6 +119,13 @@ METRICS: dict[str, dict] = {
                 "paged_decode_attn, ...); also fields of the compiled_cost "
                 "event (mosaic_custom_calls, mosaic_kernels)",
     },
+    "flash_visited_pair_share": {
+        "type": GAUGE, "labeled": True,
+        "help": "grouped native-layout flash kernels, per kernel (flash_fwd, "
+                "flash_bwd) of the train step as traced: key columns a q "
+                "block visits / (q rows x k_len); 1.0 for a whole-row tile, "
+                "0.625 for causal prefixes at 1024 x 1024 in 256-row blocks",
+    },
     # ---- SLO / alerting plane (obs/slo.py) ------------------------------
     "slo_alert_transitions": {
         "type": COUNTER, "labeled": False,

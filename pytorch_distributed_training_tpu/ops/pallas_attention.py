@@ -563,14 +563,16 @@ _flash_nlhd.defvjp(_flash_nlhd_vjp_fwd, _flash_nlhd_vjp_bwd)
 #
 # The whole-heads single-tile kernels above blow the ~16 MB scoped-VMEM
 # budget at k_len 1024 (all H heads' k/v rows plus per-head (L, L) f32
-# intermediates in one grid cell — measured 17.4 MB).  These variants tile
-# BOTH the heads (Hg-head groups, lane-aligned 128-element column slices of
-# the (B, L, H*D) layout) and the query length (dk/dv accumulate in VMEM
-# scratch across q blocks), so the flagship L=1024 shape also runs without
-# the (B, L, H, D) <-> (B, H, L, D) boundary transposes: GPT-2 136.2k ->
-# 142.5k tok/s (54.0% MFU).  At <= 512 the whole-heads kernels measured
-# slightly faster (154.7k vs 153.1k at seq 512; 146.8k vs 146.0k at 256),
-# so both families stay: whole-heads when its tiles fit, grouped otherwise.
+# intermediates in one grid cell).  These variants tile BOTH the heads
+# (Hg-head groups, lane-aligned 128-element column slices of the
+# (B, L, H*D) layout) and the query length (dk/dv accumulate in VMEM scratch
+# across q blocks), so GPT-2's L = 1024 runs without the (B, L, H, D) <->
+# (B, H, L, D) boundary transposes.  The key row of a head group stays in
+# VMEM whole.  Without ``causal`` a q block takes it as ONE tile; under
+# ``causal`` a q block takes the row's prefix up to its own frontier
+# (``_causal_spans``), and only the columns the diagonal (or ``kv_len``)
+# crosses build a mask.  What each form reads on the chip, and the forms
+# that were tried: PERF.md, sections 5 and 6.
 # ---------------------------------------------------------------------------
 
 
@@ -592,13 +594,22 @@ def _nlhd_single_fits(q_len, k_len, hd_all, itemsize):
     return fwd <= _VMEM_BUDGET and bwd <= _VMEM_BUDGET
 
 
-def _nlhd_group_config(q_len, k_len, num_heads, head_dim, itemsize):
+# The tallest q block of the causal form.  A q block computes every pair
+# above the diagonal inside its own rows, and re-reads (and transposes) the
+# key prefix once a block: 256 rows read fastest at 1024 x 1024 (PERF.md §6).
+_CAUSAL_BLOCK_Q = 256
+
+
+def _nlhd_group_config(q_len, k_len, num_heads, head_dim, itemsize,
+                       causal=False):
     """(heads_per_group, block_q_fwd, block_q_bwd) for the grouped kernels,
     or None when no configuration fits the VMEM budget.
 
     Group column slices must start at 128-element lane boundaries, so
     heads_per_group * head_dim % 128 == 0 (whole groups are exempt).
-    Prefers the largest group (best k/v reuse), then the largest blocks.
+    Prefers the largest group (best k/v reuse), then the largest blocks, up
+    to ``_CAUSAL_BLOCK_Q`` under ``causal``.  The estimates hold for both
+    forms: the last causal q block's tiles are as wide as the whole row.
     """
     def fwd_est(bq, hg):
         hd = hg * head_dim
@@ -614,7 +625,8 @@ def _nlhd_group_config(q_len, k_len, num_heads, head_dim, itemsize):
 
     # Candidate q blocks must tile q_len exactly — a non-divisor block
     # truncates the grid and silently skips trailing query rows.
-    bqs = [b for b in (512, 256, 128) if b <= q_len and q_len % b == 0]
+    tallest = min(q_len, _CAUSAL_BLOCK_Q if causal else 512)
+    bqs = [b for b in (512, 256, 128) if b <= tallest and q_len % b == 0]
     if not bqs:
         bqs = [q_len]
     for hg in range(num_heads, 0, -1):
@@ -629,13 +641,62 @@ def _nlhd_group_config(q_len, k_len, num_heads, head_dim, itemsize):
     return None
 
 
-def _fwd_kernel_grouped(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal,
+def _causal_spans(q_len, k_len, block_q, causal_offset, kv_len):
+    """``[(first, last, full, visit)]``: q blocks ``first .. last`` see the
+    key columns ``[0, visit)`` and need no mask over ``[0, full)``; both are
+    multiples of 128.  Static: the launcher's shapes decide it."""
+    keys = k_len if kv_len is None else kv_len
+    spans = []
+    for qi in range(q_len // block_q):
+        # keys the block's first row sees; its last row sees block_q - 1 more
+        first = qi * block_q + causal_offset + 1
+        last = first + block_q - 1
+        width = (
+            min(max(first, 0), keys) // _LANES * _LANES,
+            -(-min(max(last, 0), keys) // _LANES) * _LANES,
+        )
+        if spans and spans[-1][2:] == width:
+            spans[-1] = (spans[-1][0], qi, *width)
+        else:
+            spans.append((qi, qi, *width))
+    return spans
+
+
+# kernel name -> key columns visited ÷ (q rows × k_len) in the grouped launch
+# traced last: 1.0 for a whole-row tile, the causal prefixes' share otherwise.
+_visited_pair_share: dict[str, float] = {}
+
+
+def flash_visited_pair_share() -> dict[str, float]:
+    """The ``flash_visited_pair_share[kernel=..]`` gauges' values."""
+    return dict(_visited_pair_share)
+
+
+def _note_visited_share(kernel, spans, q_len, k_len, block_q):
+    _visited_pair_share[kernel] = 1.0 if spans is None else sum(
+        (last - first + 1) * block_q * visit
+        for first, last, _, visit in spans
+    ) / (q_len * k_len)
+
+
+def _in_each_span(qi, spans, body):
+    """Run ``body(parts)`` in the branch of the span that holds q block
+    ``qi``; ``parts`` are its ``(lo, hi, masked)`` key column ranges."""
+    for first, last, full, visit in spans:
+        parts = [(lo, hi, masked)
+                 for lo, hi, masked in ((0, full, False), (full, visit, True))
+                 if hi > lo]
+        pl.when((qi >= first) & (qi <= last))(functools.partial(body, parts))
+
+
+def _fwd_kernel_grouped(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                         causal_offset, scale, block_q, heads_per_group,
                         head_dim, kv_len):
-    """Grouped-heads one-tile-k forward (grid: b, head_groups, q_blocks)."""
+    """Grouped-heads one-tile-k forward without the causal mask (grid: b,
+    head_groups, q_blocks)."""
     qi = pl.program_id(2)
     mask = _single_tile_mask(
-        qi, block_q, k_ref.shape[1], causal=causal,
+        qi, block_q, k_ref.shape[1], causal=False,
         causal_offset=causal_offset, kv_len=kv_len,
     )
     for j in range(heads_per_group):
@@ -650,14 +711,69 @@ def _fwd_kernel_grouped(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal,
         lse_ref[0, 0, :, j] = lse[:, 0]
 
 
+def _fwd_kernel_grouped_causal(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                               causal_offset, scale, block_q, spans,
+                               heads_per_group, head_dim, kv_len):
+    """Grouped-heads causal forward (grid: b, head_groups, q_blocks): a
+    direct softmax over the key row's prefix up to the q block's frontier,
+    taken in an unmasked and a masked range of columns."""
+    qi = pl.program_id(2)
+
+    def prefix(parts):
+        for j in range(heads_per_group):
+            cols = slice(j * head_dim, (j + 1) * head_dim)
+            q = q_ref[0, :, cols]
+            m = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
+            tiles = []
+            for lo, hi, masked in parts:
+                s = jax.lax.dot_general(
+                    q, k_ref[0, lo:hi, cols],
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale
+                mask = None
+                if masked:
+                    q_ids = qi * block_q + jax.lax.broadcasted_iota(
+                        jnp.int32, s.shape, 0)
+                    k_ids = lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                    mask = q_ids + causal_offset >= k_ids
+                    if kv_len is not None:
+                        mask &= k_ids < kv_len
+                    s = jnp.where(mask, s, _NEG_INF)
+                m = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                tiles.append((s, mask, slice(lo, hi)))
+            l = jnp.zeros((block_q, 1), jnp.float32)
+            o = jnp.zeros((block_q, head_dim), jnp.float32)
+            for s, mask, rows in tiles:
+                p = jnp.exp(s - m)
+                if mask is not None:
+                    # a row that sees no key keeps m at _NEG_INF, where
+                    # exp(s - m) is 1: zero it, so l counts visible keys only
+                    p = jnp.where(mask, p, 0.0)
+                l += jnp.sum(p, axis=1, keepdims=True)
+                v = v_ref[0, rows, cols]
+                o += jax.lax.dot_general(
+                    p.astype(v.dtype), v,
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, :, cols] = (o / l_safe).astype(o_ref.dtype)
+            lse_ref[0, 0, :, j] = (m + jnp.log(l_safe))[:, 0]
+
+    _in_each_span(qi, spans, prefix)
+
+
 def _bwd_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, causal,
+                        dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                         causal_offset, scale, block_q, heads_per_group,
-                        head_dim, kv_len):
+                        head_dim, kv_len, spans=None):
     """Grouped-heads backward, q-blocked (grid: b, head_groups, q_blocks).
 
     dq writes per q block; dk/dv accumulate in f32 VMEM scratch across the
-    (innermost) q-block dimension and flush on its last iteration."""
+    (innermost) q-block dimension and flush on its last iteration.  Without
+    ``spans`` the key row is one tile; with them (the causal form) a q block
+    takes the prefix up to its frontier and adds into those rows only."""
     qi = pl.program_id(2)
     num_q = pl.num_programs(2)
     k_len = k_ref.shape[1]
@@ -667,33 +783,52 @@ def _bwd_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    for j in range(heads_per_group):
-        lo = j * head_dim
-        q = q_ref[0, :, lo:lo + head_dim]
-        k = k_ref[0, :, lo:lo + head_dim]
-        v = v_ref[0, :, lo:lo + head_dim]
-        do = do_ref[0, :, lo:lo + head_dim]
-        lse = lse_ref[0, 0, :, j][:, None]
-        delta = delta_ref[0, 0, :, j][:, None]
-        p, ds = _bwd_block(
-            q, k, v, do, lse, delta, qi, 0,
-            causal=causal, causal_offset=causal_offset, scale=scale,
-            block_q=block_q, block_k=k_len, kv_len=kv_len,
-        )
-        ds_c = ds.astype(k.dtype)
-        dq_ref[0, :, lo:lo + head_dim] = jax.lax.dot_general(
-            ds_c, k, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(dq_ref.dtype)
-        dk_scr[:, lo:lo + head_dim] += jax.lax.dot_general(
-            ds_c, q, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dv_scr[:, lo:lo + head_dim] += jax.lax.dot_general(
-            p.astype(do.dtype), do,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def rows(parts):
+        for j in range(heads_per_group):
+            lo = j * head_dim
+            q = q_ref[0, :, lo:lo + head_dim]
+            dq = jnp.zeros((block_q, head_dim), jnp.float32) if not parts \
+                else None       # a q block that sees no key
+            tiles = []
+            for k_lo, k_hi, masked in parts:
+                k = k_ref[0, k_lo:k_hi, lo:lo + head_dim]
+                v = v_ref[0, k_lo:k_hi, lo:lo + head_dim]
+                if not tiles:
+                    do = do_ref[0, :, lo:lo + head_dim]
+                    lse = lse_ref[0, 0, :, j][:, None]
+                    delta = delta_ref[0, 0, :, j][:, None]
+                # a range's columns count from k_lo: the mask shifts with it
+                p, ds = _bwd_block(
+                    q, k, v, do, lse, delta, qi, 0,
+                    causal=masked and spans is not None,
+                    causal_offset=causal_offset - k_lo, scale=scale,
+                    block_q=block_q, block_k=k_hi - k_lo,
+                    kv_len=kv_len - k_lo if masked and kv_len is not None
+                    else None,
+                )
+                ds_c = ds.astype(k.dtype)
+                part = jax.lax.dot_general(
+                    ds_c, k, dimension_numbers=(((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                dq = part if dq is None else dq + part
+                tiles.append((slice(k_lo, k_hi), ds_c, p))
+            dq_ref[0, :, lo:lo + head_dim] = dq.astype(dq_ref.dtype)
+            for k_rows, ds_c, p in tiles:
+                dk_scr[k_rows, lo:lo + head_dim] += jax.lax.dot_general(
+                    ds_c, q, dimension_numbers=(((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                dv_scr[k_rows, lo:lo + head_dim] += jax.lax.dot_general(
+                    p.astype(do.dtype), do,
+                    dimension_numbers=(((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+
+    if spans is None:
+        rows([(0, k_len, True)])
+    else:
+        _in_each_span(qi, spans, rows)
 
     @pl.when(qi == num_q - 1)
     def _finalize():
@@ -701,6 +836,10 @@ def _bwd_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+# The two launchers are jitted on their own: a model's layers hand them the
+# same shapes, so the kernel is traced and lowered to Mosaic once a program
+# and not once a layer (XLA inlines the calls again).
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_fwd_grouped(q, k, v, causal, scale, interpret, causal_offset,
                        kv_len, num_heads, cfg):
     b, q_len, hd_all = q.shape
@@ -709,16 +848,18 @@ def _flash_fwd_grouped(q, k, v, causal, scale, interpret, causal_offset,
     hg, bq, _ = cfg
     ng = num_heads // hg
     hd = hg * d
-    kernel = functools.partial(
-        _fwd_kernel_grouped,
-        causal=causal,
-        causal_offset=k_len - q_len if causal_offset is None else causal_offset,
-        scale=scale,
-        block_q=bq,
-        heads_per_group=hg,
-        head_dim=d,
-        kv_len=kv_len,
-    )
+    causal_offset = k_len - q_len if causal_offset is None else causal_offset
+    tile = dict(causal_offset=causal_offset, scale=scale, block_q=bq,
+                heads_per_group=hg, head_dim=d, kv_len=kv_len)
+    spans = None
+    if causal:
+        spans = _causal_spans(q_len, k_len, bq, causal_offset, kv_len)
+        kernel = functools.partial(
+            _fwd_kernel_grouped_causal, spans=spans, **tile
+        )
+    else:
+        kernel = functools.partial(_fwd_kernel_grouped, **tile)
+    _note_visited_share("flash_fwd", spans, q_len, k_len, bq)
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, ng, q_len // bq),
@@ -741,6 +882,7 @@ def _flash_fwd_grouped(q, k, v, causal, scale, interpret, causal_offset,
     return out, lse
 
 
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12))
 def _flash_bwd_grouped(q, k, v, out, lse, do, causal, scale, interpret,
                        causal_offset, kv_len, num_heads, cfg):
     b, q_len, hd_all = q.shape
@@ -756,16 +898,20 @@ def _flash_bwd_grouped(q, k, v, out, lse, do, causal, scale, interpret,
         ),
         axis=-1,
     ).transpose(0, 2, 1, 3)
+    spans = None
+    if causal:
+        spans = _causal_spans(q_len, k_len, bq, causal_offset, kv_len)
     kernel = functools.partial(
         _bwd_kernel_grouped,
-        causal=causal,
         causal_offset=causal_offset,
         scale=scale,
         block_q=bq,
         heads_per_group=hg,
         head_dim=d,
         kv_len=kv_len,
+        spans=spans,
     )
+    _note_visited_share("flash_bwd", spans, q_len, k_len, bq)
     qspec = pl.BlockSpec((1, bq, hd), lambda b_, g, qi: (b_, qi, g))
     kspec = pl.BlockSpec((1, k_len, hd), lambda b_, g, qi: (b_, 0, g))
     hspec = pl.BlockSpec((1, 1, bq, hg), lambda b_, g, qi: (b_, g, qi, 0))
@@ -1424,6 +1570,8 @@ def native_layout_selected(
     ):
         return True
     if kp <= min(bk, 1024):
+        # the same answer under ``causal``: its cap on the q block never
+        # takes the smallest block away, and the smallest decides the fit
         return _nlhd_group_config(qp, kp, num_heads, head_dim, itemsize) \
             is not None
     return False
@@ -1535,7 +1683,9 @@ def flash_attention(
         # short key row, or wide models the whole-heads path cannot fit:
         # the grouped-heads variants tile heads AND query length to stay
         # inside VMEM while still consuming the native layout.
-        cfg = _nlhd_group_config(ql, k.shape[1], h, d, q.dtype.itemsize)
+        cfg = _nlhd_group_config(
+            ql, k.shape[1], h, d, q.dtype.itemsize, causal
+        )
         if cfg is not None:
             q2, k2, v2 = (x.reshape(x.shape[0], x.shape[1], h * d)
                           for x in (q, k, v))

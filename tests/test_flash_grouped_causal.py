@@ -1,0 +1,228 @@
+"""The grouped native-layout flash pair (k_len 513..1024): under ``causal`` a
+q block takes the key row's prefix up to its frontier and masks only the
+columns the diagonal crosses.  Interpret mode, two heads of 64, lengths that
+still take the grouped path."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pytorch_distributed_training_tpu.ops import flash_attention
+from pytorch_distributed_training_tpu.ops import pallas_attention as pa
+from pytorch_distributed_training_tpu.ops.attention import _xla_attention
+
+CASES = {
+    # name: (q_len, k_len, causal)
+    "causal_square": (1024, 1024, True),
+    "causal_square_640": (640, 640, True),
+    "causal_q_shorter": (384, 768, True),       # causal_offset 384
+    "causal_padded_kv_len": (700, 700, True),   # padded to 768, kv_len 700
+    "causal_q_shorter_padded": (200, 900, True),
+    "causal_fully_masked_rows": (768, 640, True),  # rows 0..127 see no key
+    "non_causal": (640, 640, False),
+    "non_causal_padded": (520, 700, False),
+}
+
+
+@pytest.fixture
+def grouped_launches(monkeypatch):
+    """Configurations the grouped launchers were handed, forward first."""
+    seen = []
+    for name in ("_flash_fwd_grouped", "_flash_bwd_grouped"):
+        real = getattr(pa, name)
+
+        def spy(*args, _real=real):
+            seen.append(args[-1])
+            return _real(*args)
+
+        monkeypatch.setattr(pa, name, spy)
+    return seen
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_grouped_pair_matches_xla(case, grouped_launches):
+    q_len, k_len, causal = CASES[case]
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    q = jax.random.normal(kq, (1, q_len, 2, 64))
+    k = jax.random.normal(kk, (1, k_len, 2, 64))
+    v = jax.random.normal(kv, (1, k_len, 2, 64))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, interpret=True)
+
+    def ref(q, k, v):
+        return _xla_attention(q, k, v, causal=causal)
+
+    np.testing.assert_allclose(flash(q, k, v), ref(q, k, v), atol=3e-5, rtol=3e-5)
+    # a weighted sum, so that every output element has a gradient of its own
+    w = jax.random.normal(jax.random.PRNGKey(1), (1, q_len, 2, 64))
+    loss = lambda f: lambda q, k, v: jnp.sum(f(q, k, v) * w)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        np.testing.assert_allclose(a, b, atol=3e-4, rtol=3e-4, err_msg=f"d{name}")
+    assert len(grouped_launches) >= 2, "the call did not take the grouped pair"
+    if case == "causal_fully_masked_rows":
+        assert not np.any(np.asarray(flash(q, k, v))[:, :128])
+        assert not np.any(np.asarray(got[0])[:, :128])
+
+
+def test_bf16_causal_square_close_to_f32_reference():
+    """The precisions the train step runs: bf16 operands, f32 statistics."""
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(3), 3)
+    q, k, v = (jax.random.normal(x, (2, 1024, 2, 64)) for x in (kq, kk, kv))
+    got = flash_attention(*(x.astype(jnp.bfloat16) for x in (q, k, v)),
+                          causal=True, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    want = _xla_attention(
+        *(x.astype(jnp.bfloat16).astype(jnp.float32) for x in (q, k, v)),
+        causal=True,
+    )
+    np.testing.assert_allclose(got.astype(jnp.float32), want, atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("q_len,k_len,offset,kv_len", [
+    (1024, 1024, 0, None), (384, 768, 384, None), (768, 768, 0, 700),
+    (768, 640, -128, None), (640, 640, 0, None), (1024, 256, -768, None),
+])
+@pytest.mark.parametrize("block_q", [128, 256])
+def test_causal_spans_against_the_dense_mask(q_len, k_len, offset, kv_len, block_q):
+    """Every q block lies in one span; columns ``[0, full)`` hold no masked
+    pair, columns past ``visit`` no live one, and both are as tight as 128
+    columns allow — by the dense mask itself."""
+    rows, cols = np.arange(q_len)[:, None], np.arange(k_len)[None, :]
+    dense = (rows + offset >= cols) & (cols < (k_len if kv_len is None else kv_len))
+    spans = pa._causal_spans(q_len, k_len, block_q, offset, kv_len)
+    blocks = [qi for first, last, _, _ in spans for qi in range(first, last + 1)]
+    assert blocks == list(range(q_len // block_q))
+    for first, last, full, visit in spans:
+        assert full % 128 == 0 and visit % 128 == 0 and full <= visit <= k_len
+        for qi in range(first, last + 1):
+            tile = dense[qi * block_q:(qi + 1) * block_q]
+            assert tile[:, :full].all() and not tile[:, visit:].any()
+            assert visit == 0 or tile[:, visit - 128:visit].any()
+            assert full == visit or not tile[:, full:full + 128].all()
+
+
+def test_visited_pair_share_pinned_for_gpt2(monkeypatch):
+    """(1024, 1024) at the blocks the rule picks: 256-row q blocks visit 10
+    of the square's 16 tiles of 256; without ``causal`` the whole row."""
+    q = jnp.zeros((1, 1024, 12 * 64), jnp.bfloat16)
+    # the launchers note the share as they trace, and are jitted: call them
+    # bare, so that a trace an earlier test cached cannot stand in
+    for name in ("_flash_fwd_grouped", "_flash_bwd_grouped"):
+        monkeypatch.setattr(pa, name, getattr(pa, name).__wrapped__)
+    for causal, share in ((True, 0.625), (False, 1.0)):
+        cfg = pa._nlhd_group_config(1024, 1024, 12, 64, 2, causal)
+        monkeypatch.setattr(pa, "_visited_pair_share", {})
+        # abstract evaluation only
+        jax.eval_shape(
+            lambda q: jax.vjp(
+                lambda q, k, v: pa._flash_nlhd_grouped(
+                    q, k, v, causal, 0.125, True, 0, None, 12, cfg),
+                q, q, q,
+            )[1](q),
+            q,
+        )
+        assert pa.flash_visited_pair_share() == {
+            "flash_fwd": share, "flash_bwd": share,
+        }
+
+
+GPT2 = dict(q_len=1024, k_len=1024, num_heads=12, head_dim=64, itemsize=2)
+
+
+def test_group_config_for_gpt2_stays_under_the_vmem_budget():
+    """The rule's choice at GPT-2's shape, and its footprint counted here
+    tile by tile at the widest causal q block (the last: the whole row)."""
+    assert not pa._nlhd_single_fits(1024, 1024, 768, 2)
+    hg, bq_f, bq_b = pa._nlhd_group_config(**GPT2, causal=True)
+    assert (hg, bq_f, bq_b) == (6, 256, 256)
+    hd, k_len = hg * 64, 1024
+    widest = max(visit for _, _, _, visit in pa._causal_spans(1024, 1024, bq_b, 0, None))
+    assert widest == k_len
+    fwd = (2 * k_len * hd + 2 * bq_f * hd) * 2 + 2 * bq_f * widest * 4
+    bwd = (3 * bq_b * hd + 4 * k_len * hd) * 2 + 2 * k_len * hd * 4 \
+        + 4 * bq_b * widest * 4
+    assert fwd <= pa._VMEM_BUDGET and bwd <= pa._VMEM_BUDGET
+    # the whole-row form keeps its blocks
+    assert pa._nlhd_group_config(**GPT2) == (6, 512, 256)
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("q_len,k_len,heads,dim,causal", [
+    (1024, 1024, 12, 64, True),     # GPT-2's microbatch
+    (1024, 1024, 12, 64, False),
+    (700, 700, 12, 64, True),       # padded to 768, kv_len 700
+    (384, 768, 12, 64, True),
+    (256, 256, 16, 256, True),      # a wide model: the rule's VMEM edge
+])
+def test_grouped_pair_compiles_for_a_v5e(one_v5e, q_len, k_len, heads, dim, causal):
+    """Mosaic takes the pair at real widths (VMEM, tiling, the static prefix
+    slices), and the calls keep the names and results the roofline readers
+    tell them apart by.  Compiled for a described chip: nothing runs."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def pair(q, k, v):
+        out, vjp = jax.vjp(
+            lambda q, k, v: pa.flash_attention(q, k, v, causal=causal, interpret=False),
+            q, k, v,
+        )
+        return (out,) + vjp(out)
+
+    shape = lambda n: jax.ShapeDtypeStruct((8, n, heads, dim), jnp.bfloat16,
+                                           sharding=one_v5e)
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(pair).lower(shape(q_len), shape(k_len), shape(k_len)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+        compilation_cache.reset_cache()
+    # outside a step the instruction's name carries the transformations
+    # around the call (``%jvp_flash_fwd_.1``); the result follows `` = ``
+    calls = [line.split(" custom-call(")[0].split(" = ")
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2
+    results = {role: result for name, result in calls
+               for role in ("flash_fwd", "flash_bwd") if role in name}
+    qp, kp, w = q_len + (-q_len) % 128, k_len + (-k_len) % 128, heads * dim
+    assert results["flash_fwd"].count(f"bf16[8,{qp},{w}]") == 1 and "f32[" in results["flash_fwd"]
+    assert results["flash_bwd"].count("bf16[8,") == 3 and "f32[" not in results["flash_bwd"]
+    assert f"bf16[8,{kp},{w}]" in results["flash_bwd"]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("q_len,k_len,heads,dim,itemsize", [
+    (1024, 1024, 12, 64, 2), (1024, 1024, 12, 64, 4), (197, 197, 12, 64, 2),
+    (512, 512, 12, 64, 2), (640, 640, 2, 64, 4), (384, 768, 2, 64, 4),
+    (256, 1024, 16, 1024, 4), (2048, 2048, 12, 64, 2),
+])
+def test_native_layout_selected_agrees_with_the_dispatch(
+        monkeypatch, causal, q_len, k_len, heads, dim, itemsize):
+    taken = []
+    monkeypatch.setattr(pa, "_flash_nlhd", lambda q, *a: taken.append("single") or q)
+    monkeypatch.setattr(pa, "_flash_nlhd_grouped",
+                        lambda q, *a: taken.append("grouped") or q)
+    monkeypatch.setattr(pa, "_flash", lambda q, *a: taken.append("transposed") or q)
+    dtype = {2: jnp.bfloat16, 4: jnp.float32}[itemsize]
+    q = jax.ShapeDtypeStruct((1, q_len, heads, dim), dtype)
+    kv = jax.ShapeDtypeStruct((1, k_len, heads, dim), dtype)
+    jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal=causal), q, kv, kv)
+    assert len(taken) == 1
+    assert pa.native_layout_selected(
+        q_len, k_len, heads, dim, itemsize=itemsize
+    ) == (taken[0] != "transposed")
