@@ -964,8 +964,6 @@ class _RuleRunner:
 DEFAULT_LINT_TARGETS = (
     "pytorch_distributed_training_tpu",
     "tools",
-    "bench.py",
-    "bench_attention.py",
     "__graft_entry__.py",
 )
 

@@ -1193,8 +1193,8 @@ def run(
     policy = make_policy(precision)
     # MoE dispatch auto-selection: the CLI mesh has no expert axis, so the
     # scatter formulation (no (T,E,C) one-hots — models/moe.py, measured
-    # +15% tok/s in MOE_BENCH.json) is always sound here; an explicit
-    # --model-overrides moe_dispatch=einsum wins.
+    # +15% tok/s in rounds 1-5, another machine) is always sound here; an
+    # explicit --model-overrides moe_dispatch=einsum wins.
     is_moe = model == "gpt2_moe" or (
         model.startswith("gpt2") and int(overrides.get("num_experts", 0) or 0) > 0
     )
@@ -1998,8 +1998,7 @@ def _run_serve(
     if emitter is not None:
         emitter.phase("serve_params_ready")
     # Serving reads every weight once per tick; compute-dtype params halve
-    # the per-tick weight traffic vs the train-state fp32 tree (same trade
-    # as bench.py --generate).
+    # the per-tick weight traffic vs the train-state fp32 tree.
     params = jax.tree_util.tree_map(
         lambda x: jnp.asarray(x, policy.compute_dtype), params
     )

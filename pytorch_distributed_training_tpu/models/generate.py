@@ -28,8 +28,7 @@ from jax import lax
 
 def uses_approx_top_k(exact_top_k: bool = False) -> bool:
     """True when :func:`sample_logits` will take the approx_max_k
-    threshold — the single source of the dispatch rule, shared with the
-    bench so recorded metadata cannot drift from behavior."""
+    threshold — the single source of the dispatch rule."""
     return not exact_top_k and jax.default_backend() == "tpu"
 
 
@@ -65,8 +64,8 @@ def sample_logits(logits, rng, *, temperature=1.0, top_k=None, exact_top_k=False
     cut may land a few ranks off among near-tied logits, a sub-temperature
     perturbation of the sampling distribution).  A full-vocab
     ``lax.top_k`` sort measured 45% of the whole decode step at GPT-2's
-    50k vocab (GEN_BENCH.json); pass ``exact_top_k=True`` for the exact
-    semantics where that matters more than throughput.
+    50k vocab (rounds 1-5, another machine); pass ``exact_top_k=True`` for
+    the exact semantics where that matters more than throughput.
     """
     if temperature == 0.0 or top_k == 1:
         # top_k=1 IS greedy whatever the temperature; keeping it on the

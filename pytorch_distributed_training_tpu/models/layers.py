@@ -182,15 +182,14 @@ class SelfAttention(nn.Module):
     # path).  "none" is the native-dtype status quo.
     kv_quant: str = "none"
     # "auto" routes through ops.dot_product_attention's measured dispatch.
-    # "bhld" keeps activations (B, H, L, Dh) end-to-end between the qkv and
-    # output projections: q/k/v transpose ONCE into the layout XLA's
-    # batched-dot canonicalization wants (batch dims b,h leading), the
-    # score/combine einsums run canonically with zero internal relayouts,
-    # and the output projection consumes (B, H, L, Dh) directly by
-    # contracting (h, d) against the reshaped proj kernel — the
-    # model-layer-contract experiment VIT_ROOFLINE (deleted: not measured on
-    # the current machine) names (~10 GB/step
-    # of dot-canonicalization relayout traffic at ViT batch 128).  XLA
+    # "bhld2" keeps activations (B, H, L, Dh) end-to-end between the qkv and
+    # output projections: q/k/v come head-major straight from the
+    # projection GEMMs — the layout XLA's batched-dot canonicalization
+    # wants (batch dims b,h leading) — the score/combine einsums run
+    # canonically with zero internal relayouts, and the output projection
+    # consumes (B, H, L, Dh) directly by contracting (h, d) against the
+    # reshaped proj kernel (~10 GB/step of dot-canonicalization relayout
+    # traffic at ViT batch 128; rounds 1-5, another machine).  XLA
     # non-causal path only (ViT); param tree is identical to "auto".
     attn_layout: str = "auto"
 
@@ -206,22 +205,22 @@ class SelfAttention(nn.Module):
 
         b, l, d = x.shape
         head_dim = d // self.num_heads
-        bhld_ok = (
-            self.attn_layout in ("bhld", "bhld2")
+        if self.attn_layout not in ("auto", "bhld2"):
+            raise ValueError(
+                f"unknown attn_layout {self.attn_layout!r} (auto|bhld2)"
+            )
+        if (
+            self.attn_layout == "bhld2"
             and not self.decode
             and not self.causal
             and self.sp_mesh is None
-        )
-        if bhld_ok and self.attn_layout == "bhld2":
-            # Variant: head-major q/k/v straight from the projection GEMMs.
+        ):
             q3, k3, v3 = _QkvToHeads(
                 features=d, num_heads=self.num_heads, dtype=self.dtype,
                 name="qkv",
             )(x)
             return self._bhld_core(q3, k3, v3, d)
         qkv = nn.Dense(3 * d, dtype=self.dtype, name="qkv")(x)
-        if bhld_ok:
-            return self._bhld_attend(qkv, b, l, d, head_dim)
         # Both split forms select the IDENTICAL elements (q is columns
         # 0..d-1 either way: axis 2 of the (3, H, Dh) reshape is the
         # slowest-varying of the packed columns), so the choice is pure
@@ -268,28 +267,9 @@ class SelfAttention(nn.Module):
         out = out.reshape(b, l, d)
         return nn.Dense(d, dtype=self.dtype, name="proj")(out)
 
-    def _bhld_attend(self, qkv, b, l, d, head_dim):
-        """(B, H, L, Dh)-contract front end: q/k/v as last-axis column
-        spans of the fused qkv (identical elements to the other splits),
-        transposed once to (B, H, L, Dh), then ``_bhld_core``.  The
-        parameter tree (qkv/proj Dense) is identical to the default path;
-        only activation layouts differ.
-        """
-        h = self.num_heads
-        q = jnp.transpose(
-            qkv[..., :d].reshape(b, l, h, head_dim), (0, 2, 1, 3)
-        )
-        k = jnp.transpose(
-            qkv[..., d:2 * d].reshape(b, l, h, head_dim), (0, 2, 1, 3)
-        )
-        v = jnp.transpose(
-            qkv[..., 2 * d:].reshape(b, l, h, head_dim), (0, 2, 1, 3)
-        )
-        return self._bhld_core(q, k, v, d)
-
     def _bhld_core(self, q, k, v, d):
         """Canonical (b, h)-leading attention + head-consuming projection
-        shared by both bhld front ends.  Both attention einsums have batch
+        (``attn_layout="bhld2"``).  Both attention einsums have batch
         dims (b, h) leading — the canonical form XLA's batched-dot
         lowering wants, so no internal relayouts are emitted — and the
         output projection contracts (h, d) straight off the attention
@@ -367,7 +347,7 @@ class SelfAttention(nn.Module):
         # (b, h) with a contiguous (L, Dh) tile per head, which the TPU
         # executes 2x faster than the (B, L, H, Dh) layout's interleaved
         # heads (measured 89.5 → 45.1 µs per layer at B=32/L=256,
-        # tools/gen_diag.py sweep; decode attention is the largest tick
+        # rounds 1-5, another machine; decode attention is the largest tick
         # component, 12×87 µs ≈ half the step before this).
         #
         # Quantized storage (kv_quant): the SAME layout at the stored

@@ -118,10 +118,9 @@ def _top1_scatter_indices(logits: jax.Array, capacity: int):
     the combine einsum a row-gather: ``flat = expert·C + slot`` indexes the
     flattened (E·C, D) expert buffers, with dropped tokens pointed one past
     the end.  Replacing the einsums with scatter-add/gather removes both
-    the (T, E, C) one-hot bytes and their O(T·E·C·D) matmul FLOPs — on the
-    MOE_BENCH config (T=4096, E=8, C=640, D=768) that is ~32 GFLOP per
-    einsum per layer of pure dispatch overhead, ~30% of the routed step
-    FLOPs (tools/moe_diag.py measures the compiled totals for both modes).
+    the (T, E, C) one-hot bytes and their O(T·E·C·D) matmul FLOPs — at
+    T=4096, E=8, C=640, D=768 that is ~32 GFLOP per einsum per layer of
+    pure dispatch overhead, ~30% of the routed step FLOPs.
     """
     expert_idx, slot, gate, aux_loss = _top1_route(logits, capacity)
     keep = (slot >= 0).astype(jnp.float32)

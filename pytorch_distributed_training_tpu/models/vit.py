@@ -8,7 +8,8 @@ encoder blocks.  Attention routes through ``ops.dot_product_attention``,
 whose measured dispatch picks the low-memory XLA attention (bf16 score
 matmul + bf16-saved probabilities, the AMP-faithful path) at ViT's L=197,
 below the flash kernel's measured L>=1024 win threshold — see
-ops/attention.py; full-model: 894 vs 607 img/s, VIT_BENCH.json.  Compute
+ops/attention.py; full-model: 894 vs 607 img/s (rounds 1-5, another
+machine).  Compute
 dtype is threaded for the bf16 (AMP-equivalent) policy.
 """
 
@@ -77,15 +78,12 @@ class VisionTransformer(nn.Module):
     remat: bool = False
     # Attention activation-layout contract (models/layers.SelfAttention
     # .attn_layout) — the (B,H,L,Dh)-between-projections experiment
-    # VIT_ROOFLINE (deleted: not measured on the current machine)'s analysis
-    # named.  "bhld2" (head-major q/k/v
+    # (rounds 1-5, another machine).  "bhld2" (head-major q/k/v
     # straight from the projection GEMMs, canonical bh-leading einsums,
     # head-consuming output projection) measured BEST at the batch-44
     # residency optimum: 1070.5 vs 1014-1039 img/s auto (MFU 0.556 vs
-    # 0.53-0.54) and is the TPU default; "bhld" (transpose the packed qkv
-    # activation post-hoc) measured strictly worse than auto at every
-    # batch and is kept as the recorded negative.  Param trees are
-    # identical across all three.
+    # 0.53-0.54) and is the TPU default.  Param trees are identical
+    # across both.
     attn_layout: str = "bhld2"
 
     @nn.compact

@@ -156,17 +156,13 @@ def _xla_masked_attention(q, k, v, mask, *, scale=None):
     return out.reshape(b, q_len, h, d)
 
 
-def _xla_attention_remat(q, k, v, *, causal=False, scale=None):
-    """XLA attention with rematerialized internals: only q/k/v are saved
-    for the backward, which recomputes the (B, H, L, L) logits/softmax
-    chain instead of reading it back from HBM.  At short L (ViT's 197)
-    this removes the step's largest saved tensors for a rounding error of
-    extra FLOPs (attention is ~1.4% of ViT-B's total) — flash-attention's
-    memory behavior without the Pallas kernel's tile-padding waste."""
-    fn = jax.checkpoint(
-        functools.partial(_xla_attention, causal=causal, scale=scale)
-    )
-    return fn(q, k, v)
+def _xla_path(q, k, v, *, causal, scale, block_diffusion):
+    """The XLA implementation for the call's mask."""
+    if block_diffusion is not None:
+        return _xla_masked_attention(
+            q, k, v, block_diffusion_mask(*block_diffusion), scale=scale
+        )
+    return _xla_attention(q, k, v, causal=causal, scale=scale)
 
 
 def flash_attention(
@@ -176,8 +172,6 @@ def flash_attention(
     *,
     causal: bool = False,
     scale: float | None = None,
-    block_q: int | None = None,
-    block_k: int | None = None,
     interpret: bool | None = None,
     block_diffusion: tuple[int, int] | None = None,
 ) -> jax.Array:
@@ -187,22 +181,8 @@ def flash_attention(
     and masks padded keys internally (``ops.pallas_attention``).  Falls back
     to the XLA implementation only when running on a backend the kernel does
     not target (neither TPU nor the CPU interpreter).
-
-    ``block_q``/``block_k`` default to 1024x1024 (the measured full-model
-    optimum at L>=1024).  The ``PDT_FLASH_BLOCK_Q/K`` env hooks override the
-    *defaults only* — an explicit caller argument always wins — and are read
-    at trace time: changing them mid-process does not retrace already
-    compiled shapes, so A/Bs need a fresh process per setting.
     """
-    import os
-
     from . import pallas_attention
-
-    # Block-size experiment hook (full-model A/Bs; see PDT_FORCE_ATTN).
-    if block_q is None:
-        block_q = int(os.environ.get("PDT_FLASH_BLOCK_Q") or 1024)
-    if block_k is None:
-        block_k = int(os.environ.get("PDT_FLASH_BLOCK_K") or 1024)
 
     backend = jax.default_backend()
     # CPU only counts when the interpreter is allowed: interpret=False on CPU
@@ -213,15 +193,13 @@ def flash_attention(
         or bool(interpret)
     )
     if not backend_ok:
-        if block_diffusion is not None:
-            return _xla_masked_attention(
-                q, k, v, block_diffusion_mask(*block_diffusion), scale=scale
-            )
-        return _xla_attention(q, k, v, causal=causal, scale=scale)
+        return _xla_path(
+            q, k, v, causal=causal, scale=scale,
+            block_diffusion=block_diffusion,
+        )
     kernel = functools.partial(
         pallas_attention.flash_attention, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interpret,
-        block_diffusion=block_diffusion,
+        interpret=interpret, block_diffusion=block_diffusion,
     )
     # heads split over ``tensor`` only where the K/V heads divide too
     partition = _kernel_partition(q.shape[0], math.gcd(q.shape[2], k.shape[2]))
@@ -285,46 +263,55 @@ def flash_preferred(
     better with the (B, L, 3, H, Dh) axis-2 split (ViT batch 44: 943 vs
     872 img/s) — both forms select the identical elements.
 
-    ``num_heads`` (when the caller knows it) additionally routes the
-    decision through ``pallas_attention.native_layout_selected`` — the
-    SAME padding/block/VMEM-fit rules the kernel dispatch applies — so
-    wide models whose native-layout configs do not fit VMEM (both the
-    single-tile and grouped variants return None and execution falls to
-    the transposed multi-tile path) get the XLA-favored split instead of
-    paying the relayout twice.  Without ``num_heads`` the size heuristic
-    alone answers (the dispatcher's own q-side call).
-
-    Honors the ``PDT_FORCE_ATTN`` A/B override the dispatcher honors:
-    a forced-XLA measurement must also get the XLA-favored split, or the
-    full-model A/Bs that set this very threshold would understate the
-    XLA path by the layout penalty."""
-    import os
-
-    forced = os.environ.get("PDT_FORCE_ATTN", "").lower()
-    if forced in ("xla", "xla_remat"):
-        return False
-    if forced == "flash":
-        return True
+    ``num_heads`` (when the caller knows it) additionally asks
+    ``pallas_attention.flash_plan`` — the plan the kernel dispatch itself
+    runs — so wide models whose native-layout configs do not fit VMEM
+    (execution falls to the transposed kernels) get the XLA-favored split
+    instead of paying the relayout twice.  Without ``num_heads`` the size
+    rule alone answers (the dispatcher's own call)."""
+    # The size rule: set by *full-model* measurement, not the isolated
+    # micro-bench (rounds 1-5, another machine).  GPT-2 124M tokens/sec,
+    # flash vs the low-memory XLA path (bf16 probs, _softmax_lowp), after
+    # the r4 heads-fused native-layout kernels (the single-tile fwd/bwd
+    # consume (B, L, H*D) directly — a free reshape — so the
+    # (B,L,H,D) <-> (B,H,L,D) boundary transposes that used to hand
+    # XLA the sub-1024 win are gone, ops/pallas_attention.py):
+    #   L=197 (ViT-B/16): 946.9 vs 1038.7 img/s -> XLA (pad-to-256
+    #                     waste: 30% dead keys + sub-tile q blocks)
+    #   L=256: 146.8k vs 143.8k                 -> flash (+2%)
+    #   L=512: 154.7k vs 134.0k                 -> flash (+15%)
+    #   L=768: 143.3k vs 122.0k                 -> flash (+17%)
+    #   L=1024: 142.5k vs 89.4k                 -> flash (+59%,
+    #           grouped-heads native-layout variant)
+    # The crossover sits at the 256 tile boundary: below it the kernel
+    # pays pad-to-tile waste XLA does not.  Above ~2k the XLA path's
+    # (B, H, L, L) materialization also stops fitting, so flash is the
+    # only option on memory.  Only full-model A/Bs are trusted for this
+    # threshold.
     size_ok = (
         jax.default_backend() == "tpu"
         and q_len >= 256
         and k_len >= 64
         and head_dim >= 64
     )
-    # The native-config consultation applies only inside the native
-    # kernels' k-band (padded k_len <= 1024): beyond it the multi-tile
-    # transposed kernel runs regardless (XLA's (B,H,L,L) materialization
-    # stops fitting at long L), and the last-axis split keeps its
-    # measured long-context behavior.  ``itemsize`` must be the
-    # activations' real byte width — the kernel's VMEM fits use
-    # q.dtype.itemsize, and an fp32 run checked at bf16 sizes would pick
-    # the flash-favored split for configs the dispatch then rejects.
-    if size_ok and num_heads is not None and (k_len + (-k_len) % 128) <= 1024:
-        from .pallas_attention import native_layout_selected
+    if size_ok and num_heads is not None:
+        from .pallas_attention import flash_plan
 
-        return native_layout_selected(
-            q_len, k_len, num_heads, head_dim, itemsize=itemsize
+        # ``itemsize`` must be the activations' real byte width: the VMEM
+        # fits use it, and an fp32 run checked at bf16 sizes would pick the
+        # flash-favored split for configs the dispatch then rejects.  The
+        # kind is the same under ``causal``: its cap on the q block never
+        # takes the smallest block away, and the smallest decides the fit.
+        plan = flash_plan(
+            q_len, k_len, num_heads, num_heads, head_dim, itemsize,
+            causal=False, block_diffusion=None,
         )
+        # Beyond the native kernels' k-band the multi-tile transposed
+        # kernel runs regardless (XLA's (B,H,L,L) materialization stops
+        # fitting at long L), and the last-axis split keeps its measured
+        # long-context behavior.
+        if plan.k_len <= 1024:
+            return plan.kind in ("single", "grouped")
     return size_ok
 
 
@@ -340,8 +327,9 @@ def dot_product_attention(
 ) -> jax.Array:
     """Public attention entry point. q/k/v: (B, L, H, D) → (B, L, H, D).
 
-    ``use_flash=None`` auto-selects: Pallas flash kernel on TPU backends for
-    tile-aligned shapes, XLA everywhere else.
+    ``use_flash=None`` auto-selects by :func:`flash_preferred`'s size rule:
+    the Pallas flash kernels on a TPU from q_len 256 up, XLA (the dense-mask
+    path under ``block_diffusion``) elsewhere and for short lengths.
 
     ``block_diffusion=(L, B)`` applies the block-diffusion training mask
     (:func:`block_diffusion_mask`): q and k hold 2L positions, a noised copy
@@ -367,57 +355,9 @@ def dot_product_attention(
             raise ValueError(
                 f"k/v carry {k.shape[2]}/{v.shape[2]} heads, q {q.shape[2]}"
             )
-        # The multi-tile flash kernels on a TPU from the same size rule, the
-        # dense-mask XLA path elsewhere and for short lengths.
-        if use_flash is None:
-            use_flash = flash_preferred(q.shape[1], k.shape[1], q.shape[3])
-        if use_flash:
-            return flash_attention(
-                q, k, v, scale=scale, block_diffusion=block_diffusion,
-            )
-        return _xla_masked_attention(
-            q, k, v, block_diffusion_mask(*block_diffusion), scale=scale
-        )
     if use_flash is None:
-        import os
-
-        # Experiment escape hatch: force one backend for full-model A/Bs
-        # (micro-benches mislead — see the ViT L=197 story below).
-        forced = os.environ.get("PDT_FORCE_ATTN", "").lower()
-        if forced:
-            if forced == "flash":
-                return flash_attention(q, k, v, causal=causal, scale=scale)
-            if forced == "xla":
-                return _xla_attention(q, k, v, causal=causal, scale=scale)
-            if forced == "xla_remat":
-                return _xla_attention_remat(q, k, v, causal=causal, scale=scale)
-            raise ValueError(
-                f"PDT_FORCE_ATTN={forced!r}: expected 'flash', 'xla' or "
-                "'xla_remat' (a typo here would silently A/B the default "
-                "path twice)"
-            )
-        # Dispatch threshold set by *full-model* measurement, not the
-        # isolated micro-bench.  GPT-2 124M tokens/sec, flash vs the
-        # low-memory XLA path (bf16 probs, _softmax_lowp), after the r4
-        # heads-fused native-layout kernels (the single-tile fwd/bwd now
-        # consume (B, L, H*D) directly — a free reshape — so the
-        # (B,L,H,D) <-> (B,H,L,D) boundary transposes that used to hand
-        # XLA the sub-1024 win are gone, ops/pallas_attention.py):
-        #   L=197 (ViT-B/16): 946.9 vs 1038.7 img/s -> XLA (pad-to-256
-        #                     waste: 30% dead keys + sub-tile q blocks)
-        #   L=256: 146.8k vs 143.8k                 -> flash (+2%)
-        #   L=512: 154.7k vs 134.0k                 -> flash (+15%)
-        #   L=768: 143.3k vs 122.0k                 -> flash (+17%)
-        #   L=1024: 142.5k vs 89.4k                 -> flash (+59%,
-        #           grouped-heads native-layout variant)
-        # The crossover now sits at the 256 tile boundary: below it the
-        # kernel pays pad-to-tile waste XLA does not.  Above ~2k the XLA
-        # path's (B, H, L, L) materialization also stops fitting, so
-        # flash is the only option on memory.  Only full-model A/Bs are
-        # trusted for this threshold; ATTN_MICRO (deleted: not measured on the
-        # current machine)'s slope protocol
-        # catches kernel-level regressions cheaply.
         use_flash = flash_preferred(q.shape[1], k.shape[1], q.shape[3])
-    if use_flash:
-        return flash_attention(q, k, v, causal=causal, scale=scale)
-    return _xla_attention(q, k, v, causal=causal, scale=scale)
+    attend = flash_attention if use_flash else _xla_path
+    return attend(
+        q, k, v, causal=causal, scale=scale, block_diffusion=block_diffusion
+    )

@@ -13,10 +13,10 @@ sequence-parallel path reuses per shard.
 Backward pass: ``jax.custom_vjp`` with saved logsumexp, computed by two
 Pallas kernels (dq over kv blocks; dk/dv over q blocks) that recompute p/ds
 per tile — the (L×L) score matrix never materializes in the backward either.
-Perf claims rest on FULL-MODEL A/Bs (GPT2_BENCH.json sweep, a round-4
-session: flash wins from L=1024 up while the low-memory XLA path wins
-below; not measured on the current machine).  Default blocks are
-1024x1024, that sweep's optimum.  O(L) memory where XLA materializes the
+Perf claims rest on FULL-MODEL A/Bs (a round-4 sweep on another machine:
+flash wins from L=1024 up while the low-memory XLA path wins below; not
+measured on the current machine).  Default blocks are 1024x1024, that
+sweep's optimum.  O(L) memory where XLA materializes the
 (L x L) scores.
 
 Layout: public API takes (batch, length, heads, head_dim); the kernel tiles
@@ -26,6 +26,7 @@ over (batch, heads, q_blocks, kv_blocks) on a (B, H, L, D) transpose.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -383,9 +384,8 @@ def _fwd_kernel_single_nlhd(
 
     The (B, H, L, D) kernels force (B, L, H, D) -> (B, H, L, D) boundary
     transposes in the surrounding program — measured as the residual
-    full-model gap to the XLA path below L=1024 (ATTN_MICRO (deleted: not
-    measured on the current machine) vs
-    GPT2_BENCH.json sweep).  This kernel instead takes q/k/v as
+    full-model gap to the XLA path below L=1024 (rounds 1-5, another
+    machine).  This kernel instead takes q/k/v as
     (B, L, H*D) — a FREE reshape of the model's (B, L, H, D) — and loops
     the heads inside the tile, slicing 64-wide column groups out of VMEM.
     Grid: (b, q_blocks); the whole key row sits in one tile (the small-L
@@ -984,8 +984,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
         # Whole key row in one tile: the online-softmax machinery buys
         # nothing, and dropping it (plus the narrow LSE) measured
         # 220 -> 62 us on the GPT-2 L=512 microbatch shape — past the XLA
-        # fused attention (77 us, ATTN_MICRO (deleted: not measured on the
-        # current machine)).
+        # fused attention (77 us; rounds 1-5, another machine).
         return _flash_fwd_single(
             q, k, v, causal, scale, block_q, interpret, causal_offset,
             kv_len,
@@ -1536,45 +1535,77 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, causal_offset,
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-def native_layout_selected(
+class FlashPlan(NamedTuple):
+    """What ``flash_attention`` does with one call's static facts."""
+
+    q_len: int  # padded to the 128-lane tile
+    k_len: int
+    block_q: int
+    block_k: int
+    kind: str  # "single" | "grouped" | "transposed" | "tabled"
+    # "grouped" only: (heads_per_group, block_q_fwd, block_q_bwd)
+    group: tuple[int, int, int] | None
+
+
+def flash_plan(
     q_len: int,
     k_len: int,
     num_heads: int,
+    kv_heads: int,
     head_dim: int,
+    itemsize: int,
     *,
-    itemsize: int = 2,
+    causal: bool,
+    block_diffusion: tuple[int, int] | None,
     block_q: int = 1024,
     block_k: int = 1024,
-) -> bool:
-    """Whether ``flash_attention`` will take a native-(B, L, H·D)-layout
-    kernel (single-tile or grouped-heads) for these shapes — the SAME
-    padding, block-picking, and VMEM-fit rules the dispatch below applies,
-    exposed so layout co-optimizers (``ops.attention.flash_preferred``)
-    cannot drift from the actual kernel selection: a producer that picks
-    the flash-favored qkv split while execution falls to the transposed
-    multi-tile path would re-pay the relayout the split was meant to
-    avoid."""
-    qp = q_len + ((-q_len) % _LANES)
-    kp = k_len + ((-k_len) % _LANES)
+) -> FlashPlan:
+    """Which kernels a call takes, from its shapes alone: the one place
+    that pads to the lane tile, picks blocks and asks the VMEM fits.
+    ``flash_attention`` runs the plan; ``ops.attention.flash_preferred``
+    reads its ``kind``, so a producer that picks the flash-favored qkv
+    split cannot meet a call that relays out anyway.
 
-    def pick(length: int, preferred: int) -> int:
+    - ``single``: the whole-heads native-(B, L, H*D) pair, a free reshape
+      of the operands: padded k_len and q_len <= 512 where every head's
+      rows and (L, L) tiles fit VMEM in one grid cell.
+    - ``grouped``: the grouped-heads native pair, which tiles heads and
+      query length: padded k_len <= 1024 (the GPT-2 L = 1024 band), long q
+      over a short key row, or widths ``single`` cannot fit.
+    - ``transposed``: (B, H, L, D) operands; ``_flash_fwd`` / ``_flash_bwd``
+      take the single-tile kernels where the key row is one block and the
+      multi-tile ones beyond.
+    - ``tabled``: the transposed multi-tile pair under the block-diffusion
+      mask, the only kernels that read K/V at their own head count.
+    """
+    q_len += (-q_len) % _LANES
+    k_len += (-k_len) % _LANES
+
+    def pick_block(length: int, preferred: int) -> int:
         for b in (preferred, 256, 128):
             if length % min(b, length) == 0:
                 return b
-        return _LANES
+        return _LANES  # padded lengths are multiples of 128 by construction
 
-    bk = pick(kp, block_k)
-    hd = num_heads * head_dim
-    if kp <= min(bk, 512) and qp <= 512 and _nlhd_single_fits(
-        qp, kp, hd, itemsize
-    ):
-        return True
-    if kp <= min(bk, 1024):
-        # the same answer under ``causal``: its cap on the q block never
-        # takes the smallest block away, and the smallest decides the fit
-        return _nlhd_group_config(qp, kp, num_heads, head_dim, itemsize) \
-            is not None
-    return False
+    block_q = pick_block(q_len, block_q)
+    block_k = pick_block(k_len, block_k)
+    plan = functools.partial(FlashPlan, q_len, k_len, block_q, block_k)
+    if block_diffusion is not None:
+        return plan("tabled", None)
+    if kv_heads == num_heads:  # the native pairs know one head count
+        if (
+            k_len <= min(block_k, 512)
+            and q_len <= 512
+            and _nlhd_single_fits(q_len, k_len, num_heads * head_dim, itemsize)
+        ):
+            return plan("single", None)
+        if k_len <= min(block_k, 1024):
+            group = _nlhd_group_config(
+                q_len, k_len, num_heads, head_dim, itemsize, causal
+            )
+            if group is not None:
+                return plan("grouped", group)
+    return plan("transposed", None)
 
 
 def flash_attention(
@@ -1612,27 +1643,8 @@ def flash_attention(
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     scale = scale if scale is not None else q.shape[-1] ** -0.5
-    q_len, k_len = q.shape[1], k.shape[1]
-    pad_q = (-q_len) % _LANES
-    pad_k = (-k_len) % _LANES
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
-    if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-
-    def pick_block(length: int, preferred: int) -> int:
-        for b in (preferred, 256, 128):
-            if length % min(b, length) == 0:
-                return b
-        return _LANES  # padded lengths are multiples of 128 by construction
-
-    block_q = pick_block(q.shape[1], block_q)
-    block_k = pick_block(k.shape[1], block_k)
-    # Causal alignment follows the ORIGINAL lengths; kv_len masks padded keys.
-    causal_offset = k_len - q_len
-    kv_len = k_len if pad_k else None
-    b, ql, h, d = q.shape
+    b, q_len, h, d = q.shape
+    k_len = k.shape[1]
     if block_diffusion is None and k.shape[2] != h:
         raise ValueError(
             f"k carries {k.shape[2]} heads and q {h}: grouped K/V heads run "
@@ -1645,63 +1657,46 @@ def flash_attention(
             raise ValueError(
                 f"{h} query heads are not a multiple of {k.shape[2]} K/V heads"
             )
-        # The scale goes onto q once (a (P, d) pass XLA fuses into q's
-        # producer), not onto every (block_q, block_k) score tile.  q is
-        # rounded to its dtype a second time by this, which the causal
-        # kernels (scale on the float32 scores) are not.
-        q, scale = q * jnp.asarray(scale, q.dtype), 1.0
+    plan = flash_plan(
+        q_len, k_len, h, k.shape[2], d, q.dtype.itemsize, causal=causal,
+        block_diffusion=block_diffusion, block_q=block_q, block_k=block_k,
+    )
+    pad_q, pad_k = plan.q_len - q_len, plan.k_len - k_len
+    if pad_q:
+        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
+    if pad_k:
+        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
+    # Causal alignment follows the ORIGINAL lengths; kv_len masks padded keys.
+    causal_offset = k_len - q_len
+    kv_len = k_len if pad_k else None
+    if plan.kind in ("single", "grouped"):
+        q2, k2, v2 = (x.reshape(b, x.shape[1], h * d) for x in (q, k, v))
+        if plan.kind == "single":
+            out = _flash_nlhd(
+                q2, k2, v2, causal, scale, plan.block_q, interpret,
+                causal_offset, kv_len, h,
+            )
+        else:
+            out = _flash_nlhd_grouped(
+                q2, k2, v2, causal, scale, interpret, causal_offset,
+                kv_len, h, plan.group,
+            )
+        out = out.reshape(b, plan.q_len, h, d)
+    else:
+        if plan.kind == "tabled":
+            # The scale goes onto q once (a (P, d) pass XLA fuses into q's
+            # producer), not onto every (block_q, block_k) score tile.  q is
+            # rounded to its dtype a second time by this, which the causal
+            # kernels (scale on the float32 scores) are not.
+            q, scale = q * jnp.asarray(scale, q.dtype), 1.0
+        # (B, L, H, D) → (B, H, L, D) for blocking.
         qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
         out = _flash(
-            qt, kt, vt, causal, scale, block_q, block_k, interpret,
+            qt, kt, vt, causal, scale, plan.block_q, plan.block_k, interpret,
             causal_offset, kv_len, block_diffusion,
         )
         out = jnp.swapaxes(out, 1, 2)
-        return out[:, :q_len] if pad_q else out
-    if (
-        k.shape[1] <= min(block_k, 512)
-        and ql <= 512
-        and _nlhd_single_fits(ql, k.shape[1], h * d, q.dtype.itemsize)
-    ):
-        # Single-tile small-L regime: the heads-fused kernels consume the
-        # native (B, L, H*D) layout, a free reshape, eliminating the
-        # (B, L, H, D) <-> (B, H, L, D) boundary transposes that were the
-        # measured full-model gap to XLA below L=1024.  The fit check
-        # guards VMEM: the backward runs grid (b,) with whole-row tiles
-        # for every head, which wide-attention models (large H*D)
-        # overflow even at short L — those fall through to the grouped
-        # variant below.
-        q2, k2, v2 = (x.reshape(x.shape[0], x.shape[1], h * d)
-                      for x in (q, k, v))
-        out = _flash_nlhd(
-            q2, k2, v2, causal, scale, block_q, interpret, causal_offset,
-            kv_len, h,
-        )
-        out = out.reshape(b, ql, h, d)
-        return out[:, :q_len] if pad_q else out
-    if k.shape[1] <= min(block_k, 1024):
-        # k_len up to 1024 (the GPT-2 L=1024 flagship band), long-q over a
-        # short key row, or wide models the whole-heads path cannot fit:
-        # the grouped-heads variants tile heads AND query length to stay
-        # inside VMEM while still consuming the native layout.
-        cfg = _nlhd_group_config(
-            ql, k.shape[1], h, d, q.dtype.itemsize, causal
-        )
-        if cfg is not None:
-            q2, k2, v2 = (x.reshape(x.shape[0], x.shape[1], h * d)
-                          for x in (q, k, v))
-            out = _flash_nlhd_grouped(
-                q2, k2, v2, causal, scale, interpret, causal_offset,
-                kv_len, h, cfg,
-            )
-            out = out.reshape(b, ql, h, d)
-            return out[:, :q_len] if pad_q else out
-    # (B, L, H, D) → (B, H, L, D) for blocking.
-    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    out = _flash(
-        qt, kt, vt, causal, scale, block_q, block_k, interpret,
-        causal_offset, kv_len,
-    )
-    out = jnp.swapaxes(out, 1, 2)
     return out[:, :q_len] if pad_q else out
 
 

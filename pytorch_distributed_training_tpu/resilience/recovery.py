@@ -12,7 +12,7 @@ host-side:
    stages a host-numpy copy of the learned state (params, optimizer
    slots, batch stats, EF residuals).  Staging blocks on the state's
    in-flight computation — that pipeline bubble is the cost
-   ``bench.py --resilience-overhead`` prices (<1% step-time target).
+   (<1% step-time target).
 2. **rollback**: when the device-side bad-streak counter reaches
    ``rollback_after`` (read at trainer log points, where the host syncs
    anyway), the snapshot is restored into the live shardings, the streak

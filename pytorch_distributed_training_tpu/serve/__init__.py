@@ -54,7 +54,7 @@ artifact and the same flax ``cache`` collection:
   serving chaos plane (``resilience.ServeFaultInjector``).
 - ``metrics``   — per-request SLO records (TTFT/TPOT), percentile summaries,
   goodput/queue-depth and speculation (acceptance rate, tokens-per-tick)
-  accounting (``bench.py --serve`` → SERVE_BENCH.json).
+  accounting.
 """
 
 from .autoscale import AutoscaleController

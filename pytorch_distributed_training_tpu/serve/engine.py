@@ -235,7 +235,8 @@ class ServingEngine:
         # one below the max (floored at 2): longest-match-first with a
         # single fallback level — looser floors draft noise that verifies
         # to nothing, tighter ones miss the short-period repetition that
-        # is the drafter's bread and butter (bench-swept, SERVE_BENCH).
+        # is the drafter's bread and butter (swept in rounds 1-5, another
+        # machine).
         self.spec_k = spec_k
         self.spec_ngram = spec_ngram
         # A prefill-role engine never decodes, so it neither drafts nor

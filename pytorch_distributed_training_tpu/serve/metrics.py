@@ -5,8 +5,7 @@ The two latencies that define an interactive serving SLO:
 - **TTFT** (time to first token): arrival → first sampled token.  Under
   continuous batching this is queue wait + prefill; under static batching
   it also eats batch assembly AND the whole batch's decode (tokens only
-  materialize when the batch completes) — the head-to-head in
-  ``bench.py --serve`` measures exactly that gap.
+  materialize when the batch completes).
 - **TPOT** (time per output token): mean inter-token latency after the
   first token, ``(finish - first_token) / (generated - 1)``.
 
@@ -33,8 +32,8 @@ def percentile(xs, q: float) -> float | None:
 def finalize_record(rec: dict) -> dict:
     """Derive ttft/tpot in place from a completed request's raw
     timestamps (scheduler record or a re-read JSONL line — the derivation
-    is the same either way, so SERVE_BENCH percentiles are recomputable
-    from the raw per-request logs)."""
+    is the same either way, so percentiles are recomputable from the raw
+    per-request logs)."""
     if rec.get("first_token") is not None:
         rec["ttft"] = rec["first_token"] - rec["arrival"]
     else:

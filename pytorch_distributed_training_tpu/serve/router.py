@@ -31,8 +31,7 @@ record so the merged metrics stay attributable.
 
 Router accounting rides the obs spine (per-replica queue-depth/occupancy
 gauges, routed/affinity-hit/rebalance counters) and is surfaced by
-``tools/telemetry_report.py``; ``bench.py --serve`` drives the
-replica-scaling and affinity-routing legs (SERVE_BENCH.json).
+``tools/telemetry_report.py``.
 """
 
 from __future__ import annotations

@@ -233,8 +233,8 @@ def make_train_step(
                 # Chunked CE: the model returns hidden states and the LM
                 # head runs inside the loss's checkpointed scan, so the
                 # (B, L, vocab) logits are never resident — the memory fix
-                # that unlocks large per-chip batches (GPT2_BENCH batch 32
-                # OOM'd on the full-logits path).
+                # that unlocks large per-chip batches (batch 32 OOM'd on
+                # the full-logits path; rounds 1-5, another machine).
                 hidden, new_stats, aux_l, stats = _forward(
                     state, params, tokens, train=True, rng=rng, policy=policy,
                     return_hidden=True,

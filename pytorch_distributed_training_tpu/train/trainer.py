@@ -437,9 +437,8 @@ class Trainer:
                         self.ledger.note_progress(self._global_step)
                     if self.recovery is not None:
                         # Host snapshot at its own cadence: device_get
-                        # blocks on the state's in-flight computation —
-                        # the staging bubble bench.py --resilience-
-                        # overhead prices.
+                        # blocks on the state's in-flight computation:
+                        # the staging bubble.
                         snap = (
                             self.spans.start_span(
                                 "train/snapshot", parent=sspan,

@@ -61,7 +61,7 @@ class MetricsLogger(_JsonlEmitter):
 class RequestLogger(_JsonlEmitter):
     """Per-request serving records, one JSONL line per finished request.
 
-    The serving bench reports TTFT/TPOT *percentiles* (SERVE_BENCH.json);
+    Serving summaries report TTFT/TPOT *percentiles*;
     this logger persists the raw material those numbers reduce —
     request id, prompt length, TTFT, TPOT, finish reason, generated count,
     timestamps — so any percentile (or a different SLO cut entirely) is

@@ -206,13 +206,16 @@ def test_grouped_pair_compiles_for_a_v5e(one_v5e, q_len, k_len, heads, dim, caus
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("q_len,k_len,heads,dim,itemsize", [
-    (1024, 1024, 12, 64, 2), (1024, 1024, 12, 64, 4), (197, 197, 12, 64, 2),
-    (512, 512, 12, 64, 2), (640, 640, 2, 64, 4), (384, 768, 2, 64, 4),
-    (256, 1024, 16, 1024, 4), (2048, 2048, 12, 64, 2),
+@pytest.mark.parametrize("q_len,k_len,heads,dim,itemsize,kind", [
+    (1024, 1024, 12, 64, 2, "grouped"), (1024, 1024, 12, 64, 4, "grouped"),
+    (197, 197, 12, 64, 2, "single"), (512, 512, 12, 64, 2, "single"),
+    (640, 640, 2, 64, 4, "grouped"), (384, 768, 2, 64, 4, "grouped"),
+    (256, 1024, 16, 1024, 4, "transposed"), (2048, 2048, 12, 64, 2, "transposed"),
 ])
-def test_native_layout_selected_agrees_with_the_dispatch(
-        monkeypatch, causal, q_len, k_len, heads, dim, itemsize):
+def test_flash_plan_is_what_the_dispatch_runs(
+        monkeypatch, causal, q_len, k_len, heads, dim, itemsize, kind):
+    """The generation each shape takes, as a literal: asserted on the plan
+    and on the entry function ``flash_attention`` really calls."""
     taken = []
     monkeypatch.setattr(pa, "_flash_nlhd", lambda q, *a: taken.append("single") or q)
     monkeypatch.setattr(pa, "_flash_nlhd_grouped",
@@ -222,7 +225,57 @@ def test_native_layout_selected_agrees_with_the_dispatch(
     q = jax.ShapeDtypeStruct((1, q_len, heads, dim), dtype)
     kv = jax.ShapeDtypeStruct((1, k_len, heads, dim), dtype)
     jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal=causal), q, kv, kv)
-    assert len(taken) == 1
-    assert pa.native_layout_selected(
-        q_len, k_len, heads, dim, itemsize=itemsize
-    ) == (taken[0] != "transposed")
+    assert taken == [kind]
+    plan = pa.flash_plan(q_len, k_len, heads, heads, dim, itemsize,
+                         causal=causal, block_diffusion=None)
+    assert plan.kind == kind
+    assert (plan.group is not None) == (kind == "grouped")
+
+
+# Which generation each registered transformer reaches at padded lengths
+# 256, 512, 1024, by itemsize.  Native everywhere except gpt2_xl: 25 heads of
+# 64 have no lane-aligned head group, and all 25 at once fit the whole-heads
+# pair only at 256 in bf16 (ROADMAP D14, D16).
+LENGTHS = (256, 512, 1024)
+REGISTRY_KINDS = {
+    "vit_s16": {2: ("single", "single", "grouped"), 4: ("single", "single", "grouped")},
+    "vit_b16": {2: ("single", "single", "grouped"), 4: ("single", "grouped", "grouped")},
+    "vit_l16": {2: ("single", "single", "grouped"), 4: ("single", "grouped", "grouped")},
+    "gpt2": {2: ("single", "single", "grouped"), 4: ("single", "grouped", "grouped")},
+    "gpt2_medium": {2: ("single", "single", "grouped"), 4: ("single", "grouped", "grouped")},
+    "gpt2_large": {2: ("single", "grouped", "grouped"), 4: ("single", "grouped", "grouped")},
+    "gpt2_xl": {2: ("single", "transposed", "transposed"),
+                4: ("transposed", "transposed", "transposed")},
+    # its 32 query heads of 128 as a plain shape (its cell runs ``tabled``)
+    "sdar_30b_a3b": {2: ("grouped", "grouped", "grouped"), 4: ("grouped", "grouped", "grouped")},
+}
+
+
+def _attention_shape(name):
+    """(heads, head_dim) from the registered model's own config."""
+    from pytorch_distributed_training_tpu.models import create_model
+
+    model = create_model(name)
+    cfg = getattr(model, "cfg", model)
+    if hasattr(cfg, "num_attention_heads"):
+        return cfg.num_attention_heads, cfg.head_dim
+    return cfg.num_heads, cfg.hidden_dim // cfg.num_heads
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+@pytest.mark.parametrize("length", LENGTHS)
+@pytest.mark.parametrize("name", REGISTRY_KINDS)
+def test_generation_each_registered_model_reaches(name, length, itemsize):
+    heads, dim = _attention_shape(name)
+    want = REGISTRY_KINDS[name][itemsize][LENGTHS.index(length)]
+    for causal in (False, True):
+        plan = pa.flash_plan(length, length, heads, heads, dim, itemsize,
+                             causal=causal, block_diffusion=None)
+        assert plan.kind == want, (causal, plan)
+
+
+def test_sdar_cell_shape_plans_the_tabled_pair():
+    """8192 positions, 32 / 4 heads of 128, block 4: the cell's call."""
+    plan = pa.flash_plan(8192, 8192, 32, 4, 128, 2, causal=False,
+                         block_diffusion=(4096, 4))
+    assert plan == pa.FlashPlan(8192, 8192, 1024, 1024, "tabled", None)

@@ -220,11 +220,11 @@ def test_bf16_compute_f32_logits():
 
 
 def test_vit_attn_layout_variants_parity():
-    """The three attention layout contracts (auto / bhld / bhld2 —
+    """The two attention layout contracts (auto / bhld2 —
     models/layers.SelfAttention.attn_layout) must share one param tree and
     produce matching outputs and gradients; bhld2 is the measured TPU
-    default (VIT_ROOFLINE (deleted: not measured on the current machine) r5
-    experiments)."""
+    default (r5 experiments, another machine).  Any other value raises:
+    "bhld", the recorded negative, is gone."""
     from pytorch_distributed_training_tpu.models.vit import vit_b16
 
     x = jnp.asarray(
@@ -236,7 +236,7 @@ def test_vit_attn_layout_variants_parity():
         layout: vit_b16(
             num_classes=10, cfg_overrides={**common, "attn_layout": layout}
         )
-        for layout in ("auto", "bhld", "bhld2")
+        for layout in ("auto", "bhld2")
     }
     inits = {
         layout: m.init(jax.random.PRNGKey(0), x, train=False)
@@ -253,11 +253,12 @@ def test_vit_attn_layout_variants_parity():
         )
         outs[layout] = m.apply({"params": ref}, x, train=False)
     np.testing.assert_allclose(
-        np.asarray(outs["auto"]), np.asarray(outs["bhld"]), atol=2e-5
-    )
-    np.testing.assert_allclose(
         np.asarray(outs["auto"]), np.asarray(outs["bhld2"]), atol=2e-5
     )
+    with pytest.raises(ValueError, match="unknown attn_layout 'bhld'"):
+        vit_b16(
+            num_classes=10, cfg_overrides={**common, "attn_layout": "bhld"}
+        ).init(jax.random.PRNGKey(0), x, train=False)
 
     def loss(m, p):
         return jnp.sum(m.apply({"params": p}, x, train=False) ** 2)

@@ -58,6 +58,25 @@ def test_flash_bf16_runs():
     )
 
 
+def test_attention_dispatch_reads_no_environment():
+    """The choice of kernel is a function of shapes and the backend: no
+    environment variable reaches ``ops/attention.py``."""
+    import ast
+    import inspect
+
+    from pytorch_distributed_training_tpu.ops import attention
+
+    tree = ast.parse(inspect.getsource(attention))
+    reads = [
+        node.lineno for node in ast.walk(tree)
+        if (isinstance(node, ast.Attribute) and node.attr in ("environ", "getenv"))
+        or (isinstance(node, ast.Import)
+            and any(a.name.split(".")[0] == "os" for a in node.names))
+        or (isinstance(node, ast.ImportFrom) and node.module == "os")
+    ]
+    assert reads == []
+
+
 def test_dispatch_uses_xla_on_cpu():
     q, k, v = _qkv(jax.random.PRNGKey(3), l=128)
     out = dot_product_attention(q, k, v, causal=True)

@@ -406,7 +406,7 @@ def test_restore_params_from_fresh_manager(model_and_params, tmp_path):
 
 
 def test_request_logger_roundtrip_recomputes_percentiles(tmp_path):
-    """Per-request JSONL is the raw material of SERVE_BENCH percentiles:
+    """Per-request JSONL is the raw material of serving percentiles:
     records read back from disk must finalize to the same ttft/tpot."""
     from pytorch_distributed_training_tpu.utils.metrics import RequestLogger
 

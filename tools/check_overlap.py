@@ -407,14 +407,12 @@ def main_suite() -> None:
 
     here = os.path.abspath(__file__)
 
-    def leg(args, tpu_flags=None, env_extra=None):
+    def leg(args, tpu_flags=None):
         env = dict(os.environ)
         if tpu_flags:
             env["LIBTPU_INIT_ARGS"] = (
                 env.get("LIBTPU_INIT_ARGS", "") + " " + tpu_flags
             ).strip()
-        if env_extra:
-            env.update(env_extra)
         try:
             out = subprocess.run(
                 [sys.executable, here, *args], env=env, capture_output=True,
@@ -443,25 +441,8 @@ def main_suite() -> None:
     # per-layer param all-gathers must ride under forward/backward, and
     # TP-2, where each row-parallel matmul's activation all-reduce must
     # interleave with compute.
-    # Attention forced to the XLA path for these AOT-partitioned compiles:
-    # the current jax build's GSPMD cannot auto-partition the Mosaic flash
-    # custom-call across the fsdp/tensor-sharded mesh ("Mosaic kernels
-    # cannot be automatically partitioned" — the r4 toolchain could).  The
-    # question these legs answer — do the per-layer param all-gathers /
-    # activation all-reduces ride under forward/backward compute? — is a
-    # property of the FSDP/TP sharding schedule, not of which attention
-    # kernel computes the scores, so the forced-XLA graph answers it
-    # faithfully; the rows are labeled accordingly.
-    gpt2_env = {"PDT_FORCE_ATTN": "xla"}
-    fsdp8 = leg(["--gpt2-leg", "fsdp8"], env_extra=gpt2_env)
-    tp2 = leg(["--gpt2-leg", "tp2"], env_extra=gpt2_env)
-    for row in (fsdp8, tp2):
-        if "error" not in row:
-            row["attention"] = (
-                "xla (PDT_FORCE_ATTN=xla: current jax AOT cannot "
-                "auto-partition the Mosaic flash call; interleave "
-                "conclusions are attention-kernel-independent)"
-            )
+    fsdp8 = leg(["--gpt2-leg", "fsdp8"])
+    tp2 = leg(["--gpt2-leg", "tp2"])
 
     # Comm share of the DP-8 step from the committed scaling model
     # (AOT-measured collective bytes over the public ICI bandwidth vs the

@@ -28,8 +28,7 @@ tests/test_convergence_stack.py harness) showing the error-feedback
 trajectories land in the fp32 loss band.
 
 Usage: python tools/grad_sync_diag.py [--steps N] [--save]
-       python bench.py --grad-sync-diag --save     (same entry, registered)
---save writes GRAD_SYNC_BENCH.json with the bench session fingerprint.
+--save writes GRAD_SYNC_BENCH.json.
 """
 
 from __future__ import annotations
@@ -48,9 +47,9 @@ def _ensure_devices():
 
     if jax.default_backend() != "tpu" and jax.local_device_count() < 8:
         raise SystemExit(
-            "grad_sync_diag needs 8 devices; run via bench.py or set "
-            "JAX_PLATFORMS=cpu with the CPU device count applied before "
-            "JAX initializes (compat.set_cpu_device_count)"
+            "grad_sync_diag needs 8 devices: set JAX_PLATFORMS=cpu with "
+            "the CPU device count applied before JAX initializes "
+            "(compat.set_cpu_device_count)"
         )
 
 
@@ -656,12 +655,6 @@ def main():
             "within_fp32_band": band(conv_topk, conv_flat_sgdm),
         },
     }
-    try:
-        from bench import _fingerprint
-
-        out["session"] = _fingerprint()
-    except Exception:
-        pass
     print(json.dumps(out))
     if "--save" in sys.argv[1:]:
         path = os.path.join(

@@ -158,8 +158,8 @@ def main():
     packed_rate = seen / (time.perf_counter() - t0)
 
     # 4. End to end on the chip: packed-from-JPEG records → device cache →
-    #    ResNet-50 train steps (the bench.py --device-cache shape, fed by
-    #    THIS data instead of synthetic records).
+    #    ResNet-50 train steps (the --device-cache shape, fed by THIS
+    #    data instead of synthetic records).
     import jax
     import jax.numpy as jnp
     import optax

@@ -7,7 +7,8 @@ ResNet-50 train step for real v5e topologies (8 = 2x4, 16 = 2x8, 64 = 8x8) via
 ``jax.experimental.topologies``, read the *actual* collective traffic XLA
 emitted (every all-reduce operand, classified gradient-bucket vs sync-BN
 stat as in check_overlap.py), and combine it with the *measured*
-single-chip step time (bench.py) under a documented ring model:
+single-chip step time (rounds 1-5, another machine) under a documented
+ring model:
 
     T_comm(n)  = 2 * S * (n-1)/n / BW_ici      (bidirectional ring
                  all-reduce of S bytes over the ICI torus; BW_ici is the
@@ -214,9 +215,9 @@ def compile_moe_ep_step(topology: str = "v5e:2x4", batch: int = 16,
         return step_fn.lower(state, {"tokens": tokens}).compile().as_text()
 
 
-def moe_ep_census(save: bool) -> dict:
-    """Compile the expert-sharded MoE step and record its all-to-all
-    traffic (merged into MOE_BENCH.json under "ep_traffic" with --save)."""
+def moe_ep_census() -> dict:
+    """Compile the expert-sharded MoE step and print its all-to-all
+    traffic."""
     hlo = compile_moe_ep_step()
     row = {
         "topology": "v5e:2x4 (data=2 x expert=4)",
@@ -233,27 +234,13 @@ def moe_ep_census(save: bool) -> dict:
         ),
     }
     print(json.dumps(row))
-    if save:
-        # Anchor to the repo root — a CWD-relative open from tools/ would
-        # silently write a fragment file instead of merging the tracked
-        # artifact.
-        path = os.path.join(_REPO_ROOT, "MOE_BENCH.json")
-        try:
-            with open(path) as f:
-                bench = json.load(f)
-        except FileNotFoundError:
-            bench = {}
-        bench["ep_traffic"] = row
-        with open(path, "w") as f:
-            json.dump(bench, f, indent=1)
-        print(f"merged ep_traffic into {path}")
     return row
 
 
 def compile_for(topology: str, num_slices: int = 1):
     from check_overlap import compile_dp_step_for_topology
 
-    # bench.py's per-chip batch (128) held fixed per chip: weak scaling,
+    # The per-chip batch (128) held fixed per chip: weak scaling,
     # the DDP regime the reference runs.
     return compile_dp_step_for_topology(
         topology, per_chip_batch=128, image_dtype="bfloat16",
@@ -368,10 +355,10 @@ def multislice_row(
 
 
 def main():
-    step_ms = 49.0  # measured single-chip step at batch 128 (bench.py)
+    step_ms = 49.0  # single-chip step at batch 128 (rounds 1-5, another machine)
     args = sys.argv[1:]
     if "--moe-ep" in args:
-        moe_ep_census(save="--save" in args)
+        moe_ep_census()
         return
     if "--step-ms" in args:
         i = args.index("--step-ms")
