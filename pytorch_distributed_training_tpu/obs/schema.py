@@ -121,10 +121,15 @@ METRICS: dict[str, dict] = {
     },
     "flash_visited_pair_share": {
         "type": GAUGE, "labeled": True,
-        "help": "grouped native-layout flash kernels, per kernel (flash_fwd, "
-                "flash_bwd) of the train step as traced: key columns a q "
-                "block visits / (q rows x k_len); 1.0 for a whole-row tile, "
-                "0.625 for causal prefixes at 1024 x 1024 in 256-row blocks",
+        "help": "flash kernels of the train step as traced, per kernel: key "
+                "columns a q block visits / (q rows x k_len).  flash_fwd, "
+                "flash_bwd (the grouped native-layout pair): 1.0 for a "
+                "whole-row tile, 0.625 for causal prefixes at 1024 x 1024 in "
+                "256-row blocks.  flash_bd_fwd, flash_bd_bwd (the tabled pair "
+                "under the block-diffusion mask): the live tiles, the "
+                "diagonal ones at their sub-tile ranges; 0.2734 at 8192 "
+                "positions, block 4 (0.375 with every live tile whole, "
+                "0.2502 of the pairs live)",
     },
     # ---- SLO / alerting plane (obs/slo.py) ------------------------------
     "slo_alert_transitions": {

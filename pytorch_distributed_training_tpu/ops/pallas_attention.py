@@ -105,15 +105,25 @@ def _bd_live_block(qi, ki, block_q, block_k, seq_len, block_len):
     return same | earlier | clean
 
 
-def _when_live(live, compute, qi, ki, block_q, block_k, bd):
+def _when_live(live, compute, qi, ki, block_q, block_k, bd,
+               compute_ranges=None, subs=None):
     """Run a tile's ``compute`` where the tile is live.  Under the
     block-diffusion mask a live tile whose every pair is live (half of them
     at L = 4096) takes ``compute(masked=False)``: no mask is built and none
-    applied."""
+    applied.  A tile of one of the two diagonal classes (``_bd_sub_class``)
+    takes ``compute_ranges(sub, ranges)``, straight-line code over the
+    ``_bd_sub_ranges`` of its q sub-blocks and nothing past the mask's
+    frontier.  Whatever is left is computed whole under the mask."""
     if bd is not None:
         full = _bd_full_block(qi, ki, block_q, block_k, *bd)
         pl.when(live & full)(functools.partial(compute, masked=False))
-        pl.when(live & jnp.logical_not(full))(compute)
+        rest = live & jnp.logical_not(full)
+        for hit, sub, ranges in _bd_sub_classes(
+            qi, ki, block_q, block_k, bd, subs
+        ):
+            pl.when(hit)(functools.partial(compute_ranges, sub, ranges))
+            rest &= jnp.logical_not(hit)
+        pl.when(rest)(compute)
     elif live is not None:
         pl.when(live)(compute)
     else:
@@ -132,6 +142,67 @@ def _bd_full_block(qi, ki, block_q, block_k, seq_len, block_len):
     noisy_q = (q1 < seq_len) & (kc_hi < q0 // block_len)
     clean_q = (q0 >= seq_len) & (kc_hi <= (q0 - seq_len) // block_len)
     return k_clean & (noisy_q | clean_q)
+
+
+# Rows of a q sub-block (and columns of its masked range) in the two classes
+# of diagonal tile below, as (frontier, same-block), for the kernel each
+# launcher runs.  Chosen on the chip (PERF.md §6, PR 31): the forward is bound
+# by the VPU's passes over masked scores and wants its frontier narrow; the
+# backward by the MXU, whose products want 256 rows.
+_BD_SUBS = {"flash_bd_fwd": (128, 256), "flash_bd_bwd": (256, 128)}
+
+
+def _bd_sub_class(frontier, qi, ki, block_q, block_k, seq_len, block_len, sub):
+    """Whether tile (qi, ki) belongs to a class of diagonal tile whose live
+    pairs lie in static ranges of ``sub`` columns (``_bd_sub_ranges``), or
+    None where the static numbers rule the class out: tiles that are not
+    square, shorter than two sub-blocks or no multiple of one, or blocks that
+    straddle sub-blocks.  Such a tile is live, never full, and holds no
+    padded key.
+
+    - ``frontier``: the diagonal tiles of the noisy→clean and the
+      clean→clean quarter, a block-causal lower triangle each.  Only where
+      the halves are whole tiles (``seq_len`` a multiple of the tile: no
+      padded length).
+    - otherwise the same-block class: a diagonal tile of the noisy→noisy
+      quarter that lies wholly in the noisy half, live in ``block_len``
+      squares along its diagonal alone.
+    """
+    if (
+        block_q != block_k or block_q % sub or block_q < 2 * sub
+        or sub % block_len
+    ):
+        return None
+    if not frontier:
+        return (qi == ki) & ((qi + 1) * block_q <= seq_len)
+    if seq_len % block_q:
+        return None
+    half = seq_len // block_q
+    return (ki >= half) & ((ki - half == qi) | (ki == qi))
+
+
+def _bd_sub_ranges(frontier, block, sub):
+    """``[[(lo, hi, masked), ..], ..]``: the key columns of the tile that q
+    sub-block ``r`` (rows ``[r * sub, (r + 1) * sub)``) visits.  Every class
+    masks the ``sub`` columns on the diagonal; a frontier tile's sub-block
+    sees the columns before them whole."""
+    ranges = []
+    for r in range(block // sub):
+        diagonal = (r * sub, (r + 1) * sub, True)
+        ranges.append(
+            [(0, r * sub, False), diagonal] if frontier and r else [diagonal]
+        )
+    return ranges
+
+
+def _bd_sub_classes(qi, ki, block_q, block_k, bd, subs):
+    """``(hit, sub, ranges)`` of each diagonal class the static numbers
+    allow at ``subs = (frontier sub, same-block sub)``: the tile predicate,
+    the sub-block rows and the classes' ``_bd_sub_ranges``."""
+    for frontier, sub in zip((True, False), subs):
+        hit = _bd_sub_class(frontier, qi, ki, block_q, block_k, *bd, sub)
+        if hit is not None:
+            yield hit, sub, _bd_sub_ranges(frontier, block_q, sub)
 
 
 def _bd_mask(q0, k0, block_q, block_k, seq_len, block_len):
@@ -238,11 +309,81 @@ def _fwd_kernel(
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
+    def _compute_ranges(sub, ranges):
+        # A diagonal tile under the block-diffusion mask: q sub-block r takes
+        # its own key ranges, with one max over them and the rows' running
+        # one.  Written phase by phase over ALL sub-blocks — scores, maxima,
+        # probabilities, p v, stores — and not sub-block by sub-block: the
+        # sub-blocks' chains of product, lane reduction and exp are short
+        # and independent, and in this order they overlap (PERF.md §6, PR 31).
+        strips = [slice(r * sub, (r + 1) * sub) for r in range(len(ranges))]
+        lane_chunks = lambda x: [
+            x[:, j:j + _LANES] for j in range(0, x.shape[1], _LANES)
+        ]
+        scores = []
+        for rows, parts in zip(strips, ranges):
+            q = q_ref[0, 0, rows, :]
+            scores.append([
+                jax.lax.dot_general(
+                    q, k_ref[0, 0, lo:hi, :],
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) for lo, hi, _ in parts
+            ])
+        m_prev = [m_scr[rows, 0:1] for rows in strips]
+        masks, m_new = [], []
+        for r, parts in enumerate(ranges):
+            masks.append([
+                _bd_mask(qi * block_q + r * sub, ki * block_k + lo, sub,
+                         hi - lo, *bd) if masked else None
+                for lo, hi, masked in parts
+            ])
+            for i, mask in enumerate(masks[r]):
+                s = scores[r][i] if scale == 1.0 else scores[r][i] * scale
+                scores[r][i] = s if mask is None else jnp.where(mask, s, _NEG_INF)
+            # lanes first, across the ranges: one lane reduction a sub-block
+            widest = functools.reduce(
+                jnp.maximum, [c for s in scores[r] for c in lane_chunks(s)]
+            )
+            m_new.append(jnp.maximum(
+                m_prev[r], jnp.max(widest, axis=1, keepdims=True)
+            ))
+        probs, alpha, l_new = [], [], []
+        for r, rows in enumerate(strips):
+            probs.append([])
+            sums = []
+            for s, mask in zip(scores[r], masks[r]):
+                p = jnp.exp(s - m_new[r])
+                if mask is not None:
+                    # as in ``_compute``: a row that has seen no key yet
+                    p = jnp.where(mask, p, 0.0)
+                sums.extend(lane_chunks(p))
+                probs[r].append(p.astype(v_ref.dtype))
+            alpha.append(jnp.exp(m_prev[r] - m_new[r]))
+            l_new.append(
+                alpha[r] * l_scr[rows, 0:1] + jnp.sum(
+                    functools.reduce(jnp.add, sums), axis=1, keepdims=True
+                )
+            )
+        acc = [acc_scr[rows, :] * alpha[r] for r, rows in enumerate(strips)]
+        for r, parts in enumerate(ranges):
+            for p, (lo, hi, _) in zip(probs[r], parts):
+                acc[r] += jax.lax.dot_general(
+                    p, v_ref[0, 0, lo:hi, :],
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+        for r, rows in enumerate(strips):
+            acc_scr[rows, :] = acc[r]
+            m_scr[rows, :] = jnp.broadcast_to(m_new[r], (sub, m_scr.shape[1]))
+            l_scr[rows, :] = jnp.broadcast_to(l_new[r], (sub, l_scr.shape[1]))
+
     block_live = _live_block(
         qi, ki, causal=causal, causal_offset=causal_offset, kv_len=kv_len,
         block_q=block_q, block_k=block_k, bd=bd,
     )
-    _when_live(block_live, _compute, qi, ki, block_q, block_k, bd)
+    _when_live(block_live, _compute, qi, ki, block_q, block_k, bd,
+               _compute_ranges, _BD_SUBS["flash_bd_fwd"])
 
     @pl.when(ki == num_k - 1)
     def _finalize():
@@ -662,8 +803,10 @@ def _causal_spans(q_len, k_len, block_q, causal_offset, kv_len):
     return spans
 
 
-# kernel name -> key columns visited ÷ (q rows × k_len) in the grouped launch
-# traced last: 1.0 for a whole-row tile, the causal prefixes' share otherwise.
+# kernel name -> key columns visited ÷ (q rows × k_len) in the launch traced
+# last.  The grouped pair: 1.0 for a whole-row tile, the causal prefixes'
+# share otherwise.  The tabled pair: its live tiles, the diagonal classes at
+# their sub-ranges.
 _visited_pair_share: dict[str, float] = {}
 
 
@@ -677,6 +820,29 @@ def _note_visited_share(kernel, spans, q_len, k_len, block_q):
         (last - first + 1) * block_q * visit
         for first, last, _, visit in spans
     ) / (q_len * k_len)
+
+
+def _note_tabled_visited_share(kernel, nq, nk, **mask):
+    """The tabled launchers' share, by the kernels' own tile predicates
+    (``_live_block``, ``_bd_sub_class``) on the whole grid at trace time."""
+    import numpy as np
+
+    block_q, block_k, bd = mask["block_q"], mask["block_k"], mask["bd"]
+    qi = np.arange(nq, dtype=np.int32)[:, None]
+    ki = np.arange(nk, dtype=np.int32)[None, :]
+    with jax.ensure_compile_time_eval():
+        visited = np.broadcast_to(
+            np.asarray(_live_block(qi, ki, **mask)), (nq, nk)
+        ) * float(block_q * block_k)
+        for hit, sub, ranges in _bd_sub_classes(
+            qi, ki, block_q, block_k, bd, _BD_SUBS[kernel]
+        ):
+            visited[np.broadcast_to(np.asarray(hit), (nq, nk))] = sub * sum(
+                hi - lo for parts in ranges for lo, hi, _ in parts
+            )
+    _visited_pair_share[kernel] = float(
+        visited.sum() / (nq * block_q * nk * block_k)
+    )
 
 
 def _in_each_span(qi, spans, body):
@@ -1246,6 +1412,9 @@ def _live_table(nq, nk, **mask):
     return table
 
 
+# Jitted on their own, as the grouped launchers are: a model's layers trace
+# and lower the pair once a program.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_tabled_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                       causal_offset, kv_len, bd):
     """The multi-tile forward under the block-diffusion mask, whose dead
@@ -1265,6 +1434,7 @@ def _flash_tabled_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     )
     nq, nk = q_len // block_q, k_len // block_k
     kv_of = _live_table(nq, nk, **mask)
+    _note_tabled_visited_share("flash_bd_fwd", nq, nk, **mask)
     q_index = lambda b_, h_, qi, ki, tbl: (b_, h_, qi, 0)
     kv_index = lambda b_, h_, qi, ki, tbl: (b_, h_ // group, tbl[qi, ki], 0)
     kernel = functools.partial(_fwd_kernel, scale=scale, **mask)
@@ -1347,11 +1517,54 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
+    def _compute_ranges(sub, ranges):
+        # A diagonal tile under the block-diffusion mask: five matmuls a
+        # visited range, into its q sub-block's rows of dq and its keys' rows
+        # of the head's dk / dv.  Phase by phase over all sub-blocks, as the
+        # forward's: p and ds of every range, then dq, then dk / dv.
+        tiles = []
+        for r, parts in enumerate(ranges):
+            rows = slice(r * sub, (r + 1) * sub)
+            q, do = q_ref[0, 0, rows, :], do_ref[0, 0, rows, :]
+            lse, delta = lse_ref[0, 0, rows, :], delta_ref[0, 0, rows, :]
+            tiles.append([])
+            for lo, hi, masked in parts:
+                k = k_ref[0, 0, lo:hi, :]
+                # a masked range is ``sub`` columns at a multiple of ``sub``:
+                # the mask's origin in ``_bwd_block``'s own units
+                p, ds = _bwd_block(
+                    q, k, v_ref[0, 0, lo:hi, :], do, lse, delta,
+                    qi * (block_q // sub) + r, ki * (block_k // sub) + lo // sub,
+                    causal=False, causal_offset=0, scale=scale, block_q=sub,
+                    block_k=sub, bd=bd, masked=masked,
+                )
+                tiles[r].append(
+                    (lo, hi, q, do, k, p.astype(do.dtype), ds.astype(k.dtype))
+                )
+        for r, row in enumerate(tiles):
+            dq_scr[r * sub:(r + 1) * sub, :] += sum(
+                jax.lax.dot_general(
+                    ds, k, dimension_numbers=(((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) for _, _, _, _, k, _, ds in row
+            )
+        for lo, hi, q, do, _, p, ds in (t for row in tiles for t in row):
+            keys = pl.ds(pl.multiple_of(ki * block_k + lo, sub), hi - lo)
+            dv_scr[keys, :] += jax.lax.dot_general(
+                p, do, dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dk_scr[keys, :] += jax.lax.dot_general(
+                ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+
     live = _live_block(
         qi, ki, causal=causal, causal_offset=causal_offset, kv_len=kv_len,
         block_q=block_q, block_k=block_k, bd=bd,
     )
-    _when_live(live, _compute, qi, ki, block_q, block_k, bd)
+    _when_live(live, _compute, qi, ki, block_q, block_k, bd, _compute_ranges,
+               _BD_SUBS["flash_bd_bwd"])
 
     @pl.when(ki == num_k - 1)
     def _finalize_rows():
@@ -1369,6 +1582,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 _FUSED_BWD_VMEM = 64 * 2**20
 
 
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
 def _flash_tabled_bwd(q, k, v, lse, delta, do, causal, scale, block_q,
                       block_k, interpret, causal_offset, kv_len, bd):
     """The backward behind ``_flash_tabled_fwd``: one fused kernel
@@ -1385,6 +1599,7 @@ def _flash_tabled_bwd(q, k, v, lse, delta, do, causal, scale, block_q,
     )
     nq, nk = q_len // block_q, k_len // block_k
     kv_of = _live_table(nq, nk, **mask)
+    _note_tabled_visited_share("flash_bd_bwd", nq, nk, **mask)
 
     q_index = lambda b_, n_, g_, qi, ki, tbl: (b_, n_ * group + g_, qi, 0)
     kv_index = lambda b_, n_, g_, qi, ki, tbl: (b_, n_, tbl[qi, ki], 0)

@@ -130,6 +130,36 @@ def test_visited_pair_share_pinned_for_gpt2(monkeypatch):
         }
 
 
+def test_visited_pair_share_pinned_for_sdar(monkeypatch):
+    """8192 positions under ``bd = (4096, 4)`` at 1024-tiles: of 64 tiles a
+    head 24 are live, 12 of them whole; the 8 frontier and 4 same-block tiles
+    take their sub-ranges, 17.5 tile-equivalents in all (24, or 0.375, when
+    every live tile is computed whole); 0.2502 of the pairs are live."""
+    for name in ("_flash_tabled_fwd", "_flash_tabled_bwd"):
+        monkeypatch.setattr(pa, name, getattr(pa, name).__wrapped__)
+    monkeypatch.setattr(pa, "_visited_pair_share", {})
+    q = jnp.zeros((1, 32, 8192, 128), jnp.bfloat16)
+    kv = jnp.zeros((1, 4, 8192, 128), jnp.bfloat16)
+    jax.eval_shape(
+        lambda q, k, v: jax.vjp(
+            lambda q, k, v: pa._flash(q, k, v, False, 1.0, 1024, 1024, True,
+                                      0, None, (4096, 4)),
+            q, k, v,
+        )[1](q),
+        q, kv, kv,
+    )
+    assert pa.flash_visited_pair_share() == {
+        "flash_bd_fwd": 17.5 / 64, "flash_bd_bwd": 17.5 / 64,
+    }
+    # a tile too short for either class: every live tile whole
+    monkeypatch.setattr(pa, "_visited_pair_share", {})
+    jax.eval_shape(
+        lambda q: pa._flash(q, q, q, False, 1.0, 128, 128, True, 0, None, (128, 4)),
+        jnp.zeros((1, 2, 256, 64), jnp.bfloat16),
+    )
+    assert pa.flash_visited_pair_share() == {"flash_bd_fwd": 0.75}
+
+
 GPT2 = dict(q_len=1024, k_len=1024, num_heads=12, head_dim=64, itemsize=2)
 
 
@@ -161,6 +191,28 @@ def one_v5e():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def mosaic_calls_compiled_for(fn, *shapes):
+    """``([[name, result], ..], text)``: the Mosaic custom calls in ``fn``
+    compiled for the described chip ``shapes`` are placed on, and the whole
+    compiled text; the persistent cache off (an entry written for a described
+    chip cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(fn).lower(*shapes).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+        compilation_cache.reset_cache()
+    # outside a step the instruction's name carries the transformations
+    # around the call (``%jvp_flash_fwd_.1``); the result follows `` = ``
+    return [line.split(" custom-call(")[0].split(" = ")
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line], text
+
+
 @pytest.mark.parametrize("q_len,k_len,heads,dim,causal", [
     (1024, 1024, 12, 64, True),     # GPT-2's microbatch
     (1024, 1024, 12, 64, False),
@@ -172,8 +224,6 @@ def test_grouped_pair_compiles_for_a_v5e(one_v5e, q_len, k_len, heads, dim, caus
     """Mosaic takes the pair at real widths (VMEM, tiling, the static prefix
     slices), and the calls keep the names and results the roofline readers
     tell them apart by.  Compiled for a described chip: nothing runs."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     def pair(q, k, v):
         out, vjp = jax.vjp(
             lambda q, k, v: pa.flash_attention(q, k, v, causal=causal, interpret=False),
@@ -183,19 +233,7 @@ def test_grouped_pair_compiles_for_a_v5e(one_v5e, q_len, k_len, heads, dim, caus
 
     shape = lambda n: jax.ShapeDtypeStruct((8, n, heads, dim), jnp.bfloat16,
                                            sharding=one_v5e)
-    cache_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(pair).lower(shape(q_len), shape(k_len), shape(k_len)).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_on)
-        compilation_cache.reset_cache()
-    # outside a step the instruction's name carries the transformations
-    # around the call (``%jvp_flash_fwd_.1``); the result follows `` = ``
-    calls = [line.split(" custom-call(")[0].split(" = ")
-             for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+    calls, _ = mosaic_calls_compiled_for(pair, shape(q_len), shape(k_len), shape(k_len))
     assert len(calls) == 2
     results = {role: result for name, result in calls
                for role in ("flash_fwd", "flash_bwd") if role in name}
@@ -203,6 +241,29 @@ def test_grouped_pair_compiles_for_a_v5e(one_v5e, q_len, k_len, heads, dim, caus
     assert results["flash_fwd"].count(f"bf16[8,{qp},{w}]") == 1 and "f32[" in results["flash_fwd"]
     assert results["flash_bwd"].count("bf16[8,") == 3 and "f32[" not in results["flash_bwd"]
     assert f"bf16[8,{kp},{w}]" in results["flash_bwd"]
+
+
+def test_tabled_pair_compiles_for_a_v5e_at_sdars_shape(one_v5e):
+    """Mosaic takes the masked pair with its sub-tile branches at the cell's
+    widths — 8192 positions, 32 / 4 heads of 128, block 4 — inside the fused
+    backward's scoped VMEM (``_FUSED_BWD_VMEM``).  Nothing runs."""
+    def pair(q, k, v):
+        out, vjp = jax.vjp(
+            lambda q, k, v: pa.flash_attention(
+                q, k, v, block_diffusion=(4096, 4), interpret=False),
+            q, k, v,
+        )
+        return (out,) + vjp(out)
+
+    shape = lambda h: jax.ShapeDtypeStruct((1, 8192, h, 128), jnp.bfloat16,
+                                           sharding=one_v5e)
+    calls, text = mosaic_calls_compiled_for(pair, shape(32), shape(4), shape(4))
+    assert len(calls) == 2
+    results = {role: result for name, result in calls
+               for role in ("flash_bd_fwd", "flash_bd_bwd") if role in name}
+    assert "bf16[1,32,8192,128]" in results["flash_bd_fwd"]
+    assert results["flash_bd_bwd"].count("bf16[1,4,8192,128]") == 2     # dk, dv at the K/V heads
+    assert f"{pa._FUSED_BWD_VMEM}" in text
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -55,13 +55,12 @@ def test_tile_predicate_and_in_tile_mask_equal_the_dense_definition(seq_len, blo
                for q in range(n) for k in range(n))
 
 
-@pytest.mark.parametrize("seq_len, block, tile", [(32, 4, 1024), (48, 8, 1024), (256, 4, 128)])
-def test_flash_kernels_under_the_mask_match_the_xla_path(seq_len, block, tile):
-    """The forward and the fused backward kernel through the Pallas interpreter,
-    grouped K/V heads, against the dense-mask XLA path."""
+def flash_and_xla_under_the_mask(seq_len, block, tile, batch, kv_heads):
+    """(out, dq, dk, dv) of the kernels through the Pallas interpreter and of
+    the dense-mask XLA path, 4 query heads over ``kv_heads``."""
     keys = jax.random.split(jax.random.PRNGKey(seq_len), 3)
-    q = jax.random.normal(keys[0], (2, 2 * seq_len, 4, 16))
-    k, v = (jax.random.normal(x, (2, 2 * seq_len, 2, 16)) for x in keys[1:])
+    q = jax.random.normal(keys[0], (batch, 2 * seq_len, 4, 16))
+    k, v = (jax.random.normal(x, (batch, 2 * seq_len, kv_heads, 16)) for x in keys[1:])
 
     def run(flash):
         def loss(q, k, v):
@@ -75,8 +74,100 @@ def test_flash_kernels_under_the_mask_match_the_xla_path(seq_len, block, tile):
         (_, o), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
         return (o, *grads)
 
-    for got, want in zip(run(True), run(False)):
+    return run(True), run(False)
+
+
+@pytest.mark.parametrize("seq_len, block, tile", [(32, 4, 1024), (48, 8, 1024), (256, 4, 128)])
+def test_flash_kernels_under_the_mask_match_the_xla_path(seq_len, block, tile):
+    """The forward and the fused backward kernel through the Pallas interpreter,
+    grouped K/V heads, against the dense-mask XLA path."""
+    for got, want in zip(*flash_and_xla_under_the_mask(seq_len, block, tile, 2, 2)):
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=3e-5)
+
+
+@pytest.mark.parametrize("seq_len, block, tile, sub, frontier_tiles, same_tiles", [
+    (4096, 4, 1024, 256, 8, 4),     # SDAR's call, the frontier class's sub
+    (4096, 4, 1024, 128, 8, 4),     # and the same-block class's
+    (1024, 32, 512, 128, 4, 2),     # block_len 32
+    (600, 4, 256, 128, 0, 2),       # padded: 1200 keys of 1280, tile 2 straddles the halves
+    (512, 4, 256, 256, 0, 0),       # a tile of ``sub`` rows: the whole tile, as before
+    (768, 12, 256, 64, 0, 0),       # a block_len that does not divide ``sub``
+    (384, 4, 256, 64, 0, 1),        # halves that are no whole tiles: no frontier class
+])
+def test_sub_tile_classes_and_ranges_against_the_dense_mask(
+        seq_len, block, tile, sub, frontier_tiles, same_tiles):
+    """Every tile of a diagonal class is live and not full; its visited
+    ranges hold every live pair, an unmasked range no dead one, and a masked
+    range is the in-tile mask's own; the classes never overlap, and where the
+    static numbers rule a class out every tile keeps the whole-tile form."""
+    padded = -(-2 * seq_len // 128) * 128
+    want = np.zeros((padded, padded), bool)
+    want[:2 * seq_len, :2 * seq_len] = attention.block_diffusion_mask(seq_len, block)
+    n = padded // tile
+    counts = {True: 0, False: 0}
+    for qi in range(n):
+        for ki in range(n):
+            cut = want[qi * tile:(qi + 1) * tile, ki * tile:(ki + 1) * tile]
+            hits = []
+            for frontier in (True, False):
+                hit = pa._bd_sub_class(frontier, qi, ki, tile, tile, seq_len, block, sub)
+                if hit is not None and bool(hit):
+                    hits.append(frontier)
+            assert len(hits) <= 1, (qi, ki)
+            if not hits:
+                continue
+            counts[hits[0]] += 1
+            assert cut.any() and not cut.all(), (qi, ki)
+            assert (ki + 1) * tile <= 2 * seq_len       # no padded key: no kv_len mask
+            visited = np.zeros_like(cut)
+            ranges = pa._bd_sub_ranges(hits[0], tile, sub)
+            assert len(ranges) == tile // sub
+            for r, parts in enumerate(ranges):
+                rows = slice(r * sub, (r + 1) * sub)
+                for lo, hi, masked in parts:
+                    assert 0 <= lo < hi <= tile and not visited[rows, lo:hi].any()
+                    visited[rows, lo:hi] = True
+                    if masked:
+                        np.testing.assert_array_equal(
+                            pa._bd_mask(qi * tile + r * sub, ki * tile + lo, sub, hi - lo,
+                                        seq_len, block),
+                            cut[rows, lo:hi])
+                    else:
+                        assert cut[rows, lo:hi].all(), (qi, ki, r, lo)
+            assert not (cut & ~visited).any(), (qi, ki)
+    assert counts == {True: frontier_tiles, False: same_tiles}
+    if (seq_len, block, tile) == (4096, 4, 1024):
+        # SDAR's grid: whatever is live and neither full nor of a class
+        live = sum(bool(pa._bd_live_block(q, k, tile, tile, seq_len, block))
+                   for q in range(n) for k in range(n))
+        full = sum(bool(pa._bd_full_block(q, k, tile, tile, seq_len, block))
+                   for q in range(n) for k in range(n))
+        assert (live, full) == (24, 12) and live - full == frontier_tiles + same_tiles
+
+
+@pytest.mark.parametrize("seq_len, block, tile, reaches", [
+    (1024, 4, 512, {"full", "frontier", "same"}),
+    (512, 32, 512, {"frontier", "same"}),
+    (600, 4, 256, {"full", "same", "whole"}),     # padded keys, a tile across the halves
+])
+def test_flash_kernels_in_the_sub_tile_forms_match_the_xla_path(seq_len, block, tile, reaches):
+    """Forward, dq, dk and dv through the interpreter at tiles wide enough
+    for the diagonal classes, K/V grouped 4:1 as SDAR's, against XLA."""
+    padded = -(-2 * seq_len // 128) * 128
+    n, forms = padded // tile, set()
+    for qi in range(n):
+        for ki in range(n):
+            if not pa._bd_live_block(qi, ki, tile, tile, seq_len, block) or ki * tile >= 2 * seq_len:
+                continue
+            hit = {name for subs in pa._BD_SUBS.values()        # either kernel's
+                   for name, frontier, sub in zip(("frontier", "same"), (True, False), subs)
+                   if pa._bd_sub_class(frontier, qi, ki, tile, tile, seq_len, block, sub)}
+            full = bool(pa._bd_full_block(qi, ki, tile, tile, seq_len, block))
+            forms |= hit or {"full" if full else "whole"}
+    assert forms == reaches
+
+    for got, want in zip(*flash_and_xla_under_the_mask(seq_len, block, tile, 1, 1)):
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=5e-5)
 
 
 def test_xla_path_equals_attention_with_repeated_heads():
