@@ -10,9 +10,10 @@ mesh reserves an ``expert`` axis and a complete framework fills it.
   ``expert`` axis — GSPMD turns dispatch/combine into all-to-alls) and a
   row scatter/gather for experts that are not mesh-sharded.  Static shapes
   throughout.
-- :class:`TopKMoe` (``sdar_moe``): top-k routing over the published router
-  width with normalised weights and NO capacity: every assignment to an
-  expert this chip holds is computed.  The layer is told which contiguous
+- :class:`TopKMoe` (``sdar_moe``, ``instella_moe``): top-k routing over the
+  published router width (softmax scores, or DeepSeek-V3's sigmoid scores
+  with a selection-only bias) with normalised weights and NO capacity:
+  every assignment to an expert this chip holds is computed.  The layer is told which contiguous
   range of experts it holds (``experts_held``), routes over all of them,
   and returns its own experts' part of the result — what expert
   parallelism asks of a chip before the exchange, and nothing standing in
@@ -267,15 +268,47 @@ class MoeBlock(nn.Module):
         return x + y
 
 
-def topk_route(logits: jax.Array, k: int, norm_topk_prob: bool = True):
+def topk_route(logits: jax.Array, k: int, norm_topk_prob: bool = True, *,
+               scoring: str = "softmax", bias: jax.Array | None = None,
+               scale: float = 1.0):
     """Router math of the top-k layer.  logits: (T, E) → (weights (T, k)
-    f32, expert ids (T, k) int32): softmax in f32 over ALL E outputs, the k
-    largest, renormalised to sum to one when ``norm_topk_prob``."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, experts = lax.top_k(probs, k)
+    f32, expert ids (T, k) int32, scores (T, E) f32).  ``scoring``
+    ``"softmax"``: softmax in f32 over ALL E outputs; ``"sigmoid"``
+    (DeepSeek-V3's): each output's own sigmoid.  The k experts are the k
+    largest scores — of ``scores + bias`` when a selection ``bias`` (E,) is
+    given (``noaux_tc``: it decides WHICH experts, takes no gradient, and
+    never enters a weight) — and the weights are their unbiased scores,
+    renormalised to sum to one when ``norm_topk_prob``, times ``scale``."""
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring {scoring!r}: softmax or sigmoid")
+    squash = jax.nn.softmax if scoring == "softmax" else jax.nn.sigmoid
+    scores = squash(logits.astype(jnp.float32))
+    if bias is None:
+        weights, experts = lax.top_k(scores, k)
+    else:
+        _, experts = lax.top_k(scores + lax.stop_gradient(bias.astype(jnp.float32)), k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return weights, experts.astype(jnp.int32)
+    if scale != 1.0:
+        weights = weights * scale
+    return weights, experts.astype(jnp.int32), scores
+
+
+def sequence_balance(scores: jax.Array, experts: jax.Array) -> jax.Array:
+    """DeepSeek-V3's sequence-wise balance term (``seq_aux``), before its
+    coefficient: scores (B, L, E) f32 and chosen experts (B, L, k) →
+    ``mean_b Σ_i f_i P_i`` with ``f_i = E / (k L) · #{t : i chosen at t}``
+    (a count: no gradient) and ``P_i = mean_t s_{i,t} / Σ_j s_{j,t}``, over
+    ALL E outputs: the router is whole on every chip."""
+    e, (_, length, k) = scores.shape[-1], experts.shape
+    chosen = jnp.sum(
+        experts[..., None] == jnp.arange(e, dtype=experts.dtype), axis=(1, 2),
+        dtype=jnp.float32,
+    )                                                      # (B, E)
+    f = chosen * (e / (k * length))
+    p = jnp.mean(scores / jnp.sum(scores, axis=-1, keepdims=True), axis=1)
+    return jnp.mean(jnp.sum(f * p, axis=-1))
 
 
 def group_held_assignments(experts: jax.Array, first: int, held: int):
@@ -407,6 +440,13 @@ class TopKMoe(nn.Module):
     experts have weights here.  Every held assignment is computed
     (:func:`held_experts`, ``ROWS_CHUNK`` sorted rows a pass of its loop).
 
+    The router is SDAR's by default (softmax over all outputs).
+    ``scoring="sigmoid"``, ``selection_bias`` (a ``router_bias`` (E,) leaf
+    that starts at zero, is added to the scores for the SELECTION only and
+    takes no gradient; nothing here updates it), ``routed_scaling_factor``
+    and ``seq_aux`` (the sequence-wise balance term, sown into ``losses``
+    WITHOUT its coefficient) make it DeepSeek-V3's (``models/instella_moe``).
+
     Sown into ``moe_counters`` (one scalar a layer, f32): the held
     assignments and the busiest held expert's rows.
     """
@@ -416,6 +456,10 @@ class TopKMoe(nn.Module):
     mlp_dim: int
     experts_held: tuple | None = None
     norm_topk_prob: bool = True
+    scoring: str = "softmax"
+    selection_bias: bool = False
+    routed_scaling_factor: float = 1.0
+    seq_aux: bool = False
     dtype: Any = jnp.float32
 
     @nn.compact
@@ -436,11 +480,20 @@ class TopKMoe(nn.Module):
                                 ("w_down", (held, self.mlp_dim, d)))
         )
 
+        bias = (self.param("router_bias", nn.initializers.zeros, (e,), jnp.float32)
+                if self.selection_bias else None)
+
         with scope("moe/route"):
             logits = jnp.dot(tokens, router.astype(self.dtype),
                              preferred_element_type=jnp.float32)
-            weights, experts = topk_route(logits, k, self.norm_topk_prob)
+            weights, experts, scores = topk_route(
+                logits, k, self.norm_topk_prob, scoring=self.scoring, bias=bias,
+                scale=self.routed_scaling_factor,
+            )
             order, counts = group_held_assignments(experts, first, held)
+            if self.seq_aux:
+                self.sow("losses", "moe_balance", sequence_balance(
+                    scores.reshape(b, l, e), experts.reshape(b, l, k)))
         self.sow("moe_counters", "moe_held_assignments", jnp.sum(counts).astype(jnp.float32))
         self.sow("moe_counters", "moe_load_max", jnp.max(counts).astype(jnp.float32))
 

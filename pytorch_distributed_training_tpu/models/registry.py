@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from .resnet import resnet18, resnet34, resnet50, resnet101, resnet152
 from .vit import vit_b16, vit_l16, vit_s16
 from .gpt2 import gpt2_124m, gpt2_large, gpt2_medium, gpt2_xl
+from .instella_moe import instella_moe_16b_a3b
 from .sdar import sdar_30b_a3b
 
 
@@ -49,6 +50,9 @@ MODEL_REGISTRY: dict[str, ModelEntry] = {
     # (``SdarMoe.lm_objective``, read by train/step.py): same batches, same
     # step, another loss.
     "sdar_30b_a3b": ModelEntry(sdar_30b_a3b, "lm"),
+    # Next-token CE plus its MTP module's and the experts' balance term
+    # (``InstellaMoe.lm_objective`` = "next_token_mtp").
+    "instella_moe_16b_a3b": ModelEntry(instella_moe_16b_a3b, "lm"),
 }
 
 

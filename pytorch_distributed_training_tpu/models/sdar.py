@@ -92,10 +92,12 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotate-half rotary embedding.  x: (B, P, H, D), positions: (P,)."""
+def rope(x: jax.Array, positions: jax.Array, theta: float, inv_freq=None) -> jax.Array:
+    """Rotate-half rotary embedding.  x: (B, P, H, D), positions: (P,).
+    ``inv_freq`` (D/2,) replaces the plain ``theta`` ladder (a scaled one:
+    ``models/instella_moe.yarn_inv_freq``)."""
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half) if inv_freq is None else inv_freq
     angle = positions.astype(jnp.float32)[:, None] * freq[None, :]       # (P, D/2)
     cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
     x32 = x.astype(jnp.float32)

@@ -110,6 +110,19 @@ METRICS: dict[str, dict] = {
         "help": "block-diffusion objective: positions the step's noise "
                 "masked, over all its microbatches",
     },
+    # ---- parts of a multi-part loss (train/step.py::STEP_LOSS_PARTS) ----
+    "mtp_loss": {
+        "type": GAUGE, "labeled": False,
+        "help": "next_token_mtp objective: the MTP module's cross entropy on "
+                "the token two ahead, before its weight, mean over the "
+                "step's microbatches",
+    },
+    "moe_balance_loss": {
+        "type": GAUGE, "labeled": False,
+        "help": "next_token_mtp objective: alpha x the experts' "
+                "sequence-wise balance term summed over the expert layers, "
+                "as it enters the loss",
+    },
     # ---- what was lowered (obs/cost.py) --------------------------------
     "mosaic_custom_calls": {
         "type": GAUGE, "labeled": True,
@@ -125,7 +138,9 @@ METRICS: dict[str, dict] = {
                 "columns a q block visits / (q rows x k_len).  flash_fwd, "
                 "flash_bwd (the grouped native-layout pair): 1.0 for a "
                 "whole-row tile, 0.625 for causal prefixes at 1024 x 1024 in "
-                "256-row blocks.  flash_bd_fwd, flash_bd_bwd (the tabled pair "
+                "256-row blocks; (the transposed multi-tile pair, causal): "
+                "its live tiles whole, 0.5625 at 8192 x 8192 in 1024-tiles.  "
+                "flash_bd_fwd, flash_bd_bwd (the tabled pair "
                 "under the block-diffusion mask): the live tiles, the "
                 "diagonal ones at their sub-tile ranges; 0.2734 at 8192 "
                 "positions, block 4 (0.375 with every live tile whole, "

@@ -51,8 +51,11 @@ PHASES = (
     "train/optimizer",       # the update gate: optimizer + apply (in the program)
     "train/noise",           # block diffusion: the step's noise and its 2L input
     "attn/block_diffusion",  # attention under the block-diffusion mask
+    "attn/mla",              # latent attention: projections, norms, rotation, output gate
     "moe/route",             # top-k router: scores, top-k, grouping by expert
     "moe/experts",           # the held experts: row gather, grouped products, combine
+    "moe/shared",            # the shared experts' MLP, every token's
+    "train/mtp",             # the multi-token-prediction module and its loss
     "grad_accum/microbatch",  # fwd+bwd of one accumulation microbatch
     "grad_sync/rs_ici",      # tier 1: reduce-scatter over ICI
     "grad_sync/ar_dcn",      # tier 2: cross-slice all-reduce over DCN

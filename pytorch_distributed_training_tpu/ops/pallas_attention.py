@@ -823,18 +823,21 @@ def _note_visited_share(kernel, spans, q_len, k_len, block_q):
 
 
 def _note_tabled_visited_share(kernel, nq, nk, **mask):
-    """The tabled launchers' share, by the kernels' own tile predicates
-    (``_live_block``, ``_bd_sub_class``) on the whole grid at trace time."""
+    """The multi-tile launchers' share, by the kernels' own tile predicates
+    (``_live_block``, ``_bd_sub_class``) on the whole grid at trace time:
+    the tabled pair's, and the causal pair's (``bd`` None: its live tiles
+    whole, the diagonal ones with their dead half)."""
     import numpy as np
 
-    block_q, block_k, bd = mask["block_q"], mask["block_k"], mask["bd"]
+    block_q, block_k, bd = mask["block_q"], mask["block_k"], mask.get("bd")
     qi = np.arange(nq, dtype=np.int32)[:, None]
     ki = np.arange(nk, dtype=np.int32)[None, :]
     with jax.ensure_compile_time_eval():
+        live = _live_block(qi, ki, **mask)
         visited = np.broadcast_to(
-            np.asarray(_live_block(qi, ki, **mask)), (nq, nk)
+            True if live is None else np.asarray(live), (nq, nk)
         ) * float(block_q * block_k)
-        for hit, sub, ranges in _bd_sub_classes(
+        for hit, sub, ranges in () if bd is None else _bd_sub_classes(
             qi, ki, block_q, block_k, bd, _BD_SUBS[kernel]
         ):
             visited[np.broadcast_to(np.asarray(hit), (nq, nk))] = sub * sum(
@@ -1157,15 +1160,15 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
         )
 
     grid = (b, h, q_len // block_q, k_len // block_k)
-    kernel = functools.partial(
-        _fwd_kernel,
+    mask = dict(
         causal=causal,
         causal_offset=k_len - q_len if causal_offset is None else causal_offset,
-        scale=scale,
         block_q=block_q,
         block_k=block_k,
         kv_len=kv_len,
     )
+    _note_tabled_visited_share("flash_fwd", *grid[2:], **mask)
+    kernel = functools.partial(_fwd_kernel, scale=scale, **mask)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -1666,11 +1669,15 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret
             kv_len,
         )
 
-    common = dict(
+    mask = dict(
         causal=causal,
         causal_offset=k_len - q_len if causal_offset is None else causal_offset,
-        scale=scale, block_q=block_q, block_k=block_k, kv_len=kv_len,
+        block_q=block_q, block_k=block_k, kv_len=kv_len,
     )
+    _note_tabled_visited_share(
+        "flash_bwd", q_len // block_q, k_len // block_k, **mask
+    )
+    common = dict(mask, scale=scale)
     q_spec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0))
     k_spec = pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, qi, ki: (b_, h_, ki, 0))
     row_spec = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, qi, ki: (b_, h_, qi, 0))

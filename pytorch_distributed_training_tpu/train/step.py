@@ -31,6 +31,10 @@ from .state import TrainState
 # objective's.  Declared in obs/schema.py::METRICS; the trainer publishes
 # them at its log points.
 STEP_COUNTERS = ("moe_held_assignments", "moe_load_max", "masked_tokens")
+# Parts of the loss a multi-part objective returns beside it (means over the
+# step's microbatches; obs/schema.py says which carries its weight):
+# published where the counters are.
+STEP_LOSS_PARTS = ("mtp_loss", "moe_balance_loss")
 
 
 def prepare_image_input(
@@ -118,9 +122,10 @@ def _forward(
 
 def lm_objective(state: TrainState):
     """How the model behind ``state.apply_fn`` trains as an LM:
-    ``("next_token", None)``, or ``("block_diffusion", cfg)`` for a module
-    that says so (``lm_objective`` on the module ``apply_fn`` is bound to,
-    e.g. ``models/sdar.SdarMoe``).  Read from the model, so no caller
+    ``("next_token", None)``, or ``("block_diffusion", cfg)`` /
+    ``("next_token_mtp", cfg)`` for a module that says so (``lm_objective``
+    on the module ``apply_fn`` is bound to: ``models/sdar.SdarMoe``,
+    ``models/instella_moe.InstellaMoe``).  Read from the model, so no caller
     passes it."""
     model = getattr(state.apply_fn, "__self__", None)
     kind = getattr(model, "lm_objective", "next_token")
@@ -226,6 +231,24 @@ def make_train_step(
             return loss + aux_loss_weight * aux_l, {
                 "batch_stats": new_stats, **stats,
                 "masked_tokens": jnp.sum(masked).astype(jnp.float32),
+            }
+        if objective == "next_token_mtp":
+            # DeepSeek-V3's objective (models/instella_moe.py): next-token
+            # CE, λ × the MTP module's CE on the token two ahead, and α × the
+            # experts' sequence-wise balance term summed over the layers
+            # (sown without its coefficient).
+            tokens = batch["tokens"]
+            (logits, mtp_logits), new_stats, aux_l, stats = _forward(
+                state, params, tokens, train=True, rng=rng, policy=policy,
+                mtp=True,
+            )
+            loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+            with scope("train/mtp"):
+                mtp_loss = cross_entropy_loss(mtp_logits[:, :-2], tokens[:, 2:])
+            balance = model_cfg.seq_aux_alpha * aux_l
+            return loss + model_cfg.mtp_loss_weight * mtp_loss + balance, {
+                "batch_stats": new_stats, **stats,
+                "mtp_loss": mtp_loss, "moe_balance_loss": balance,
             }
         if kind == "lm":
             tokens = batch["tokens"]

@@ -37,7 +37,7 @@ from ..obs.trace import phase_span, step_annotation
 from ..parallel.sharding import shard_batch
 from ..utils.compile_cache import compile_events, compile_phase, compile_totals
 from .state import TrainState
-from .step import STEP_COUNTERS
+from .step import STEP_COUNTERS, STEP_LOSS_PARTS
 
 
 @dataclasses.dataclass
@@ -367,9 +367,10 @@ class Trainer:
                         if step_s is not None:
                             step_fields["steps_per_sec"] = 1.0 / step_s
                         # A step's counters (the dropless MoE layer's,
-                        # the block-diffusion objective's): device scalars
-                        # beside the loss, read where the host syncs anyway.
-                        for name in STEP_COUNTERS:
+                        # the block-diffusion objective's) and the parts of
+                        # a multi-part loss: device scalars beside the
+                        # loss, read where the host syncs anyway.
+                        for name in STEP_COUNTERS + STEP_LOSS_PARTS:
                             if name in metrics:
                                 step_fields[name] = float(metrics[name])
                                 if self.emitter is not None:

@@ -223,7 +223,7 @@ def test_topk_routing_drops_nothing_under_a_biased_router():
     counters = {k: float(v[0]) for k, v in sown["moe_counters"].items()}
     assert counters == {"moe_held_assignments": 256.0, "moe_load_max": 128.0}
     tokens = x.reshape(-1, 64)
-    w, idx = moe.topk_route(tokens @ params["router"], 2)
+    w, idx, _ = moe.topk_route(tokens @ params["router"], 2)
     assert set(np.unique(idx)) == {3, 6}
     want = sum(
         w[:, j:j + 1] * ((jax.nn.silu(tokens @ params["w_gate"][e]) * (tokens @ params["w_up"][e]))
@@ -246,7 +246,7 @@ def test_a_share_under_a_biased_router_needs_no_room(rows_chunk, monkeypatch):
     assert float(sown["moe_counters"]["moe_held_assignments"][0]) == 64.0
     assert float(sown["moe_counters"]["moe_load_max"][0]) == 64.0
     tokens = x[0]
-    w, idx = moe.topk_route(tokens @ params["router"], 2)
+    w, idx, _ = moe.topk_route(tokens @ params["router"], 2)
     want = w[:, :1] * ((jax.nn.silu(tokens @ params["w_gate"][1]) * (tokens @ params["w_up"][1]))
                        @ params["w_down"][1])
     assert set(np.unique(idx[:, 0])) == {3}

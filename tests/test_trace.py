@@ -827,7 +827,8 @@ def _vocabulary(name):
     ("PHASES", {
         "train/step", "train/input_wait", "train/host_sync", "train/loss",
         "train/optimizer", "train/noise", "attn/block_diffusion",
-        "moe/route", "moe/experts", "grad_accum/microbatch",
+        "attn/mla", "moe/route", "moe/experts", "moe/shared", "train/mtp",
+        "grad_accum/microbatch",
         "grad_sync/rs_ici", "grad_sync/ar_dcn", "grad_sync/ag_ici",
         "grad_sync/stripe",
         "pipeline/tick", "serve/prefill", "serve/decode", "serve/verify",
