@@ -138,13 +138,14 @@ METRICS: dict[str, dict] = {
                 "columns a q block visits / (q rows x k_len).  flash_fwd, "
                 "flash_bwd (the grouped native-layout pair): 1.0 for a "
                 "whole-row tile, 0.625 for causal prefixes at 1024 x 1024 in "
-                "256-row blocks; (the transposed multi-tile pair, causal): "
-                "its live tiles whole, 0.5625 at 8192 x 8192 in 1024-tiles.  "
-                "flash_bd_fwd, flash_bd_bwd (the tabled pair "
-                "under the block-diffusion mask): the live tiles, the "
-                "diagonal ones at their sub-tile ranges; 0.2734 at 8192 "
-                "positions, block 4 (0.375 with every live tile whole, "
-                "0.2502 of the pairs live)",
+                "256-row blocks; (the tabled multi-tile pair under the "
+                "causal mask): the live tiles, the diagonal ones at their "
+                "sub-tile ranges; 0.5078 / 0.5156 at 8192 x 8192 in "
+                "1024-tiles (0.5625 with every live tile whole, 0.5001 of "
+                "the pairs live).  flash_bd_fwd, flash_bd_bwd (the same pair "
+                "under the block-diffusion mask): 0.2734 at 8192 positions, "
+                "block 4 (0.375 with every live tile whole, 0.2502 of the "
+                "pairs live)",
     },
     # ---- SLO / alerting plane (obs/slo.py) ------------------------------
     "slo_alert_transitions": {

@@ -10,9 +10,10 @@ No counterpart exists in the reference (no attention at all — SURVEY.md §5
 (BASELINE.json configs[2]/[3]) and the building block the ring-attention
 sequence-parallel path reuses per shard.
 
-Backward pass: ``jax.custom_vjp`` with saved logsumexp, computed by two
-Pallas kernels (dq over kv blocks; dk/dv over q blocks) that recompute p/ds
-per tile — the (L×L) score matrix never materializes in the backward either.
+Backward pass: ``jax.custom_vjp`` with saved logsumexp; the kernels
+recompute p/ds per tile (one fused kernel for dq, dk and dv wherever a
+head's dk/dv fit VMEM, dq over kv blocks and dk/dv over q blocks beyond) —
+the (L×L) score matrix never materializes in the backward either.
 Perf claims rest on FULL-MODEL A/Bs (a round-4 sweep on another machine:
 flash wins from L=1024 up while the low-memory XLA path wins below; not
 measured on the current machine).  Default blocks are 1024x1024, that
@@ -45,10 +46,10 @@ _NEG_INF = -1e30
 KERNEL_NAMES = (
     "flash_fwd",          # every forward variant
     "flash_bwd",          # a fused backward (dq, dk, dv from one call)
-    "flash_bwd_dq",       # the split backward's two halves
-    "flash_bwd_dkv",
-    "flash_bd_fwd",       # under the block-diffusion mask: names of their own,
-    "flash_bd_bwd",       # so a causal kernel's metric never reads them
+    "flash_bwd_dq",       # the split backward's two halves (key rows past
+    "flash_bwd_dkv",      # the fused backward's fit)
+    "flash_bd_fwd",       # the tabled pair under the block-diffusion mask: names
+    "flash_bd_bwd",       # of their own, so a causal kernel's metric never reads them
     "decode_attn",        # contiguous cache, one token a row
     "decode_multi_attn",  # contiguous cache, a C-token chunk (verify)
     "paged_decode_attn",  # paged pool, one token a row
@@ -105,29 +106,43 @@ def _bd_live_block(qi, ki, block_q, block_k, seq_len, block_len):
     return same | earlier | clean
 
 
-def _when_live(live, compute, qi, ki, block_q, block_k, bd,
-               compute_ranges=None, subs=None):
-    """Run a tile's ``compute`` where the tile is live.  Under the
-    block-diffusion mask a live tile whose every pair is live (half of them
-    at L = 4096) takes ``compute(masked=False)``: no mask is built and none
-    applied.  A tile of one of the two diagonal classes (``_bd_sub_class``)
-    takes ``compute_ranges(sub, ranges)``, straight-line code over the
-    ``_bd_sub_ranges`` of its q sub-blocks and nothing past the mask's
-    frontier.  Whatever is left is computed whole under the mask."""
-    if bd is not None:
-        full = _bd_full_block(qi, ki, block_q, block_k, *bd)
-        pl.when(live & full)(functools.partial(compute, masked=False))
-        rest = live & jnp.logical_not(full)
-        for hit, sub, ranges in _bd_sub_classes(
-            qi, ki, block_q, block_k, bd, subs
-        ):
-            pl.when(hit)(functools.partial(compute_ranges, sub, ranges))
-            rest &= jnp.logical_not(hit)
-        pl.when(rest)(compute)
-    elif live is not None:
-        pl.when(live)(compute)
-    else:
+def _when_live(compute, qi, ki, mask, bd=None, compute_ranges=None, subs=None):
+    """Run a tile's ``compute`` where the tile is live under ``mask`` (the
+    keywords of ``_live_block`` but ``bd``).  A live tile whose every pair
+    is live — half of them under the block-diffusion mask at L = 4096, 28 of
+    36 under the causal one at 8192 — takes ``compute(masked=False)``: no
+    mask is built and none applied.  A tile of a diagonal class
+    (``_sub_classes``) takes ``compute_ranges(sub, ranges)``, straight-line
+    code over the ``_bd_sub_ranges`` of its q sub-blocks and nothing past
+    the mask's frontier.  Whatever is left is computed whole under the
+    mask."""
+    live = _live_block(qi, ki, bd=bd, **mask)
+    if live is None:        # no mask and no padded key: ``compute`` builds none
         compute()
+        return
+    if bd is not None:
+        full = _bd_full_block(qi, ki, mask["block_q"], mask["block_k"], *bd)
+    else:
+        full = _causal_full_block(qi, ki, **mask)
+    pl.when(live & full)(functools.partial(compute, masked=False))
+    rest = live & jnp.logical_not(full)
+    for hit, sub, ranges in _sub_classes(qi, ki, mask, bd, subs):
+        pl.when(hit)(functools.partial(compute_ranges, sub, ranges))
+        rest &= jnp.logical_not(hit)
+    pl.when(rest)(compute)
+
+
+def _causal_full_block(qi, ki, *, causal, causal_offset, kv_len, block_q,
+                       block_k):
+    """Whether EVERY pair of tile (qi, ki) is live under the causal (or the
+    empty) mask: the tile's last key is one its first query row sees, and
+    none of its keys is padding."""
+    full = True
+    if causal:
+        full = (ki + 1) * block_k - 1 <= qi * block_q + causal_offset
+    if kv_len is not None:
+        full &= (ki + 1) * block_k <= kv_len
+    return full
 
 
 def _bd_full_block(qi, ki, block_q, block_k, seq_len, block_len):
@@ -144,12 +159,13 @@ def _bd_full_block(qi, ki, block_q, block_k, seq_len, block_len):
     return k_clean & (noisy_q | clean_q)
 
 
-# Rows of a q sub-block (and columns of its masked range) in the two classes
-# of diagonal tile below, as (frontier, same-block), for the kernel each
-# launcher runs.  Chosen on the chip (PERF.md §6, PR 31): the forward is bound
-# by the VPU's passes over masked scores and wants its frontier narrow; the
-# backward by the MXU, whose products want 256 rows.
-_BD_SUBS = {"flash_bd_fwd": (128, 256), "flash_bd_bwd": (256, 128)}
+# Rows of a q sub-block (and columns of its masked range) in the classes of
+# diagonal tile below, as (frontier, same-block), for the multi-tile forward
+# and the fused backward.  Chosen on the chip (PERF.md §6, PR 31): the forward
+# is bound by the VPU's passes over masked scores and wants its frontier
+# narrow; the backward by the MXU, whose products want 256 rows.  The causal
+# mask's diagonal tile is a frontier tile and reads the same (PR 33).
+_SUBS = {"fwd": (128, 256), "bwd": (256, 128)}
 
 
 def _bd_sub_class(frontier, qi, ki, block_q, block_k, seq_len, block_len, sub):
@@ -195,14 +211,56 @@ def _bd_sub_ranges(frontier, block, sub):
     return ranges
 
 
-def _bd_sub_classes(qi, ki, block_q, block_k, bd, subs):
-    """``(hit, sub, ranges)`` of each diagonal class the static numbers
-    allow at ``subs = (frontier sub, same-block sub)``: the tile predicate,
-    the sub-block rows and the classes' ``_bd_sub_ranges``."""
-    for frontier, sub in zip((True, False), subs):
-        hit = _bd_sub_class(frontier, qi, ki, block_q, block_k, *bd, sub)
+def _causal_sub_class(qi, ki, *, causal, causal_offset, kv_len, block_q,
+                      block_k, sub):
+    """Whether tile (qi, ki) is a diagonal tile of the causal mask that
+    ``_bd_sub_ranges``' frontier form describes (the frontier class at block
+    length 1: q sub-block r sees the columns before its own whole and its
+    own ``sub`` under the triangle), or None where the static numbers rule
+    it out: no causal mask, queries that do not end where the keys do, tiles
+    that are not square, shorter than two sub-blocks or no multiple of one.
+    Such a tile holds no padded key."""
+    if (
+        not causal or causal_offset or block_q != block_k
+        or block_q % sub or block_q < 2 * sub
+    ):
+        return None
+    hit = qi == ki
+    if kv_len is not None:
+        hit &= (ki + 1) * block_k <= kv_len
+    return hit
+
+
+def _sub_classes(qi, ki, mask, bd, subs):
+    """``(hit, sub, ranges)`` of each class of diagonal tile that the call's
+    mask (``mask``: the keywords of ``_live_block`` but ``bd``) and the
+    static numbers allow — the tile predicate, the sub-block rows and the
+    class's ``_bd_sub_ranges`` — at the kernel's ``subs = (frontier sub,
+    same-block sub)`` (``_SUBS``; None for a kernel that knows no class)."""
+    if subs is None:
+        return
+    block_q, block_k = mask["block_q"], mask["block_k"]
+    if bd is None:      # the causal diagonal: a frontier tile
+        hits = [(True, subs[0], _causal_sub_class(qi, ki, sub=subs[0], **mask))]
+    else:
+        hits = [
+            (frontier, sub,
+             _bd_sub_class(frontier, qi, ki, block_q, block_k, *bd, sub))
+            for frontier, sub in zip((True, False), subs)
+        ]
+    for frontier, sub, hit in hits:
         if hit is not None:
             yield hit, sub, _bd_sub_ranges(frontier, block_q, sub)
+
+
+def _range_mask(q0, k0, rows, cols, *, causal_offset, bd):
+    """(rows, cols) boolean tile of the call's mask — block-diffusion, else
+    causal — whose first row is position ``q0`` and first column ``k0``."""
+    if bd is not None:
+        return _bd_mask(q0, k0, rows, cols, *bd)
+    q = q0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    k = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+    return q + causal_offset >= k
 
 
 def _bd_mask(q0, k0, block_q, block_k, seq_len, block_len):
@@ -266,11 +324,11 @@ def _fwd_kernel(
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        if bd is None or scale != 1.0:   # the masked kinds hand in a scaled q
+        if scale != 1.0:   # the block-diffusion mask hands in a scaled q
             s = s * scale  # (block_q, block_k)
 
         mask = None
-        if causal:
+        if causal and masked:
             # Bottom-right-aligned causal mask (matches _xla_attention and the
             # VJP backward): query row i attends keys j <= i + (k_len - q_len).
             q_ids = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -334,8 +392,9 @@ def _fwd_kernel(
         masks, m_new = [], []
         for r, parts in enumerate(ranges):
             masks.append([
-                _bd_mask(qi * block_q + r * sub, ki * block_k + lo, sub,
-                         hi - lo, *bd) if masked else None
+                _range_mask(qi * block_q + r * sub, ki * block_k + lo, sub,
+                            hi - lo, causal_offset=causal_offset, bd=bd)
+                if masked else None
                 for lo, hi, masked in parts
             ])
             for i, mask in enumerate(masks[r]):
@@ -378,12 +437,12 @@ def _fwd_kernel(
             m_scr[rows, :] = jnp.broadcast_to(m_new[r], (sub, m_scr.shape[1]))
             l_scr[rows, :] = jnp.broadcast_to(l_new[r], (sub, l_scr.shape[1]))
 
-    block_live = _live_block(
-        qi, ki, causal=causal, causal_offset=causal_offset, kv_len=kv_len,
-        block_q=block_q, block_k=block_k, bd=bd,
+    _when_live(
+        _compute, qi, ki,
+        dict(causal=causal, causal_offset=causal_offset, kv_len=kv_len,
+             block_q=block_q, block_k=block_k),
+        bd, _compute_ranges, _SUBS["fwd"],
     )
-    _when_live(block_live, _compute, qi, ki, block_q, block_k, bd,
-               _compute_ranges, _BD_SUBS["flash_bd_fwd"])
 
     @pl.when(ki == num_k - 1)
     def _finalize():
@@ -719,6 +778,11 @@ _flash_nlhd.defvjp(_flash_nlhd_vjp_fwd, _flash_nlhd_vjp_bwd)
 
 _VMEM_BUDGET = 11 * 2**20  # conservative: the 16 MB scoped limit minus slack
 
+# Scoped VMEM the fused backward may take: two float32 (Lk, d) accumulators,
+# the (Lk, d) dk / dv output blocks and four (block_q, block_k) float32
+# tiles are past the 16 MB default at 8192 keys.
+_FUSED_BWD_VMEM = 64 * 2**20
+
 
 def _nlhd_single_fits(q_len, k_len, hd_all, itemsize):
     """Whether the whole-heads single-tile pair fits the VMEM budget.
@@ -733,6 +797,22 @@ def _nlhd_single_fits(q_len, k_len, hd_all, itemsize):
     bwd = (3 * q_len + 4 * k_len) * hd_all * itemsize \
         + 4 * q_len * k_len * 4
     return fwd <= _VMEM_BUDGET and bwd <= _VMEM_BUDGET
+
+
+def _fused_bwd_fits(k_len, head_dim, itemsize, block_q, block_k):
+    """Whether ``_bwd_fused_kernel`` fits ``_FUSED_BWD_VMEM`` at this key row:
+    it keeps a K/V head's dk and dv whole — two float32 (k_len, d)
+    accumulators and the double-buffered (k_len, d) output blocks — beside
+    its four float32 score tiles and the double-buffered q / do / dq and
+    k / v blocks.  8192 x 128 in bfloat16 at 1024-tiles takes 35 MB."""
+    head = 2 * k_len * head_dim * 4 + 2 * 2 * k_len * head_dim * itemsize
+    tiles = 4 * block_q * block_k * 4
+    blocks = (
+        2 * (3 * block_q + 2 * block_k) * head_dim * itemsize
+        + 2 * 2 * block_q * _LANES * 4      # lse, delta: a column pads to the lanes
+        + block_q * head_dim * 4            # dq's accumulator
+    )
+    return head + tiles + blocks <= _FUSED_BWD_VMEM
 
 
 # The tallest q block of the causal form.  A q block computes every pair
@@ -822,24 +902,24 @@ def _note_visited_share(kernel, spans, q_len, k_len, block_q):
     ) / (q_len * k_len)
 
 
-def _note_tabled_visited_share(kernel, nq, nk, **mask):
-    """The multi-tile launchers' share, by the kernels' own tile predicates
-    (``_live_block``, ``_bd_sub_class``) on the whole grid at trace time:
-    the tabled pair's, and the causal pair's (``bd`` None: its live tiles
-    whole, the diagonal ones with their dead half)."""
+def _note_tabled_visited_share(kernel, nq, nk, subs, **mask):
+    """The multi-tile launchers' share (the tabled pair's, the plain
+    forward's and the split backward's), by the kernels' own tile predicates
+    (``_live_block``, ``_sub_classes``) on the whole grid at trace time: a
+    live tile whole, a diagonal one of a class at its sub-ranges (``subs``
+    as the kernel takes them; the split backward knows no class)."""
     import numpy as np
 
-    block_q, block_k, bd = mask["block_q"], mask["block_k"], mask.get("bd")
+    bd = mask.pop("bd", None)
+    block_q, block_k = mask["block_q"], mask["block_k"]
     qi = np.arange(nq, dtype=np.int32)[:, None]
     ki = np.arange(nk, dtype=np.int32)[None, :]
     with jax.ensure_compile_time_eval():
-        live = _live_block(qi, ki, **mask)
+        live = _live_block(qi, ki, bd=bd, **mask)
         visited = np.broadcast_to(
             True if live is None else np.asarray(live), (nq, nk)
         ) * float(block_q * block_k)
-        for hit, sub, ranges in () if bd is None else _bd_sub_classes(
-            qi, ki, block_q, block_k, bd, _BD_SUBS[kernel]
-        ):
+        for hit, sub, ranges in _sub_classes(qi, ki, mask, bd, subs):
             visited[np.broadcast_to(np.asarray(hit), (nq, nk))] = sub * sum(
                 hi - lo for parts in ranges for lo, hi, _ in parts
             )
@@ -1136,17 +1216,29 @@ _flash_nlhd_grouped.defvjp(_flash_nlhd_grouped_vjp_fwd,
                            _flash_nlhd_grouped_vjp_bwd)
 
 
+def _takes_tabled(k_len, head_dim, itemsize, block_q, block_k, bd):
+    """Whether a transposed call takes the tabled pair: the block-diffusion
+    mask always (the only kernels that read K/V at their own head count), any
+    other mask where the key row is several tiles and the fused backward
+    fits.  ``flash_plan`` answers with it, ``_flash_fwd`` / ``_flash_bwd``
+    follow it."""
+    return bd is not None or (
+        k_len > block_k
+        and _fused_bwd_fits(k_len, head_dim, itemsize, block_q, block_k)
+    )
+
+
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                causal_offset=None, kv_len=None, bd=None):
-    if bd is not None:
-        return _flash_tabled_fwd(
-            q, k, v, causal, scale, block_q, block_k, interpret,
-            causal_offset, kv_len, bd,
-        )
     b, h, q_len, d = q.shape
     k_len = k.shape[2]
     block_q = min(block_q, q_len)
     block_k = min(block_k, k_len)
+    if _takes_tabled(k_len, d, q.dtype.itemsize, block_q, block_k, bd):
+        return _flash_tabled_fwd(
+            q, k, v, causal, scale, block_q, block_k, interpret,
+            causal_offset, kv_len, bd,
+        )
     if q_len % block_q or k_len % block_k:
         raise ValueError(f"seq lens ({q_len},{k_len}) not divisible by blocks ({block_q},{block_k})")
     if k_len <= block_k:
@@ -1167,7 +1259,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
         block_k=block_k,
         kv_len=kv_len,
     )
-    _note_tabled_visited_share("flash_fwd", *grid[2:], **mask)
+    _note_tabled_visited_share("flash_fwd", *grid[2:], _SUBS["fwd"], **mask)
     kernel = functools.partial(_fwd_kernel, scale=scale, **mask)
     out, lse = pl.pallas_call(
         kernel,
@@ -1216,11 +1308,11 @@ def _bwd_block(q, k, v, do, lse, delta, qi, ki, *, causal, causal_offset,
         q, k, dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
-    rescale = bd is None or scale != 1.0    # the masked kinds hand in a scaled q
+    rescale = scale != 1.0    # the block-diffusion mask hands in a scaled q
     if rescale:
         s = s * scale
     p = jnp.exp(s - lse)
-    if causal:
+    if causal and masked:
         q_ids = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         k_ids = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # Explicit zero (not -inf then exp): a fully-masked row has lse ≈
@@ -1390,10 +1482,11 @@ def _flash_bwd_single(q, k, v, lse, delta, do, causal, scale, interpret,
 
 
 def _live_table(nq, nk, **mask):
-    """Block table ``kv_of[qi, ki]`` for the K/V index maps of the masked
+    """Block table ``kv_of[qi, ki]`` for the K/V index maps of the tabled
     multi-tile kernels: a dead tile's block index repeats a live
     neighbour's, so the pipeline issues no copy for a tile the kernel skips
-    (three quarters of the tiles under the block-diffusion mask).  The
+    (three quarters of the tiles under the block-diffusion mask, 28 of 64
+    under the causal one at 8192).  The
     tile predicate is the kernels' own (``_live_block``), evaluated here on
     the whole grid at trace time."""
     import numpy as np
@@ -1420,10 +1513,11 @@ def _live_table(nq, nk, **mask):
 @functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_tabled_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                       causal_offset, kv_len, bd):
-    """The multi-tile forward under the block-diffusion mask, whose dead
-    tiles are many.  q: (B, H, Lq, D); k, v: (B, Hkv, Lk, D) with H a
-    multiple of Hkv: query head h reads K/V head h // (H / Hkv) through the
-    block index, never a repeated copy in HBM."""
+    """The multi-tile forward under any mask (``_takes_tabled``), named
+    ``flash_bd_fwd`` under the block-diffusion mask and ``flash_fwd``
+    otherwise.  q: (B, H, Lq, D); k, v: (B, Hkv, Lk, D) with H a multiple of
+    Hkv: query head h reads K/V head h // (H / Hkv) through the block index,
+    never a repeated copy in HBM."""
     b, h, q_len, d = q.shape
     k_len = k.shape[2]
     group = h // k.shape[1]
@@ -1437,7 +1531,10 @@ def _flash_tabled_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
     )
     nq, nk = q_len // block_q, k_len // block_k
     kv_of = _live_table(nq, nk, **mask)
-    _note_tabled_visited_share("flash_bd_fwd", nq, nk, **mask)
+    _note_tabled_visited_share(
+        "flash_bd_fwd" if bd is not None else "flash_fwd", nq, nk,
+        _SUBS["fwd"], **mask
+    )
     q_index = lambda b_, h_, qi, ki, tbl: (b_, h_, qi, 0)
     kv_index = lambda b_, h_, qi, ki, tbl: (b_, h_ // group, tbl[qi, ki], 0)
     kernel = functools.partial(_fwd_kernel, scale=scale, **mask)
@@ -1465,7 +1562,7 @@ def _flash_tabled_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((b, h, q_len, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, q_len, 8), jnp.float32),
         ],
-        name="flash_bd_fwd",
+        name="flash_bd_fwd" if bd is not None else "flash_fwd",
         interpret=interpret,
     )(jnp.asarray(kv_of), q, k, v)
     return out, lse[..., 0]
@@ -1538,8 +1635,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 p, ds = _bwd_block(
                     q, k, v_ref[0, 0, lo:hi, :], do, lse, delta,
                     qi * (block_q // sub) + r, ki * (block_k // sub) + lo // sub,
-                    causal=False, causal_offset=0, scale=scale, block_q=sub,
-                    block_k=sub, bd=bd, masked=masked,
+                    causal=causal, causal_offset=causal_offset, scale=scale,
+                    block_q=sub, block_k=sub, bd=bd, masked=masked,
                 )
                 tiles[r].append(
                     (lo, hi, q, do, k, p.astype(do.dtype), ds.astype(k.dtype))
@@ -1562,12 +1659,12 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 preferred_element_type=jnp.float32,
             )
 
-    live = _live_block(
-        qi, ki, causal=causal, causal_offset=causal_offset, kv_len=kv_len,
-        block_q=block_q, block_k=block_k, bd=bd,
+    _when_live(
+        _compute, qi, ki,
+        dict(causal=causal, causal_offset=causal_offset, kv_len=kv_len,
+             block_q=block_q, block_k=block_k),
+        bd, _compute_ranges, _SUBS["bwd"],
     )
-    _when_live(live, _compute, qi, ki, block_q, block_k, bd, _compute_ranges,
-               _BD_SUBS["flash_bd_bwd"])
 
     @pl.when(ki == num_k - 1)
     def _finalize_rows():
@@ -1579,17 +1676,12 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0, 0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-# Scoped VMEM the fused backward may take: two float32 (Lk, d) accumulators,
-# the (Lk, d) dk / dv output blocks and four (block_q, block_k) float32
-# tiles are past the 16 MB default at 8192 keys.
-_FUSED_BWD_VMEM = 64 * 2**20
-
-
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
 def _flash_tabled_bwd(q, k, v, lse, delta, do, causal, scale, block_q,
                       block_k, interpret, causal_offset, kv_len, bd):
     """The backward behind ``_flash_tabled_fwd``: one fused kernel
-    (``_bwd_fused_kernel``) over grid (b, K/V head, its query heads,
+    (``_bwd_fused_kernel``; ``flash_bd_bwd`` under the block-diffusion mask,
+    ``flash_bwd`` otherwise) over grid (b, K/V head, its query heads,
     q blocks, kv blocks).  K/V are read through the block index of their own
     head, and dk / dv come out at the K/V head count."""
     b, h, q_len, d = q.shape
@@ -1602,7 +1694,10 @@ def _flash_tabled_bwd(q, k, v, lse, delta, do, causal, scale, block_q,
     )
     nq, nk = q_len // block_q, k_len // block_k
     kv_of = _live_table(nq, nk, **mask)
-    _note_tabled_visited_share("flash_bd_bwd", nq, nk, **mask)
+    _note_tabled_visited_share(
+        "flash_bd_bwd" if bd is not None else "flash_bwd", nq, nk,
+        _SUBS["bwd"], **mask
+    )
 
     q_index = lambda b_, n_, g_, qi, ki, tbl: (b_, n_ * group + g_, qi, 0)
     kv_index = lambda b_, n_, g_, qi, ki, tbl: (b_, n_, tbl[qi, ki], 0)
@@ -1631,7 +1726,7 @@ def _flash_tabled_bwd(q, k, v, lse, delta, do, causal, scale, block_q,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_FUSED_BWD_VMEM),
-        name="flash_bd_bwd",
+        name="flash_bd_bwd" if bd is not None else "flash_bwd",
         interpret=interpret,
     )(jnp.asarray(kv_of), q, k, v, do, lse, delta)
 
@@ -1640,9 +1735,12 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret
                causal_offset=None, kv_len=None, bd=None):
     """Blockwise backward: never materializes the (L, L) score matrix.
 
-    Two kernels (the standard flash-attention backward split): dq accumulates
-    over kv blocks with q outermost; dk/dv accumulate over q blocks with kv
-    outermost.  p/ds tiles are recomputed from q/k/lse per block.
+    The tabled pair's fused kernel where ``_takes_tabled`` says so, the
+    one-tile fused kernel where the call is one tile, and otherwise two
+    kernels (the standard flash-attention backward split, for key rows past
+    the fused kernel's fit): dq accumulates over kv blocks with q outermost;
+    dk/dv accumulate over q blocks with kv outermost.  p/ds tiles are
+    recomputed from q/k/lse per block.
     """
     b, h, q_len, d = q.shape
     k_len = k.shape[2]
@@ -1655,7 +1753,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret
     )
     lse = lse[..., None]
 
-    if bd is not None:
+    if _takes_tabled(k_len, d, q.dtype.itemsize, block_q, block_k, bd):
         return _flash_tabled_bwd(
             q, k, v, lse, delta, do, causal, scale, block_q, block_k,
             interpret, causal_offset, kv_len, bd,
@@ -1675,7 +1773,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k, interpret
         block_q=block_q, block_k=block_k, kv_len=kv_len,
     )
     _note_tabled_visited_share(
-        "flash_bwd", q_len // block_q, k_len // block_k, **mask
+        "flash_bwd", q_len // block_q, k_len // block_k, None, **mask
     )
     common = dict(mask, scale=scale)
     q_spec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0))
@@ -1794,11 +1892,17 @@ def flash_plan(
     - ``grouped``: the grouped-heads native pair, which tiles heads and
       query length: padded k_len <= 1024 (the GPT-2 L = 1024 band), long q
       over a short key row, or widths ``single`` cannot fit.
-    - ``transposed``: (B, H, L, D) operands; ``_flash_fwd`` / ``_flash_bwd``
-      take the single-tile kernels where the key row is one block and the
-      multi-tile ones beyond.
-    - ``tabled``: the transposed multi-tile pair under the block-diffusion
-      mask, the only kernels that read K/V at their own head count.
+    - ``tabled``: (B, H, L, D) operands and a key row of several tiles,
+      under any mask (block-diffusion, causal with any offset, padded keys,
+      none): the tile predicate skips dead tiles, a block table keeps them
+      from being copied, a tile that is wholly live builds no mask, and ONE
+      fused backward keeps a head's dk / dv in VMEM (``_fused_bwd_fits``).
+      The block-diffusion mask always plans it: these are the only kernels
+      that read K/V at their own head count.
+    - ``transposed``: (B, H, L, D) operands otherwise; ``_flash_fwd`` /
+      ``_flash_bwd`` take the single-tile kernels where the key row is one
+      block, and the plain multi-tile forward with the split backward where
+      the key row is past the fused backward's fit.
     """
     q_len += (-q_len) % _LANES
     k_len += (-k_len) % _LANES
@@ -1827,7 +1931,11 @@ def flash_plan(
             )
             if group is not None:
                 return plan("grouped", group)
-    return plan("transposed", None)
+    tabled = _takes_tabled(
+        k_len, head_dim, itemsize, min(block_q, q_len), min(block_k, k_len),
+        None,
+    )
+    return plan("tabled" if tabled else "transposed", None)
 
 
 def flash_attention(
@@ -1844,15 +1952,17 @@ def flash_attention(
 ) -> jax.Array:
     """Flash attention. q/k/v: (B, L, H, D) → (B, L, H, D).
 
+    Which kernels run is ``flash_plan``'s answer, from the shapes alone.
     ``block_diffusion=(L, B)`` applies the block-diffusion training mask
     over 2L positions (noised copy, then clean copy, blocks of B; see
-    ``_bd_mask``) and takes the transposed multi-tile kernels
-    ``flash_bd_fwd`` / ``flash_bd_bwd``, whose tile predicate skips the dead
-    tiles.  Under it ``k``/``v`` may carry fewer heads than ``q``
-    (grouped-query attention: H a multiple of their head count), read in
-    place through the block index.  Grouped K/V under the causal or the
-    empty mask is refused: no model runs it, and the kernels named
-    ``flash_fwd`` / ``flash_bwd*`` know one head count.
+    ``_bd_mask``) and takes the tabled multi-tile pair under the names
+    ``flash_bd_fwd`` / ``flash_bd_bwd``; a causal or unmasked call whose key
+    row is several tiles takes the same pair as ``flash_fwd`` /
+    ``flash_bwd``.  Under ``block_diffusion`` ``k``/``v`` may carry fewer
+    heads than ``q`` (grouped-query attention: H a multiple of their head
+    count), read in place through the block index.  Grouped K/V under the
+    causal or the empty mask is refused: no model runs it, and below several
+    tiles those calls take kernels that know one head count.
 
     Sequence lengths need not be lane-aligned: non-multiples of 128 (e.g.
     ViT-B/16's L = 197) are zero-padded to the next multiple, padded keys
@@ -1906,11 +2016,14 @@ def flash_attention(
             )
         out = out.reshape(b, plan.q_len, h, d)
     else:
-        if plan.kind == "tabled":
+        if block_diffusion is not None:
             # The scale goes onto q once (a (P, d) pass XLA fuses into q's
             # producer), not onto every (block_q, block_k) score tile.  q is
-            # rounded to its dtype a second time by this, which the causal
-            # kernels (scale on the float32 scores) are not.
+            # rounded to its dtype a second time by this.  The other masks
+            # keep the scale on the float32 scores: at Instella's scale (no
+            # power of two) the second rounding showed in the reference
+            # check's q / k norm gradients for 0.05 ms a forward call
+            # (PERF.md §6, PR 33).
             q, scale = q * jnp.asarray(scale, q.dtype), 1.0
         # (B, L, H, D) → (B, H, L, D) for blocking.
         qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))

@@ -3,6 +3,8 @@ q block takes the key row's prefix up to its frontier and masks only the
 columns the diagonal crosses.  Interpret mode, two heads of 64, lengths that
 still take the grouped path."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -264,6 +266,31 @@ def test_tabled_pair_compiles_for_a_v5e_at_sdars_shape(one_v5e):
     assert "bf16[1,32,8192,128]" in results["flash_bd_fwd"]
     assert results["flash_bd_bwd"].count("bf16[1,4,8192,128]") == 2     # dk, dv at the K/V heads
     assert f"{pa._FUSED_BWD_VMEM}" in text
+    # the causal kernels' metrics must not read these (their patterns:
+    # ``flash_fwd[_.]``, ``flash_bwd``)
+    assert not any(re.search(r"flash_(fwd|bwd)", name) for name, _ in calls)
+
+
+def test_tabled_pair_compiles_for_a_v5e_at_instellas_shape(one_v5e):
+    """The same pair under the causal mask at the Instella cell's call —
+    8192 positions, 16 heads of 128, a softmax scale that is no power of two
+    — under the causal kernels' names, the fused backward's first result in
+    the shape ``readers/kernel_roofline.py`` parses, and no split backward."""
+    def pair(q, k, v):
+        out, vjp = jax.vjp(
+            lambda q, k, v: pa.flash_attention(
+                q, k, v, causal=True, scale=0.1654, interpret=False),
+            q, k, v,
+        )
+        return (out,) + vjp(out)
+
+    shape = jax.ShapeDtypeStruct((1, 8192, 16, 128), jnp.bfloat16, sharding=one_v5e)
+    calls, text = mosaic_calls_compiled_for(pair, shape, shape, shape)
+    names = sorted(re.sub(r"^%(\w+?)[_.]*\d*$", r"\1", name.strip()) for name, _ in calls)
+    assert names == ["flash_bwd", "flash_fwd"], calls
+    for name, result in calls:
+        assert result.lstrip("(").startswith("bf16[1,16,8192,128]"), (name, result)
+    assert f"{pa._FUSED_BWD_VMEM}" in text
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -271,12 +298,16 @@ def test_tabled_pair_compiles_for_a_v5e_at_sdars_shape(one_v5e):
     (1024, 1024, 12, 64, 2, "grouped"), (1024, 1024, 12, 64, 4, "grouped"),
     (197, 197, 12, 64, 2, "single"), (512, 512, 12, 64, 2, "single"),
     (640, 640, 2, 64, 4, "grouped"), (384, 768, 2, 64, 4, "grouped"),
-    (256, 1024, 16, 1024, 4, "transposed"), (2048, 2048, 12, 64, 2, "transposed"),
+    (256, 1024, 16, 1024, 4, "transposed"), (2048, 2048, 12, 64, 2, "tabled"),
+    (8192, 8192, 16, 128, 2, "tabled"),         # Instella's call
+    (32768, 32768, 16, 128, 2, "transposed"),   # past the fused backward's fit
 ])
 def test_flash_plan_is_what_the_dispatch_runs(
         monkeypatch, causal, q_len, k_len, heads, dim, itemsize, kind):
     """The generation each shape takes, as a literal: asserted on the plan
-    and on the entry function ``flash_attention`` really calls."""
+    and on the entry function ``flash_attention`` really calls (``_flash``
+    for both transposed kinds: ``_flash_fwd`` / ``_flash_bwd`` ask
+    ``_takes_tabled``, as the plan does)."""
     taken = []
     monkeypatch.setattr(pa, "_flash_nlhd", lambda q, *a: taken.append("single") or q)
     monkeypatch.setattr(pa, "_flash_nlhd_grouped",
@@ -286,7 +317,7 @@ def test_flash_plan_is_what_the_dispatch_runs(
     q = jax.ShapeDtypeStruct((1, q_len, heads, dim), dtype)
     kv = jax.ShapeDtypeStruct((1, k_len, heads, dim), dtype)
     jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal=causal), q, kv, kv)
-    assert taken == [kind]
+    assert taken == [{"tabled": "transposed"}.get(kind, kind)]
     plan = pa.flash_plan(q_len, k_len, heads, heads, dim, itemsize,
                          causal=causal, block_diffusion=None)
     assert plan.kind == kind

@@ -3,6 +3,8 @@ block-diffusion mask of the flash kernels against its dense definition, the
 dropless top-k experts and their shares, the noising function, and the step
 that finds the objective on the model."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -159,7 +161,7 @@ def test_flash_kernels_in_the_sub_tile_forms_match_the_xla_path(seq_len, block, 
         for ki in range(n):
             if not pa._bd_live_block(qi, ki, tile, tile, seq_len, block) or ki * tile >= 2 * seq_len:
                 continue
-            hit = {name for subs in pa._BD_SUBS.values()        # either kernel's
+            hit = {name for subs in pa._SUBS.values()        # either kernel's
                    for name, frontier, sub in zip(("frontier", "same"), (True, False), subs)
                    if pa._bd_sub_class(frontier, qi, ki, tile, tile, seq_len, block, sub)}
             full = bool(pa._bd_full_block(qi, ki, tile, tile, seq_len, block))
@@ -338,6 +340,9 @@ def test_masked_kernels_lower_under_their_names():
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).as_text(debug_info=True)
     for name in ("flash_bd_fwd", "flash_bd_bwd"):
         assert name in text and name in pa.KERNEL_NAMES
+    # the tabled pair serves the causal mask too, under the causal names
+    # (tests/test_flash_tabled_causal.py): never here
+    assert set(re.findall(r"(flash_\w+)\)*/pallas_call\b", text)) == {"flash_bd_fwd", "flash_bd_bwd"}
     # (that the causal kernels' metric patterns do not match these names:
     # tests/benchmark_tests/test_bench_sdar.py, on the trace's own spelling)
 
