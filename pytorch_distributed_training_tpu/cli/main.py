@@ -28,7 +28,7 @@ from ..utils.compile_cache import compile_events, compile_phase, compile_totals
 
 # Model-config fields whose --model-overrides values are strings; all other
 # keys take int/float/bool only (value typos must fail at parse time).
-_STRING_OVERRIDE_KEYS = frozenset({"moe_dispatch"})
+_STRING_OVERRIDE_KEYS = frozenset({"moe_dispatch", "hybrid_override_pattern"})
 # --model-overrides keys whose value is a range "first:count" (a chip's
 # contiguous share of a layer's experts, models/sdar.SdarConfig).
 _RANGE_OVERRIDE_KEYS = frozenset({"experts_held"})

@@ -10,18 +10,23 @@ mesh reserves an ``expert`` axis and a complete framework fills it.
   ``expert`` axis — GSPMD turns dispatch/combine into all-to-alls) and a
   row scatter/gather for experts that are not mesh-sharded.  Static shapes
   throughout.
-- :class:`TopKMoe` (``sdar_moe``, ``instella_moe``): top-k routing over the
-  published router width (softmax scores, or DeepSeek-V3's sigmoid scores
-  with a selection-only bias) with normalised weights and NO capacity:
-  every assignment to an expert this chip holds is computed.  The layer is told which contiguous
+- :class:`TopKMoe` (``sdar_moe``, ``instella_moe``, ``nemotron_h``): top-k
+  routing over the published router width (softmax scores, or DeepSeek-V3's
+  sigmoid scores with a selection-only bias) with normalised weights and NO
+  capacity: every assignment to an expert this chip holds is computed.  An
+  expert is gated (three matrices, ``act(x·Wgate) ⊙ (x·Wup)`` then ``Wdown``:
+  SDAR's and Instella's, SiLU) or plain (two matrices, ``act(x·Wup)·Wdown``:
+  Nemotron-H's, ``relu²``), as the layer is constructed.  The layer is told which contiguous
   range of experts it holds (``experts_held``), routes over all of them,
   and returns its own experts' part of the result — what expert
   parallelism asks of a chip before the exchange, and nothing standing in
   for the absent chips.  Assignments are sorted by expert and multiplied
-  as grouped matrix products (``lax.ragged_dot``: on a TPU XLA's own
-  Mosaic grouped matmul) in chunks of ``ROWS_CHUNK`` rows under a loop
-  whose trip count follows the assignments there are: no static bound,
-  nothing to overflow.
+  under a loop whose trip count follows the assignments there are (no
+  static bound, nothing to overflow): as grouped matrix products
+  (``lax.ragged_dot``: on a TPU XLA's own Mosaic grouped matmul) in chunks
+  of ``ROWS_CHUNK`` rows, or, where the layer is constructed with an
+  ``expert_window``, as plain products: one a window of that many sorted rows
+  and expert with rows in it.
 """
 
 from __future__ import annotations
@@ -338,93 +343,137 @@ def group_held_assignments(experts: jax.Array, first: int, held: int):
 ROWS_CHUNK = 16384
 
 
-def _expert_chunk(x, w_rows, sizes, w_gate, w_up, w_down):
-    """One chunk of sorted rows through its experts: x (C, d), the rows'
-    router weights (C,), rows per held expert inside the chunk (held,)."""
-    grouped = lambda lhs, w: lax.ragged_dot(lhs, w, sizes)
-    h = nn.silu(grouped(x, w_gate)) * grouped(x, w_up)
-    return grouped(h, w_down) * w_rows[:, None].astype(x.dtype)
+def relu2(x):
+    """``relu(x)²`` (``mlp_hidden_act`` ``relu2``)."""
+    return jnp.square(nn.relu(x))
 
 
-def _chunk_plan(order, counts, weights, chunk):
-    """What every chunk of the sorted held assignments needs: the token of
-    each sorted assignment, its router weight, the group boundaries, the
-    number of held assignments and of chunks that hold any."""
+ACTIVATIONS = {"silu": nn.silu, "relu2": relu2}
+
+
+def _expert_chunk(x, w_rows, product, act, *ws):
+    """One pass's sorted rows through their experts: x (C, d), the rows'
+    router weights (C,), ``product(lhs, w)`` the pass's matrix product.  Three
+    ``ws`` ``(w_gate, w_up, w_down)`` are gated experts, two ``(w_up,
+    w_down)`` plain ones."""
+    *w_in, w_down = ws
+    h = ACTIVATIONS[act](product(x, w_in[0]))
+    if len(w_in) == 2:
+        h = h * product(x, w_in[1])
+    return product(h, w_down) * w_rows[:, None].astype(x.dtype)
+
+
+def _passes(order, counts, weights, rows, dense):
+    """The passes over the sorted held assignments, ``rows`` sorted rows each,
+    on ONE grid: window w is rows ``w·rows ...`` whatever experts they belong
+    to.  Grouped (``dense`` false): a pass is a window, multiplied as grouped
+    products.  Dense: a pass is a window and ONE expert with rows in it,
+    multiplied by that expert's matrices as plain products with the others'
+    rows masked, so the passes number ``ceil(held / rows)`` plus the expert
+    boundaries that fall inside a window (at most the experts less one): what
+    the layer costs follows how MANY assignments it holds and not how they are
+    split among its experts (PERF.md section 6, PR 34).
+
+    Returns ``(n, at, w_sorted, n_held)``: the number of passes that hold any
+    assignment, ``at(c)`` → the pass's first sorted row, its rows' tokens and
+    router weights, which of its rows are live, its ``product`` and the
+    matrices of ``stacks`` that takes, and how to add a pass's weight
+    gradients to the stacks'."""
     k = weights.shape[1]
-    pad = (-order.shape[0]) % chunk          # whole chunks to slice from
-    order = jnp.pad(order, (0, pad))
+    order = jnp.pad(order, (0, (-order.shape[0]) % rows))      # whole windows to slice from
     ends = jnp.cumsum(counts)
     n_held = ends[-1]
-    return (order // k, weights.reshape(-1)[order], ends - counts, ends, n_held,
-            (n_held + chunk - 1) // chunk)
+    token_of, w_sorted, starts = order // k, weights.reshape(-1)[order], ends - counts
+    if dense:
+        per = jnp.where(counts > 0, (ends - 1) // rows - starts // rows + 1, 0)   # windows an expert has rows in
+        through = jnp.cumsum(per)              # passes through each expert's last
+        n = through[-1]
+    else:
+        n = (n_held + rows - 1) // rows
 
+    def at(c, stacks):
+        if dense:
+            e = jnp.searchsorted(through, c, side="right").astype(jnp.int32)      # pass c is expert e's
+            lo = (starts[e] // rows + c - through[e] + per[e]) * rows
+        else:
+            lo = c * rows
+        idx = lax.dynamic_slice(token_of, (lo,), (rows,))
+        w_rows = lax.dynamic_slice(w_sorted, (lo,), (rows,))
+        if dense:
+            at_row = lo + jnp.arange(rows)
+            live = (at_row >= starts[e]) & (at_row < ends[e])
+            ws = tuple(lax.dynamic_index_in_dim(w, e, keepdims=False) for w in stacks)
+            return lo, idx, w_rows, live, jnp.dot, ws, lambda total, g: total.at[e].add(g.astype(total.dtype))
+        live = lo + jnp.arange(rows) < n_held       # the last chunk's tail holds no assignment
+        sizes = (jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)).astype(jnp.int32)
+        return (lo, idx, w_rows, live, lambda lhs, w: lax.ragged_dot(lhs, w, sizes), stacks,
+                lambda total, g: total + g.astype(total.dtype))
 
-def _chunk_inputs(c, chunk, token_of, w_sorted, starts, ends, n_held):
-    lo = c * chunk
-    idx = lax.dynamic_slice(token_of, (lo,), (chunk,))
-    w_rows = lax.dynamic_slice(w_sorted, (lo,), (chunk,))
-    live = lo + jnp.arange(chunk) < n_held       # the last chunk's tail holds no assignment
-    sizes = jnp.clip(ends, lo, lo + chunk) - jnp.clip(starts, lo, lo + chunk)
-    return lo, idx, w_rows, live, sizes.astype(jnp.int32)
+    return n, at, w_sorted, n_held
 
 
 # The held experts' part of the layer, as a custom-VJP function whose two
-# passes walk the sorted held assignments in chunks of ``chunk`` rows under a
-# loop with a DYNAMIC trip count: the work follows the assignments that are
-# there (none is ever dropped: there is no bound to overflow, whatever the
-# routing does), as the expert FLOPs do, and the memory is a chunk's.  Each chunk gathers its rows,
-# runs the grouped products (``lax.ragged_dot``; the rows past the last group
-# are left unwritten by it and masked here) and scatter-adds into the tokens.
+# passes walk the sorted held assignments ``rows`` at a time under a loop with
+# a DYNAMIC trip count: the work follows the assignments that are there (none
+# is ever dropped: there is no bound to overflow, whatever the routing does),
+# as the expert FLOPs do, and the memory is a pass's.  Each pass gathers its
+# rows, runs the products (grouped: ``lax.ragged_dot``, which leaves the rows
+# past the last group unwritten; dense: one expert's, over a window that holds
+# other experts' rows too; both masked here) and scatter-adds into the tokens.
 # Its residuals are its inputs and the routing, so a rematerialized block's
 # second forward computes no expert product at all (the backward below
-# recomputes a chunk's products where it needs them).
+# recomputes a pass's products where it needs them).
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def held_experts(tokens, weights, order, counts, w_gate_up_down, chunk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def held_experts(tokens, weights, order, counts, stacks, rows, act="silu", dense=False):
     """tokens (T, d), weights (T, k) f32, the sorted assignments of
-    :func:`group_held_assignments`, the three expert weight stacks → (T, d):
+    :func:`group_held_assignments`, the expert weight stacks (three: gated,
+    two: plain; ``act`` names the activation), ``rows`` sorted rows a pass,
+    grouped or ``dense`` (:func:`_passes`) → (T, d):
     ``out[t] = Σ_{j: expert(t, j) held} weights[t, j] · expert(tokens[t])``."""
-    token_of, w_sorted, starts, ends, n_held, n_chunks = _chunk_plan(order, counts, weights, chunk)
+    n, at, _, _ = _passes(order, counts, weights, rows, dense)
 
     def body(c, out):
-        _, idx, w_rows, live, sizes = _chunk_inputs(c, chunk, token_of, w_sorted, starts, ends, n_held)
-        y = _expert_chunk(tokens[idx], w_rows, sizes, *w_gate_up_down)
+        _, idx, w_rows, live, product, ws, _ = at(c, stacks)
+        y = _expert_chunk(tokens[idx], w_rows, product, act, *ws)
         return out.at[idx].add(jnp.where(live[:, None], y, 0).astype(out.dtype))
 
-    return lax.fori_loop(0, n_chunks, body, jnp.zeros_like(tokens))
+    return lax.fori_loop(0, n, body, jnp.zeros_like(tokens))
 
 
-def _held_experts_fwd(tokens, weights, order, counts, w_gate_up_down, chunk):
-    out = held_experts(tokens, weights, order, counts, w_gate_up_down, chunk)
-    return out, (tokens, weights, order, counts, w_gate_up_down)
+def _held_experts_fwd(tokens, weights, order, counts, stacks, rows, act, dense):
+    out = held_experts(tokens, weights, order, counts, stacks, rows, act, dense)
+    return out, (tokens, weights, order, counts, stacks)
 
 
-def _held_experts_bwd(chunk, res, d_out):
-    tokens, weights, order, counts, w_gate_up_down = res
-    token_of, w_sorted, starts, ends, n_held, n_chunks = _chunk_plan(order, counts, weights, chunk)
+def _held_experts_bwd(rows, act, dense, res, d_out):
+    tokens, weights, order, counts, stacks = res
+    n, at, w_sorted, n_held = _passes(order, counts, weights, rows, dense)
 
     def body(c, carry):
         d_tokens, d_w_sorted, d_stacks = carry
-        lo, idx, w_rows, live, sizes = _chunk_inputs(c, chunk, token_of, w_sorted, starts, ends, n_held)
+        lo, idx, w_rows, live, product, ws, add = at(c, stacks)
         _, pull = jax.vjp(
-            lambda x, w, *stacks: _expert_chunk(x, w, sizes, *stacks),
-            tokens[idx], w_rows, *w_gate_up_down)
-        d_x, d_w, *d_chunk = pull(jnp.where(live[:, None], d_out[idx], 0).astype(tokens.dtype))
+            lambda x, w, *ws: _expert_chunk(x, w, product, act, *ws),
+            tokens[idx], w_rows, *ws)
+        d_x, d_w, *d_ws = pull(jnp.where(live[:, None], d_out[idx], 0).astype(tokens.dtype))
         return (
             d_tokens.at[idx].add(jnp.where(live[:, None], d_x, 0)),
-            lax.dynamic_update_slice(d_w_sorted, jnp.where(live, d_w, 0.0), (lo,)),
-            tuple(a + g.astype(a.dtype) for a, g in zip(d_stacks, d_chunk)),
+            # a dense window's other rows are other passes', before or after this one
+            lax.dynamic_update_slice(d_w_sorted, jnp.where(
+                live, d_w, lax.dynamic_slice(d_w_sorted, (lo,), (rows,)) if dense else 0.0), (lo,)),
+            tuple(add(total, g) for total, g in zip(d_stacks, d_ws)),
         )
 
-    d_tokens, d_w_sorted, d_stacks = lax.fori_loop(0, n_chunks, body, (
+    d_tokens, d_w_sorted, d_stacks = lax.fori_loop(0, n, body, (
         jnp.zeros_like(tokens), jnp.zeros_like(w_sorted),
-        tuple(jnp.zeros(w.shape, jnp.float32) for w in w_gate_up_down),
+        tuple(jnp.zeros(w.shape, jnp.float32) for w in stacks),
     ))
     # back from sorted order: assignment a sits at place[a]
     place = jnp.argsort(order)
     d_weights = jnp.where(place < n_held, d_w_sorted[place], 0.0).reshape(weights.shape)
-    d_stacks = tuple(g.astype(w.dtype) for g, w in zip(d_stacks, w_gate_up_down))
+    d_stacks = tuple(g.astype(w.dtype) for g, w in zip(d_stacks, stacks))
     return d_tokens, d_weights.astype(weights.dtype), None, None, d_stacks
 
 
@@ -432,13 +481,22 @@ held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 class TopKMoe(nn.Module):
-    """SiLU-gated experts under a dropless top-k router: (B, L, D) →
-    (B, L, D), ``Σ_{e ∈ top-k ∩ held} w_e · (silu(x·Wgate_e) ⊙ (x·Wup_e))·Wdown_e``.
+    """Experts under a dropless top-k router: (B, L, D) → (B, L, D),
+    ``Σ_{e ∈ top-k ∩ held} w_e · expert_e(x)``.  ``gated`` (the default)
+    experts are ``(act(x·Wgate_e) ⊙ (x·Wup_e))·Wdown_e`` with ``activation``
+    ``"silu"`` (SDAR's, Instella's); ``gated=False`` ones are
+    ``act(x·Wup_e)·Wdown_e`` and hold no ``w_gate`` (Nemotron-H's, with
+    ``activation="relu2"``).
 
     ``experts_held = (first, count)`` is this chip's share of the
     ``num_experts`` the router scores (None: all of them).  Only the held
     experts have weights here.  Every held assignment is computed
-    (:func:`held_experts`, ``ROWS_CHUNK`` sorted rows a pass of its loop).
+    (:func:`held_experts`): ``ROWS_CHUNK`` sorted rows a pass of its loop as
+    grouped products, or — ``expert_window`` rows given — windows of that
+    many sorted rows, a pass for each expert with rows in the window, as plain
+    products: what a grouped product costs a live row does not fall with the
+    rows an expert has, a plain one over a few hundred rows runs near the
+    matrix unit's pace (PERF.md section 6, PR 34).
 
     The router is SDAR's by default (softmax over all outputs).
     ``scoring="sigmoid"``, ``selection_bias`` (a ``router_bias`` (E,) leaf
@@ -460,24 +518,33 @@ class TopKMoe(nn.Module):
     selection_bias: bool = False
     routed_scaling_factor: float = 1.0
     seq_aux: bool = False
+    gated: bool = True
+    activation: str = "silu"
+    expert_window: int | None = None
     dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         b, l, d = x.shape
         t, k, e = b * l, self.num_experts_per_tok, self.num_experts
+        if self.activation not in ACTIVATIONS:
+            raise ValueError(f"activation {self.activation!r}: one of {sorted(ACTIVATIONS)}")
         first, held = self.experts_held or (0, e)
         if first < 0 or held < 1 or first + held > e:
             raise ValueError(f"experts_held {self.experts_held} outside 0..{e}")
+        if self.expert_window is not None and self.expert_window < 1:
+            raise ValueError(f"expert_window {self.expert_window}: a number of rows, or None for grouped products")
         tokens = x.reshape(t, d).astype(self.dtype)
 
         init = nn.initializers.normal(stddev=0.02)
         router = self.param("router", init, (d, e), jnp.float32)
+        shapes = {"w_gate": (held, d, self.mlp_dim), "w_up": (held, d, self.mlp_dim),
+                  "w_down": (held, self.mlp_dim, d)}
+        if not self.gated:
+            del shapes["w_gate"]
         stacks = tuple(
             self.param(name, init, shape, jnp.float32).astype(self.dtype)
-            for name, shape in (("w_gate", (held, d, self.mlp_dim)),
-                                ("w_up", (held, d, self.mlp_dim)),
-                                ("w_down", (held, self.mlp_dim, d)))
+            for name, shape in shapes.items()
         )
 
         bias = (self.param("router_bias", nn.initializers.zeros, (e,), jnp.float32)
@@ -499,5 +566,6 @@ class TopKMoe(nn.Module):
 
         with scope("moe/experts"):
             out = held_experts(tokens, weights, order, counts, stacks,
-                               min(ROWS_CHUNK, t * k))
+                               self.expert_window or min(ROWS_CHUNK, t * k), self.activation,
+                               self.expert_window is not None)
         return out.reshape(b, l, d).astype(x.dtype)

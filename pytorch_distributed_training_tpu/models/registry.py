@@ -17,6 +17,7 @@ from .resnet import resnet18, resnet34, resnet50, resnet101, resnet152
 from .vit import vit_b16, vit_l16, vit_s16
 from .gpt2 import gpt2_124m, gpt2_large, gpt2_medium, gpt2_xl
 from .instella_moe import instella_moe_16b_a3b
+from .nemotron_h import nemotron_h_30b_a3b
 from .sdar import sdar_30b_a3b
 
 
@@ -53,6 +54,9 @@ MODEL_REGISTRY: dict[str, ModelEntry] = {
     # Next-token CE plus its MTP module's and the experts' balance term
     # (``InstellaMoe.lm_objective`` = "next_token_mtp").
     "instella_moe_16b_a3b": ModelEntry(instella_moe_16b_a3b, "lm"),
+    # Plain next-token CE: single-sublayer layers of Mamba-2, attention and
+    # relu² experts by a pattern string (models/nemotron_h.py).
+    "nemotron_h_30b_a3b": ModelEntry(nemotron_h_30b_a3b, "lm"),
 }
 
 

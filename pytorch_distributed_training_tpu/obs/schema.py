@@ -142,7 +142,9 @@ METRICS: dict[str, dict] = {
                 "causal mask): the live tiles, the diagonal ones at their "
                 "sub-tile ranges; 0.5078 / 0.5156 at 8192 x 8192 in "
                 "1024-tiles (0.5625 with every live tile whole, 0.5001 of "
-                "the pairs live).  flash_bd_fwd, flash_bd_bwd (the same pair "
+                "the pairs live), with equal head counts (Instella) and "
+                "with grouped K/V read in place (Nemotron-H, 32 / 2).  "
+                "flash_bd_fwd, flash_bd_bwd (the same pair "
                 "under the block-diffusion mask): 0.2734 at 8192 positions, "
                 "block 4 (0.375 with every live tile whole, 0.2502 of the "
                 "pairs live)",

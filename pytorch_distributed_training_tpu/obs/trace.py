@@ -56,6 +56,9 @@ PHASES = (
     "moe/experts",           # the held experts: row gather, grouped products, combine
     "moe/shared",            # the shared experts' MLP, every token's
     "train/mtp",             # the multi-token-prediction module and its loss
+    "ssm/conv",              # Mamba-2 mixer: the causal depthwise convolution and its SiLU
+    "ssm/scan",              # Mamba-2 mixer: steps, decays, the chunked recurrence, the D skip
+    "ssm/gate",              # Mamba-2 mixer: the output gate and the grouped RMS norm
     "grad_accum/microbatch",  # fwd+bwd of one accumulation microbatch
     "grad_sync/rs_ici",      # tier 1: reduce-scatter over ICI
     "grad_sync/ar_dcn",      # tier 2: cross-slice all-reduce over DCN

@@ -162,6 +162,12 @@ def _xla_path(q, k, v, *, causal, scale, block_diffusion):
         return _xla_masked_attention(
             q, k, v, block_diffusion_mask(*block_diffusion), scale=scale
         )
+    if k.shape[2] != q.shape[2]:  # grouped K/V under the causal or no mask
+        q_len, k_len = q.shape[1], k.shape[1]
+        mask = jnp.ones((q_len, k_len), bool)
+        return _xla_masked_attention(
+            q, k, v, jnp.tril(mask, k=k_len - q_len) if causal else mask, scale=scale
+        )
     return _xla_attention(q, k, v, causal=causal, scale=scale)
 
 
@@ -333,27 +339,24 @@ def dot_product_attention(
 
     ``block_diffusion=(L, B)`` applies the block-diffusion training mask
     (:func:`block_diffusion_mask`): q and k hold 2L positions, a noised copy
-    then the clean copy, in blocks of B.  Under it k and v may carry fewer
-    heads than q (grouped-query attention, read from ``k``'s shape: query
-    head h reads K/V head h // (H / Hkv), and neither path repeats K/V to H
-    heads).  Without it the call is the one it always was, kernel for kernel,
-    and takes equal head counts only: no model here runs grouped K/V under
-    the causal or the empty mask.
+    then the clean copy, in blocks of B.
+
+    Under any mask k and v may carry fewer heads than q (grouped-query
+    attention, read from ``k``'s shape: query head h reads K/V head
+    h // (H / Hkv)).  The XLA path and the tabled multi-tile kernels read K/V
+    at their own head count; ``pallas_attention.flash_attention`` says what
+    the kernels of a short key row do.  With equal head counts the call is
+    the one it always was, kernel for kernel.
     """
-    if block_diffusion is None and k.shape[2] != q.shape[2]:
+    if k.shape[2] != v.shape[2] or q.shape[2] % k.shape[2]:
         raise ValueError(
-            f"k carries {k.shape[2]} heads and q {q.shape[2]}: grouped K/V "
-            f"heads run under block_diffusion=(L, B) only"
+            f"k/v carry {k.shape[2]}/{v.shape[2]} heads, q {q.shape[2]}"
         )
     if block_diffusion is not None:
         if causal or q.shape[1] != 2 * block_diffusion[0] or k.shape[1] != q.shape[1]:
             raise ValueError(
                 f"block diffusion over L={block_diffusion[0]} takes 2L positions "
                 f"and no causal flag; got q {q.shape}, k {k.shape}"
-            )
-        if k.shape[2] != v.shape[2] or q.shape[2] % k.shape[2]:
-            raise ValueError(
-                f"k/v carry {k.shape[2]}/{v.shape[2]} heads, q {q.shape[2]}"
             )
     if use_flash is None:
         use_flash = flash_preferred(q.shape[1], k.shape[1], q.shape[3])

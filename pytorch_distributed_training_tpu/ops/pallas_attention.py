@@ -1958,11 +1958,12 @@ def flash_attention(
     ``_bd_mask``) and takes the tabled multi-tile pair under the names
     ``flash_bd_fwd`` / ``flash_bd_bwd``; a causal or unmasked call whose key
     row is several tiles takes the same pair as ``flash_fwd`` /
-    ``flash_bwd``.  Under ``block_diffusion`` ``k``/``v`` may carry fewer
-    heads than ``q`` (grouped-query attention: H a multiple of their head
-    count), read in place through the block index.  Grouped K/V under the
-    causal or the empty mask is refused: no model runs it, and below several
-    tiles those calls take kernels that know one head count.
+    ``flash_bwd``.  ``k``/``v`` may carry fewer heads than ``q``
+    (grouped-query attention: H a multiple of their head count): the tabled
+    pair reads them in place through the block index under any mask
+    (Nemotron-H's 32 query heads over 2 K/V heads at 8192 keys); a plan of
+    another kind takes kernels that know one head count, and K/V are
+    repeated to H heads for it.
 
     Sequence lengths need not be lane-aligned: non-multiples of 128 (e.g.
     ViT-B/16's L = 197) are zero-padded to the next multiple, padded keys
@@ -1977,22 +1978,18 @@ def flash_attention(
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     b, q_len, h, d = q.shape
     k_len = k.shape[1]
-    if block_diffusion is None and k.shape[2] != h:
+    if block_diffusion is not None and causal:
+        raise ValueError("block_diffusion replaces the causal mask")
+    if h % k.shape[2]:
         raise ValueError(
-            f"k carries {k.shape[2]} heads and q {h}: grouped K/V heads run "
-            f"under block_diffusion only"
+            f"{h} query heads are not a multiple of {k.shape[2]} K/V heads"
         )
-    if block_diffusion is not None:
-        if causal:
-            raise ValueError("block_diffusion replaces the causal mask")
-        if h % k.shape[2]:
-            raise ValueError(
-                f"{h} query heads are not a multiple of {k.shape[2]} K/V heads"
-            )
     plan = flash_plan(
         q_len, k_len, h, k.shape[2], d, q.dtype.itemsize, causal=causal,
         block_diffusion=block_diffusion, block_q=block_q, block_k=block_k,
     )
+    if k.shape[2] != h and plan.kind != "tabled":
+        k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
     pad_q, pad_k = plan.q_len - q_len, plan.k_len - k_len
     if pad_q:
         q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))

@@ -24,13 +24,17 @@ class Policy:
     param_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.float32
 
-    def cast_to_compute(self, tree: Any) -> Any:
-        """Cast float leaves to the compute dtype (int/bool leaves untouched)."""
-        def cast(x):
-            if jnp.issubdtype(x.dtype, jnp.floating):
+    def cast_to_compute(self, tree: Any, keep: tuple = ()) -> Any:
+        """Cast float leaves to the compute dtype (int/bool leaves untouched).
+        A leaf whose own key is in ``keep`` stays as stored: a model names
+        there what its arithmetic reads in float32 (``float32_params`` on the
+        module: a state-space mixer's ``A_log``, ``dt_bias``, ``D``)."""
+        def cast(path, x):
+            kept = bool(path) and getattr(path[-1], "key", None) in keep
+            if jnp.issubdtype(x.dtype, jnp.floating) and not kept:
                 return x.astype(self.compute_dtype)
             return x
-        return jax.tree_util.tree_map(cast, tree)
+        return jax.tree_util.tree_map_with_path(cast, tree)
 
     def cast_to_param(self, tree: Any) -> Any:
         def cast(x):

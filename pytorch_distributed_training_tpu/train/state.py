@@ -39,6 +39,11 @@ class TrainState(struct.PyTreeNode):
     # consecutive-bad detection without a per-step host sync.  Empty
     # otherwise, and never checkpointed (counters reset on restore).
     resilience: Any = ()
+    # Keys of the leaves a mixed-precision step hands the model as stored, not
+    # in the compute dtype (the module's ``float32_params``, read once in
+    # ``create_train_state``: however ``apply_fn`` is wrapped later, the
+    # step still knows them).
+    float32_params: tuple = struct.field(pytree_node=False, default=())
 
     def apply_gradients(self, grads: Any, **kwargs) -> "TrainState":
         updates, new_opt_state = self.tx.update(grads, self.opt_state, self.params)
@@ -123,6 +128,7 @@ def create_train_state(
             batch_stats=variables.get("batch_stats", {}),
             apply_fn=model.apply,
             tx=tx,
+            float32_params=tuple(getattr(model, "float32_params", ())),
         )
 
     if mesh is None:
@@ -149,4 +155,5 @@ def create_train_state(
         batch_stats=variables.get("batch_stats", {}),
         apply_fn=model.apply,
         tx=tx,
+        float32_params=tuple(getattr(model, "float32_params", ())),
     )

@@ -85,7 +85,7 @@ def _forward(
     the loss.  ``apply_kwargs`` pass through to the model (e.g.
     ``return_hidden`` for the chunked-CE LM path).
     """
-    variables = {"params": policy.cast_to_compute(params)}
+    variables = {"params": policy.cast_to_compute(params, keep=state.float32_params)}
     # Truthiness of the batch_stats CONTAINER (an empty-dict check on
     # pytree structure, static at trace time), not bool() of a tracer.
     # graftcheck: disable=tracer-leak — container truthiness, static

@@ -293,6 +293,31 @@ def test_tabled_pair_compiles_for_a_v5e_at_instellas_shape(one_v5e):
     assert f"{pa._FUSED_BWD_VMEM}" in text
 
 
+def test_tabled_pair_compiles_for_a_v5e_at_nemotrons_shape(one_v5e):
+    """The causal pair with grouped K/V at the Nemotron-H cell's call — 8192
+    positions, 32 query heads over 2 K/V heads of 128: K/V are read at their
+    own head count (dk, dv come back at 2 heads), under the causal kernels'
+    names and in the shape ``readers/kernel_roofline.py`` parses."""
+    def pair(q, k, v):
+        out, vjp = jax.vjp(
+            lambda q, k, v: pa.flash_attention(q, k, v, causal=True, interpret=False),
+            q, k, v,
+        )
+        return (out,) + vjp(out)
+
+    shape = lambda h: jax.ShapeDtypeStruct((1, 8192, h, 128), jnp.bfloat16,
+                                           sharding=one_v5e)
+    calls, text = mosaic_calls_compiled_for(pair, shape(32), shape(2), shape(2))
+    names = sorted(re.sub(r"^%(\w+?)[_.]*\d*$", r"\1", name.strip()) for name, _ in calls)
+    assert names == ["flash_bwd", "flash_fwd"], calls
+    results = {role: result for name, result in calls
+               for role in ("flash_fwd", "flash_bwd") if role in name}
+    assert results["flash_fwd"].lstrip("(").startswith("bf16[1,32,8192,128]")
+    assert results["flash_bwd"].lstrip("(").startswith("bf16[1,32,8192,128]")
+    assert results["flash_bwd"].count("bf16[1,2,8192,128]") == 2         # dk, dv at the K/V heads
+    assert f"{pa._FUSED_BWD_VMEM}" in text
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("q_len,k_len,heads,dim,itemsize,kind", [
     (1024, 1024, 12, 64, 2, "grouped"), (1024, 1024, 12, 64, 4, "grouped"),

@@ -188,12 +188,14 @@ def test_xla_path_equals_attention_with_repeated_heads():
 def test_attention_arguments_are_checked():
     x = jnp.zeros((1, 64, 4, 16))
     kv = jnp.zeros((1, 64, 2, 16))
+    # grouped K/V run under the causal and the empty mask too (tests/test_nemotron_h.py holds their values) ...
+    assert attention.dot_product_attention(x, kv, kv).shape == x.shape
+    assert attention.dot_product_attention(x, kv, kv, causal=True).shape == x.shape
+    odd = jnp.zeros((1, 64, 3, 16))
     with pytest.raises(ValueError):
-        attention.dot_product_attention(x, kv, kv)                                # grouped K/V, no (L, B)
+        attention.dot_product_attention(x, odd, odd, causal=True)                 # ... where the heads divide
     with pytest.raises(ValueError):
-        attention.dot_product_attention(x, kv, kv, causal=True)
-    with pytest.raises(ValueError):
-        pa.flash_attention(x, kv, kv, causal=True)                                # nor in the kernels
+        pa.flash_attention(x, odd, odd, causal=True)                              # and in the kernels alike
     with pytest.raises(ValueError):
         attention.dot_product_attention(x, kv, kv, block_diffusion=(48, 4))       # 2L != 64
     with pytest.raises(ValueError):

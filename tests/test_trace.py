@@ -828,6 +828,7 @@ def _vocabulary(name):
         "train/step", "train/input_wait", "train/host_sync", "train/loss",
         "train/optimizer", "train/noise", "attn/block_diffusion",
         "attn/mla", "moe/route", "moe/experts", "moe/shared", "train/mtp",
+        "ssm/conv", "ssm/scan", "ssm/gate",
         "grad_accum/microbatch",
         "grad_sync/rs_ici", "grad_sync/ar_dcn", "grad_sync/ag_ici",
         "grad_sync/stripe",
