@@ -2429,6 +2429,7 @@ def _probe_compiled_cost(trainer, batches, mesh, sequence_parallel, emitter):
 
     from ..obs import step_cost_report
     from ..ops.pallas_attention import flash_visited_pair_share
+    from ..ops.ssd import ssd_plans_traced
     from ..parallel.sharding import shard_batch
 
     # Bind the iterator ONCE and chain onto it — peeking via a fresh
@@ -2465,6 +2466,8 @@ def _probe_compiled_cost(trainer, batches, mesh, sequence_parallel, emitter):
                 emitter.gauge(
                     f"flash_visited_pair_share[kernel={kernel}]", share
                 )
+            for kind, sites in ssd_plans_traced().items():
+                emitter.gauge(f"ssd_plan[kind={kind}]", sites)
             # Feed the live MFU gauge: the probe's compiled FLOPs + peak
             # over the trainer's rolling step-time window (obs/live.py).
             trainer.step_flops = report.get("flops")

@@ -17,7 +17,9 @@ convolution's.
   dt_bias)`` (``time_step_limit`` (0, ∞): no clamp), ``A = -exp(A_log)``,
   ``S_t = exp(Δ_t A) S_{t-1} + Δ_t x_t ⊗ B_t``, ``y_t = S_t C_t + D x_t``
   (``ops/ssd.ssd_chunked`` in chunks of ``chunk_size``; the state and the
-  decays float32).  ``y ← GroupRMSNorm(y ⊙ silu(z))``: the gate FIRST, then
+  decays float32; at lane-aligned sizes the ``ssd_fwd`` / ``ssd_bwd`` Pallas
+  pair, which keeps a chunk's blocks and the carried state in VMEM, at toy
+  sizes the ``jnp`` form: ``ops/ssd.ssd_plan`` decides from the shapes).  ``y ← GroupRMSNorm(y ⊙ silu(z))``: the gate FIRST, then
   an RMS norm over each of the G groups of d_inner / G channels (eps
   ``layer_norm_epsilon``, one learned scale of d_inner), as ``nemotron_h``
   orders them.  ``out = y W_out``.
@@ -135,7 +137,12 @@ def _dt_bias_init(cfg: NemotronHConfig):
 class Mamba2Mixer(nn.Module):
     """``u`` (B, T, d) → (B, T, d): the ``M`` sublayer of the module docstring,
     everything between the two projections in float32 but the recurrence's
-    matrix products (``ops/ssd.py``)."""
+    matrix products.  The recurrence is ``ops/ssd.ssd_chunked``: two Mosaic
+    custom calls under ``ssm/scan`` (``ssd_fwd``, ``ssd_bwd``; no (Q, Q)
+    block, running sum or chunk state of the forward in HBM) where
+    ``ssd_plan`` finds lane-aligned shapes, plain XLA operations otherwise.
+    The convolution, the D skip, the gate and the grouped norm are XLA's
+    either way."""
 
     cfg: NemotronHConfig
     dtype: Any = jnp.float32

@@ -149,6 +149,15 @@ METRICS: dict[str, dict] = {
                 "block 4 (0.375 with every live tile whole, 0.2502 of the "
                 "pairs live)",
     },
+    "ssd_plan": {
+        "type": GAUGE, "labeled": True,
+        "help": "state-space scan call sites traced so far, per kind: pallas "
+                "(the ssd_fwd / ssd_bwd Mosaic pair, whose counts per program "
+                "are mosaic_custom_calls[kernel=ssd_fwd|ssd_bwd]) or xla (the "
+                "jnp form: a shape that misses the lane tile, or a backend the "
+                "kernels do not target); ops/ssd.ssd_plan decides from the "
+                "shapes and the backend alone",
+    },
     # ---- SLO / alerting plane (obs/slo.py) ------------------------------
     "slo_alert_transitions": {
         "type": COUNTER, "labeled": False,

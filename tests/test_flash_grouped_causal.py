@@ -318,6 +318,76 @@ def test_tabled_pair_compiles_for_a_v5e_at_nemotrons_shape(one_v5e):
     assert f"{pa._FUSED_BWD_VMEM}" in text
 
 
+@pytest.mark.parametrize("length, groups, per_group, head_dim, state, dtype", [
+    (8192, 8, 8, 64, 128, jnp.bfloat16),        # the Nemotron-H cell's mixer: two heads a 128-lane slab
+    (256, 1, 1, 64, 128, jnp.float32),          # one head a slab, half a lane tile wide
+])
+def test_the_state_space_pair_compiles_for_a_v5e(one_v5e, length, groups, per_group, head_dim, state, dtype):
+    """``ops/ssd.py``'s pair where ``ssd_plan`` answers ``pallas`` (kept in
+    this file with the other compiles for a described chip: one process may
+    load the TPU's library): both kernels under their names, the results in
+    the shapes the device trace will spell."""
+    from pytorch_distributed_training_tpu.ops import ssd
+
+    heads = groups * per_group
+    assert ssd.ssd_plan(length, heads, groups, head_dim, state, 128, jnp.dtype(dtype).itemsize, backend="tpu").kind == "pallas"
+
+    def pair(x, dt, a, b, c):
+        y, vjp = jax.vjp(lambda *inputs: ssd._ssd_pallas(*inputs, groups, 128, False), x, dt, a, b, c)
+        return (y,) + vjp(y)
+
+    shape = lambda dims, kind: jax.ShapeDtypeStruct(dims, kind, sharding=one_v5e)
+    calls, _ = mosaic_calls_compiled_for(
+        pair, shape((1, length, heads * head_dim), dtype), shape((1, length, heads), jnp.float32),
+        shape((heads,), jnp.float32), shape((1, length, groups * state), dtype),
+        shape((1, length, groups * state), dtype))
+    results = {role: result for name, result in calls for role in ssd.KERNEL_NAMES if role in name}
+    assert sorted(results) == ["ssd_bwd", "ssd_fwd"] and len(calls) == 2, calls
+    kind = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype]
+    chunks = length // 128
+    assert results["ssd_fwd"].replace(" ", "").startswith(
+        f"({kind}[1,{length},{heads * head_dim}]") and f"f32[1,{groups},{chunks},{per_group * head_dim},{state}]" in results["ssd_fwd"]
+    assert results["ssd_bwd"].count(f"{kind}[1,{length},{groups * state}]") == 2         # dB, dC
+    assert f"f32[1,{groups},{length},{per_group}]" in results["ssd_bwd"] and f"f32[1,{groups},{per_group},{length}]" in results["ssd_bwd"]
+
+
+@pytest.mark.parametrize("data, tensor", [(4, 1), (2, 2)])
+def test_a_mixers_gradient_lowers_for_four_v5es(monkeypatch, data, tensor):
+    """The data-parallel step is a GSPMD jit, and Mosaic calls "cannot be
+    automatically partitioned": ``ssd_chunked`` puts the pair in a
+    ``shard_map`` (as ``ops/attention`` puts flash).  ``jax.grad`` through one
+    ``Mamba2Mixer`` layer at lane-aligned sizes, compiled for a described
+    2x2 of v5es with the batch sharded: a chip's shard of the batch — and,
+    with ``tensor`` 2, of the groups — in each call's results."""
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pytorch_distributed_training_tpu import comm, models
+    from test_ssd_pallas import MIXER
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    mesh = comm.make_mesh(comm.MeshConfig(data=data, tensor=tensor), devices=list(topo.devices))
+    net = models.create_model("nemotron_h_30b_a3b", dtype=jnp.bfloat16, cfg_overrides=MIXER)
+    tokens = jnp.zeros((4, 256), jnp.int32)
+    params = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0), tokens, train=False)["params"])
+    on = lambda spec: lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=NamedSharding(mesh, spec))
+    loss = lambda p, t: jnp.sum(net.apply({"params": p}, t, train=False).astype(jnp.float32) ** 2)
+    # the plan and the interpreter follow the default backend, which is the CPU here: the described chips'
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with mesh:
+        calls, _ = mosaic_calls_compiled_for(
+            jax.grad(loss), jax.tree.map(on(P()), params), on(P(comm.mesh.BATCH_AXES))(tokens))
+    results = {role: result for name, result in calls for role in ("ssd_fwd", "ssd_bwd") if role in name}
+    assert sorted(results) == ["ssd_bwd", "ssd_fwd"] and len(calls) == 2, calls
+    rows, groups = 4 // data, MIXER["n_groups"] // tensor
+    width = groups * (MIXER["mamba_num_heads"] // MIXER["n_groups"]) * MIXER["mamba_head_dim"]
+    assert results["ssd_fwd"].replace(" ", "").startswith(f"(bf16[{rows},256,{width}]"), results
+    assert results["ssd_bwd"].count(f"bf16[{rows},256,{groups * MIXER['ssm_state_size']}]") == 2, results
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("q_len,k_len,heads,dim,itemsize,kind", [
     (1024, 1024, 12, 64, 2, "grouped"), (1024, 1024, 12, 64, 4, "grouped"),

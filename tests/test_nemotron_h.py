@@ -402,5 +402,7 @@ def test_cli_trains_the_toy_size(tmp_path):
     assert out.returncode == 0, out.stderr[-3000:] + out.stdout[-2000:]
     assert "training started" in out.stdout
     recorded = "".join(p.read_text() for p in tmp_path.rglob("*") if p.is_file())
-    for name in ("moe_held_assignments", "moe_load_max"):
+    # ... and which form of the scan the mixers took: the toy's shapes miss the lane tile
+    for name in ("moe_held_assignments", "moe_load_max", "ssd_plan[kind=xla]"):
         assert name in recorded, name
+    assert "ssd_plan[kind=pallas]" not in recorded
