@@ -2428,6 +2428,7 @@ def _probe_compiled_cost(trainer, batches, mesh, sequence_parallel, emitter):
     import itertools
 
     from ..obs import step_cost_report
+    from ..obs.cost import scope_census
     from ..ops.pallas_attention import flash_visited_pair_share
     from ..ops.ssd import ssd_plans_traced
     from ..parallel.sharding import shard_batch
@@ -2459,6 +2460,11 @@ def _probe_compiled_cost(trainer, batches, mesh, sequence_parallel, emitter):
             ).compile()
             report = step_cost_report(compiled)
             emitter.emit("compiled_cost", report)
+            # Instructions by scope, of the compile the step then loads: what
+            # a --profile-dir trace's events can be laid against.
+            emitter.emit("record", {
+                "record": "step_scopes", "scopes": scope_census(compiled.as_text()),
+            })
             _gauge_mosaic_kernels(
                 emitter, "train_step", report.get("mosaic_kernels", {})
             )

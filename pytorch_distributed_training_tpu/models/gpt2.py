@@ -19,6 +19,7 @@ from typing import Any
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..obs.trace import scope
 from .layers import SelfAttention
 
 
@@ -66,7 +67,8 @@ class Block(nn.Module):
     def __call__(self, x, deterministic: bool = True, positions=None,
                  block_table=None, attn_mask=None):
         cfg = self.cfg
-        y = nn.LayerNorm(dtype=self.dtype, name="ln1")(x)
+        with scope("block/norm"):
+            y = nn.LayerNorm(dtype=self.dtype, name="ln1")(x)
         y = SelfAttention(
             cfg.num_heads, causal=True, dtype=self.dtype,
             sp_mesh=self.sp_mesh, sp_mode=self.sp_mode,
@@ -74,13 +76,16 @@ class Block(nn.Module):
             kv_quant=self.kv_quant, name="attn",
         )(y, positions, block_table, attn_mask)
         y = nn.Dropout(cfg.dropout_rate)(y, deterministic=deterministic)
-        x = x + y
-        y = nn.LayerNorm(dtype=self.dtype, name="ln2")(x)
-        y = nn.Dense(cfg.hidden_dim * cfg.mlp_ratio, dtype=self.dtype, name="mlp_up")(y)
-        y = nn.gelu(y)
-        y = nn.Dense(cfg.hidden_dim, dtype=self.dtype, name="mlp_down")(y)
+        with scope("block/norm"):
+            x = x + y
+            y = nn.LayerNorm(dtype=self.dtype, name="ln2")(x)
+        with scope("block/mlp"):
+            y = nn.Dense(cfg.hidden_dim * cfg.mlp_ratio, dtype=self.dtype, name="mlp_up")(y)
+            y = nn.gelu(y)
+            y = nn.Dense(cfg.hidden_dim, dtype=self.dtype, name="mlp_down")(y)
         y = nn.Dropout(cfg.dropout_rate)(y, deterministic=deterministic)
-        return x + y
+        with scope("block/norm"):
+            return x + y
 
 
 class GPT2(nn.Module):
@@ -226,14 +231,16 @@ class GPT2(nn.Module):
                     kv_quant=self.kv_quant, name=f"block_{i}",
                 )(x, not train, positions, block_table, attn_mask)
 
-        x = nn.LayerNorm(dtype=self.dtype, name="ln_final")(x)
+        with scope("block/norm"):
+            x = nn.LayerNorm(dtype=self.dtype, name="ln_final")(x)
         if return_hidden:
             return x
-        if cfg.tie_embeddings:
-            logits = jnp.einsum("bld,vd->blv", x, wte.astype(self.dtype))
-        else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head")(x)
-        return logits.astype(jnp.float32)
+        with scope("train/head"):
+            if cfg.tie_embeddings:
+                logits = jnp.einsum("bld,vd->blv", x, wte.astype(self.dtype))
+            else:
+                logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head")(x)
+            return logits.astype(jnp.float32)
 
 
 def gpt2_124m(cfg_overrides: dict | None = None, **kw) -> GPT2:

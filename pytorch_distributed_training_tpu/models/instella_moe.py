@@ -166,13 +166,13 @@ class MlaAttention(nn.Module):
             if cfg.qk_layernorm:
                 q, k = norm("q_norm")(q), norm("k_norm")(k)
             q, k = rotate(q), rotate(k)
-        q, k, v = (checkpoint_name(t, "attn_qkv") for t in (q, k, v))
+            q, k, v = (checkpoint_name(t, "attn_qkv") for t in (q, k, v))
         o = dot_product_attention(q, k, v, causal=True, scale=softmax_scale(cfg))
-        o = o.reshape(b, p, h * dv)
-        if cfg.gated_attention:
-            with scope("attn/mla"):
+        with scope("attn/mla"):
+            o = o.reshape(b, p, h * dv)
+            if cfg.gated_attention:
                 o = o * jax.nn.sigmoid(_dense(h * dv, "wg", self.dtype)(x))
-        return _dense(cfg.hidden_size, "wo", self.dtype)(o)
+            return _dense(cfg.hidden_size, "wo", self.dtype)(o)
 
 
 class GatedMlp(nn.Module):
@@ -203,10 +203,17 @@ class InstellaBlock(nn.Module):
         cfg = self.cfg
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, self.dtype, name=name)
         attn_in = before if cfg.farskip else stream
-        mid = stream + MlaAttention(cfg, self.dtype, name="attn")(norm("ln1")(attn_in), positions)
-        y = norm("ln2")(stream if cfg.farskip else mid)
+        with scope("block/norm"):
+            y = norm("ln1")(attn_in)
+        y = MlaAttention(cfg, self.dtype, name="attn")(y, positions)
+        with scope("block/norm"):
+            mid = stream + y
+            y = norm("ln2")(stream if cfg.farskip else mid)
         if self.dense_mlp:
-            return mid, mid + GatedMlp(cfg.intermediate_size, self.dtype, name="mlp")(y)
+            with scope("block/mlp"):
+                y = GatedMlp(cfg.intermediate_size, self.dtype, name="mlp")(y)
+            with scope("block/norm"):
+                return mid, mid + y
         routed = TopKMoe(
             cfg.n_routed_experts, cfg.num_experts_per_tok, cfg.moe_intermediate_size,
             experts_held=cfg.experts_held, norm_topk_prob=cfg.norm_topk_prob,
@@ -217,7 +224,8 @@ class InstellaBlock(nn.Module):
         with scope("moe/shared"):
             shared = GatedMlp(cfg.n_shared_experts * cfg.moe_intermediate_size,
                               self.dtype, name="shared")(y)
-        return mid, mid + routed + shared
+        with scope("block/norm"):
+            return mid, mid + routed + shared
 
 
 class InstellaMoe(nn.Module):
@@ -252,11 +260,13 @@ class InstellaMoe(nn.Module):
         for i in range(cfg.num_hidden_layers):
             before, x = block_cls(cfg, i < cfg.first_k_dense_replace, self.dtype,
                                   name=f"block_{i}")(before, x, positions)
-        hidden = norm("ln_final")(x)
+        with scope("block/norm"):
+            hidden = norm("ln_final")(x)
         if return_hidden:
             return hidden
         head = _dense(cfg.vocab_size, "lm_head", self.dtype)
-        logits = head(hidden).astype(jnp.float32)
+        with scope("train/head"):
+            logits = head(hidden).astype(jnp.float32)
         if not cfg.num_nextn_predict_layers or not (mtp or self.is_initializing()):
             return logits
         with scope("train/mtp"):
@@ -264,7 +274,10 @@ class InstellaMoe(nn.Module):
             y = _dense(cfg.hidden_size, "mtp_proj", self.dtype)(
                 jnp.concatenate([norm("mtp_hnorm")(x), norm("mtp_enorm")(ahead)], axis=-1))
             _, y = block_cls(cfg, False, self.dtype, name="mtp_block")(y, y, positions)
-            mtp_logits = head(norm("mtp_final")(y)).astype(jnp.float32)
+            with scope("block/norm"):
+                y = norm("mtp_final")(y)
+            with scope("train/head"):      # the MTP head is a head: the inner name wins
+                mtp_logits = head(y).astype(jnp.float32)
         return (logits, mtp_logits) if mtp else logits
 
 

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 
+from ..obs.trace import scope
 
 def _use_decode_kernel(batch: int) -> bool:
     """Shared dispatch for the fused Pallas decode-attention kernel (both
@@ -215,12 +216,14 @@ class SelfAttention(nn.Module):
             and not self.causal
             and self.sp_mesh is None
         ):
-            q3, k3, v3 = _QkvToHeads(
-                features=d, num_heads=self.num_heads, dtype=self.dtype,
-                name="qkv",
-            )(x)
+            with scope("attn/proj"):
+                q3, k3, v3 = _QkvToHeads(
+                    features=d, num_heads=self.num_heads, dtype=self.dtype,
+                    name="qkv",
+                )(x)
             return self._bhld_core(q3, k3, v3, d)
-        qkv = nn.Dense(3 * d, dtype=self.dtype, name="qkv")(x)
+        with scope("attn/proj"):
+            qkv = nn.Dense(3 * d, dtype=self.dtype, name="qkv")(x)
         # Both split forms select the IDENTICAL elements (q is columns
         # 0..d-1 either way: axis 2 of the (3, H, Dh) reshape is the
         # slowest-varying of the packed columns), so the choice is pure
@@ -231,15 +234,16 @@ class SelfAttention(nn.Module):
         # 872 img/s).  Parameters are compatible across the switch.
         from ..ops.attention import flash_preferred
 
-        if not self.decode and flash_preferred(
-            l, l, head_dim, self.num_heads, itemsize=qkv.dtype.itemsize
-        ):
-            q = qkv[..., :d].reshape(b, l, self.num_heads, head_dim)
-            k = qkv[..., d:2 * d].reshape(b, l, self.num_heads, head_dim)
-            v = qkv[..., 2 * d:].reshape(b, l, self.num_heads, head_dim)
-        else:
-            qkv = qkv.reshape(b, l, 3, self.num_heads, head_dim)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        with scope("attn/proj"):
+            if not self.decode and flash_preferred(
+                l, l, head_dim, self.num_heads, itemsize=qkv.dtype.itemsize
+            ):
+                q = qkv[..., :d].reshape(b, l, self.num_heads, head_dim)
+                k = qkv[..., d:2 * d].reshape(b, l, self.num_heads, head_dim)
+                v = qkv[..., 2 * d:].reshape(b, l, self.num_heads, head_dim)
+            else:
+                qkv = qkv.reshape(b, l, 3, self.num_heads, head_dim)
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         if self.decode:
             out = self._decode_attend(q, k, v, positions, block_table, attn_mask)
         elif (
@@ -264,8 +268,9 @@ class SelfAttention(nn.Module):
                 )
         else:
             out = dot_product_attention(q, k, v, causal=self.causal)
-        out = out.reshape(b, l, d)
-        return nn.Dense(d, dtype=self.dtype, name="proj")(out)
+        with scope("attn/proj"):
+            out = out.reshape(b, l, d)
+            return nn.Dense(d, dtype=self.dtype, name="proj")(out)
 
     def _bhld_core(self, q, k, v, d):
         """Canonical (b, h)-leading attention + head-consuming projection
@@ -280,19 +285,20 @@ class SelfAttention(nn.Module):
 
         head_dim = q.shape[-1]
         scale = head_dim ** -0.5
-        if q.dtype == jnp.bfloat16:
-            logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * jnp.asarray(
-                scale, q.dtype
-            )
-            weights = _softmax_lowp(logits)
-        else:
-            logits = jnp.einsum(
-                "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
-            ) * scale
-            weights = nn.softmax(logits, axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
-        proj = _ProjFromHeads(features=d, dtype=self.dtype, name="proj")
-        return proj(o)
+        with scope("attn/core"):
+            if q.dtype == jnp.bfloat16:
+                logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) * jnp.asarray(
+                    scale, q.dtype
+                )
+                weights = _softmax_lowp(logits)
+            else:
+                logits = jnp.einsum(
+                    "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
+                ) * scale
+                weights = nn.softmax(logits, axis=-1)
+            o = jnp.einsum("bhqk,bhkd->bhqd", weights.astype(v.dtype), v)
+        with scope("attn/proj"):
+            return _ProjFromHeads(features=d, dtype=self.dtype, name="proj")(o)
 
     def _tp(self):
         """The tensor-parallel mesh when TP-sharded serving is active

@@ -162,7 +162,8 @@ class Mamba2Mixer(nn.Module):
         conv_b = self.param("conv_b", uniform, (wide,))
         scale = self.param("norm", nn.initializers.ones, (inner,), jnp.float32)
 
-        z, xbc, dt = jnp.split(_dense(inner + wide + h, "in_proj", self.dtype)(u), [inner, inner + wide], axis=-1)
+        with scope("ssm/proj"):
+            z, xbc, dt = jnp.split(_dense(inner + wide + h, "in_proj", self.dtype)(u), [inner, inner + wide], axis=-1)
         bsz, t, _ = z.shape
         p, n = cfg.mamba_head_dim, cfg.ssm_state_size
         with scope("ssm/conv"):
@@ -178,7 +179,8 @@ class Mamba2Mixer(nn.Module):
             y = (y.reshape(bsz, t, inner) * nn.silu(z.astype(jnp.float32))).reshape(bsz, t, g, inner // g)
             y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + cfg.layer_norm_epsilon)
             y = (y.reshape(bsz, t, inner) * scale).astype(z.dtype)
-        return _dense(cfg.hidden_size, "out_proj", self.dtype)(y)
+        with scope("ssm/proj"):
+            return _dense(cfg.hidden_size, "out_proj", self.dtype)(y)
 
 
 class GqaAttention(nn.Module):
@@ -190,12 +192,14 @@ class GqaAttention(nn.Module):
         cfg = self.cfg
         bsz, t, _ = x.shape
         h, hkv, dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-        q = _dense(h * dh, "wq", self.dtype)(x).reshape(bsz, t, h, dh)
-        k = _dense(hkv * dh, "wk", self.dtype)(x).reshape(bsz, t, hkv, dh)
-        v = _dense(hkv * dh, "wv", self.dtype)(x).reshape(bsz, t, hkv, dh)
-        q, k, v = (checkpoint_name(m, "attn_qkv") for m in (q, k, v))
+        with scope("attn/proj"):
+            q = _dense(h * dh, "wq", self.dtype)(x).reshape(bsz, t, h, dh)
+            k = _dense(hkv * dh, "wk", self.dtype)(x).reshape(bsz, t, hkv, dh)
+            v = _dense(hkv * dh, "wv", self.dtype)(x).reshape(bsz, t, hkv, dh)
+            q, k, v = (checkpoint_name(m, "attn_qkv") for m in (q, k, v))
         o = dot_product_attention(q, k, v, causal=True)
-        return _dense(cfg.hidden_size, "wo", self.dtype)(o.reshape(bsz, t, h * dh))
+        with scope("attn/proj"):
+            return _dense(cfg.hidden_size, "wo", self.dtype)(o.reshape(bsz, t, h * dh))
 
 
 class Relu2Mlp(nn.Module):
@@ -220,11 +224,14 @@ class NemotronHLayer(nn.Module):
     @nn.compact
     def __call__(self, h):
         cfg = self.cfg
-        y = RMSNorm(cfg.norm_eps, self.dtype, name="norm")(h)
-        if self.kind == "M":
-            return h + Mamba2Mixer(cfg, self.dtype, name="mixer")(y)
-        if self.kind == "*":
-            return h + GqaAttention(cfg, self.dtype, name="attn")(y)
+        with scope("block/norm"):
+            y = RMSNorm(cfg.norm_eps, self.dtype, name="norm")(h)
+        if self.kind in "M*":
+            sub = Mamba2Mixer(cfg, self.dtype, name="mixer") if self.kind == "M" \
+                else GqaAttention(cfg, self.dtype, name="attn")
+            y = sub(y)
+            with scope("block/norm"):
+                return h + y
         routed = TopKMoe(
             cfg.n_routed_experts, cfg.num_experts_per_tok, cfg.moe_intermediate_size,
             experts_held=cfg.experts_held, norm_topk_prob=cfg.norm_topk_prob,
@@ -234,7 +241,8 @@ class NemotronHLayer(nn.Module):
         )(y)
         with scope("moe/shared"):
             shared = Relu2Mlp(cfg.moe_shared_expert_intermediate_size, self.dtype, name="shared")(y)
-        return h + routed + shared
+        with scope("block/norm"):
+            return h + routed + shared
 
 
 class NemotronH(nn.Module):
@@ -265,10 +273,12 @@ class NemotronH(nn.Module):
         h = embed.astype(self.dtype)[tokens]
         for i, kind in enumerate(cfg.layers):
             h = layer_cls(cfg, kind, self.dtype, name=f"block_{i}")(h)
-        h = RMSNorm(cfg.norm_eps, self.dtype, name="ln_final")(h)
+        with scope("block/norm"):
+            h = RMSNorm(cfg.norm_eps, self.dtype, name="ln_final")(h)
         if return_hidden:
             return h
-        return _dense(cfg.vocab_size, "lm_head", self.dtype)(h).astype(jnp.float32)
+        with scope("train/head"):
+            return _dense(cfg.vocab_size, "lm_head", self.dtype)(h).astype(jnp.float32)
 
 
 def nemotron_h_30b_a3b(cfg_overrides: dict | None = None, **kw) -> NemotronH:

@@ -116,12 +116,13 @@ class SdarAttention(nn.Module):
         h, hkv, dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype, name=name,
                                          kernel_init=nn.initializers.normal(stddev=0.02))
-        q = dense(h * dh, "wq")(x).reshape(b, p, h, dh)
-        k = dense(hkv * dh, "wk")(x).reshape(b, p, hkv, dh)
-        v = dense(hkv * dh, "wv")(x).reshape(b, p, hkv, dh)
-        q = rope(RMSNorm(cfg.rms_norm_eps, self.dtype, name="q_norm")(q), positions, cfg.rope_theta)
-        k = rope(RMSNorm(cfg.rms_norm_eps, self.dtype, name="k_norm")(k), positions, cfg.rope_theta)
-        q, k, v = (checkpoint_name(t, "attn_qkv") for t in (q, k, v))
+        with scope("attn/proj"):
+            q = dense(h * dh, "wq")(x).reshape(b, p, h, dh)
+            k = dense(hkv * dh, "wk")(x).reshape(b, p, hkv, dh)
+            v = dense(hkv * dh, "wv")(x).reshape(b, p, hkv, dh)
+            q = rope(RMSNorm(cfg.rms_norm_eps, self.dtype, name="q_norm")(q), positions, cfg.rope_theta)
+            k = rope(RMSNorm(cfg.rms_norm_eps, self.dtype, name="k_norm")(k), positions, cfg.rope_theta)
+            q, k, v = (checkpoint_name(t, "attn_qkv") for t in (q, k, v))
         if block_diffusion:
             with scope("attn/block_diffusion"):
                 o = dot_product_attention(
@@ -129,8 +130,10 @@ class SdarAttention(nn.Module):
                 )
         else:
             blk = jnp.arange(p) // cfg.block_length
-            o = _xla_masked_attention(q, k, v, blk[None, :] <= blk[:, None])
-        return dense(cfg.hidden_size, "wo")(o.reshape(b, p, h * dh))
+            with scope("attn/core"):
+                o = _xla_masked_attention(q, k, v, blk[None, :] <= blk[:, None])
+        with scope("attn/proj"):
+            return dense(cfg.hidden_size, "wo")(o.reshape(b, p, h * dh))
 
 
 class SdarBlock(nn.Module):
@@ -140,14 +143,19 @@ class SdarBlock(nn.Module):
     @nn.compact
     def __call__(self, x, positions, block_diffusion):
         cfg = self.cfg
-        y = RMSNorm(cfg.rms_norm_eps, self.dtype, name="ln1")(x)
-        x = x + SdarAttention(cfg, self.dtype, name="attn")(y, positions, block_diffusion)
-        y = RMSNorm(cfg.rms_norm_eps, self.dtype, name="ln2")(x)
-        return x + TopKMoe(
+        with scope("block/norm"):
+            y = RMSNorm(cfg.rms_norm_eps, self.dtype, name="ln1")(x)
+        y = SdarAttention(cfg, self.dtype, name="attn")(y, positions, block_diffusion)
+        with scope("block/norm"):
+            x = x + y
+            y = RMSNorm(cfg.rms_norm_eps, self.dtype, name="ln2")(x)
+        y = TopKMoe(
             cfg.num_experts, cfg.num_experts_per_tok, cfg.moe_intermediate_size,
             experts_held=cfg.experts_held, norm_topk_prob=cfg.norm_topk_prob,
             dtype=self.dtype, name="moe",
         )(y)
+        with scope("block/norm"):
+            return x + y
 
 
 class SdarMoe(nn.Module):
@@ -189,15 +197,17 @@ class SdarMoe(nn.Module):
             x = block_cls(cfg, self.dtype, name=f"block_{i}")(x, positions, block_diffusion)
         if block_diffusion:
             x = x[:, :length]          # the head reads the noisy half only
-        x = RMSNorm(cfg.rms_norm_eps, self.dtype, name="ln_final")(x)
+        with scope("block/norm"):
+            x = RMSNorm(cfg.rms_norm_eps, self.dtype, name="ln_final")(x)
         if return_hidden:
             return x
-        if cfg.tie_word_embeddings:
-            logits = jnp.einsum("bld,vd->blv", x, embed.astype(self.dtype))
-        else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head",
-                              kernel_init=nn.initializers.normal(stddev=0.02))(x)
-        return logits.astype(jnp.float32)
+        with scope("train/head"):
+            if cfg.tie_word_embeddings:
+                logits = jnp.einsum("bld,vd->blv", x, embed.astype(self.dtype))
+            else:
+                logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=self.dtype, name="lm_head",
+                                  kernel_init=nn.initializers.normal(stddev=0.02))(x)
+            return logits.astype(jnp.float32)
 
 
 def sdar_30b_a3b(cfg_overrides: dict | None = None, **kw) -> SdarMoe:

@@ -20,6 +20,7 @@ from typing import Any
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..obs.trace import scope
 from .layers import SelfAttention
 
 
@@ -48,18 +49,22 @@ class EncoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True):
-        y = nn.LayerNorm(dtype=self.dtype, name="ln1")(x)
+        with scope("block/norm"):
+            y = nn.LayerNorm(dtype=self.dtype, name="ln1")(x)
         y = SelfAttention(
             self.num_heads, causal=False, dtype=self.dtype,
             attn_layout=self.attn_layout, name="attn",
         )(y)
         y = nn.Dropout(self.dropout_rate)(y, deterministic=deterministic)
-        x = x + y
-        y = nn.LayerNorm(dtype=self.dtype, name="ln2")(x)
-        y = MlpBlock(self.mlp_dim, dtype=self.dtype, dropout_rate=self.dropout_rate, name="mlp")(
-            y, deterministic=deterministic
-        )
-        return x + y
+        with scope("block/norm"):
+            x = x + y
+            y = nn.LayerNorm(dtype=self.dtype, name="ln2")(x)
+        with scope("block/mlp"):
+            y = MlpBlock(self.mlp_dim, dtype=self.dtype, dropout_rate=self.dropout_rate, name="mlp")(
+                y, deterministic=deterministic
+            )
+        with scope("block/norm"):
+            return x + y
 
 
 class VisionTransformer(nn.Module):
@@ -127,9 +132,11 @@ class VisionTransformer(nn.Module):
                 name=f"block_{i}",
             )(x, not train)
 
-        x = nn.LayerNorm(dtype=self.dtype, name="ln_final")(x)
-        cls_repr = x[:, 0]
-        return nn.Dense(self.num_classes, dtype=jnp.float32, name="head")(cls_repr)
+        with scope("block/norm"):
+            x = nn.LayerNorm(dtype=self.dtype, name="ln_final")(x)
+        with scope("train/head"):
+            cls_repr = x[:, 0]
+            return nn.Dense(self.num_classes, dtype=jnp.float32, name="head")(cls_repr)
 
 
 def vit_b16(num_classes: int = 1000, cfg_overrides: dict | None = None, **kw) -> VisionTransformer:

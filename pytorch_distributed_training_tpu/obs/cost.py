@@ -21,6 +21,8 @@ a compressed DCN hop is visible as int8 all-gather payload.
 
 from __future__ import annotations
 
+import collections
+import functools
 import re
 from typing import Any
 
@@ -142,6 +144,123 @@ def mosaic_kernels(hlo_text: str) -> dict[str, int]:
     for name in _MOSAIC_CALL.findall(hlo_text):
         counts[name] = counts.get(name, 0) + 1
     return counts
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?([\w.\-]+) = ")
+_OPCODE = re.compile(r"(?:^|[\s)\]}])([a-z][\w\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_FUSED = re.compile(r"\bcalls=%?([\w.\-]+)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+
+
+@functools.lru_cache(maxsize=None)
+def _scoped() -> re.Pattern:
+    """An ``obs.trace.PHASES`` name standing as a scope in a path: after the
+    start, a ``/`` or a ``(``, before a ``/``, a ``)`` or the end.  (Made on
+    first use: this module is also loaded by file path, with no package and
+    no JAX — ``chip_smoke.py``.)"""
+    from .trace import PHASES
+
+    names = "|".join(re.escape(p) for p in sorted(PHASES, key=len, reverse=True))
+    return re.compile(f"(?:^|(?<=[/(]))({names})(?=[/)]|$)")
+
+
+# The work a fusion is put down to, whatever its root is.
+_HEAVY = ("dot", "convolution", "custom-call")
+# Instructions that gather or run other work: they take no scope from operands.
+_STRUCTURAL = ("tuple", "while", "conditional", "call")
+
+
+def innermost_scope(op_name: str) -> str | None:
+    """The ``obs.trace.PHASES`` name that ends rightmost in an instruction's
+    ``op_name`` — ``jit(step)/grad_accum/microbatch/transpose(jvp(moe/experts))/…/checkpoint/dot_general``
+    is ``moe/experts`` — through ``jvp(…)``, ``transpose(…)``, ``checkpoint``,
+    ``shard_map`` and ``pjit`` wrappers; of several paths joined by ``;``
+    the first; None where the path holds no such name."""
+    found = _scoped().findall(op_name.split(";", 1)[0])
+    return found[-1] if found else None
+
+
+def scopes_named(text: str) -> set[str]:
+    """Every ``obs.trace.PHASES`` name that stands as a scope anywhere in a
+    program's text, lowered (with its locations) or compiled."""
+    return set(_scoped().findall(text))
+
+
+def scope_table(hlo_text: str) -> dict[str, str | None]:
+    """``{instruction name: innermost scope}`` of a compiled program's text
+    (:func:`innermost_scope` of its ``op_name``), for every instruction that
+    can appear as a device event: those of the entry, of loop bodies and
+    conditions, of branches and of called computations — not the insides of
+    a fused computation.  Names are as the device trace has them, without
+    the ``%``.
+
+    A fusion is put down to its heaviest work, not its root: where its fused
+    computation holds a ``dot``, a ``convolution`` or a custom call under a
+    scope, that instruction's scope, else the root's.  (A weight-gradient
+    product is fused with the float32 add that accumulates it, and the add's
+    ``op_name`` ends in ``grad_accum/microbatch``.)
+
+    What the compiler made has no path of the program's: a fusion whose root
+    is the compiler's cast (the end of GPT-2's cross-entropy backward, 4.9 %
+    of its step) takes the scope most of its fused instructions carry, and
+    any other instruction with no scope of its own — a relayout ``copy``, a
+    ``reshape``, XLA's grouped matmul, whose rewrite names it
+    ``ragged-dot-none`` and nothing else — the scope most of its operands
+    have (the text lists operands before their users, so this runs along a
+    chain of such instructions; a ``tuple``, a loop, a branch or a call takes
+    none)."""
+    insides: dict[str, list] = {}      # computation -> [(name, opcode, scope, is_root, callee, operands)]
+    inside = None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            head = _COMPUTATION.match(line)
+            if head:
+                inside = insides.setdefault(head.group(1), [])
+            continue
+        if inside is None:
+            continue
+        rest = line[m.end():]
+        opcode = _OPCODE.search(rest)
+        operands = _OPERAND.findall(rest[opcode.end():rest.find(")", opcode.end())]) if opcode else []
+        opcode = opcode.group(1) if opcode else ""
+        op_name = _OP_NAME.search(rest)
+        callee = _FUSED.search(rest) if opcode == "fusion" else None
+        inside.append((
+            m.group(2), opcode, innermost_scope(op_name.group(1)) if op_name else None,
+            bool(m.group(1)), callee.group(1) if callee else None, operands,
+        ))
+    fused = {callee for rows in insides.values() for *_, callee, _ in rows if callee}
+
+    def most(scopes):
+        return collections.Counter(scopes).most_common(1)[0][0] if scopes else None
+
+    table: dict[str, str | None] = {}
+    for computation, rows in insides.items():
+        if computation in fused:
+            continue
+        for name, opcode, scope, _, callee, operands in rows:
+            if callee:
+                body = insides.get(callee, ())
+                heavy = [s for _, op, s, *_ in body if op in _HEAVY and s]
+                root = [s for _, _, s, is_root, *_ in body if is_root and s]
+                scope = (heavy or root or [scope])[0] or most([s for _, _, s, *_ in body if s])
+            if scope is None and opcode not in _STRUCTURAL:
+                scope = most([table[o] for o in operands if table.get(o)])
+            table[name] = scope
+    return table
+
+
+def scope_census(hlo_text: str) -> dict[str, int]:
+    """Instructions by scope in a compiled program's text (:func:`scope_table`;
+    ``none`` holds those under no scope): what a trace's events, laid against
+    the table, can land in."""
+    census: dict[str, int] = {}
+    for scope in scope_table(hlo_text).values():
+        census[scope or "none"] = census.get(scope or "none", 0) + 1
+    return census
 
 
 def mosaic_custom_calls(hlo_text: str) -> int:

@@ -49,9 +49,14 @@ PHASES = (
     "train/host_sync",       # a loss fetch: the host waits for the device
     "train/loss",            # forward + loss of one microbatch (in the program)
     "train/optimizer",       # the update gate: optimizer + apply (in the program)
+    "train/head",            # the head's product (a classifier's too) and the cross entropy
     "train/noise",           # block diffusion: the step's noise and its 2L input
+    "block/norm",            # the residual stream's norms and adds (not a head's q/k norms)
+    "block/mlp",             # a dense MLP: up / gate product, activation, down product
+    "attn/proj",             # q/k/v/o projections, rotation, per-head norms (outside MLA)
+    "attn/core",             # the attention product itself, flash or XLA, under any mask
     "attn/block_diffusion",  # attention under the block-diffusion mask
-    "attn/mla",              # latent attention: projections, norms, rotation, output gate
+    "attn/mla",              # latent attention: projections, norms, rotation, output gate, W_o
     "moe/route",             # top-k router: scores, top-k, grouping by expert
     "moe/experts",           # the held experts: row gather, grouped products, combine
     "moe/shared",            # the shared experts' MLP, every token's
@@ -59,6 +64,7 @@ PHASES = (
     "ssm/conv",              # Mamba-2 mixer: the causal depthwise convolution and its SiLU
     "ssm/scan",              # Mamba-2 mixer: steps, decays, the chunked recurrence, the D skip
     "ssm/gate",              # Mamba-2 mixer: the output gate and the grouped RMS norm
+    "ssm/proj",              # Mamba-2 mixer: the in- and the out-projection
     "grad_accum/microbatch",  # fwd+bwd of one accumulation microbatch
     "grad_sync/rs_ici",      # tier 1: reduce-scatter over ICI
     "grad_sync/ar_dcn",      # tier 2: cross-slice all-reduce over DCN

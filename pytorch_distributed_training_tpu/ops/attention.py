@@ -20,6 +20,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..obs.trace import scope
+
 
 @jax.custom_vjp
 def _softmax_lowp(logits: jax.Array) -> jax.Array:
@@ -361,6 +363,9 @@ def dot_product_attention(
     if use_flash is None:
         use_flash = flash_preferred(q.shape[1], k.shape[1], q.shape[3])
     attend = flash_attention if use_flash else _xla_path
-    return attend(
-        q, k, v, causal=causal, scale=scale, block_diffusion=block_diffusion
-    )
+    # The one place every model's attention product is named (an enclosing
+    # ``attn/block_diffusion`` stays in the path; this, the inner name, wins).
+    with scope("attn/core"):
+        return attend(
+            q, k, v, causal=causal, scale=scale, block_diffusion=block_diffusion
+        )

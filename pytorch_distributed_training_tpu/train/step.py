@@ -10,16 +10,20 @@ in-place param/opt-state update semantics without the mutation.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from flax.traverse_util import flatten_dict
 
+from ..compat import ambient_mesh
+from ..obs.cost import scope_table, scopes_named
 from ..obs.trace import scope
 from ..ops.losses import chunked_lm_cross_entropy, cross_entropy_loss
 from ..parallel.grad_accum import accumulate_gradients
 from ..resilience.anomaly import guarded_apply
+from ..utils.compile_cache import compile_phase
 from . import block_diffusion
 from .policy import Policy
 from .state import TrainState
@@ -35,6 +39,90 @@ STEP_COUNTERS = ("moe_held_assignments", "moe_load_max", "masked_tokens")
 # step's microbatches; obs/schema.py says which carries its weight):
 # published where the counters are.
 STEP_LOSS_PARTS = ("mtp_loss", "moe_balance_loss")
+
+
+# Which compiled step a device trace shows: while a capture is open
+# (``Trainer._profile_tick`` says when) a step made by ``make_train_step``
+# notes itself, the mesh it was called under and its arguments' abstract form,
+# once; ``step_scopes`` compiles that step for its text when somebody asks.
+_capture = {"open": False, "noted": None, "table": None}
+
+
+def note_capture(is_open: bool) -> None:
+    """A capture of the train loop opened (what ran in an earlier one is
+    forgotten) or closed."""
+    _capture["open"] = is_open
+    if is_open:
+        _capture["noted"] = _capture["table"] = None
+
+
+class _TracedStep:
+    """The jitted step as ``make_train_step`` hands it out: a call is the
+    jitted function's but for one flag test, and every other attribute
+    (``lower``, ``trace``, ...) is the jitted function's own."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+
+    def __getattr__(self, name):
+        if name == "_jitted":       # a copy in the making: nothing to hand on yet
+            raise AttributeError(name)
+        return getattr(self._jitted, name)
+
+    def __call__(self, state, batch):
+        if _capture["open"] and _capture["noted"] is None:
+            # Shapes, dtypes and shardings only (an uncommitted array's is
+            # JAX's to choose, as in the call): the state is donated, and
+            # nothing live may outlast the call.
+            _capture["noted"] = (self._jitted, ambient_mesh()[0], jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, weak_type=x.weak_type,
+                    sharding=x.sharding if x.committed else None),
+                (state, batch),
+            ))
+        return self._jitted(state, batch)
+
+
+def step_scopes() -> dict[str, str | None] | None:
+    """``obs.cost.scope_table`` of the train step that ran inside the last
+    capture (instruction name -> innermost ``obs.trace.PHASES`` scope), or
+    None if none ran in one.  Made on the first request, under the compile
+    phase ``trace/scopes``: by then the loop has returned, and lowering and
+    compiling the step again for the same shapes answers from JAX's in-memory
+    caches — the executable that ran, no second one.
+
+    One thing can be wrong with that executable's text.  The persistent
+    cache's key leaves ``op_name`` metadata out, so the entry a run LOADED may
+    be another tree's, with that tree's scopes under the same instruction
+    names.  This tree's scopes are in the lowered text's locations; where one
+    of them is nowhere in the compiled text, the step is compiled once more
+    under another function object, the metadata in the key (a tree's first
+    such request compiles, its later ones load that entry)."""
+    if _capture["noted"] is None:
+        return None
+    if _capture["table"] is None:
+        jitted, mesh, args = _capture["noted"]
+        with compile_phase("trace/scopes"), mesh or contextlib.nullcontext():
+            lowered = jitted.lower(*args)
+            text = lowered.compile().as_text()
+            if scopes_named(lowered.as_text(debug_info=True)) - scopes_named(text):
+                text = _compiled_with_its_own_names(jitted.__wrapped__, args)
+        _capture["table"] = scope_table(text)
+    return _capture["table"]
+
+
+def _compiled_with_its_own_names(fun, args) -> str:
+    """The text of ``fun`` compiled anew for ``args`` (a new function object,
+    so no in-memory cache answers), with this tree's ``op_name``s: the
+    persistent cache keyed by the metadata too while it compiles."""
+    keyed = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, keyed)
+    jax.config.update(keyed, True)
+    try:
+        again = jax.jit(lambda state, batch: fun(state, batch), donate_argnums=0)
+        return again.lower(*args).compile().as_text()
+    finally:
+        jax.config.update(keyed, before)
 
 
 def prepare_image_input(
@@ -204,9 +292,10 @@ def make_train_step(
             logits, new_stats, aux_l, stats = _forward(
                 state, params, image, train=True, rng=rng, policy=policy
             )
-            loss = cross_entropy_loss(
-                logits, batch["label"], label_smoothing=label_smoothing
-            )
+            with scope("train/head"):
+                loss = cross_entropy_loss(
+                    logits, batch["label"], label_smoothing=label_smoothing
+                )
             acc = jnp.mean(jnp.argmax(logits, -1) == batch["label"])
             return loss + aux_loss_weight * aux_l, {
                 "accuracy": acc, "batch_stats": new_stats, **stats,
@@ -227,7 +316,8 @@ def make_train_step(
                 state, params, both, train=True, rng=None, policy=policy,
                 block_diffusion=True,
             )
-            loss = block_diffusion.weighted_masked_ce(logits, tokens, masked, p)
+            with scope("train/head"):
+                loss = block_diffusion.weighted_masked_ce(logits, tokens, masked, p)
             return loss + aux_loss_weight * aux_l, {
                 "batch_stats": new_stats, **stats,
                 "masked_tokens": jnp.sum(masked).astype(jnp.float32),
@@ -242,8 +332,9 @@ def make_train_step(
                 state, params, tokens, train=True, rng=rng, policy=policy,
                 mtp=True,
             )
-            loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
-            with scope("train/mtp"):
+            with scope("train/head"):
+                loss = cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+            with scope("train/mtp"), scope("train/head"):
                 mtp_loss = cross_entropy_loss(mtp_logits[:, :-2], tokens[:, 2:])
             balance = model_cfg.seq_aux_alpha * aux_l
             return loss + model_cfg.mtp_loss_weight * mtp_loss + balance, {
@@ -262,21 +353,23 @@ def make_train_step(
                     state, params, tokens, train=True, rng=rng, policy=policy,
                     return_hidden=True,
                 )
-                loss = chunked_lm_cross_entropy(
-                    hidden[:, :-1],
-                    _lm_head_matrix(params, policy),
-                    tokens[:, 1:],
-                    chunk_size=lm_loss_chunk,
-                    label_smoothing=label_smoothing,
-                )
+                with scope("train/head"):
+                    loss = chunked_lm_cross_entropy(
+                        hidden[:, :-1],
+                        _lm_head_matrix(params, policy),
+                        tokens[:, 1:],
+                        chunk_size=lm_loss_chunk,
+                        label_smoothing=label_smoothing,
+                    )
             else:
                 logits, new_stats, aux_l, stats = _forward(
                     state, params, tokens, train=True, rng=rng, policy=policy
                 )
-                loss = cross_entropy_loss(
-                    logits[:, :-1], tokens[:, 1:],
-                    label_smoothing=label_smoothing,
-                )
+                with scope("train/head"):
+                    loss = cross_entropy_loss(
+                        logits[:, :-1], tokens[:, 1:],
+                        label_smoothing=label_smoothing,
+                    )
             return loss + aux_loss_weight * aux_l, {
                 "batch_stats": new_stats, **stats,
             }
@@ -336,7 +429,7 @@ def make_train_step(
         return state, metrics
 
     if state_shardings is None:
-        return jax.jit(train_step, donate_argnums=0)
+        return _TracedStep(jax.jit(train_step, donate_argnums=0))
 
     def pinned_step(state: TrainState, batch: Any):
         new_state, metrics = train_step(state, batch)
@@ -345,7 +438,7 @@ def make_train_step(
             metrics,
         )
 
-    return jax.jit(pinned_step, donate_argnums=0)
+    return _TracedStep(jax.jit(pinned_step, donate_argnums=0))
 
 
 def make_eval_step(

@@ -37,7 +37,7 @@ from ..obs.trace import phase_span, step_annotation
 from ..parallel.sharding import shard_batch
 from ..utils.compile_cache import compile_events, compile_phase, compile_totals
 from .state import TrainState
-from .step import STEP_COUNTERS, STEP_LOSS_PARTS
+from .step import STEP_COUNTERS, STEP_LOSS_PARTS, note_capture
 
 
 @dataclasses.dataclass
@@ -162,6 +162,7 @@ class Trainer:
         if not self._profiling and start <= self._global_step < stop:
             jax.profiler.start_trace(cfg.profile_dir)
             self._profiling = True
+            note_capture(True)     # the step that runs now is the trace's (train/step.py)
             if self.emitter is not None:
                 self.emitter.phase(
                     "profile_start", step=self._global_step
@@ -183,6 +184,7 @@ class Trainer:
                 ):
                     float(metrics["loss"])
             jax.profiler.stop_trace()
+            note_capture(False)
             self._profiling = False
             self._profile_done = True
             if self.emitter is not None:
@@ -195,6 +197,7 @@ class Trainer:
         # partial xprof sessions.
         if self._profiling:
             jax.profiler.stop_trace()
+            note_capture(False)
             self._profiling = False
             self._profile_done = True
             if self.emitter is not None:

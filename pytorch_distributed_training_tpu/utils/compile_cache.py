@@ -55,6 +55,7 @@ COMPILE_PHASES = (
     "startup/restore",   # CLI: restoring a checkpoint
     "startup/step",      # CLI: building and probing the train step
     "train/epoch",       # Trainer.run_epoch, with the epoch's number
+    "trace/scopes",      # train/step.py::step_scopes: the traced step compiled for its text
 )
 
 _events: list[dict] = []

@@ -573,6 +573,17 @@ def test_cli_train_metrics_dir_smoke(tmp_path):
     assert "compiled_cost" in kinds
     cost = next(e for e in events if e["kind"] == "compiled_cost")
     assert cost["flops"] > 0 and cost["bytes_accessed"] > 0
+    # ... and from the probe's own compile the census of the step's scopes
+    # (PR 36): instructions by the innermost obs.trace.PHASES name.  Only
+    # the names older than the session's compile cache can be are asked
+    # for: the cache's key leaves op_name out, so the executable the probe
+    # LOADS may carry an older tree's scopes (train/step.py::step_scopes).
+    scopes = next(
+        e for e in events
+        if e["kind"] == "record" and e.get("record") == "step_scopes"
+    )["scopes"]
+    assert {"train/optimizer", "train/loss", "none"} <= set(scopes)
+    assert all(isinstance(n, int) and n > 0 for n in scopes.values())
     steps = [e for e in events if e["kind"] == "step"]
     assert len(steps) == 4
 

@@ -829,6 +829,8 @@ def _vocabulary(name):
         "train/optimizer", "train/noise", "attn/block_diffusion",
         "attn/mla", "moe/route", "moe/experts", "moe/shared", "train/mtp",
         "ssm/conv", "ssm/scan", "ssm/gate",
+        # PR 36: where the time is and no name was (the scope table's shares)
+        "train/head", "block/norm", "block/mlp", "attn/proj", "attn/core", "ssm/proj",
         "grad_accum/microbatch",
         "grad_sync/rs_ici", "grad_sync/ar_dcn", "grad_sync/ag_ici",
         "grad_sync/stripe",
@@ -843,6 +845,7 @@ def _vocabulary(name):
     }),
     ("COMPILE_PHASES", {
         "startup/state", "startup/restore", "startup/step", "train/epoch",
+        "trace/scopes",
     }),
     ("KERNEL_NAMES", {
         "flash_fwd", "flash_bwd", "flash_bwd_dq", "flash_bwd_dkv",
