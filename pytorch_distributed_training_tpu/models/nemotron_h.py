@@ -12,8 +12,12 @@ convolution's.
 - **M** (H heads of P, d_inner = H·P, G groups, state N, kernel K):
   ``[z | xBC | dt] = u W_in`` (d_inner | d_inner + 2·G·N | H).  ``xBC ←
   silu(b_c + Σ_{j<K} w_{c,j} · xBC_{t-K+1+j, c})``: causal, depthwise, zeros
-  before the sequence (:func:`causal_conv`).  ``xBC → x (T, H, P), B, C (T,
-  G, N)``; head h reads group ``h // (H / G)``.  ``Δ = softplus(dt +
+  before the sequence (``ops/causal_conv.causal_conv_silu``: at lane-aligned
+  sizes the ``causal_conv_fwd`` / ``causal_conv_bwd`` Pallas pair, which
+  reads ``xBC`` in place in the projection's result and hands back ``x``,
+  ``B`` and ``C``; at toy sizes ``causal_conv``'s ``jnp`` form:
+  ``ops/causal_conv.conv_plan`` decides from the shapes).  ``xBC → x (T, H,
+  P), B, C (T, G, N)``; head h reads group ``h // (H / G)``.  ``Δ = softplus(dt +
   dt_bias)`` (``time_step_limit`` (0, ∞): no clamp), ``A = -exp(A_log)``,
   ``S_t = exp(Δ_t A) S_{t-1} + Δ_t x_t ⊗ B_t``, ``y_t = S_t C_t + D x_t``
   (``ops/ssd.ssd_chunked`` in chunks of ``chunk_size``; the state and the
@@ -55,6 +59,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.trace import scope
 from ..ops.attention import dot_product_attention
+from ..ops.causal_conv import causal_conv_silu
 from ..ops.ssd import ssd_chunked
 from .instella_moe import _dense
 from .moe import TopKMoe, relu2
@@ -115,15 +120,6 @@ class NemotronHConfig:
         return self.hybrid_override_pattern[: self.num_hidden_layers]
 
 
-def causal_conv(x, w, bias):
-    """Depthwise causal convolution over time: x (B, T, C), w (K, C), bias
-    (C,) → ``bias + Σ_j w[j] · x[t - K + 1 + j]`` with zeros before the
-    sequence, float32."""
-    k, t = w.shape[0], x.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-    return bias + sum(w[j] * padded[:, j:j + t] for j in range(k))
-
-
 def _dt_bias_init(cfg: NemotronHConfig):
     """Steps log-uniform in [time_step_min, time_step_max], floored, through
     the inverse of the softplus (Mamba-2's initialisation)."""
@@ -141,8 +137,11 @@ class Mamba2Mixer(nn.Module):
     custom calls under ``ssm/scan`` (``ssd_fwd``, ``ssd_bwd``; no (Q, Q)
     block, running sum or chunk state of the forward in HBM) where
     ``ssd_plan`` finds lane-aligned shapes, plain XLA operations otherwise.
-    The convolution, the D skip, the gate and the grouped norm are XLA's
-    either way."""
+    The convolution and its SiLU are ``ops/causal_conv.causal_conv_silu``:
+    two more under ``ssm/conv`` (``causal_conv_fwd``, ``causal_conv_bwd``; no
+    float32 copy of ``xBC`` in HBM) where ``conv_plan`` finds them, the
+    ``jnp`` form otherwise.  The D skip, the gate and the grouped norm are
+    XLA's either way."""
 
     cfg: NemotronHConfig
     dtype: Any = jnp.float32
@@ -162,15 +161,16 @@ class Mamba2Mixer(nn.Module):
         conv_b = self.param("conv_b", uniform, (wide,))
         scale = self.param("norm", nn.initializers.ones, (inner,), jnp.float32)
 
-        with scope("ssm/proj"):
-            z, xbc, dt = jnp.split(_dense(inner + wide + h, "in_proj", self.dtype)(u), [inner, inner + wide], axis=-1)
-        bsz, t, _ = z.shape
         p, n = cfg.mamba_head_dim, cfg.ssm_state_size
+        with scope("ssm/proj"):
+            projected = _dense(inner + wide + h, "in_proj", self.dtype)(u)        # [z | xBC | dt]
+            z, dt = projected[..., :inner], projected[..., inner + wide:]
+        bsz, t, _ = z.shape
         with scope("ssm/conv"):
-            xbc = nn.silu(causal_conv(xbc, conv_w, conv_b)).astype(xbc.dtype)
+            x, b, c = causal_conv_silu(projected, conv_w, conv_b, offset=inner, splits=(inner, g * n, g * n))
         with scope("ssm/scan"):
-            x = xbc[..., :inner].reshape(bsz, t, h, p)
-            b, c = (m.reshape(bsz, t, g, n) for m in jnp.split(xbc[..., inner:], 2, axis=-1))
+            x = x.reshape(bsz, t, h, p)
+            b, c = b.reshape(bsz, t, g, n), c.reshape(bsz, t, g, n)
             y = ssd_chunked(
                 x, jax.nn.softplus(dt.astype(jnp.float32) + dt_bias), -jnp.exp(a_log), b, c,
                 chunk=cfg.chunk_size,

@@ -158,6 +158,17 @@ METRICS: dict[str, dict] = {
                 "kernels do not target); ops/ssd.ssd_plan decides from the "
                 "shapes and the backend alone",
     },
+    "conv_plan": {
+        "type": GAUGE, "labeled": True,
+        "help": "causal convolution + SiLU call sites (a Mamba-2 mixer's) "
+                "traced so far, per kind: pallas (the causal_conv_fwd / "
+                "causal_conv_bwd Mosaic pair, whose counts per program are "
+                "mosaic_custom_calls[kernel=causal_conv_fwd|causal_conv_bwd]) "
+                "or xla (the jnp form: channels, column spans or a length "
+                "that miss the tiles, a kernel past 8 taps, or a backend the "
+                "kernels do not target); ops/causal_conv.conv_plan decides "
+                "from the shapes and the backend alone",
+    },
     # ---- SLO / alerting plane (obs/slo.py) ------------------------------
     "slo_alert_transitions": {
         "type": COUNTER, "labeled": False,

@@ -358,7 +358,8 @@ def test_a_mixers_gradient_lowers_for_four_v5es(monkeypatch, data, tensor):
     ``shard_map`` (as ``ops/attention`` puts flash).  ``jax.grad`` through one
     ``Mamba2Mixer`` layer at lane-aligned sizes, compiled for a described
     2x2 of v5es with the batch sharded: a chip's shard of the batch — and,
-    with ``tensor`` 2, of the groups — in each call's results."""
+    with ``tensor`` 2, of the groups — in each call's results; and the
+    convolution's pair (``ops/causal_conv``) in its own, the channels whole."""
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -380,12 +381,68 @@ def test_a_mixers_gradient_lowers_for_four_v5es(monkeypatch, data, tensor):
     with mesh:
         calls, _ = mosaic_calls_compiled_for(
             jax.grad(loss), jax.tree.map(on(P()), params), on(P(comm.mesh.BATCH_AXES))(tokens))
-    results = {role: result for name, result in calls for role in ("ssd_fwd", "ssd_bwd") if role in name}
-    assert sorted(results) == ["ssd_bwd", "ssd_fwd"] and len(calls) == 2, calls
+    roles = ("causal_conv_fwd", "causal_conv_bwd", "ssd_fwd", "ssd_bwd")
+    bare = lambda result: re.sub(r"\{[^}]*\}", "", result.replace(" ", ""))        # shapes without their layouts
+    results = {role: bare(result) for name, result in calls for role in roles if role in name}
+    assert sorted(results) == sorted(roles) and len(calls) == 4, calls
     rows, groups = 4 // data, MIXER["n_groups"] // tensor
     width = groups * (MIXER["mamba_num_heads"] // MIXER["n_groups"]) * MIXER["mamba_head_dim"]
-    assert results["ssd_fwd"].replace(" ", "").startswith(f"(bf16[{rows},256,{width}]"), results
+    assert results["ssd_fwd"].startswith(f"(bf16[{rows},256,{width}]"), results
     assert results["ssd_bwd"].count(f"bf16[{rows},256,{groups * MIXER['ssm_state_size']}]") == 2, results
+    # the convolution's pair (ISSUE 37) in its own ``shard_map``: a chip's rows, the channels whole — x, B and C
+    # leave it as the in-projection's columns, which a split over ``tensor`` would cut elsewhere
+    inner, shared = MIXER["mamba_num_heads"] * MIXER["mamba_head_dim"], MIXER["n_groups"] * MIXER["ssm_state_size"]
+    assert results["causal_conv_fwd"].startswith(
+        f"(bf16[{rows},256,{inner}]" + 2 * f",bf16[{rows},256,{shared}]"), results
+    assert results["causal_conv_bwd"].startswith(
+        f"(bf16[{rows},256,{inner + 2 * shared}],f32[{rows},4,{inner + 2 * shared}]"), results
+
+
+def test_the_convolutions_pair_compiles_for_a_v5e_at_the_cells_shape(one_v5e):
+    """``ops/causal_conv.py``'s pair at the Nemotron-H cell's call (kept in
+    this file with the other compiles for a described chip): 8192 positions,
+    ``xBC`` read in place at columns [4096, 10240) of the in-projection's
+    10,304, K 4; ``x``, ``B`` and ``C`` come back as three arrays, the
+    stream's cotangent as one."""
+    from pytorch_distributed_training_tpu.ops import causal_conv as cc
+
+    length, wide, offset, splits, kernel = 8192, 10304, 4096, (4096, 1024, 1024), 4
+    plan = cc.conv_plan(length, sum(splits), kernel, 2, offset=offset, splits=splits, backend="tpu")
+    assert plan.kind == "pallas" and not plan.interpret
+
+    def pair(x, w, bias):
+        y, vjp = jax.vjp(lambda *inputs: cc._conv_pallas(*inputs, offset, splits, plan.time_tile,
+                                                         plan.channel_tile, False), x, w, bias)
+        return y + vjp(y)
+
+    shape = lambda dims, kind: jax.ShapeDtypeStruct(dims, kind, sharding=one_v5e)
+    calls, _ = mosaic_calls_compiled_for(pair, shape((1, length, wide), jnp.bfloat16),
+                                         shape((kernel, sum(splits)), jnp.float32), shape((sum(splits),), jnp.float32))
+    bare = lambda result: re.sub(r"\{[^}]*\}", "", result.replace(" ", ""))
+    results = {role: bare(result) for name, result in calls for role in cc.KERNEL_NAMES if role in name}
+    assert sorted(results) == ["causal_conv_bwd", "causal_conv_fwd"] and len(calls) == 2, calls
+    assert results["causal_conv_fwd"].startswith("(bf16[1,8192,4096]") and results["causal_conv_fwd"].count("bf16[1,8192,1024]") == 2
+    assert results["causal_conv_bwd"].startswith("(bf16[1,8192,6144]") and "f32[1,4,6144]" in results["causal_conv_bwd"]
+
+
+@pytest.mark.parametrize("data, tensor", [(2, 2), (4, 1), (1, 4)])
+def test_under_a_mesh_of_several_devices_the_convolutions_pair_runs_a_shard(data, tensor):
+    """Under GSPMD the pair goes into a ``shard_map``, the batch over
+    ``data`` where it divides (4 rows: 2 or 4 ways; under ``tensor`` 4 every
+    device runs the whole): ``y`` and the three gradients equal the
+    unsharded call's, ``dw`` and ``db`` summed over the batch's shards."""
+    from pytorch_distributed_training_tpu import comm
+    from test_causal_conv_pallas import NAMES, conv_inputs, gradients, pair
+
+    args = conv_inputs(4, bsz=4, t=256)
+    both = lambda *inputs: pair(*inputs) + gradients(pair, inputs)
+    want = both(*args)
+    mesh = comm.make_mesh(comm.MeshConfig(data=data, tensor=tensor), devices=jax.devices()[:data * tensor])
+    with mesh:
+        got = jax.jit(both)(*args)
+        assert "shard_map" in str(jax.make_jaxpr(both)(*args))
+    for name, g_, w_ in zip(["x", "B", "C"] + ["d" + n for n in NAMES], got, want):
+        np.testing.assert_allclose(g_, w_, rtol=1e-6, atol=1e-6 * float(jnp.abs(w_).max()), err_msg=name)
 
 
 @pytest.mark.parametrize("causal", [False, True])

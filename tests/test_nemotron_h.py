@@ -18,6 +18,7 @@ from benchmark.reference import nemotron_h as ref
 from pytorch_distributed_training_tpu import models, train
 from pytorch_distributed_training_tpu.models import moe, nemotron_h as nh
 from pytorch_distributed_training_tpu.ops import attention, pallas_attention as pa
+from pytorch_distributed_training_tpu.ops.causal_conv import causal_conv
 from pytorch_distributed_training_tpu.ops.losses import cross_entropy_loss
 from pytorch_distributed_training_tpu.ops.ssd import ssd_chunked
 
@@ -96,14 +97,14 @@ def test_chunked_scan_says_why_a_ragged_length_is_refused():
 def test_the_convolution_is_causal_and_depthwise():
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 16, 6))
     w, bias = jax.random.normal(jax.random.PRNGKey(1), (4, 6)), jax.random.normal(jax.random.PRNGKey(2), (6,))
-    y = nh.causal_conv(x, w, bias)
+    y = causal_conv(x, w, bias)
     for t in (0, 2, 9):            # by the definition, zeros before the sequence
         want = bias + sum(w[j] * (x[0, t - 3 + j] if t - 3 + j >= 0 else 0.0) for j in range(4))
         np.testing.assert_allclose(y[0, t], want, rtol=1e-5, atol=1e-6)
-    later = nh.causal_conv(x.at[0, 9:, :].add(1.0), w, bias)       # a later token moves
+    later = causal_conv(x.at[0, 9:, :].add(1.0), w, bias)       # a later token moves
     np.testing.assert_array_equal(later[0, :9], y[0, :9])           # ... and no earlier output does
     assert float(jnp.abs(later[0, 9] - y[0, 9]).min()) > 0
-    other = nh.causal_conv(x.at[0, :, 3].add(1.0), w, bias)         # a channel reads itself only
+    other = causal_conv(x.at[0, :, 3].add(1.0), w, bias)         # a channel reads itself only
     np.testing.assert_array_equal(jnp.delete(other, 3, axis=-1), jnp.delete(y, 3, axis=-1))
     np.testing.assert_allclose(ref.convolution(x[0], w, bias), y[0], rtol=1e-5, atol=1e-6)
 
@@ -403,6 +404,7 @@ def test_cli_trains_the_toy_size(tmp_path):
     assert "training started" in out.stdout
     recorded = "".join(p.read_text() for p in tmp_path.rglob("*") if p.is_file())
     # ... and which form of the scan the mixers took: the toy's shapes miss the lane tile
-    for name in ("moe_held_assignments", "moe_load_max", "ssd_plan[kind=xla]"):
+    # (and of the convolution before it: ``conv_plan``)
+    for name in ("moe_held_assignments", "moe_load_max", "ssd_plan[kind=xla]", "conv_plan[kind=xla]"):
         assert name in recorded, name
-    assert "ssd_plan[kind=pallas]" not in recorded
+    assert "ssd_plan[kind=pallas]" not in recorded and "conv_plan[kind=pallas]" not in recorded
