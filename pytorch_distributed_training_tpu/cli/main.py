@@ -2430,6 +2430,7 @@ def _probe_compiled_cost(trainer, batches, mesh, sequence_parallel, emitter):
     from ..obs import step_cost_report
     from ..obs.cost import scope_census
     from ..ops.causal_conv import conv_plans_traced
+    from ..ops.grouped_matmul import grouped_plans_traced
     from ..ops.pallas_attention import flash_visited_pair_share
     from ..ops.ssd import ssd_plans_traced
     from ..parallel.sharding import shard_batch
@@ -2473,7 +2474,8 @@ def _probe_compiled_cost(trainer, batches, mesh, sequence_parallel, emitter):
                 emitter.gauge(
                     f"flash_visited_pair_share[kernel={kernel}]", share
                 )
-            for gauge, traced in (("ssd_plan", ssd_plans_traced), ("conv_plan", conv_plans_traced)):
+            for gauge, traced in (("ssd_plan", ssd_plans_traced), ("conv_plan", conv_plans_traced),
+                                  ("grouped_plan", grouped_plans_traced)):
                 for kind, sites in traced().items():
                     emitter.gauge(f"{gauge}[kind={kind}]", sites)
             # Feed the live MFU gauge: the probe's compiled FLOPs + peak

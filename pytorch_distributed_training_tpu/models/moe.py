@@ -22,11 +22,20 @@ mesh reserves an ``expert`` axis and a complete framework fills it.
   parallelism asks of a chip before the exchange, and nothing standing in
   for the absent chips.  Assignments are sorted by expert and multiplied
   under a loop whose trip count follows the assignments there are (no
-  static bound, nothing to overflow): as grouped matrix products
-  (``lax.ragged_dot``: on a TPU XLA's own Mosaic grouped matmul) in chunks
-  of ``ROWS_CHUNK`` rows, or, where the layer is constructed with an
-  ``expert_window``, as plain products: one a window of that many sorted rows
-  and expert with rows in it.
+  static bound, nothing to overflow), ``ROWS_CHUNK`` sorted rows a pass as
+  grouped matrix products — by ``ops/grouped_matmul.py``'s plan either its
+  Pallas kernels (both widths multiples of the lane tile: SDAR's 2048 / 768,
+  Instella's 2048 / 1408), which are told where each expert's rows lie, skip
+  the pass's dead tiles, run the gate, the activation and the router weight
+  in the products' epilogues, read a stack as it lies for the data gradient,
+  write each expert's weight gradient once and add the rows to their tokens
+  from VMEM, or ``lax.ragged_dot`` and XLA's scatter-add (on a TPU XLA's own
+  Mosaic grouped matmul: any other width, the toy sizes) — or,
+  where the layer is constructed with an ``expert_window``, as plain
+  products: one a window of that many sorted rows and expert with rows in
+  it (Nemotron-H: its 1856-wide experts miss the lane tile, and a plain
+  product over a few hundred rows runs near the matrix unit's pace where
+  ``ragged_dot`` does not).
 """
 
 from __future__ import annotations
@@ -41,6 +50,9 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..obs.trace import scope
+from ..ops.grouped_matmul import (
+    DGRAD, REFWD, add_rows, grouped_matmul, grouped_plan, grouped_products, grouped_weight_grad,
+)
 
 
 def _constrain_for_ep(x: jax.Array, spec: P) -> jax.Array:
@@ -363,6 +375,86 @@ def _expert_chunk(x, w_rows, product, act, *ws):
     return product(h, w_down) * w_rows[:, None].astype(x.dtype)
 
 
+# Where the grouped products are the kernels' (``ops/grouped_matmul.py``), what
+# follows a product row by row runs in the kernel that made it, on the run of
+# rows still in VMEM and in float32: the activation and the gate, the router
+# weight, and in the backward their derivatives.  Each is a product's
+# ``epilogue(products, extras)``; made once an activation, because the jitted
+# launcher knows an epilogue by the object it is.
+
+
+@functools.lru_cache(maxsize=None)
+def _into_experts(act):
+    """``h = act(x·W_gate) ⊙ (x·W_up)``, or ``act(x·W_up)`` of one product."""
+    return lambda products, _: (ACTIVATIONS[act](products[0]) * products[1] if len(products) == 2
+                                else ACTIVATIONS[act](products[0]),)
+
+
+def _weighted(products, extras):
+    """``(h·W_down) ⊙ w``: the row's router weight."""
+    return (products[0] * extras[0],)
+
+
+@functools.lru_cache(maxsize=None)
+def _into_experts_again(act):
+    """The backward's second look at :func:`_into_experts`, with ``d_h =
+    d_y·W_down^T`` (before the router weight) and the weight ``w`` the rows'
+    own: → ``(h ⊙ w, d_gate [, d_up], <d_h, h>)``, the last the router
+    weight's gradient, one number a row."""
+    def epilogue(products, extras):
+        d_h, w = extras
+        a, pull = jax.vjp(ACTIVATIONS[act], products[0])
+        h = a * products[1] if len(products) == 2 else a
+        d_w = jnp.sum(d_h * h, axis=-1, keepdims=True)
+        d_h = d_h * w
+        (d_a,) = pull(d_h * products[1] if len(products) == 2 else d_h)
+        return (h * w, d_a, *((d_h * a,) if len(products) == 2 else ()), d_w)
+    return epilogue
+
+
+def _summed(products, _):
+    return (sum(products),)
+
+
+def _expert_chunk_kernels(x, w_rows, sizes, act, ws):
+    """:func:`_expert_chunk` where the grouped products are the kernels':
+    two calls, the products into the experts' width with the activation and
+    the gate, and the product back with the router weight."""
+    *w_in, w_down = ws
+    wide = ((x.dtype.name, False),)
+    (h,) = grouped_products((x,), w_in, sizes, _into_experts(act), wide)
+    return grouped_products((h,), (w_down,), sizes, _weighted, wide, extras=(w_rows[:, None],))[0]
+
+
+def _expert_chunk_grads(x, w_rows, spans, act, ws, d_y, grads):
+    """:func:`_expert_chunk_kernels`' backward over one pass, written out:
+    d_y (C, d) is the pass's cotangent (whatever its dead rows hold); ``spans`` the
+    pass's ``(sizes, continued, continues)``; ``grads`` a ``(stack gradient,
+    carried float32 block)`` for each of ``ws`` → ``(d_x, d_w_rows, grads)``.
+    Three calls beside the weight gradients': ``d_y·W_down^T``; the products
+    into the experts' width AGAIN (no residual but the inputs; the product
+    back is not needed: the router weights' gradient is ``<d_y·W_down^T, h>``
+    a row) with every derivative of the activation, the gate and the weight;
+    and the data gradients of those products, summed, each meeting its stack
+    as it lies.  Each weight gradient is written into its stack by the
+    kernel, the groups that end in this pass (``grouped_weight_grad``).  What
+    the kernels left unwritten past the last live row stays in those rows,
+    which the caller masks."""
+    sizes = spans[0]
+    *w_in, w_down = ws
+    kind = x.dtype.name
+    d_h = grouped_matmul(d_y, w_down, sizes, transposed=True)
+    *wide, d_w = grouped_products(
+        (x,), w_in, sizes, _into_experts_again(act), ((kind, False),) * (1 + len(w_in)) + (("float32", True),),
+        extras=(d_h, w_rows[:, None]), name=REFWD)
+    hw, *d_in = wide
+    (d_x,) = grouped_products(d_in, w_in, sizes, _summed, ((kind, False),), lhs_of=range(len(w_in)), transposed=True,
+                              name=DGRAD)
+    pairs = [*((x, d) for d in d_in), (hw, d_y)]
+    return d_x, d_w[:, 0], tuple(grouped_weight_grad(lhs, rhs, sizes, *grad, *spans[1:])
+                                 for (lhs, rhs), grad in zip(pairs, grads))
+
+
 def _passes(order, counts, weights, rows, dense):
     """The passes over the sorted held assignments, ``rows`` sorted rows each,
     on ONE grid: window w is rows ``w·rows ...`` whatever experts they belong
@@ -376,9 +468,11 @@ def _passes(order, counts, weights, rows, dense):
 
     Returns ``(n, at, w_sorted, n_held)``: the number of passes that hold any
     assignment, ``at(c)`` → the pass's first sorted row, its rows' tokens and
-    router weights, which of its rows are live, its ``product`` and the
-    matrices of ``stacks`` that takes, and how to add a pass's weight
-    gradients to the stacks'."""
+    router weights, which of its rows are live, its ``(sizes, continued,
+    continues)`` — each held expert's rows in it, whether its first expert
+    began in the pass before and whether its last goes on in the next
+    (grouped; None dense) —, its ``product`` and the matrices of ``stacks``
+    that takes, and how to add a pass's weight gradients to the stacks'."""
     k = weights.shape[1]
     order = jnp.pad(order, (0, (-order.shape[0]) % rows))      # whole windows to slice from
     ends = jnp.cumsum(counts)
@@ -403,10 +497,12 @@ def _passes(order, counts, weights, rows, dense):
             at_row = lo + jnp.arange(rows)
             live = (at_row >= starts[e]) & (at_row < ends[e])
             ws = tuple(lax.dynamic_index_in_dim(w, e, keepdims=False) for w in stacks)
-            return lo, idx, w_rows, live, jnp.dot, ws, lambda total, g: total.at[e].add(g.astype(total.dtype))
+            return lo, idx, w_rows, live, None, jnp.dot, ws, lambda total, g: total.at[e].add(g.astype(total.dtype))
         live = lo + jnp.arange(rows) < n_held       # the last chunk's tail holds no assignment
         sizes = (jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)).astype(jnp.int32)
-        return (lo, idx, w_rows, live, lambda lhs, w: lax.ragged_dot(lhs, w, sizes), stacks,
+        # does the pass's first expert come from the pass before, does its last go on in the next
+        spans = (sizes, *(jnp.any((starts < edge) & (ends > edge)) for edge in (lo, lo + rows)))
+        return (lo, idx, w_rows, live, spans, functools.partial(grouped_matmul, sizes=sizes), stacks,
                 lambda total, g: total + g.astype(total.dtype))
 
     return n, at, w_sorted, n_held
@@ -417,12 +513,22 @@ def _passes(order, counts, weights, rows, dense):
 # a DYNAMIC trip count: the work follows the assignments that are there (none
 # is ever dropped: there is no bound to overflow, whatever the routing does),
 # as the expert FLOPs do, and the memory is a pass's.  Each pass gathers its
-# rows, runs the products (grouped: ``lax.ragged_dot``, which leaves the rows
-# past the last group unwritten; dense: one expert's, over a window that holds
-# other experts' rows too; both masked here) and scatter-adds into the tokens.
-# Its residuals are its inputs and the routing, so a rematerialized block's
-# second forward computes no expert product at all (the backward below
-# recomputes a pass's products where it needs them).
+# rows, runs the products (grouped: ``ops/grouped_matmul.grouped_matmul``,
+# which leaves the rows past the last group unwritten; dense: one expert's,
+# over a window that holds other experts' rows too; both masked here) and
+# adds the rows to their tokens (a scatter-add, or where the kernels run
+# ``ops/grouped_matmul.add_rows`` over the live rows).  Its residuals are its
+# inputs and the routing, so a rematerialized block's second forward computes
+# no expert product at all (the backward recomputes a pass's products where
+# it needs them).  The backward differentiates ``_expert_chunk`` where the
+# products are XLA's (``lax.ragged_dot``, a dense window's ``jnp.dot``) and
+# is written out where they are the kernels' (``_expert_chunk_grads``).
+
+
+def _kernels_planned(tokens, stacks, rows, dense) -> bool:
+    """Whether the grouped products of these passes are the kernels'."""
+    held, d_in, d_out = stacks[0].shape
+    return not dense and grouped_plan(rows, d_in, d_out, held, tokens.dtype).kind == "pallas"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -432,10 +538,13 @@ def held_experts(tokens, weights, order, counts, stacks, rows, act="silu", dense
     two: plain; ``act`` names the activation), ``rows`` sorted rows a pass,
     grouped or ``dense`` (:func:`_passes`) → (T, d):
     ``out[t] = Σ_{j: expert(t, j) held} weights[t, j] · expert(tokens[t])``."""
-    n, at, _, _ = _passes(order, counts, weights, rows, dense)
+    n, at, _, n_held = _passes(order, counts, weights, rows, dense)
+    kernels = _kernels_planned(tokens, stacks, rows, dense)
 
     def body(c, out):
-        _, idx, w_rows, live, product, ws, _ = at(c, stacks)
+        lo, idx, w_rows, live, spans, product, ws, _ = at(c, stacks)
+        if kernels:       # (the combine walks the pass's live rows alone)
+            return add_rows(out, idx, _expert_chunk_kernels(tokens[idx], w_rows, spans[0], act, ws), n_held - lo)
         y = _expert_chunk(tokens[idx], w_rows, product, act, *ws)
         return out.at[idx].add(jnp.where(live[:, None], y, 0).astype(out.dtype))
 
@@ -453,7 +562,7 @@ def _held_experts_bwd(rows, act, dense, res, d_out):
 
     def body(c, carry):
         d_tokens, d_w_sorted, d_stacks = carry
-        lo, idx, w_rows, live, product, ws, add = at(c, stacks)
+        lo, idx, w_rows, live, _, product, ws, add = at(c, stacks)
         _, pull = jax.vjp(
             lambda x, w, *ws: _expert_chunk(x, w, product, act, *ws),
             tokens[idx], w_rows, *ws)
@@ -466,10 +575,33 @@ def _held_experts_bwd(rows, act, dense, res, d_out):
             tuple(add(total, g) for total, g in zip(d_stacks, d_ws)),
         )
 
-    d_tokens, d_w_sorted, d_stacks = lax.fori_loop(0, n, body, (
-        jnp.zeros_like(tokens), jnp.zeros_like(w_sorted),
-        tuple(jnp.zeros(w.shape, jnp.float32) for w in stacks),
-    ))
+    def kernels_body(c, carry):
+        d_tokens, d_w_sorted, grads = carry
+        lo, idx, w_rows, live, spans, _, ws, _ = at(c, stacks)
+        # (no kernel reads a row past its group's, so the dead rows' cotangents need no zeroing)
+        d_x, d_w, grads = _expert_chunk_grads(
+            tokens[idx], w_rows, spans, act, ws, d_out[idx].astype(tokens.dtype), grads)
+        return (
+            add_rows(d_tokens, idx, d_x, n_held - lo),
+            lax.dynamic_update_slice(d_w_sorted, jnp.where(live, d_w, 0.0), (lo,)),
+            grads,
+        )
+
+    # The stacks' gradients: float32 sums the passes add to, rounded at the
+    # end — or, where the kernels run, the stacks' own dtype from the start,
+    # every expert's block written by the pass its rows end in, beside ONE
+    # float32 block a stack for the expert that straddles two passes.
+    if _kernels_planned(tokens, stacks, rows, dense):
+        d_tokens, d_w_sorted, grads = lax.fori_loop(0, n, kernels_body, (
+            jnp.zeros_like(tokens), jnp.zeros_like(w_sorted),
+            tuple((jnp.zeros_like(w), jnp.zeros(w.shape[1:], jnp.float32)) for w in stacks),
+        ))
+        d_stacks = tuple(stack for stack, _ in grads)
+    else:
+        d_tokens, d_w_sorted, d_stacks = lax.fori_loop(0, n, body, (
+            jnp.zeros_like(tokens), jnp.zeros_like(w_sorted),
+            tuple(jnp.zeros(w.shape, jnp.float32) for w in stacks),
+        ))
     # back from sorted order: assignment a sits at place[a]
     place = jnp.argsort(order)
     d_weights = jnp.where(place < n_held, d_w_sorted[place], 0.0).reshape(weights.shape)
@@ -492,11 +624,14 @@ class TopKMoe(nn.Module):
     ``num_experts`` the router scores (None: all of them).  Only the held
     experts have weights here.  Every held assignment is computed
     (:func:`held_experts`): ``ROWS_CHUNK`` sorted rows a pass of its loop as
-    grouped products, or — ``expert_window`` rows given — windows of that
-    many sorted rows, a pass for each expert with rows in the window, as plain
-    products: what a grouped product costs a live row does not fall with the
-    rows an expert has, a plain one over a few hundred rows runs near the
-    matrix unit's pace (PERF.md section 6, PR 34).
+    grouped products — the Pallas kernels of ``ops/grouped_matmul.py`` where
+    its plan takes the widths (multiples of the lane tile: SDAR's,
+    Instella's), ``lax.ragged_dot`` where not — or, ``expert_window`` rows
+    given, windows of that many sorted rows, a pass for each expert with rows
+    in the window, as plain products: what ``ragged_dot`` costs a live row
+    does not fall with the rows an expert has, a plain one over a few hundred
+    rows runs near the matrix unit's pace (PERF.md section 6, PR 34;
+    Nemotron-H's form, whose 1856-wide experts the kernels' plan refuses).
 
     The router is SDAR's by default (softmax over all outputs).
     ``scoring="sigmoid"``, ``selection_bias`` (a ``router_bias`` (E,) leaf

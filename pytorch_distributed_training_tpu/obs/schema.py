@@ -169,6 +169,18 @@ METRICS: dict[str, dict] = {
                 "kernels do not target); ops/causal_conv.conv_plan decides "
                 "from the shapes and the backend alone",
     },
+    "grouped_plan": {
+        "type": GAUGE, "labeled": True,
+        "help": "grouped matrix products of held experts (models/moe.TopKMoe "
+                "without an expert_window) traced so far, per kind: pallas (the "
+                "ragged-dot-held-fwd / -refwd / -dgrad / -wgrad Mosaic calls, "
+                "whose counts per program are mosaic_custom_calls[kernel="
+                "ragged-dot-held-fwd|-refwd|-dgrad|-wgrad]) or xla "
+                "(lax.ragged_dot: a width off the lane tile, rows off the row "
+                "tile, a matrix past VMEM, or a backend the kernels do not "
+                "target); ops/grouped_matmul.grouped_plan decides from the "
+                "shapes and the backend alone",
+    },
     # ---- SLO / alerting plane (obs/slo.py) ------------------------------
     "slo_alert_transitions": {
         "type": COUNTER, "labeled": False,

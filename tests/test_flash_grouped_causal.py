@@ -425,6 +425,103 @@ def test_the_convolutions_pair_compiles_for_a_v5e_at_the_cells_shape(one_v5e):
     assert results["causal_conv_bwd"].startswith("(bf16[1,8192,6144]") and "f32[1,4,6144]" in results["causal_conv_bwd"]
 
 
+@pytest.mark.parametrize("width, inner, groups", [(2048, 768, 16), (2048, 1408, 8)])      # SDAR's, Instella's
+def test_the_grouped_products_compile_for_a_v5e_at_the_cells_widths(one_v5e, width, inner, groups):
+    """``ops/grouped_matmul.py``'s three kernels at a pass of the two cells
+    (kept in this file with the other compiles for a described chip):
+    ``ROWS_CHUNK`` sorted rows, a group's matrix a whole block, under the
+    names and in the result shapes the benchmark's committed pattern reads
+    off a device trace."""
+    import json
+    import os
+
+    from pytorch_distributed_training_tpu.models import moe
+    from pytorch_distributed_training_tpu.ops import grouped_matmul as gm
+
+    rows = moe.ROWS_CHUNK
+    plan = gm.grouped_plan(rows, width, inner, groups, jnp.bfloat16, backend="tpu")
+    assert plan.kind == "pallas" and not plan.interpret
+
+    def products(x, d_h, w, sizes, carry):
+        h = gm._gmm(x, w, sizes, False, plan.row_tile, False)
+        d_x = gm._gmm(d_h, w, sizes, True, plan.row_tile, False)
+        # the widest call of an expert layer's backward: two products, the rows' own operands, four results
+        again = gm._gmm_call((x,), (w, w), (d_h, carry[0, 0] * jnp.ones((rows, 1))), sizes, lhs_of=(0, 0),
+                             epilogue=moe._into_experts_again("silu"), outs=(("bfloat16", False),) * 3 + (("float32", True),),
+                             transposed=False, row_tile=plan.row_tile, name=gm.REFWD, interpret=False)
+        return (h, d_x, *again) + tuple(gm._wgrad_call(x, d_h, sizes, jnp.zeros_like(w), carry, jnp.zeros((2,), jnp.int32),
+                                                       plan.row_tile, False))
+
+    shape = lambda dims, kind: jax.ShapeDtypeStruct(dims, kind, sharding=one_v5e)
+    calls, _ = mosaic_calls_compiled_for(
+        products, shape((rows, width), jnp.bfloat16), shape((rows, inner), jnp.bfloat16),
+        shape((groups, width, inner), jnp.bfloat16), shape((groups,), jnp.int32), shape((width, inner), jnp.float32))
+    bare = lambda result: re.sub(r"\{[^}]*\}", "", result.replace(" ", ""))
+    results = {name.strip().split(".")[0]: bare(result) for name, result in calls}
+    assert results == {
+        "%ragged-dot-held-fwd": f"bf16[{rows},{inner}]", "%ragged-dot-held-dgrad": f"bf16[{rows},{width}]",
+        "%ragged-dot-held-refwd": "(" + 3 * f"bf16[{rows},{inner}]," + f"f32[{rows},1])",
+        "%ragged-dot-held-wgrad": f"(bf16[{groups},{width},{inner}],f32[{width},{inner}])"}, calls
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for layer in ("kernel.ragged_dot_roofline.train", "moe.grouped_matmul_share.train"):
+        rx = re.compile(json.load(open(os.path.join(root, "benchmark", "layers", layer + ".json")))["args"]["pattern"])
+        for name, result in calls:          # as ``benchmark/tracered.short_name`` spells a trace's event
+            assert rx.search(f"{name.strip()} = {re.sub(r'[{][^}]*[}]', '', result)} custom-call tpu_custom_call"), name
+
+
+def test_the_combine_compiles_for_a_v5e_at_the_cells_size(one_v5e):
+    """``ops/grouped_matmul.add_rows`` at both cells' call: ``ROWS_CHUNK``
+    rows of 2048 added to 8192 tokens, every token's float32 sums of 512
+    columns in VMEM; under a name the products' pattern does not read."""
+    from pytorch_distributed_training_tpu.models import moe
+    from pytorch_distributed_training_tpu.ops import grouped_matmul as gm
+
+    plan = gm.add_rows_plan(8192, moe.ROWS_CHUNK, 2048, jnp.bfloat16, backend="tpu")
+    assert plan.kind == "pallas" and not plan.interpret
+    shape = lambda dims, kind: jax.ShapeDtypeStruct(dims, kind, sharding=one_v5e)
+    calls, _ = mosaic_calls_compiled_for(
+        lambda into, token_of, rows, n: gm._add_rows_call(into, token_of, rows, n, plan.row_tile, False),
+        shape((8192, 2048), jnp.bfloat16), shape((moe.ROWS_CHUNK,), jnp.int32),
+        shape((moe.ROWS_CHUNK, 2048), jnp.bfloat16), shape((), jnp.int32))
+    ((name, result),) = calls
+    assert name.strip().replace("ROOT ", "").startswith("%held-rows-add") and result.startswith("bf16[8192,2048]"), calls
+
+
+def test_an_expert_layers_gradient_lowers_for_four_v5es(monkeypatch):
+    """Sorted rows have no batch axis to split, and Mosaic calls "cannot be
+    automatically partitioned": under ``data`` 4 the grouped products go
+    into a ``shard_map`` with everything whole.  ``jax.grad`` through one
+    ``TopKMoe`` at lane-aligned toy sizes, compiled for a described 2x2 of
+    v5es with the batch sharded: the forward's 2 calls, the backward's 3, 3
+    weight gradients and the combine twice, each under its role's name with
+    no transformation's prefix."""
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pytorch_distributed_training_tpu import comm
+    from pytorch_distributed_training_tpu.models import moe
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    mesh = comm.make_mesh(comm.MeshConfig(data=4), devices=list(topo.devices))
+    layer = moe.TopKMoe(num_experts=8, num_experts_per_tok=2, mlp_dim=128, experts_held=(2, 4), dtype=jnp.bfloat16)
+    x = jnp.zeros((4, 128, 256), jnp.bfloat16)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), x)["params"])
+    on = lambda spec: lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=NamedSharding(mesh, spec))
+    loss = lambda p, x: jnp.sum(layer.apply({"params": p}, x, mutable=["moe_counters"])[0].astype(jnp.float32) ** 2)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")      # the plan's: the described chips'
+    with mesh:
+        calls, text = mosaic_calls_compiled_for(
+            jax.grad(loss, (0, 1)), jax.tree.map(on(P()), params), on(P(comm.mesh.BATCH_AXES))(x))
+    names = sorted(name.strip().split(".")[0] for name, _ in calls)
+    assert names == ["%held-rows-add"] * 2 + ["%ragged-dot-held-dgrad"] * 2 + ["%ragged-dot-held-fwd"] * 2 + [
+        "%ragged-dot-held-refwd"] + ["%ragged-dot-held-wgrad"] * 3, calls
+    rows = 4 * 128 * 2                                      # every device runs the whole pass
+    assert sum(result.startswith(f"bf16[{rows},128]") for _, result in calls) == 2, calls
+
+
 @pytest.mark.parametrize("data, tensor", [(2, 2), (4, 1), (1, 4)])
 def test_under_a_mesh_of_several_devices_the_convolutions_pair_runs_a_shard(data, tensor):
     """Under GSPMD the pair goes into a ``shard_map``, the batch over
