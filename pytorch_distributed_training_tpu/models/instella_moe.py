@@ -52,6 +52,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ..obs.trace import scope
 from ..ops.attention import dot_product_attention
 from ..ops.qk_norm_rope import rope
+from .layers import GatedMlp, dense
 from .moe import TopKMoe
 from .sdar import REMAT_SAVE, NormRope, RMSNorm
 
@@ -132,11 +133,6 @@ def softmax_scale(cfg: InstellaMoeConfig) -> float:
     return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
 
 
-def _dense(n, name, dtype):
-    return nn.Dense(n, use_bias=False, dtype=dtype, name=name,
-                    kernel_init=nn.initializers.normal(stddev=0.02))
-
-
 class MlaAttention(nn.Module):
     cfg: InstellaMoeConfig
     dtype: Any = jnp.float32
@@ -154,9 +150,9 @@ class MlaAttention(nn.Module):
         inv_freq = yarn_inv_freq(dr, cfg.rope_theta, scaling)
 
         with scope("attn/mla"):
-            q = _dense(h * (dn + dr), "wq", self.dtype)(x).reshape(b, p, h, dn + dr)
-            latent = _dense(rank + dr, "wkv_a", self.dtype)(x)
-            kv = _dense(h * (dn + dv), "wkv_b", self.dtype)(norm("kv_norm")(latent[..., :rank]))
+            q = dense(h * (dn + dr), "wq", self.dtype)(x).reshape(b, p, h, dn + dr)
+            latent = dense(rank + dr, "wkv_a", self.dtype)(x)
+            kv = dense(h * (dn + dv), "wkv_b", self.dtype)(norm("kv_norm")(latent[..., :rank]))
             kv = kv.reshape(b, p, h, dn + dv)
             k_rope = jnp.broadcast_to(latent[:, :, None, rank:], (b, p, h, dr))
             k, v = jnp.concatenate([kv[..., :dn], k_rope], axis=-1), kv[..., dn:]
@@ -170,20 +166,8 @@ class MlaAttention(nn.Module):
         with scope("attn/mla"):
             o = o.reshape(b, p, h * dv)
             if cfg.gated_attention:
-                o = o * jax.nn.sigmoid(_dense(h * dv, "wg", self.dtype)(x))
-            return _dense(cfg.hidden_size, "wo", self.dtype)(o)
-
-
-class GatedMlp(nn.Module):
-    """``(silu(x W_gate) ⊙ (x W_up)) W_down``, no bias."""
-
-    width: int
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        h = nn.silu(_dense(self.width, "w_gate", self.dtype)(x)) * _dense(self.width, "w_up", self.dtype)(x)
-        return _dense(x.shape[-1], "w_down", self.dtype)(h)
+                o = o * jax.nn.sigmoid(dense(h * dv, "wg", self.dtype)(x))
+            return dense(cfg.hidden_size, "wo", self.dtype)(o)
 
 
 class InstellaBlock(nn.Module):
@@ -263,14 +247,14 @@ class InstellaMoe(nn.Module):
             hidden = norm("ln_final")(x)
         if return_hidden:
             return hidden
-        head = _dense(cfg.vocab_size, "lm_head", self.dtype)
+        head = dense(cfg.vocab_size, "lm_head", self.dtype)
         with scope("train/head"):
             logits = head(hidden).astype(jnp.float32)
         if not cfg.num_nextn_predict_layers or not (mtp or self.is_initializing()):
             return logits
         with scope("train/mtp"):
             ahead = embed[jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))]
-            y = _dense(cfg.hidden_size, "mtp_proj", self.dtype)(
+            y = dense(cfg.hidden_size, "mtp_proj", self.dtype)(
                 jnp.concatenate([norm("mtp_hnorm")(x), norm("mtp_enorm")(ahead)], axis=-1))
             _, y = block_cls(cfg, False, self.dtype, name="mtp_block")(y, y, positions)
             with scope("block/norm"):

@@ -1,4 +1,5 @@
-"""Shared transformer building blocks used by ViT and GPT-2."""
+"""Shared transformer building blocks: ViT's and GPT-2's attention, and the
+bias-free projection and SiLU-gated MLP of the MoE decoders."""
 
 from __future__ import annotations
 
@@ -709,3 +710,21 @@ class SelfAttention(nn.Module):
         return self._ragged_attend(
             q, kk, vv, cols, nb * bs, attn_mask,
         )
+
+
+def dense(n, name, dtype):
+    """A bias-free projection to ``n`` drawn at normal 0.02."""
+    return nn.Dense(n, use_bias=False, dtype=dtype, name=name,
+                    kernel_init=nn.initializers.normal(stddev=0.02))
+
+
+class GatedMlp(nn.Module):
+    """``(silu(x W_gate) ⊙ (x W_up)) W_down``, no bias."""
+
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        h = nn.silu(dense(self.width, "w_gate", self.dtype)(x)) * dense(self.width, "w_up", self.dtype)(x)
+        return dense(x.shape[-1], "w_down", self.dtype)(h)

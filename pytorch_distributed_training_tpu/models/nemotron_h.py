@@ -61,7 +61,7 @@ from ..obs.trace import scope
 from ..ops.attention import dot_product_attention
 from ..ops.causal_conv import causal_conv_silu
 from ..ops.ssd import ssd_chunked
-from .instella_moe import _dense
+from .layers import dense
 from .moe import TopKMoe, relu2
 from .sdar import REMAT_SAVE, RMSNorm
 
@@ -163,7 +163,7 @@ class Mamba2Mixer(nn.Module):
 
         p, n = cfg.mamba_head_dim, cfg.ssm_state_size
         with scope("ssm/proj"):
-            projected = _dense(inner + wide + h, "in_proj", self.dtype)(u)        # [z | xBC | dt]
+            projected = dense(inner + wide + h, "in_proj", self.dtype)(u)        # [z | xBC | dt]
             z, dt = projected[..., :inner], projected[..., inner + wide:]
         bsz, t, _ = z.shape
         with scope("ssm/conv"):
@@ -180,7 +180,7 @@ class Mamba2Mixer(nn.Module):
             y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + cfg.layer_norm_epsilon)
             y = (y.reshape(bsz, t, inner) * scale).astype(z.dtype)
         with scope("ssm/proj"):
-            return _dense(cfg.hidden_size, "out_proj", self.dtype)(y)
+            return dense(cfg.hidden_size, "out_proj", self.dtype)(y)
 
 
 class GqaAttention(nn.Module):
@@ -193,13 +193,13 @@ class GqaAttention(nn.Module):
         bsz, t, _ = x.shape
         h, hkv, dh = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
         with scope("attn/proj"):
-            q = _dense(h * dh, "wq", self.dtype)(x).reshape(bsz, t, h, dh)
-            k = _dense(hkv * dh, "wk", self.dtype)(x).reshape(bsz, t, hkv, dh)
-            v = _dense(hkv * dh, "wv", self.dtype)(x).reshape(bsz, t, hkv, dh)
+            q = dense(h * dh, "wq", self.dtype)(x).reshape(bsz, t, h, dh)
+            k = dense(hkv * dh, "wk", self.dtype)(x).reshape(bsz, t, hkv, dh)
+            v = dense(hkv * dh, "wv", self.dtype)(x).reshape(bsz, t, hkv, dh)
             q, k, v = (checkpoint_name(m, "attn_qkv") for m in (q, k, v))
         o = dot_product_attention(q, k, v, causal=True)
         with scope("attn/proj"):
-            return _dense(cfg.hidden_size, "wo", self.dtype)(o.reshape(bsz, t, h * dh))
+            return dense(cfg.hidden_size, "wo", self.dtype)(o.reshape(bsz, t, h * dh))
 
 
 class Relu2Mlp(nn.Module):
@@ -210,7 +210,7 @@ class Relu2Mlp(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        return _dense(x.shape[-1], "w_down", self.dtype)(relu2(_dense(self.width, "w_up", self.dtype)(x)))
+        return dense(x.shape[-1], "w_down", self.dtype)(relu2(dense(self.width, "w_up", self.dtype)(x)))
 
 
 class NemotronHLayer(nn.Module):
@@ -278,7 +278,7 @@ class NemotronH(nn.Module):
         if return_hidden:
             return h
         with scope("train/head"):
-            return _dense(cfg.vocab_size, "lm_head", self.dtype)(h).astype(jnp.float32)
+            return dense(cfg.vocab_size, "lm_head", self.dtype)(h).astype(jnp.float32)
 
 
 def nemotron_h_30b_a3b(cfg_overrides: dict | None = None, **kw) -> NemotronH:

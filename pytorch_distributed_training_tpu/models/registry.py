@@ -18,6 +18,7 @@ from .vit import vit_b16, vit_l16, vit_s16
 from .gpt2 import gpt2_124m, gpt2_large, gpt2_medium, gpt2_xl
 from .instella_moe import instella_moe_16b_a3b
 from .nemotron_h import nemotron_h_30b_a3b
+from .qwen3_next import qwen3_next_80b_a3b
 from .sdar import sdar_30b_a3b
 
 
@@ -57,6 +58,9 @@ MODEL_REGISTRY: dict[str, ModelEntry] = {
     # Plain next-token CE: single-sublayer layers of Mamba-2, attention and
     # relu² experts by a pattern string (models/nemotron_h.py).
     "nemotron_h_30b_a3b": ModelEntry(nemotron_h_30b_a3b, "lm"),
+    # Plain next-token CE: Gated DeltaNet and gated attention 3 : 1, softmax
+    # experts beside a gated shared one (models/qwen3_next.py).
+    "qwen3_next_80b_a3b": ModelEntry(qwen3_next_80b_a3b, "lm"),
 }
 
 

@@ -83,15 +83,20 @@ REMAT_SAVE = (*FLASH_RESIDUALS, "attn_qkv")
 
 
 class RMSNorm(nn.Module):
+    """``x · rsqrt(mean(x²) + eps) · w`` over the last axis, ``w`` starting at
+    one; ``zero_centred``: ``· (1 + w)`` with ``w`` starting at zero (Qwen3-Next's)."""
+
     eps: float
     dtype: Any = jnp.float32
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        init = nn.initializers.zeros if self.zero_centred else nn.initializers.ones
+        scale = self.param("scale", init, (x.shape[-1],), jnp.float32)
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
-        return (y * scale).astype(self.dtype)
+        return (y * (1.0 + scale if self.zero_centred else scale)).astype(self.dtype)
 
 
 class NormRope(nn.Module):

@@ -65,6 +65,10 @@ PHASES = (
     "ssm/scan",              # Mamba-2 mixer: steps, decays, the chunked recurrence, the D skip
     "ssm/gate",              # Mamba-2 mixer: the output gate and the grouped RMS norm
     "ssm/proj",              # Mamba-2 mixer: the in- and the out-projection
+    "gdn/proj",              # Gated DeltaNet: the qkvz, ba and out projections
+    "gdn/conv",              # Gated DeltaNet: the causal depthwise convolution and its SiLU
+    "gdn/delta",             # Gated DeltaNet: q/k L2 norms, beta, the decays, the chunked delta rule
+    "gdn/gate",              # Gated DeltaNet: the gated RMS norm of the output
     "grad_accum/microbatch",  # fwd+bwd of one accumulation microbatch
     "grad_sync/rs_ici",      # tier 1: reduce-scatter over ICI
     "grad_sync/ar_dcn",      # tier 2: cross-slice all-reduce over DCN

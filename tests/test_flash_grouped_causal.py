@@ -425,7 +425,7 @@ def test_the_convolutions_pair_compiles_for_a_v5e_at_the_cells_shape(one_v5e):
     assert results["causal_conv_bwd"].startswith("(bf16[1,8192,6144]") and "f32[1,4,6144]" in results["causal_conv_bwd"]
 
 
-@pytest.mark.parametrize("width, inner, groups", [(2048, 768, 16), (2048, 1408, 8)])      # SDAR's, Instella's
+@pytest.mark.parametrize("width, inner, groups", [(2048, 768, 16), (2048, 1408, 8), (2048, 512, 32)])   # SDAR's, Instella's, Qwen3-Next's
 def test_the_grouped_products_compile_for_a_v5e_at_the_cells_widths(one_v5e, width, inner, groups):
     """``ops/grouped_matmul.py``'s three kernels at a pass of the two cells
     (kept in this file with the other compiles for a described chip):
@@ -648,3 +648,51 @@ def test_the_norm_rope_pair_compiles_for_a_v5e_at_the_cells_shape(one_v5e, heads
     assert sorted(results) == ["qk_norm_rope_bwd", "qk_norm_rope_fwd"] and len(calls) == 2, calls
     assert results["qk_norm_rope_fwd"] == f"bf16[1,{length},{heads * 128}]"
     assert results["qk_norm_rope_bwd"] == f"(bf16[1,{length},{heads * 128}],f32[8,128])"
+
+
+def test_tabled_pair_compiles_for_a_v5e_at_qwen3_nexts_shape(one_v5e):
+    """The causal pair with grouped K/V at the Qwen3-Next cell's gated
+    attention: 8192 positions, 16 query heads over 2 K/V heads of 256 (two
+    lane tiles a head; the fused backward's key row 58.7 MB of its 64 MiB),
+    under the causal kernels' names and in the shape
+    ``readers/kernel_roofline.py`` parses (the head's 256 from the call)."""
+    assert pa.flash_plan(8192, 8192, 16, 2, 256, 2, causal=True, block_diffusion=None).kind == "tabled"
+
+    def pair(q, k, v):
+        out, vjp = jax.vjp(lambda q, k, v: pa.flash_attention(q, k, v, causal=True, interpret=False), q, k, v)
+        return (out,) + vjp(out)
+
+    shape = lambda h: jax.ShapeDtypeStruct((1, 8192, h, 256), jnp.bfloat16, sharding=one_v5e)
+    calls, text = mosaic_calls_compiled_for(pair, shape(16), shape(2), shape(2))
+    names = sorted(re.sub(r"^%(\w+?)[_.]*\d*$", r"\1", name.strip()) for name, _ in calls)
+    assert names == ["flash_bwd", "flash_fwd"], calls
+    results = {role: result for name, result in calls for role in ("flash_fwd", "flash_bwd") if role in name}
+    assert results["flash_fwd"].lstrip("(").startswith("bf16[1,16,8192,256]")
+    assert results["flash_bwd"].lstrip("(").startswith("bf16[1,16,8192,256]")
+    assert results["flash_bwd"].count("bf16[1,2,8192,256]") == 2         # dk, dv at the K/V heads
+
+
+def test_the_convolutions_pair_compiles_for_a_v5e_at_qwen3_nexts_shape(one_v5e):
+    """``ops/causal_conv.py``'s pair at the Qwen3-Next cell's Gated DeltaNet
+    call: 8192 positions, ``[q | k | v]`` read in place at columns [0, 8192)
+    of the projection's 12,288, K 4, no bias (zeros); q, k and v come back as
+    three arrays, the stream's cotangent as one."""
+    from pytorch_distributed_training_tpu.ops import causal_conv as cc
+
+    length, wide, splits, kernel = 8192, 12288, (2048, 2048, 4096), 4
+    plan = cc.conv_plan(length, sum(splits), kernel, 2, splits=splits, backend="tpu")
+    assert plan.kind == "pallas" and not plan.interpret
+
+    def pair(x, w, bias):
+        y, vjp = jax.vjp(lambda *inputs: cc._conv_pallas(*inputs, 0, splits, plan.time_tile,
+                                                         plan.channel_tile, False), x, w, bias)
+        return y + vjp(y)
+
+    shape = lambda dims, kind: jax.ShapeDtypeStruct(dims, kind, sharding=one_v5e)
+    calls, _ = mosaic_calls_compiled_for(pair, shape((1, length, wide), jnp.bfloat16),
+                                         shape((kernel, sum(splits)), jnp.float32), shape((sum(splits),), jnp.float32))
+    bare = lambda result: re.sub(r"\{[^}]*\}", "", result.replace(" ", ""))
+    results = {role: bare(result) for name, result in calls for role in cc.KERNEL_NAMES if role in name}
+    assert sorted(results) == ["causal_conv_bwd", "causal_conv_fwd"] and len(calls) == 2, calls
+    assert results["causal_conv_fwd"].startswith("(bf16[1,8192,2048],bf16[1,8192,2048],bf16[1,8192,4096]"), results
+    assert results["causal_conv_bwd"].startswith("(bf16[1,8192,8192]") and "f32[1,4,8192]" in results["causal_conv_bwd"]

@@ -831,6 +831,8 @@ def _vocabulary(name):
         "ssm/conv", "ssm/scan", "ssm/gate",
         # PR 36: where the time is and no name was (the scope table's shares)
         "train/head", "block/norm", "block/mlp", "attn/proj", "attn/core", "ssm/proj",
+        # the Gated DeltaNet mixer's four
+        "gdn/proj", "gdn/conv", "gdn/delta", "gdn/gate",
         "grad_accum/microbatch",
         "grad_sync/rs_ici", "grad_sync/ar_dcn", "grad_sync/ag_ici",
         "grad_sync/stripe",
