@@ -14,11 +14,8 @@ positions, with ``γ_i = Σ_{j<=i} g_j`` inside a chunk (every decay below is
 so no exponent is ever positive and ``e^{-γ}`` is never formed):
 
 - ``L_ij = β_i (k_i·k_j) e^{γ_i - γ_j}`` for ``j < i``; the WY form's
-  ``[U | W] = (I + L)^{-1} [β ⊙ V | β ⊙ e^{γ} ⊙ K]``, by one triangular solve
-  a chunk and head (on the TPU XLA's ``InvertDiagBlocksLowerTriangular``
-  custom call, 1.4 ms a span of 16 chunks × 32 heads and about a quarter of
-  the Qwen3-Next cell's step; the same elimination by blockwise doubling in
-  HIGHEST-precision products was slower still: PERF.md section 6);
+  ``[U | W] = (I + L)^{-1} [β ⊙ V | β ⊙ e^{γ} ⊙ K]``, one unit-lower solve a
+  chunk and head in float32 (the plan's ``solve``, below);
 - the state ENTERING each chunk, by the recurrence over chunks: ``V' = U - W
   S`` and ``S ← e^{γ_C} S + (e^{γ_C - γ} ⊙ K)ᵀ V'`` (T / C dependent steps of
   two products);
@@ -37,37 +34,104 @@ Whole, at 8192 positions of 32 heads of 128, those are 3.4 GB alive in one
 layer's backward, which a v5e's 16 GB beside a 10 GB training state cannot
 hold (PERF.md section 4).
 
-One form today, ``gated_delta_plan``'s only answer: ``xla``, plain ``lax`` /
-``jnp`` with ``jax.grad``'s backward (a chunk's (C, C) blocks a head and the
-state entering each chunk are what it keeps), in chunks of ``CHUNK``.  The
-plan is the one place a kernel's shapes would be asked for, so that a Pallas
-form slots in behind ``gated_delta_rule`` without a change to the model.
+Two forms of the solve, ``gated_delta_plan``'s choice from the shapes and
+the backend alone; the rest of the rule is the same ``lax`` / ``jnp`` code in
+both, with ``jax.grad``'s backward (a chunk's (C, C) blocks a head and the
+state entering each chunk are what it keeps), in chunks of ``CHUNK``:
+
+- ``pallas``: a Mosaic pair under one ``jax.custom_vjp``
+  (``gated_delta_solve_fwd``, ``gated_delta_solve_bwd``) over a span's
+  chunk-heads, 128 a grid step.  The forward forms ``T = (I + L)^{-1}`` in
+  VMEM by forward substitution with each chunk-head on a lane of its own, so
+  the 63 dependent row steps are plain float32 multiply-adds over 128
+  independent systems: ``L`` comes in rows-major (``(C, N, C)``, a
+  relayout XLA fuses into the blocks' producer) and is turned onto the
+  lanes a row at a time on the XLU; the rows are summed in place a block of
+  8 at a time, each block's share only as wide as the block (``T`` is lower
+  triangular), in loops whose bodies Mosaic lowers once (straight-line code
+  for every pair of rows cost each program 1.3 s more of lowering, which no
+  cache keeps).  ``T`` goes back to one (C, C) matrix a chunk-head by strided
+  stores, and ``T · rhs`` runs on the MXU at
+  ``HIGHEST`` precision, as XLA's expander runs it after its
+  ``InvertDiagBlocksLowerTriangular`` custom call (1.4 ms a span of 16
+  chunks × 32 heads on a v5e, about a quarter of the Qwen3-Next cell's step;
+  blockwise doubling in XLA products was slower still: PERF.md section 6).
+  It writes the solution and ``T``, the backward's residual (made again
+  under the span's ``jax.checkpoint`` with everything else).  The backward
+  is two products a chunk-head and no solve: ``d_rhs = Tᵀ d`` and ``d_L =
+  -strict_lower(d_rhs · solvedᵀ)``, at ``HIGHEST`` too.  A Mosaic call is
+  opaque to the partitioner: under a mesh of several devices the pair runs a
+  shard inside a ``shard_map``, the batch over the batch axes and the heads
+  over ``tensor`` where each divides (``ops/attention._kernel_partition``,
+  as the flash pair).
+- ``xla``: ``lax.linalg.triangular_solve`` and its autodiff, where a head's
+  keys or values are no multiple of 128 or the backend is neither the TPU
+  nor the CPU's interpreter; the toy sizes, and the tests' second opinion.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec
+
+from ..compat import shard_map
+from .attention import _kernel_partition
 
 _F32 = jnp.float32
+_HIGHEST = lax.Precision.HIGHEST      # the precision of XLA's own products round its solve
+_LANES = 128
+_SUBLANES = 8
 CHUNK = 64                            # positions a chunk (the published chunked form's)
 SPAN_CHUNKS = 16                      # chunks a checkpointed span holds
+
+# Every ``pl.pallas_call`` below carries one of these as ``name`` (the HLO
+# instruction, and so the device trace's event, is ``%<name>.<n>``;
+# ``obs/cost.mosaic_kernels`` counts them).
+KERNEL_NAMES = ("gated_delta_solve_fwd", "gated_delta_solve_bwd")
+
+_FWD_HEADS = _LANES                   # chunk-heads a forward grid step: one a lane
+_BWD_HEADS = 64                       # chunk-heads a backward grid step
+_UNROLL = 4                           # chunk-heads a step of the product loops: the MXU's slots 85 % full, 55 % at one
+_VMEM_BYTES = 64 * 2**20              # the forward's blocks twice over (52 MB) and its two (C, C, 128) scratches
 
 
 class GatedDeltaPlan(NamedTuple):
     """What ``gated_delta_rule`` does with one call's static facts."""
 
-    kind: str         # "xla" (no kernel is written yet)
-    chunk: int        # positions a chunk
+    kind: str           # "pallas" | "xla"
+    chunk: int          # positions a chunk
+    interpret: bool     # the kernels under Pallas's interpreter: the CPU
 
 
-def gated_delta_plan(seq_len: int, key_heads: int, value_heads: int, key_dim: int,
-                     value_dim: int) -> GatedDeltaPlan:
-    """Which form a call takes, from its shapes alone."""
-    return GatedDeltaPlan("xla", CHUNK)
+# kind -> call sites traced with it since the process started (the
+# ``gated_delta_plan[kind=..]`` gauges)
+_plans_traced: dict[str, int] = {}
+
+
+def gated_delta_plans_traced() -> dict[str, int]:
+    """The ``gated_delta_plan[kind=pallas|xla]`` gauges' values."""
+    return dict(_plans_traced)
+
+
+def gated_delta_plan(seq_len: int, key_heads: int, value_heads: int, key_dim: int, value_dim: int, *,
+                     chunk: int | None = None, backend: str | None = None) -> GatedDeltaPlan:
+    """Which form a call takes, from its shapes and the backend alone: the
+    kernels are written for chunks of ``CHUNK`` and heads whose keys and
+    values are whole lane tiles.  ``chunk`` is ``CHUNK`` unless given;
+    ``backend`` is ``jax.default_backend()`` unless given: the kernels run
+    on the TPU and, interpreted, on the CPU."""
+    backend = jax.default_backend() if backend is None else backend
+    chunk = chunk or CHUNK
+    if backend in ("tpu", "cpu") and chunk == CHUNK and key_dim % _LANES == 0 and value_dim % _LANES == 0:
+        return GatedDeltaPlan("pallas", chunk, backend == "cpu")
+    return GatedDeltaPlan("xla", chunk, False)
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int | None = None):
@@ -81,7 +145,10 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int | None = None):
     heads = v.shape[2]
     if heads % groups:
         raise ValueError(f"{heads} value heads are no multiple of {groups} key heads")
-    chunk = chunk or gated_delta_plan(t, groups, heads, q.shape[-1], v.shape[-1]).chunk
+    plan = gated_delta_plan(t, groups, heads, q.shape[-1], v.shape[-1], chunk=chunk)
+    _plans_traced[plan.kind] = _plans_traced.get(plan.kind, 0) + 1
+    chunk = plan.chunk
+    solve = _solve_xla if plan.kind == "xla" else functools.partial(_solve_pallas, interpret=plan.interpret)
     span = min(chunk * SPAN_CHUNKS, -(-t // chunk) * chunk)
     pad = -t % span
     if pad:
@@ -89,7 +156,7 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int | None = None):
 
     @jax.checkpoint
     def one_span(state, inputs):
-        return _gated_delta_xla(*inputs, chunk, state)
+        return _gated_delta_xla(*inputs, chunk, state, solve)
 
     spans = lambda m: jnp.moveaxis(m.reshape(bsz, -1, span, *m.shape[2:]), 1, 0)
     _, o = lax.scan(one_span, jnp.zeros((bsz, heads, q.shape[-1], v.shape[-1]), _F32),
@@ -97,9 +164,10 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int | None = None):
     return jnp.moveaxis(o, 0, 1).reshape(bsz, -1, heads, v.shape[-1])[:, :t]
 
 
-def _gated_delta_xla(q, k, v, g, beta, chunk, state):
-    """The ``xla`` form of the module docstring over one span, from ``state``
-    (B, H, d_k, d_v) float32 → (the state after it, the span's output)."""
+def _gated_delta_xla(q, k, v, g, beta, chunk, state, solve):
+    """The chunked rule of the module docstring over one span, from
+    ``state`` (B, H, d_k, d_v) float32 → (the state after it, the span's
+    output); ``solve(lower, rhs)`` is ``(I + lower)^{-1} rhs`` a chunk-head."""
     bsz, t, groups, dk = q.shape
     heads, dv, dtype = v.shape[2], v.shape[3], v.dtype
     nc, per = t // chunk, heads // groups
@@ -122,7 +190,7 @@ def _gated_delta_xla(q, k, v, g, beta, chunk, state):
     lower = beta[..., :, None] * dot("bhcid,bhcjd->bhcij", k, k) * decay_below
     rhs = jnp.concatenate([beta[..., None] * v.astype(_F32),
                            (beta * jnp.exp(gamma))[..., None] * k.astype(_F32)], axis=-1)
-    solved = lax.linalg.triangular_solve(lower, rhs, left_side=True, lower=True, unit_diagonal=True)
+    solved = solve(lower, rhs)
     u, w = solved[..., :dv], solved[..., dv:]
     to_end = (jnp.exp(gamma[..., -1:] - gamma)[..., None] * k.astype(_F32))     # e^{γ_C - γ} ⊙ K
 
@@ -139,3 +207,165 @@ def _gated_delta_xla(q, k, v, g, beta, chunk, state):
     o = dot("bhcid,bhcde->bhcie", jnp.exp(gamma)[..., None] * q.astype(_F32), entering)
     o = o + dot("bhcij,bhcje->bhcie", dot("bhcid,bhcjd->bhcij", q, k) * decay_seen, new)
     return state, jnp.moveaxis(o, 1, 3).reshape(bsz, t, heads, dv).astype(dtype)
+
+
+def _solve_xla(lower, rhs):
+    """The ``xla`` form's solve: XLA's triangular solve and its autodiff."""
+    return lax.linalg.triangular_solve(lower, rhs, left_side=True, lower=True, unit_diagonal=True)
+
+
+def _dot(lhs, rhs, contract=((1,), (0,))):
+    """A float32 product at ``HIGHEST``: ``contract`` names the lhs and rhs
+    dimension summed over ((0, 0): lhs transposed; (1, 1): rhs transposed)."""
+    return lax.dot_general(lhs, rhs, (contract, ((), ())), preferred_element_type=_F32, precision=_HIGHEST)
+
+
+def _substitute(lower_t, inverse_t):
+    """``T = (I + L)^{-1}`` of 128 chunk-heads, one a lane: ``lower_t`` and
+    ``inverse_t`` (C, C, 128) float32 hold ``L[i, j]`` and ``T[i, m]`` at
+    ``[i, j]`` / ``[i, m]``.  Row i of ``T`` is ``e_i - Σ_{j<i} L[i, j]
+    T[j]``, summed in place block by block of 8 j: once a block's rows are
+    final, every row from the block's first on takes off their share.  A row
+    of the block itself takes its share from the rows before it alone,
+    because ``L[i, j]`` is 0 for ``j >= i``; and row j of ``T`` is zero past
+    column j, so a block's share touches no column past the block.  The rows
+    are walked by a ``pl.loop`` a block, its body traced and lowered once."""
+    chunk, _, lanes = lower_t.shape
+    column = lax.broadcasted_iota(jnp.int32, (chunk, lanes), 0)
+
+    @pl.loop(0, chunk)
+    def _unit(i):
+        inverse_t[i] = jnp.where(column == i, 1.0, 0.0).astype(_F32)
+
+    for jb in range(chunk // _SUBLANES):
+        at_j = pl.ds(jb * _SUBLANES, _SUBLANES)
+        width = pl.ds(0, (jb + 1) * _SUBLANES)
+
+        @pl.loop(jb * _SUBLANES, chunk)
+        def _share(i, at_j=at_j, width=width):
+            l_i = lower_t[i, at_j, :]                                       # L[i, j] of the block's 8 j
+            rows = inverse_t[at_j, width, :]                                # their rows of T
+            even = inverse_t[i, width, :]
+            odd = jnp.zeros_like(even)                                      # two sums: half the dependent chain
+            for u in range(0, _SUBLANES, 2):
+                even = even - l_i[u:u + 1] * rows[u]
+                odd = odd - l_i[u + 1:u + 2] * rows[u + 1]
+            inverse_t[i, width, :] = even + odd
+
+
+def _fwd_kernel(lower_ref, rhs_ref, solved_ref, inverse_ref, lower_t, inverse_t):
+    """A grid step's 128 chunk-heads: ``lower_ref`` (C, 128, C) holds
+    ``L[i]`` of each chunk-head as its row, ``rhs_ref`` (128, C, W); writes
+    the solution and ``T`` a chunk-head (``inverse_ref`` (128·C, C), row
+    ``c·C + i``)."""
+    chunk, heads = lower_ref.shape[:2]
+
+    @pl.loop(0, chunk)
+    def _onto_lanes(i):
+        lower_t[i] = lower_ref[i].T
+
+    _substitute(lower_t, inverse_t)
+
+    @pl.loop(0, chunk)
+    def _off_lanes(i):
+        inverse_ref[pl.ds(i, heads, stride=chunk), :] = inverse_t[i].T
+
+    @pl.loop(0, heads, step=_UNROLL)
+    def _product(h0):
+        for h in (h0 + u for u in range(_UNROLL)):
+            solved_ref[h] = _dot(inverse_ref[pl.ds(pl.multiple_of(h * chunk, chunk), chunk), :], rhs_ref[h])
+
+
+def _bwd_kernel(inverse_ref, solved_ref, d_ref, drhs_ref, dlower_ref):
+    """A grid step's chunk-heads: ``d_rhs = Tᵀ d``, ``d_L = -strict_lower(d_rhs
+    · solvedᵀ)``."""
+    heads, chunk = inverse_ref.shape[:2]
+    rows = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    below = rows > lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+
+    @pl.loop(0, heads, step=_UNROLL)
+    def _heads(h0):
+        for h in (h0 + u for u in range(_UNROLL)):
+            drhs = _dot(inverse_ref[h], d_ref[h], ((0,), (0,)))
+            drhs_ref[h] = drhs
+            dlower_ref[h] = jnp.where(below, -_dot(drhs, solved_ref[h], ((1,), (1,))), 0.0)
+
+
+# Jitted on their own, as the other launchers are: a model's call sites
+# trace and lower the pair once a program.
+@functools.partial(jax.jit, static_argnums=2)
+def _solve_fwd(lower, rhs, interpret):
+    """lower (N, C, C) and rhs (N, C, W) float32, N a multiple of 128 → the
+    solution (N, C, W) and ``T`` (N, C, C)."""
+    n, chunk, _ = lower.shape
+    width = rhs.shape[-1]
+    heads = _FWD_HEADS
+    solved, inverse = pl.pallas_call(
+        _fwd_kernel,
+        grid=(n // heads,),
+        in_specs=[pl.BlockSpec((chunk, heads, chunk), lambda s: (0, s, 0)),
+                  pl.BlockSpec((heads, chunk, width), lambda s: (s, 0, 0))],
+        out_specs=[pl.BlockSpec((heads, chunk, width), lambda s: (s, 0, 0)),
+                   pl.BlockSpec((heads * chunk, chunk), lambda s: (s, 0))],
+        out_shape=[jax.ShapeDtypeStruct((n, chunk, width), _F32), jax.ShapeDtypeStruct((n * chunk, chunk), _F32)],
+        scratch_shapes=[pltpu.VMEM((chunk, chunk, heads), _F32), pltpu.VMEM((chunk, chunk, heads), _F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",), vmem_limit_bytes=_VMEM_BYTES),
+        name="gated_delta_solve_fwd",
+        interpret=interpret,
+    )(jnp.swapaxes(lower, 0, 1), rhs)
+    return solved, inverse.reshape(n, chunk, chunk)
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def _solve_bwd(inverse, solved, d, interpret):
+    """The cotangents of lower and rhs from the solution's ``d``."""
+    n, chunk, width = solved.shape
+    heads = _BWD_HEADS
+    block = lambda cols: pl.BlockSpec((heads, chunk, cols), lambda s: (s, 0, 0))
+    return pl.pallas_call(
+        _bwd_kernel,
+        grid=(n // heads,),
+        in_specs=[block(chunk), block(width), block(width)],
+        out_specs=[block(width), block(chunk)],
+        out_shape=[jax.ShapeDtypeStruct((n, chunk, width), _F32), jax.ShapeDtypeStruct((n, chunk, chunk), _F32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",), vmem_limit_bytes=_VMEM_BYTES),
+        name="gated_delta_solve_bwd",
+        interpret=interpret,
+    )(inverse, solved, d)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _solve_pair(lower, rhs, interpret):
+    return _solve_fwd(lower, rhs, interpret)[0]
+
+
+def _solve_pair_fwd(lower, rhs, interpret):
+    solved, inverse = _solve_fwd(lower, rhs, interpret)
+    return solved, (inverse, solved)
+
+
+def _solve_pair_bwd(interpret, saved, d):
+    inverse, solved = saved
+    drhs, dlower = _solve_bwd(inverse, solved, d, interpret)
+    return dlower, drhs
+
+
+_solve_pair.defvjp(_solve_pair_fwd, _solve_pair_bwd)
+
+
+def _solve_pallas(lower, rhs, *, interpret):
+    """The ``pallas`` form's solve over lower (B, H, nc, C, C) and rhs (B,
+    H, nc, C, W) float32."""
+    def kernels(lower, rhs):                  # the call's operands, or one device's shard of them
+        *lead, chunk, width = rhs.shape
+        n = lower.size // (chunk * chunk)
+        pad = -n % _FWD_HEADS                 # chunk-heads past the last: L 0, rhs 0
+        flat = lambda m: jnp.pad(m.reshape(n, chunk, -1), ((0, pad), (0, 0), (0, 0)))
+        return _solve_pair(flat(lower), flat(rhs), interpret)[:n].reshape(*lead, chunk, width)
+
+    partition = _kernel_partition(lower.shape[0], lower.shape[1])
+    if partition is None:
+        return kernels(lower, rhs)
+    mesh, spec = partition                    # (batch axes, None, tensor, None)
+    heads = PartitionSpec(spec[0], spec[2], None, None, None)
+    return shard_map(kernels, mesh=mesh, in_specs=(heads, heads), out_specs=heads, check_vma=False)(lower, rhs)

@@ -696,3 +696,50 @@ def test_the_convolutions_pair_compiles_for_a_v5e_at_qwen3_nexts_shape(one_v5e):
     assert sorted(results) == ["causal_conv_bwd", "causal_conv_fwd"] and len(calls) == 2, calls
     assert results["causal_conv_fwd"].startswith("(bf16[1,8192,2048],bf16[1,8192,2048],bf16[1,8192,4096]"), results
     assert results["causal_conv_bwd"].startswith("(bf16[1,8192,8192]") and "f32[1,4,8192]" in results["causal_conv_bwd"]
+
+
+def test_the_delta_rules_solve_pair_compiles_for_a_v5e_at_qwen3_nexts_shape(one_v5e):
+    """``ops/gated_delta.py``'s pair at the Qwen3-Next cell's call: a span's
+    512 chunk-heads (32 value heads × 16 chunks of 64), ``lower`` (64, 64)
+    and ``rhs`` (64, 256) float32 each; the forward writes the solution and
+    ``T`` a chunk-head's rows, the backward the cotangents of rhs and lower."""
+    from pytorch_distributed_training_tpu.ops import gated_delta as gd
+
+    assert gd.gated_delta_plan(8192, 16, 32, 128, 128, backend="tpu") == ("pallas", 64, False)
+
+    def pair(lower, rhs):
+        solved, vjp = jax.vjp(lambda lower, rhs: gd._solve_pair(lower, rhs, False), lower, rhs)
+        return (solved,) + vjp(solved)
+
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_v5e)
+    calls, _ = mosaic_calls_compiled_for(pair, shape(512, 64, 64), shape(512, 64, 256))
+    bare = lambda result: re.sub(r"\{[^}]*\}", "", result.replace(" ", ""))
+    results = {role: bare(result) for name, result in calls for role in gd.KERNEL_NAMES if role in name}
+    assert sorted(results) == sorted(gd.KERNEL_NAMES) and len(calls) == 2, calls
+    assert results["gated_delta_solve_fwd"] == "(f32[512,64,256],f32[32768,64])"
+    assert results["gated_delta_solve_bwd"] == "(f32[512,64,256],f32[512,64,64])"
+
+
+def test_a_gated_deltanet_steps_solve_calls_are_under_gdn_delta_in_the_scope_table(one_v5e, monkeypatch):
+    """One Gated DeltaNet layer at heads of 128 (toy depth and width), its
+    gradient compiled for a described v5e: ``obs/cost.scope_table`` puts every
+    ``gated_delta_solve_*`` instruction under ``gdn/delta``, so
+    ``gdn.delta_share.train`` keeps reading the solve."""
+    from pytorch_distributed_training_tpu import models
+    from pytorch_distributed_training_tpu.obs.cost import scope_table
+    from pytorch_distributed_training_tpu.ops import gated_delta as gd
+    from test_gated_delta_pallas import LAYER
+
+    net = models.create_model("qwen3_next_80b_a3b", dtype=jnp.bfloat16, cfg_overrides={**LAYER, "remat": True})
+    tokens = jnp.zeros((1, 256), jnp.int32)
+    params = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0), tokens, train=False)["params"])
+    on_chip = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_v5e)
+    loss = lambda p, t: jnp.sum(net.apply({"params": p}, t, train=False).astype(jnp.float32) ** 2)
+    # the plan and the interpreter follow the default backend, which is the CPU here: the described chip's
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    calls, text = mosaic_calls_compiled_for(jax.grad(loss), jax.tree.map(on_chip, params), on_chip(tokens))
+    assert "InvertDiagBlocksLowerTriangular" not in text               # no triangular solve of XLA's is left
+    table = scope_table(text)
+    ours = {name: scope for name, scope in table.items() if name.startswith(gd.KERNEL_NAMES)}
+    assert {name.split(".")[0] for name in ours} == set(gd.KERNEL_NAMES), ours
+    assert set(ours.values()) == {"gdn/delta"}, ours

@@ -74,7 +74,13 @@ def test_a_decay_near_minus_infinity_forgets_and_beta_one_overwrites():
 
 
 def test_the_plan_has_one_answer_today_and_heads_must_group():
-    assert gated_delta_plan(8192, 16, 32, 128, 128) == ("xla", 64)
+    """The plan's two answers: the Pallas solve at the cell's shapes on the
+    TPU or the CPU's interpreter, XLA's at toy widths (and on any other
+    backend); the chunk is 64 unless a call gives its own."""
+    assert gated_delta_plan(8192, 16, 32, 128, 128, backend="tpu") == ("pallas", 64, False)
+    assert gated_delta_plan(8192, 16, 32, 128, 128, backend="cpu") == ("pallas", 64, True)
+    assert gated_delta_plan(8192, 16, 32, 128, 128, backend="gpu") == ("xla", 64, False)
+    assert gated_delta_plan(96, 2, 4, 8, 16) == ("xla", 64, False)
     args = inputs(t=96)
     np.testing.assert_array_equal(gated_delta_rule(*args), gated_delta_rule(*args, chunk=64))
     q, k, v, g, beta = inputs(heads=3)

@@ -262,5 +262,6 @@ def test_cli_trains_the_toy_size(tmp_path):
     assert "training started" in out.stdout
     recorded = "".join(p.read_text() for p in tmp_path.rglob("*") if p.is_file())
     # which form each op took: the toy's shapes miss the lane tiles
-    for name in ("moe_held_assignments", "moe_load_max", "conv_plan[kind=xla]", "grouped_plan[kind=xla]"):
+    for name in ("moe_held_assignments", "moe_load_max", "conv_plan[kind=xla]", "grouped_plan[kind=xla]",
+                 "gated_delta_plan[kind=xla]"):
         assert name in recorded, name
