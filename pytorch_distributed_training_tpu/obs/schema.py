@@ -199,11 +199,13 @@ METRICS: dict[str, dict] = {
         "help": "chunked gated delta rule call sites (models/qwen3_next."
                 "GatedDeltaNet) traced so far, per kind: pallas (the chunk's "
                 "unit-lower solve as the gated_delta_solve_fwd / "
-                "gated_delta_solve_bwd Mosaic pair, whose counts per program "
-                "are mosaic_custom_calls[kernel=gated_delta_solve_fwd|"
-                "gated_delta_solve_bwd]) or xla (lax.linalg.triangular_solve: "
-                "keys or values no multiple of 128 lanes, a chunk other than "
-                "64, or a backend the kernels do not target); "
+                "gated_delta_solve_bwd Mosaic pair and the recurrence over "
+                "chunks with their output as the gated_delta_chunk_fwd / "
+                "gated_delta_chunk_bwd pair, whose counts per program are "
+                "mosaic_custom_calls[kernel=..]) or xla (lax.linalg."
+                "triangular_solve and a lax.scan over chunks: keys or values "
+                "no multiple of 128 lanes, a chunk other than 64, or a "
+                "backend the kernels do not target); "
                 "ops/gated_delta.gated_delta_plan decides from the shapes and "
                 "the backend alone",
     },

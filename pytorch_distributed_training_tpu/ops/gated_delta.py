@@ -34,14 +34,13 @@ Whole, at 8192 positions of 32 heads of 128, those are 3.4 GB alive in one
 layer's backward, which a v5e's 16 GB beside a 10 GB training state cannot
 hold (PERF.md section 4).
 
-Two forms of the solve, ``gated_delta_plan``'s choice from the shapes and
-the backend alone; the rest of the rule is the same ``lax`` / ``jnp`` code in
-both, with ``jax.grad``'s backward (a chunk's (C, C) blocks a head and the
-state entering each chunk are what it keeps), in chunks of ``CHUNK``:
+Two forms, ``gated_delta_plan``'s choice from the shapes and the backend
+alone, in chunks of ``CHUNK``; the blocks ``L`` and the right-hand side are
+the same ``lax`` / ``jnp`` code in both, with ``jax.grad``'s backward:
 
-- ``pallas``: a Mosaic pair under one ``jax.custom_vjp``
-  (``gated_delta_solve_fwd``, ``gated_delta_solve_bwd``) over a span's
-  chunk-heads, 128 a grid step.  The forward forms ``T = (I + L)^{-1}`` in
+- ``pallas``: two Mosaic pairs, each under one ``jax.custom_vjp``.  The
+  solve (``gated_delta_solve_fwd``, ``gated_delta_solve_bwd``) runs over a
+  span's chunk-heads, 128 a grid step.  The forward forms ``T = (I + L)^{-1}`` in
   VMEM by forward substitution with each chunk-head on a lane of its own, so
   the 63 dependent row steps are plain float32 multiply-adds over 128
   independent systems: ``L`` comes in rows-major (``(C, N, C)``, a
@@ -60,13 +59,43 @@ state entering each chunk are what it keeps), in chunks of ``CHUNK``:
   under the span's ``jax.checkpoint`` with everything else).  The backward
   is two products a chunk-head and no solve: ``d_rhs = Tᵀ d`` and ``d_L =
   -strict_lower(d_rhs · solvedᵀ)``, at ``HIGHEST`` too.  A Mosaic call is
-  opaque to the partitioner: under a mesh of several devices the pair runs a
-  shard inside a ``shard_map``, the batch over the batch axes and the heads
+  opaque to the partitioner: under a mesh of several devices each pair runs
+  a shard inside a ``shard_map``, the batch over the batch axes and the heads
   over ``tensor`` where each divides (``ops/attention._kernel_partition``,
-  as the flash pair).
-- ``xla``: ``lax.linalg.triangular_solve`` and its autodiff, where a head's
-  keys or values are no multiple of 128 or the backend is neither the TPU
-  nor the CPU's interpreter; the toy sizes, and the tests' second opinion.
+  as the flash pair; the chunk pair splits the key heads, a key head's value
+  heads staying together).
+  The recurrence over the span's chunks and their output
+  (``gated_delta_chunk_fwd``, ``gated_delta_chunk_bwd``) take the solve's
+  ``[U | W]`` as it lies, the queries and keys at their key heads (the
+  layer's (B, T, G·d_k), value head h reading key head h // (H / G) by the
+  block's lanes) and ``γ``, and write ``O`` as (B, T, H·d_v).  Grid (batch,
+  a block of value heads: whole key heads, ``_CHUNK_HEADS`` value heads, so
+  that their dependent chains interleave), a step's blocks the whole span;
+  the chunks are walked by a ``pl.loop`` with each head's (d_k, d_v) state
+  float32 in VMEM.  Per chunk and head ``V' = U - W S``, ``O = (e^{γ} ⊙ Q)
+  S + ((Q Kᵀ) ⊙ E) V'`` and ``S ← e^{γ_C} S + (e^{γ_C - γ} ⊙ K)ᵀ V'``: the
+  two products with S are one over ``[W; e^{γ} ⊙ Q]`` and the two with V'
+  one over ``[A; K̃ᵀ]``, ``Kᵀ`` turned once a key head and the decays formed
+  in whichever layout a product takes.  The forward writes nothing beyond
+  ``O`` and the state after the span, whether a backward follows or not
+  (a residual of the entering states would be 33.5 MB a span written by
+  every forward call, three a span in the cell's step against one
+  backward): the backward's first walk makes the states entering each chunk
+  and each ``V'`` again in VMEM from the inputs (5 MB for a step's four
+  heads at the cell), its second walks the chunks from the last with the
+  state's cotangent in VMEM and writes the cotangents of q, k (a key head's
+  value heads summed), ``[U | W]``, ``γ`` and the entering state.  Precision is the parent ``jnp`` form's: every
+  product accumulates in float32 and takes in ``q``'s dtype the operands the
+  parent casts (the float32 cotangents ``dS'``, ``dV'`` and ``dA ⊙ E``
+  stay float32 at the MXU's default precision, as the parent's transposed
+  products take them); ``γ``, every decay, ``V'``, ``S`` and ``dS`` are
+  float32.
+- ``xla``: ``lax.linalg.triangular_solve`` and its autodiff, and the
+  recurrence as a ``lax.scan`` over chunks with the output from the states
+  that entered them (``jax.grad``'s backward keeps a chunk's (C, C) blocks a
+  head and those states), where a head's keys or values are no multiple of
+  128 or the backend is neither the TPU nor the CPU's interpreter; the toy
+  sizes, and the tests' second opinion.
 """
 
 from __future__ import annotations
@@ -83,6 +112,7 @@ from jax.sharding import PartitionSpec
 
 from ..compat import shard_map
 from .attention import _kernel_partition
+from .ssd import _exact
 
 _F32 = jnp.float32
 _HIGHEST = lax.Precision.HIGHEST      # the precision of XLA's own products round its solve
@@ -94,7 +124,7 @@ SPAN_CHUNKS = 16                      # chunks a checkpointed span holds
 # Every ``pl.pallas_call`` below carries one of these as ``name`` (the HLO
 # instruction, and so the device trace's event, is ``%<name>.<n>``;
 # ``obs/cost.mosaic_kernels`` counts them).
-KERNEL_NAMES = ("gated_delta_solve_fwd", "gated_delta_solve_bwd")
+KERNEL_NAMES = ("gated_delta_solve_fwd", "gated_delta_solve_bwd", "gated_delta_chunk_fwd", "gated_delta_chunk_bwd")
 
 _FWD_HEADS = _LANES                   # chunk-heads a forward grid step: one a lane
 _BWD_HEADS = 64                       # chunk-heads a backward grid step
@@ -148,7 +178,10 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int | None = None):
     plan = gated_delta_plan(t, groups, heads, q.shape[-1], v.shape[-1], chunk=chunk)
     _plans_traced[plan.kind] = _plans_traced.get(plan.kind, 0) + 1
     chunk = plan.chunk
-    solve = _solve_xla if plan.kind == "xla" else functools.partial(_solve_pallas, interpret=plan.interpret)
+    solve, chunks = _solve_xla, None
+    if plan.kind == "pallas":
+        solve = functools.partial(_solve_pallas, interpret=plan.interpret)
+        chunks = functools.partial(_chunks_pallas, interpret=plan.interpret)
     span = min(chunk * SPAN_CHUNKS, -(-t // chunk) * chunk)
     pad = -t % span
     if pad:
@@ -156,7 +189,7 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int | None = None):
 
     @jax.checkpoint
     def one_span(state, inputs):
-        return _gated_delta_xla(*inputs, chunk, state, solve)
+        return _gated_delta_xla(*inputs, chunk, state, solve, chunks)
 
     spans = lambda m: jnp.moveaxis(m.reshape(bsz, -1, span, *m.shape[2:]), 1, 0)
     _, o = lax.scan(one_span, jnp.zeros((bsz, heads, q.shape[-1], v.shape[-1]), _F32),
@@ -164,15 +197,19 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int | None = None):
     return jnp.moveaxis(o, 0, 1).reshape(bsz, -1, heads, v.shape[-1])[:, :t]
 
 
-def _gated_delta_xla(q, k, v, g, beta, chunk, state, solve):
+def _gated_delta_xla(q, k, v, g, beta, chunk, state, solve, chunks=None):
     """The chunked rule of the module docstring over one span, from
     ``state`` (B, H, d_k, d_v) float32 → (the state after it, the span's
-    output); ``solve(lower, rhs)`` is ``(I + lower)^{-1} rhs`` a chunk-head."""
+    output); ``solve(lower, rhs)`` is ``(I + lower)^{-1} rhs`` a chunk-head;
+    ``chunks(q, k, solved, gamma, state)``, where given, is the recurrence
+    and the output from the solution (``_chunks_pallas``), in place of the
+    ``lax.scan`` below."""
     bsz, t, groups, dk = q.shape
     heads, dv, dtype = v.shape[2], v.shape[3], v.dtype
     nc, per = t // chunk, heads // groups
     dot = lambda spec, lhs, rhs: jnp.einsum(spec, lhs.astype(dtype), rhs.astype(dtype),
                                             preferred_element_type=_F32)
+    at_key_heads = q, k
     # (B, H, nc, C, ...): value head h next to key head h // per
     by_head = lambda m: jnp.moveaxis(m.reshape(bsz, nc, chunk, *m.shape[2:]), 3, 1)
     q, k = (jnp.repeat(by_head(m), per, axis=1) for m in (q, k))
@@ -191,6 +228,8 @@ def _gated_delta_xla(q, k, v, g, beta, chunk, state, solve):
     rhs = jnp.concatenate([beta[..., None] * v.astype(_F32),
                            (beta * jnp.exp(gamma))[..., None] * k.astype(_F32)], axis=-1)
     solved = solve(lower, rhs)
+    if chunks is not None:
+        return chunks(*(m.astype(dtype) for m in at_key_heads), solved, gamma, state)
     u, w = solved[..., :dv], solved[..., dv:]
     to_end = (jnp.exp(gamma[..., -1:] - gamma)[..., None] * k.astype(_F32))     # e^{γ_C - γ} ⊙ K
 
@@ -214,10 +253,11 @@ def _solve_xla(lower, rhs):
     return lax.linalg.triangular_solve(lower, rhs, left_side=True, lower=True, unit_diagonal=True)
 
 
-def _dot(lhs, rhs, contract=((1,), (0,))):
-    """A float32 product at ``HIGHEST``: ``contract`` names the lhs and rhs
-    dimension summed over ((0, 0): lhs transposed; (1, 1): rhs transposed)."""
-    return lax.dot_general(lhs, rhs, (contract, ((), ())), preferred_element_type=_F32, precision=_HIGHEST)
+def _dot(lhs, rhs, contract=((1,), (0,)), precision=_HIGHEST):
+    """A product accumulated in float32, at ``HIGHEST`` unless told:
+    ``contract`` names the lhs and rhs dimension summed over ((0, 0): lhs
+    transposed; (1, 1): rhs transposed)."""
+    return lax.dot_general(lhs, rhs, (contract, ((), ())), preferred_element_type=_F32, precision=precision)
 
 
 def _substitute(lower_t, inverse_t):
@@ -369,3 +409,289 @@ def _solve_pallas(lower, rhs, *, interpret):
     mesh, spec = partition                    # (batch axes, None, tensor, None)
     heads = PartitionSpec(spec[0], spec[2], None, None, None)
     return shard_map(kernels, mesh=mesh, in_specs=(heads, heads), out_specs=heads, check_vma=False)(lower, rhs)
+
+
+# ---- the recurrence over a span's chunks and their output: the chunk pair ----
+
+_CHUNK_HEADS = 4                      # value heads a grid step of the chunk pair: their dependent chains interleave
+_NT, _TN = ((1,), (1,)), ((0,), (0,))
+
+
+def _heads_a_step(groups: int, heads: int) -> int:
+    """Value heads a grid step of the chunk pair: whole key heads' worth
+    (``heads // groups`` each), as many as ``_CHUNK_HEADS`` holds."""
+    per = heads // groups
+    return per * max(m for m in range(1, groups + 1) if groups % m == 0 and per * m <= max(_CHUNK_HEADS, per))
+
+
+class _Decays(NamedTuple):
+    """A chunk-head's decays, from its ``γ`` along the lanes (1, C) and down
+    the rows (C, 1), and their transposes (``_t``: positions along the
+    lanes), formed as they are and not turned; no exponent is ever
+    positive."""
+
+    since: jax.Array        # (C, d_k) e^{γ_i}, each row one value
+    to_end: jax.Array       # (C, d_k) e^{γ_C - γ_i}
+    seen: jax.Array         # (C, C) E_ij = e^{γ_i - γ_j} on i >= j, 0 above
+    kept: jax.Array         # (d_k, d_v) e^{γ_C}
+    since_t: jax.Array      # (d_k, C)
+    to_end_t: jax.Array     # (d_k, C)
+    seen_t: jax.Array       # (C, C) Eᵀ
+
+
+def _decays(row, column, dk, dv):
+    chunk = row.shape[1]
+    rows, cols = (lax.broadcasted_iota(jnp.int32, (chunk, chunk), a) for a in (0, 1))
+    down, along = jnp.broadcast_to(column, (chunk, chunk)), jnp.broadcast_to(row, (chunk, chunk))
+    wide, tall = (lambda m: jnp.broadcast_to(m, (chunk, dk))), (lambda m: jnp.broadcast_to(m, (dk, chunk)))
+    last = jnp.broadcast_to(row[:, chunk - 1:], (1, chunk))
+    return _Decays(wide(jnp.exp(column)), wide(jnp.exp(column[chunk - 1:] - column)),
+                   jnp.exp(jnp.where(rows >= cols, down - along, -jnp.inf)),
+                   # e^{γ_C} along the lanes first: Mosaic broadcasts a scalar along one axis at a time
+                   jnp.broadcast_to(jnp.exp(jnp.broadcast_to(column[chunk - 1:], (1, dv))), (dk, dv)),
+                   tall(jnp.exp(row)), tall(jnp.exp(last - row)), jnp.exp(jnp.where(rows <= cols, along - down, -jnp.inf)))
+
+
+class _Step(NamedTuple):
+    """What a grid step reads of chunk ``c``: ``rows``, the chunk's
+    positions; ``gamma`` (heads, C), each value head's ``γ`` along the
+    lanes, and ``down``, the same down the rows (C, heads)."""
+
+    rows: object
+    gamma: jax.Array
+    down: jax.Array
+
+    @classmethod
+    def at(cls, c, gamma_ref):
+        chunk = gamma_ref.shape[4]
+        gamma = gamma_ref[0, 0, c]
+        return cls(pl.ds(pl.multiple_of(c * chunk, chunk), chunk), gamma, gamma.T)
+
+
+def _chunk_fwd_kernel(q_ref, k_ref, solved_ref, gamma_ref, state_ref, o_ref, out_ref):
+    """Grid (batch, a block of value heads): the span's chunks in order, a
+    ``pl.loop``, each head's float32 state in ``out_ref`` (VMEM until the
+    step ends).  Per chunk and head: ``V' = U - W S``, ``O = (e^{γ} ⊙ Q) S +
+    ((Q Kᵀ) ⊙ E) V'``, ``S ← e^{γ_C} S + (e^{γ_C - γ} ⊙ K)ᵀ V'``, the
+    products' operands in ``q``'s dtype (the parent ``jnp`` form's); the
+    two products with S run as one over ``[W; e^{γ} ⊙ Q]`` and the two with
+    V' as one over ``[(Q Kᵀ) ⊙ E; (e^{γ_C - γ} ⊙ K)ᵀ]``, so that each of
+    S and V' goes into the MXU once a chunk."""
+    heads, nc, chunk = solved_ref.shape[1:4]
+    dk, dv = out_ref.shape[2:]
+    per = heads * dk // q_ref.shape[2]
+    dtype = q_ref.dtype
+    mm = lambda lhs, rhs, contract=((1,), (0,)): _dot(lhs.astype(dtype), rhs.astype(dtype), contract, None)
+    out_ref[...] = state_ref[...]
+
+    @pl.loop(0, nc)
+    def _chunk(c):
+        step = _Step.at(c, gamma_ref)
+        for r in range(heads):
+            lanes = pl.ds((r // per) * dk, dk)
+            q, k = q_ref[0, step.rows, lanes], k_ref[0, step.rows, lanes]
+            if r % per == 0:
+                scores = mm(q, k, _NT)                                          # Q Kᵀ, a key head's
+                keys_t = k.astype(_F32).T                                       # Kᵀ (d_k, C)
+            decay = _decays(step.gamma[r:r + 1], step.down[:, r:r + 1], dk, dv)
+            solved, state = solved_ref[0, r, c], out_ref[0, r]
+            by_state = mm(jnp.concatenate([solved[:, dv:], decay.since * q.astype(_F32)]), state)
+            new = solved[:, :dv] - by_state[:chunk]                             # V' = U - W S
+            by_new = mm(jnp.concatenate([scores * decay.seen, keys_t * decay.to_end_t]), new)
+            o_ref[0, step.rows, pl.ds(r * dv, dv)] = (by_state[chunk:] + by_new[:chunk]).astype(o_ref.dtype)
+            out_ref[0, r] = decay.kept * state + by_new[chunk:]
+
+
+def _chunk_bwd_kernel(q_ref, k_ref, solved_ref, gamma_ref, state_ref, do_ref, dout_ref,
+                      dq_ref, dk_ref, dsolved_ref, dgamma_ref, dstate_ref, entering, values):
+    """The same grid.  A first ``pl.loop`` walks the chunks in order and
+    keeps the state entering each in ``entering`` (nc, heads, d_k, d_v)
+    float32 and each ``V'`` in ``values`` (nc, heads, C, d_v) in the
+    products' dtype — the forward's only residual is its inputs —, a second
+    walks them from the last with the state's cotangent ``dS'`` in
+    ``dstate_ref``:
+
+        dV' = Aᵀ dO + K̃ dS'        dU = dV'        dW = -dV' Sᵀ
+        dS  = Qdᵀ dO + e^{γ_C} dS' - Wᵀ dV'
+        dQ  = e^{γ} ⊙ (dO Sᵀ) + (dA ⊙ E) K       dK = e^{γ_C - γ} ⊙ (V' dS'ᵀ) + (dA ⊙ E)ᵀ Q
+
+    with ``Qd = e^{γ} ⊙ Q``, ``K̃ = e^{γ_C - γ} ⊙ K``, ``A = (Q Kᵀ) ⊙ E``
+    and ``dA = dO V'ᵀ``; a key head's ``dA ⊙ E`` is summed over its value
+    heads before its two products.  ``dγ_i = Σ_d dQd ⊙ Qd - Σ_d dK̃ ⊙ K̃ +
+    Σ_j M_ij - Σ_j M_ji`` with ``M = dA ⊙ A`` below the diagonal (the
+    diagonal's share is in both sums), and the last position also takes
+    ``Σ dK̃ ⊙ K̃ + e^{γ_C} Σ dS' ⊙ S``: the sums over the lanes come out down
+    the rows and are turned onto the lanes with the step's other heads'.
+    Operands the parent's ``jax.grad`` takes in ``q``'s dtype are cast to
+    it; the float32 cotangents ``dS'``, ``dV'`` and ``dA ⊙ E`` stay
+    float32, as in the parent's transposed products."""
+    heads, nc, chunk = solved_ref.shape[1:4]
+    dk, dv = dstate_ref.shape[2:]
+    per = heads * dk // q_ref.shape[2]
+    dtype = q_ref.dtype
+    mm = lambda lhs, rhs, contract=((1,), (0,)): _dot(lhs, rhs, contract, None)
+    rows_i, cols_i = (lax.broadcasted_iota(jnp.int32, (chunk, chunk), a) for a in (0, 1))
+    last = lax.broadcasted_iota(jnp.int32, (1, chunk), 1) == chunk - 1
+    entering[0] = state_ref[0]
+
+    @pl.loop(0, nc)
+    def _forward(c):
+        step = _Step.at(c, gamma_ref)
+        for r in range(heads):
+            if r % per == 0:
+                keys_t = k_ref[0, step.rows, pl.ds((r // per) * dk, dk)].astype(_F32).T
+            decay = _decays(step.gamma[r:r + 1], step.down[:, r:r + 1], dk, dv)
+            solved, state = solved_ref[0, r, c], entering[c, r]
+            new = (solved[:, :dv] - mm(solved[:, dv:].astype(dtype), state.astype(dtype))).astype(dtype)
+            values[c, r] = new
+
+            @pl.when(c < nc - 1)
+            def _carry():
+                entering[c + 1, r] = decay.kept * state + mm((keys_t * decay.to_end_t).astype(dtype), new)
+
+    dstate_ref[...] = dout_ref[...]
+
+    @pl.loop(0, nc)
+    def _reverse(back):
+        c = nc - 1 - back
+        step = _Step.at(c, gamma_ref)
+        down, along = [], []                  # each head's dγ: the sums over lanes (C, 1), over rows (1, C)
+        for i in range(heads // per):
+            lanes = pl.ds(i * dk, dk)
+            q, k = q_ref[0, step.rows, lanes], k_ref[0, step.rows, lanes]
+            scores, scores_t = mm(q, k, _NT), mm(k, q, _NT)                     # Q Kᵀ and K Qᵀ
+            queries_t = q.astype(_F32).T
+            dq = dk_sum = dp_sum = None
+            for r in range(i * per, (i + 1) * per):
+                decay = _decays(step.gamma[r:r + 1], step.down[:, r:r + 1], dk, dv)
+                state, dleaving, new_c = entering[c, r], dstate_ref[0, r], values[c, r]
+                state_c, w_c = state.astype(dtype), solved_ref[0, r, c, :, dv:].astype(dtype)
+                a = scores * decay.seen
+                qd, kt = decay.since * q.astype(_F32), decay.to_end * k.astype(_F32)
+                do = do_ref[0, step.rows, pl.ds(r * dv, dv)]
+                # [Aᵀ dO; Qdᵀ dO], both transposes formed directly
+                by_do = mm(jnp.concatenate([scores_t * decay.seen_t, queries_t * decay.since_t]).astype(dtype), do)
+                dnew = by_do[:chunk] + mm(kt.astype(dtype), dleaving)              # dV'
+                by_state = mm(jnp.concatenate([do.astype(_F32), dnew]), state_c, _NT)  # [dO Sᵀ; dV' Sᵀ]
+                dqd = by_state[:chunk]
+                dkt = mm(new_c, dleaving, _NT)                                    # V' dS'ᵀ
+                dstate_ref[0, r] = by_do[chunk:] + decay.kept * dleaving - mm(w_c, dnew, _TN)
+                dsolved_ref[0, r, c] = jnp.concatenate([dnew, -by_state[chunk:]], axis=1)
+                da = jnp.where(rows_i >= cols_i, mm(do, new_c, _NT), 0.0)
+                dp = da * decay.seen
+                dq_r, dk_r = decay.since * dqd, decay.to_end * dkt
+                dq, dk_sum, dp_sum = ((dq_r, dk_r, dp) if dq is None else (dq + dq_r, dk_sum + dk_r, dp_sum + dp))
+                moved = jnp.where(rows_i > cols_i, da * a, 0.0)
+                down.append(jnp.sum(dqd * qd - dkt * kt, axis=1, keepdims=True) + jnp.sum(moved, axis=1, keepdims=True))
+                total = jnp.sum(dkt * kt, keepdims=True) + decay.kept[:1, :1] * jnp.sum(dleaving * state, keepdims=True)
+                along.append(jnp.where(last, total, 0.0) - jnp.sum(moved, axis=0, keepdims=True))
+            dq_ref[0, step.rows, lanes] = (dq + mm(dp_sum, k)).astype(dq_ref.dtype)
+            dk_ref[0, step.rows, lanes] = (dk_sum + mm(dp_sum, q, _TN)).astype(dk_ref.dtype)
+        dgamma_ref[0, 0, c] = jnp.concatenate(down, axis=1).T + jnp.concatenate(along)
+
+
+def _chunk_specs(q, solved, state):
+    """The grid, value heads a step, and block specs of a (batch, block of
+    value heads) grid step: keys and queries (B, T, G·d_k) the step's key
+    heads, outputs (B, T, H·d_v) its value heads, the per-head operands (B,
+    H, …) whole past the head, and ``γ`` (B, H / step, nc, step, C) its
+    block's."""
+    bsz, t, _ = q.shape
+    heads, nc, chunk = solved.shape[1:4]
+    dk, dv = state.shape[2:]
+    step = _heads_a_step(q.shape[2] // dk, heads)
+    key_heads = step * q.shape[2] // (dk * heads)
+    keys = pl.BlockSpec((1, t, key_heads * dk), lambda b, j: (b, 0, j))
+    values = pl.BlockSpec((1, t, step * dv), lambda b, j: (b, 0, j))
+    per_head = lambda m: pl.BlockSpec((1, step) + m.shape[2:], lambda b, j: (b, j) + (0,) * (m.ndim - 2))
+    gamma = pl.BlockSpec((1, 1, nc, step, chunk), lambda b, j: (b, j, 0, 0, 0))
+    return (bsz, heads // step), step, keys, values, per_head, gamma
+
+
+def _gamma_blocks(gamma, step):
+    """(B, H, nc, C) → (B, H / step, nc, step, C): a grid step's heads of a
+    chunk in one (step, C) block."""
+    bsz, heads, nc, chunk = gamma.shape
+    return jnp.swapaxes(gamma.reshape(bsz, heads // step, step, nc, chunk), 2, 3)
+
+
+_CHUNK_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=_VMEM_BYTES)
+
+
+# Jitted on their own, as the solve's launchers are.
+@functools.partial(jax.jit, static_argnums=5)
+def _chunk_fwd(q, k, solved, gamma, state, interpret):
+    """q, k (B, T, G·d_k) in the products' dtype; solved (B, H, nc, C, d_v +
+    d_k), gamma (B, H, nc, C) and state (B, H, d_k, d_v) float32 → o (B, T,
+    H·d_v) in q's dtype and the state after the span."""
+    grid, step, keys, values, per_head, gammas = _chunk_specs(q, solved, state)
+    heads, dv = solved.shape[1], state.shape[3]
+    return pl.pallas_call(
+        _chunk_fwd_kernel,
+        grid=grid,
+        in_specs=[keys, keys, per_head(solved), gammas, per_head(state)],
+        out_specs=[values, per_head(state)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape[:2] + (heads * dv,), q.dtype), jax.ShapeDtypeStruct(state.shape, _F32)],
+        compiler_params=_CHUNK_PARAMS,
+        name="gated_delta_chunk_fwd",
+        interpret=interpret,
+    )(q, k, solved, _gamma_blocks(gamma, step), state)
+
+
+@functools.partial(jax.jit, static_argnums=7)
+def _chunk_bwd(q, k, solved, gamma, state, do, dout, interpret):
+    """The cotangents of ``_chunk_fwd``'s five operands from those of its
+    two results."""
+    grid, step, keys, values, per_head, gammas = _chunk_specs(q, solved, state)
+    nc = solved.shape[2]
+    blocks = _gamma_blocks(gamma, step)
+    dq, dk, dsolved, dgamma, dstate = pl.pallas_call(
+        _chunk_bwd_kernel,
+        grid=grid,
+        in_specs=[keys, keys, per_head(solved), gammas, per_head(state), values, per_head(state)],
+        out_specs=[keys, keys, per_head(solved), gammas, per_head(state)],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(solved.shape, _F32), jax.ShapeDtypeStruct(blocks.shape, _F32),
+                   jax.ShapeDtypeStruct(state.shape, _F32)],
+        scratch_shapes=[pltpu.VMEM((nc, step) + state.shape[2:], _F32),
+                        pltpu.VMEM((nc, step, solved.shape[3], state.shape[3]), q.dtype)],
+        compiler_params=_CHUNK_PARAMS,
+        name="gated_delta_chunk_bwd",
+        interpret=interpret,
+    )(q, k, solved, blocks, state, do, dout)
+    return dq, dk, dsolved, jnp.swapaxes(dgamma, 2, 3).reshape(gamma.shape), dstate
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _chunk_pair(q, k, solved, gamma, state, interpret):
+    return _chunk_fwd(q, k, solved, gamma, state, interpret)
+
+
+def _chunk_pair_fwd(q, k, solved, gamma, state, interpret):
+    return _chunk_fwd(q, k, solved, gamma, state, interpret), (q, k, solved, gamma, state)
+
+
+def _chunk_pair_bwd(interpret, saved, cotangents):
+    return _chunk_bwd(*saved, *cotangents, interpret)
+
+
+_chunk_pair.defvjp(_chunk_pair_fwd, _chunk_pair_bwd)
+
+
+def _chunks_pallas(q, k, solved, gamma, state, *, interpret):
+    """The ``pallas`` form's recurrence and output over a span: q, k (B, T,
+    G, d_k) at their key heads in the products' dtype, solved (B, H, nc, C,
+    d_v + d_k) the solve's ``[U | W]``, gamma (B, H, nc, C) and state (B,
+    H, d_k, d_v) float32 → (the state after the span, o (B, T, H, d_v))."""
+    def kernels(q, k, solved, gamma, state):  # the call's operands, or one device's shard of them
+        wide = lambda m: m.reshape(*m.shape[:2], -1)
+        o, state = _chunk_pair(wide(q), wide(k), solved, gamma, state, interpret)
+        return state, o.reshape(*o.shape[:2], state.shape[1], -1)
+
+    partition = _kernel_partition(q.shape[0], q.shape[2])
+    if partition is None:
+        return kernels(q, k, solved, gamma, state)
+    mesh, spec = partition                    # (batch axes, None, tensor, None): a key head's value heads stay together
+    per_head = lambda ndim: PartitionSpec(spec[0], spec[2], *(None,) * (ndim - 2))
+    return shard_map(kernels, mesh=mesh, in_specs=(spec, spec, per_head(5), per_head(4), per_head(4)),
+                     out_specs=(per_head(4), spec), check_vma=False)(q, k, solved, gamma, state)

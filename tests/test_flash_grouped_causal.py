@@ -715,16 +715,41 @@ def test_the_delta_rules_solve_pair_compiles_for_a_v5e_at_qwen3_nexts_shape(one_
     calls, _ = mosaic_calls_compiled_for(pair, shape(512, 64, 64), shape(512, 64, 256))
     bare = lambda result: re.sub(r"\{[^}]*\}", "", result.replace(" ", ""))
     results = {role: bare(result) for name, result in calls for role in gd.KERNEL_NAMES if role in name}
-    assert sorted(results) == sorted(gd.KERNEL_NAMES) and len(calls) == 2, calls
+    assert sorted(results) == ["gated_delta_solve_bwd", "gated_delta_solve_fwd"] and len(calls) == 2, calls
     assert results["gated_delta_solve_fwd"] == "(f32[512,64,256],f32[32768,64])"
     assert results["gated_delta_solve_bwd"] == "(f32[512,64,256],f32[512,64,64])"
+
+
+def test_the_delta_rules_chunk_pair_compiles_for_a_v5e_at_qwen3_nexts_shape(one_v5e):
+    """``ops/gated_delta.py``'s chunk recurrence and output at the Qwen3-Next
+    cell's call: a span of 1024 positions, 16 key heads and 32 value heads of
+    128, the solve's ``[U | W]`` read whole; the forward writes the output
+    (B, T, H·d_v) and the state after the span, the backward the cotangents
+    of q, k, the solution, γ's grid-step blocks and the entering state."""
+    from pytorch_distributed_training_tpu.ops import gated_delta as gd
+
+    def pair(q, k, solved, gamma, state):
+        out, vjp = jax.vjp(lambda *a: gd._chunk_pair(*a, False), q, k, solved, gamma, state)
+        return out, vjp(out)
+
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(dims, dtype, sharding=one_v5e)
+    calls, _ = mosaic_calls_compiled_for(pair, shape(1, 1024, 2048, dtype=jnp.bfloat16),
+                                         shape(1, 1024, 2048, dtype=jnp.bfloat16), shape(1, 32, 16, 64, 256),
+                                         shape(1, 32, 16, 64), shape(1, 32, 128, 128))
+    bare = lambda result: re.sub(r"\{[^}]*\}", "", result.replace(" ", ""))
+    results = {role: bare(result) for name, result in calls for role in gd.KERNEL_NAMES if role in name}
+    assert sorted(results) == ["gated_delta_chunk_bwd", "gated_delta_chunk_fwd"] and len(calls) == 2, calls
+    assert results["gated_delta_chunk_fwd"] == "(bf16[1,1024,4096],f32[1,32,128,128])"
+    assert results["gated_delta_chunk_bwd"] == ("(bf16[1,1024,2048],bf16[1,1024,2048],f32[1,32,16,64,256],"
+                                                "f32[1,8,16,4,64],f32[1,32,128,128])")
 
 
 def test_a_gated_deltanet_steps_solve_calls_are_under_gdn_delta_in_the_scope_table(one_v5e, monkeypatch):
     """One Gated DeltaNet layer at heads of 128 (toy depth and width), its
     gradient compiled for a described v5e: ``obs/cost.scope_table`` puts every
-    ``gated_delta_solve_*`` instruction under ``gdn/delta``, so
-    ``gdn.delta_share.train`` keeps reading the solve."""
+    ``gated_delta_solve_*`` and ``gated_delta_chunk_*`` instruction under
+    ``gdn/delta``, so ``gdn.delta_share.train`` keeps reading both pairs; the
+    chunks' ``lax.scan`` is gone (the spans' is the rule's one loop)."""
     from pytorch_distributed_training_tpu import models
     from pytorch_distributed_training_tpu.obs.cost import scope_table
     from pytorch_distributed_training_tpu.ops import gated_delta as gd
@@ -743,3 +768,6 @@ def test_a_gated_deltanet_steps_solve_calls_are_under_gdn_delta_in_the_scope_tab
     ours = {name: scope for name, scope in table.items() if name.startswith(gd.KERNEL_NAMES)}
     assert {name.split(".")[0] for name in ours} == set(gd.KERNEL_NAMES), ours
     assert set(ours.values()) == {"gdn/delta"}, ours
+    # the parent's chunk scan, inside the spans' scan, traced as ``gdn/delta/while/body/…/while``
+    loops = [line.split('op_name="')[1] for line in text.splitlines() if " while(" in line and "gdn/delta" in line]
+    assert not any("/while/body/" in loop for loop in loops), loops

@@ -6,8 +6,10 @@ and near -inf, β near 1 and entries of ``L`` near 1; the pair's gradients
 against ``jax.grad`` of the triangular-solve form; the whole rule in the
 ``pallas`` form against the position-by-position recurrence at heads of 128;
 the plan's answers; the kernels' names; the pair under a mesh of several
-devices; and a toy Qwen3-Next layer whose traced gradient holds both calls
-under ``gdn/delta``.  (The pair compiled for a described v5e at the cell's
+devices; and a toy Qwen3-Next layer whose traced gradient holds both pairs'
+calls under ``gdn/delta``.  (The chunk recurrence's pair alone is in
+``tests/test_gated_delta_chunk_pallas.py``; the whole rule here runs it
+too.)  (The pair compiled for a described v5e at the cell's
 call, and its calls under ``gdn/delta`` in ``obs/cost.scope_table`` of a
 compiled step, are in ``tests/test_flash_grouped_causal.py``, with the other
 compiles for a described chip.)"""
@@ -103,17 +105,26 @@ def rule_inputs(t, groups=1, heads=2, decay=(-3.0, 1.0)):
     return q, k, v, g, beta
 
 
-@pytest.mark.parametrize("t, decay", [(192, (-3.0, 1.0)), (128, (-6.0, 0.5)), (200, (2.0, 0.5))],
+def _rule_and_grads(fn):
+    cost = lambda *a: jnp.sum(jnp.sin(fn(*a)) * jnp.arange(128))
+    return jax.jit(fn), jax.jit(jax.grad(cost, argnums=(0, 1, 2, 3, 4)))
+
+
+# jitted once for the module: the cases of one length share their compiled programs
+RULE, RULE_GRADS = _rule_and_grads(gd.gated_delta_rule)
+RECURRENCE, RECURRENCE_GRADS = _rule_and_grads(recurrence)
+
+
+@pytest.mark.parametrize("t, decay", [(192, (-3.0, 1.0)), (192, (-6.0, 0.5)), (200, (2.0, 0.5))],
                          ids=["three-chunks", "near-0", "padded-near-inf"])
 def test_the_rule_in_the_pallas_form_is_the_recurrence_forward_and_backward(t, decay):
     args = rule_inputs(t, decay=decay)
     assert gd.gated_delta_plan(t, 1, 2, 128, 128) == ("pallas", 64, True)
-    want = recurrence(*args)
-    got = gd.gated_delta_rule(*args)
+    want = RECURRENCE(*args)
+    got = RULE(*args)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    cost = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)) * jnp.arange(128))
-    grads = jax.grad(cost(gd.gated_delta_rule), argnums=(0, 1, 2, 3, 4))(*args)
-    wants = jax.grad(cost(recurrence), argnums=(0, 1, 2, 3, 4))(*args)
+    grads = RULE_GRADS(*args)
+    wants = RECURRENCE_GRADS(*args)
     for name, g, w in zip("q k v g beta".split(), grads, wants):
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5 * max(1.0, float(jnp.abs(w).max())), err_msg=name)
 
@@ -155,10 +166,13 @@ def test_every_kernel_is_launched_under_a_listed_name_that_mosaic_kernels_counts
     calls = [n for n in ast.walk(tree) if isinstance(n, ast.Call) and ast.unparse(n.func) == "pl.pallas_call"]
     names = [next(k.value.value for k in call.keywords if k.arg == "name") for call in calls]
     assert sorted(names) == sorted(gd.KERNEL_NAMES)
-    assert gd.KERNEL_NAMES == ("gated_delta_solve_fwd", "gated_delta_solve_bwd")
+    assert gd.KERNEL_NAMES == ("gated_delta_solve_fwd", "gated_delta_solve_bwd", "gated_delta_chunk_fwd",
+                               "gated_delta_chunk_bwd")
     text = "\n".join(f'  %{name}.{i} = f32[512,64,256] custom-call(), custom_call_target="tpu_custom_call"'
-                     for i, name in enumerate(["gated_delta_solve_fwd"] * 3 + ["gated_delta_solve_bwd"]))
-    assert mosaic_kernels(text) == {"gated_delta_solve_fwd": 3, "gated_delta_solve_bwd": 1}
+                     for i, name in enumerate(["gated_delta_solve_fwd", "gated_delta_chunk_fwd"] * 3
+                                              + ["gated_delta_solve_bwd", "gated_delta_chunk_bwd"]))
+    assert mosaic_kernels(text) == {"gated_delta_solve_fwd": 3, "gated_delta_solve_bwd": 1,
+                                    "gated_delta_chunk_fwd": 3, "gated_delta_chunk_bwd": 1}
 
 
 @pytest.mark.parametrize("data, tensor", [(2, 2), (4, 1), (1, 4)])
@@ -187,9 +201,9 @@ LAYER = dict(vocab_size=256, hidden_size=128, num_hidden_layers=1, num_attention
 
 
 def test_a_layers_gradient_runs_the_pair_under_gdn_delta():
-    """Remat on: the forward kernel runs for the first forward and for the
-    span's recompute, the backward kernel once, all under ``gdn/delta``; the
-    layer's loss and gradients are the ``xla`` form's."""
+    """Remat on: each pair's forward kernel runs for the first forward and
+    for the span's recompute, its backward kernel once, all under
+    ``gdn/delta``; the layer's loss and gradients are the ``xla`` form's."""
     net = models.create_model("qwen3_next_80b_a3b", cfg_overrides={**LAYER, "remat": True})
     tokens = jax.random.randint(jax.random.PRNGKey(1), (1, 128), 0, 255)
     params = net.init(jax.random.PRNGKey(0), tokens, train=False)["params"]
@@ -198,7 +212,8 @@ def test_a_layers_gradient_runs_the_pair_under_gdn_delta():
     found = {name: scopes for name, scopes in kernels_in(grad, params).items() if name.startswith("gated_delta")}
     assert sorted(found) == sorted(gd.KERNEL_NAMES), found            # (the convolution's pair is there too)
     assert all("gdn/delta" in scope for name in gd.KERNEL_NAMES for scope in found[name]), found
-    assert len(found["gated_delta_solve_bwd"]) == 1 and len(found["gated_delta_solve_fwd"]) >= 2, found
+    for pair in ("solve", "chunk"):
+        assert len(found[f"gated_delta_{pair}_bwd"]) == 1 and len(found[f"gated_delta_{pair}_fwd"]) >= 2, found
     value, grads = jax.jit(grad)(params)
     xla = lambda *a, **k: gd.GatedDeltaPlan("xla", gd.CHUNK, False)
     with pytest.MonkeyPatch.context() as patch:
