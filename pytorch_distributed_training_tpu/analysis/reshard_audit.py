@@ -36,7 +36,8 @@ every entry is auditable:
   column-parallel qkv output by re-forming it replicated (bounded by the
   qkv activation size, so a param gather can never hide in this bucket);
 - the flat train step is f32 all-reduces only (one per gradient tensor,
-  plus the tied-embedding extra and the scalar metrics psum);
+  plus the tied-embedding extra and the scalar metrics psum), counted
+  per operand where XLA combined them into one tuple all-reduce;
 - the hier/compressed train steps carry exactly the two-tier engine's
   scoped collectives (``grad_sync/{rs_ici,ar_dcn,ag_ici}``, payload
   dtypes per codec) plus the scalar metrics psum;
@@ -277,17 +278,32 @@ def expected_inventory(prog: AuditProgram) -> list[ExpectedCollective]:
     )
 
 
+def carried(lines: Iterable[Any]) -> Iterable[Any]:
+    """Each collective line as the collectives it carries.  XLA's
+    all-reduce combiner folds independent all-reduces (the gradients'
+    psums) into one instruction over a tuple; each operand is still one
+    all-reduce of its own dtype and bytes, under the instruction's groups
+    and op_name, and is counted as one."""
+    for line in lines:
+        if line.op == "all-reduce" and len(line.shapes) > 1:
+            yield from (dataclasses.replace(line, shapes=(shape,))
+                        for shape in line.shapes)
+        else:
+            yield line
+
+
 def match_inventory(
     lines: Iterable[Any],
     expected: list[ExpectedCollective],
     program: str,
 ) -> tuple[list[Finding], dict[str, Any]]:
-    """Assign every collective line to the first expected pattern that
-    admits it; unmatched lines and violated count ranges are findings."""
+    """Assign every collective the lines carry (``carried``) to the first
+    expected pattern that admits it; unmatched collectives and violated
+    count ranges are findings."""
     findings: list[Finding] = []
     counts = [0] * len(expected)
     inventory: list[dict[str, Any]] = []
-    for line in lines:
+    for line in carried(lines):
         matched = None
         for i, exp in enumerate(expected):
             if exp.matches(line):
@@ -386,7 +402,7 @@ def train_memory_model(prog: AuditProgram) -> dict[str, int]:
     """Analytic per-device HBM model for one train-step program: every
     TrainState leaf over its ruleset's shard factor, the batch over the
     batch axes, the EF residual over the data axis, plus the activation
-    working-set estimate."""
+    working-set estimate (and the top-k codec's decode buffers)."""
     import jax
     import numpy as np
 
@@ -423,6 +439,12 @@ def train_memory_model(prog: AuditProgram) -> dict[str, int]:
         batch_rows_per_device=rows // batch_shard_size(mesh),
         seq_len=seq, vocab=vocab,
     )
+    if sync is not None and sync.config.mode == "hier-topk":
+        # The top-k codec decodes the gathered selections densely: every
+        # slice's pick scattered back into a float32 shard, beside the
+        # int32 iota the selection sorts — two (slices x shard) buffers,
+        # the shard being the residual's.
+        activations += 2 * sync.n_slices * resid_dev
     arguments = state_dev + batch_dev
     return {
         "params": params_dev,

@@ -496,25 +496,25 @@ def kv_block_model_bytes(
 
 def serve_activation_estimate(
     *, num_slots: int, width: int, hidden: int, num_heads: int,
-    vocab: int, mask_len: int, paged: bool = False,
-    cache_bytes: int = 0, itemsize: int = 4, head_dim: int = 0,
-    kv_quant: bool = False,
+    vocab: int, mask_len: int, cache_bytes: int = 0, itemsize: int = 4,
+    head_dim: int = 0, kv_quant: bool = False,
 ) -> int:
     """Coarse working-set estimate for one serving forward of ``width``
     positions per slot: the qkv/mlp intermediates, attention scores over
     the cache window, and the logits row — per LAYER, which is also the
-    peak (XLA reuses the buffers layer to layer).  Paged layouts add a
-    gather allowance (~cache/4) for the block-indexed K/V reads; a
-    QUANTIZED pool additionally materializes the dequantized f32 K and V
-    read windows (``(S, H, mask_len, Dh)`` each) on the XLA gather path
-    — the fused kernels dequantize per block tile in VMEM instead, which
-    is the point of in-kernel dequantization.  Calibrated to within ~15%
-    of CPU XLA's ``temp_size_in_bytes`` on the audit micro models; the
-    audit consumes it only inside the peak-total tolerance."""
+    peak (XLA reuses the buffers layer to layer) — plus one copy of the
+    pool (``cache_bytes``): XLA writes the new K/V rows by scattering into
+    a position-major relayout of every layer's K and V and relaying it
+    back (the contiguous decode program's buffer assignment shows it; the
+    paged and the interpreted kernels' programs hold one pool-sized copy
+    too).  A QUANTIZED pool additionally materializes the dequantized f32
+    K and V read windows (``(S, H, mask_len, Dh)`` each) on the XLA gather
+    path — the fused kernels dequantize per block tile in VMEM instead,
+    which is the point of in-kernel dequantization.  Calibrated against
+    CPU XLA's ``temp_size_in_bytes`` on the audit micro models; the audit
+    consumes it only inside the peak-total tolerance."""
     per_pos = 3 * hidden + 4 * hidden + vocab + num_heads * mask_len
-    est = num_slots * width * per_pos * itemsize
-    if paged:
-        est += cache_bytes // 4
+    est = num_slots * width * per_pos * itemsize + cache_bytes
     if kv_quant:
         est += 2 * num_slots * num_heads * mask_len * head_dim * 4
     return est

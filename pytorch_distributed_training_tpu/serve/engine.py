@@ -350,20 +350,6 @@ class ServingEngine:
         # Abstract-signature hash per AOT program (graftcheck's recompile
         # guard pins each to exactly one compile over a scheduler trace).
         self.program_signatures: dict[str, str] = {}
-        # Whether the programs about to be traced carry the fused Pallas
-        # kernels in INTERPRET mode (CPU backend + PDT_DECODE_ATTN=pallas
-        # — the forced-pallas test/audit path): the emulation scratches
-        # roughly one extra copy of the cache blocks, which the memory
-        # model must price or the pass-3 peak pin drifts.  Recorded NOW
-        # because the env override is read at trace time and often
-        # restored right after construction.
-        import os as _os
-
-        self._interpret_kernels = (
-            self.paged
-            and jax.default_backend() == "cpu"
-            and _os.environ.get("PDT_DECODE_ATTN", "").lower() == "pallas"
-        )
         self._prefill_fn, self._decode_fn, self._verify_fn = self._compile()
 
     # ------------------------------------------------------------------ #
@@ -1095,15 +1081,9 @@ class ServingEngine:
         activations = serve_activation_estimate(
             num_slots=s, width=width, hidden=cfg.hidden_dim,
             num_heads=cfg.num_heads, vocab=cfg.vocab_size,
-            mask_len=self.pool.mask_len, paged=self.paged,
-            cache_bytes=cache_dev, head_dim=head_dim,
-            kv_quant=self._kv_quant is not None,
+            mask_len=self.pool.mask_len, cache_bytes=cache_dev,
+            head_dim=head_dim, kv_quant=self._kv_quant is not None,
         )
-        if self._interpret_kernels:
-            # Interpret-mode Pallas emulation (forced-pallas on the CPU
-            # audit mesh) double-buffers the block operands: ~one extra
-            # cache-sized scratch copy in XLA temp.
-            activations += cache_dev
         arguments = params_dev + cache_dev + operands
         return {
             "params": params_dev,

@@ -27,9 +27,12 @@ from pytorch_distributed_training_tpu.utils.compile_cache import (  # noqa: E402
 set_cpu_device_count(8)
 jax.config.update("jax_threefry_partitionable", True)
 # Persistent compilation cache: the suite's cost is dominated by XLA
-# compiles of near-static graphs (pipeline schedules, GPT-2 step fns), so
-# warm reruns — including the CLI smoke tests' subprocesses, which share
-# the same cache — skip straight to execution.
+# compiles of near-static graphs (pipeline schedules, GPT-2 step fns).
+# Every xdist worker and every CLI smoke test's subprocess reads and
+# writes the one directory, so a program one process compiled for longer
+# than the threshold below is loaded, not compiled, by the next that
+# builds it, in this run and in warm reruns.  Each process still traces
+# and lowers on its own, and its in-memory caches are its own.
 enable_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
@@ -45,12 +48,14 @@ def devices8():
 
 @pytest.fixture(scope="session")
 def audit_programs(devices8):
-    """The graftcheck lowering cache, shared across test FILES: every
-    audited program (train step per --grad-sync mode + the zero1 leg +
-    all serving programs at tp=1/tp=2) lowered and compiled exactly once
-    per tier-1 run — pass 2's audits (tests/test_analysis.py) and pass
-    3's census/memory pins (tests/test_shardcheck.py) read the same
-    artifacts, mirroring the runner's shared-cache contract."""
+    """The graftcheck lowering cache: every audited program (train step
+    per --grad-sync mode + the zero1 leg + all serving programs at
+    tp=1/tp=2) lowered and compiled once per test process.  Under xdist
+    each worker is a process of its own and would build its own copy, so
+    every reader lives in one file, tests/test_shardcheck.py (pass 2's
+    audits and pass 3's census/memory pins), which ``--dist loadfile``
+    hands to one worker: one build a tier-1 run, read by both passes as
+    the runner's shared cache is."""
     from pytorch_distributed_training_tpu.analysis.hlo_audit import (
         build_audit_programs,
     )

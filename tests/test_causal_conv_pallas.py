@@ -5,6 +5,7 @@ alone; the mixer's traced program; and the names the benchmark's mixer share
 reads."""
 
 import ast
+import functools
 import inspect
 import json
 import os
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import compiled
 from pytorch_distributed_training_tpu import models
 from pytorch_distributed_training_tpu.obs.cost import mosaic_kernels
 from pytorch_distributed_training_tpu.ops import causal_conv as cc
@@ -59,15 +61,29 @@ def pair(wide, w, bias, offset=OFFSET, splits=SPLITS):
     return cc.causal_conv_silu(wide, w, bias, offset=offset, splits=splits)
 
 
-def cost(fn):
+def cost(*inputs, fn):
     """Every piece under another weight, so that a piece handed back in
     another's place shows."""
-    return lambda *inputs: sum((i + 1.0) * jnp.sum(jnp.sin(piece.astype(jnp.float32)))
-                               for i, piece in enumerate(fn(*inputs)))
+    return sum((i + 1.0) * jnp.sum(jnp.sin(piece.astype(jnp.float32))) for i, piece in enumerate(fn(*inputs)))
 
 
-def gradients(fn, args):
-    return jax.grad(cost(fn), argnums=(0, 1, 2))(*args)
+def late(*inputs, fn):
+    """The outputs past the first two time tiles under a sine, summed."""
+    return jnp.sum(jnp.sin(jnp.concatenate(fn(*inputs), axis=-1)[:, 256:]))
+
+
+def run(fn):
+    """``fn`` as the tests call it: the kernels and the ``jnp`` form as one
+    compiled program each, the definition op by op (its loop, unrolled into
+    one program, takes XLA minutes to compile where it runs in seconds)."""
+    return fn if fn is by_position else compiled.jit(fn)
+
+
+def gradients(fn, args, of=cost, argnums=(0, 1, 2)):
+    """The gradients of ``of`` at ``fn``, run as ``run`` runs ``fn``."""
+    if fn is by_position:
+        return jax.grad(functools.partial(of, fn=fn), argnums=argnums)(*args)
+    return compiled.grad(of, argnums=argnums, fn=fn)(*args)
 
 
 @pytest.mark.parametrize("kernel", [4, 2])
@@ -80,7 +96,7 @@ def test_the_pair_is_the_definition_in_float32(kernel, what):
     plan = cc.conv_plan(T, sum(SPLITS), kernel, 4, offset=OFFSET, splits=SPLITS)
     assert (plan.kind, plan.time_tile, plan.channel_tile) == ("pallas", 128, 128)
     if what == "y":
-        for got, want, same in zip(pair(*args), by_position(*args), plain(*args)):
+        for got, want, same in zip(*(run(fn)(*args) for fn in (pair, by_position, plain))):
             np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
             np.testing.assert_allclose(got, same, rtol=2e-5, atol=2e-6)
         return
@@ -97,7 +113,7 @@ def test_the_pair_is_the_definition_in_float32(kernel, what):
 def test_the_pair_in_bf16_rounds_where_the_jnp_form_does(kernel):
     args = conv_inputs(kernel, jnp.bfloat16)
     exact_args = (args[0].astype(jnp.float32),) + args[1:]
-    for got, same, exact in zip(pair(*args), plain(*args), by_position(*exact_args)):
+    for got, same, exact in zip(run(pair)(*args), run(plain)(*args), by_position(*exact_args)):
         assert got.dtype == jnp.bfloat16
         # float32 inside both: they differ by the output's rounding where the two float32 sums differ in the last bit
         assert float(jnp.abs(got.astype(jnp.float32) - same.astype(jnp.float32)).max()) <= 2 ** -7 * float(jnp.abs(exact).max())
@@ -118,13 +134,12 @@ def test_the_carries_over_three_time_tiles_are_not_small():
     tile take gradient from the tile after: a call on the last tile alone
     reads otherwise there, and nowhere else."""
     args = conv_inputs(4)
-    whole = jnp.concatenate(pair(*args), axis=-1)
-    alone = jnp.concatenate(pair(args[0][:, -128:], *args[1:]), axis=-1)
+    whole = jnp.concatenate(run(pair)(*args), axis=-1)
+    alone = jnp.concatenate(run(pair)(args[0][:, -128:], *args[1:]), axis=-1)
     assert float(jnp.abs(alone[:, :3] - whole[:, -128:-125]).max()) > 0.1
     np.testing.assert_allclose(alone[:, 3:], whole[:, -125:], rtol=1e-6, atol=1e-6)
-    late = lambda fn: lambda *inputs: jnp.sum(jnp.sin(jnp.concatenate(fn(*inputs), axis=-1)[:, 256:]))
-    got = jax.grad(late(pair))(*args)[..., OFFSET:OFFSET + sum(SPLITS)]
-    want = jax.grad(late(by_position))(*args)[..., OFFSET:OFFSET + sum(SPLITS)]
+    got = gradients(pair, args, late, 0)[..., OFFSET:OFFSET + sum(SPLITS)]
+    want = gradients(by_position, args, late, 0)[..., OFFSET:OFFSET + sum(SPLITS)]
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)
     assert float(jnp.abs(got[:, 253:256]).max()) > 0.01 and not np.asarray(got[:, :253]).any()
 

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 from flax import linen as nn
 
+import compiled
 from pytorch_distributed_training_tpu.models import resnet50
 from pytorch_distributed_training_tpu.ops import (
     FusedBNRelu,
@@ -269,8 +270,8 @@ def test_shallow_resnet_zero_init_residual_parity():
         )
         return jnp.sum(y * y)
 
-    lf, gf = jax.value_and_grad(lambda p: loss(fused, p))(v["params"])
-    lp, gp = jax.value_and_grad(lambda p: loss(plain, p))(v["params"])
+    lf, gf = compiled.value_and_grad(lambda p: loss(fused, p))(v["params"])
+    lp, gp = compiled.value_and_grad(lambda p: loss(plain, p))(v["params"])
     np.testing.assert_allclose(float(lf), float(lp), rtol=1e-5)
     np.testing.assert_allclose(
         np.asarray(ravel_pytree(gf)[0]), np.asarray(ravel_pytree(gp)[0]),
@@ -303,8 +304,8 @@ def test_resnet_fused_grads_match_plain():
         logp = jax.nn.log_softmax(logits)
         return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
 
-    gf = jax.grad(lambda p: loss(fused, p))(v["params"])
-    gp = jax.grad(lambda p: loss(plain, p))(v["params"])
+    gf = compiled.grad(lambda p: loss(fused, p))(v["params"])
+    gp = compiled.grad(lambda p: loss(plain, p))(v["params"])
     from jax.flatten_util import ravel_pytree
 
     flat_f = np.asarray(ravel_pytree(gf)[0])
@@ -344,8 +345,8 @@ def test_mini_resnet_fused_grads_exact_x64():
 
         from jax.flatten_util import ravel_pytree
 
-        gf = np.asarray(ravel_pytree(jax.grad(lambda p: loss(fused, p))(v["params"]))[0])
-        gp = np.asarray(ravel_pytree(jax.grad(lambda p: loss(plain, p))(v["params"]))[0])
+        gf = np.asarray(ravel_pytree(compiled.grad(lambda p: loss(fused, p))(v["params"]))[0])
+        gp = np.asarray(ravel_pytree(compiled.grad(lambda p: loss(plain, p))(v["params"]))[0])
         np.testing.assert_allclose(gf, gp, rtol=1e-6, atol=1e-9)
     finally:
         jax.config.update("jax_enable_x64", False)

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import compiled
 from pytorch_distributed_training_tpu.ops.gated_delta import gated_delta_plan, gated_delta_rule
 
 
@@ -39,16 +40,22 @@ def inputs(t=64, groups=2, heads=4, dk=8, dv=16, decay=(-3.0, 1.0)):
     return q, k, v, g, beta
 
 
+def cost(*args, chunk=None):
+    """The chunked rule's output (the recurrence's, without a chunk) under a sine and a weight a value lane."""
+    out = recurrence(*args) if chunk is None else gated_delta_rule(*args, chunk=chunk)
+    return jnp.sum(jnp.sin(out) * jnp.arange(args[2].shape[-1]))
+
+
+# the decay regime is data: the three of one (t, chunk) share these programs' compiles
 @pytest.mark.parametrize("t, chunk", [(64, 16), (64, 64), (48, 16), (40, 16), (16, 64), (96, 32)])
 @pytest.mark.parametrize("decay", [(-6.0, 0.5), (-1.0, 1.0), (3.0, 0.5)], ids=["near-0", "mixed", "near-inf"])
 def test_chunked_rule_is_the_recurrence_forward_and_backward(t, chunk, decay):
     args = inputs(t=t, decay=decay)
-    want = recurrence(*args)
-    got = gated_delta_rule(*args, chunk=chunk)
+    want = compiled.jit(recurrence)(*args)
+    got = compiled.jit(gated_delta_rule, chunk=chunk)(*args)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    cost = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a)) * jnp.arange(a[2].shape[-1]))
-    grads = jax.grad(cost(lambda *a: gated_delta_rule(*a, chunk=chunk)), argnums=(0, 1, 2, 3, 4))(*args)
-    wants = jax.grad(cost(recurrence), argnums=(0, 1, 2, 3, 4))(*args)
+    grads = compiled.grad(cost, argnums=(0, 1, 2, 3, 4), chunk=chunk)(*args)
+    wants = compiled.grad(cost, argnums=(0, 1, 2, 3, 4))(*args)
     for name, g, w in zip("q k v g beta".split(), grads, wants):
         assert bool(jnp.all(jnp.isfinite(g))), name
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5 * max(1.0, float(jnp.abs(w).max())), err_msg=name)
@@ -56,7 +63,7 @@ def test_chunked_rule_is_the_recurrence_forward_and_backward(t, chunk, decay):
 
 def test_the_state_carried_between_chunks_is_not_small():
     args = inputs(t=64, decay=(-4.0, 0.5))
-    want = recurrence(*args)
+    want = compiled.jit(recurrence)(*args)
     alone = gated_delta_rule(*(m[:, -16:] for m in args), chunk=16)      # the last chunk from a zero state
     assert float(jnp.abs(alone - want[:, -16:]).max()) > 0.05 * float(jnp.abs(want).max())
 
@@ -92,7 +99,7 @@ def test_bf16_inputs_keep_a_float32_state():
     """bf16 operands to the products, float32 decays, solve and state: the
     result stays near the float32 rule over several chunks."""
     args = inputs(t=128, decay=(-3.0, 0.5))
-    want = recurrence(*args)
+    want = compiled.jit(recurrence)(*args)
     low = [m.astype(jnp.bfloat16) if i < 3 else m for i, m in enumerate(args)]
     got = gated_delta_rule(*low, chunk=32)
     assert got.dtype == jnp.bfloat16

@@ -22,10 +22,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import compiled
 from pytorch_distributed_training_tpu import models
 from pytorch_distributed_training_tpu.obs.cost import mosaic_kernels
 from pytorch_distributed_training_tpu.ops import gated_delta as gd
-from test_gated_delta import recurrence
+from test_gated_delta import cost as rule_cost, recurrence
 from test_qk_norm_rope_pallas import kernels_in
 
 C, W = 64, 256                        # the cell's chunk, and d_v + d_k of its heads of 128
@@ -62,6 +63,10 @@ def pair(lower, rhs):
     return gd._solve_pallas(lower, rhs, interpret=True)
 
 
+def cost(lower, rhs, d, solve):
+    return jnp.sum(jnp.sin(solve(lower, rhs)) * d)
+
+
 @pytest.mark.parametrize("regime", sorted(REGIMES))
 def test_the_forward_kernel_is_the_triangular_solve(regime):
     """288 chunk-heads: three grid steps of 128, the last one padded; ``T``
@@ -69,14 +74,15 @@ def test_the_forward_kernel_is_the_triangular_solve(regime):
     lower, rhs = blocks(regime)
     if regime == "entries-near-1":
         assert float(jnp.abs(lower).max()) > 0.9
-    want = plain(lower, rhs)
-    np.testing.assert_allclose(pair(lower, rhs), want, rtol=1e-5, atol=1e-5 * float(jnp.abs(want).max()))
+    want = compiled.jit(plain)(lower, rhs)
+    np.testing.assert_allclose(compiled.jit(pair)(lower, rhs), want, rtol=1e-5, atol=1e-5 * float(jnp.abs(want).max()))
     flat = lambda m: m.reshape(-1, *m.shape[-2:])
     n = flat(lower).shape[0]
     padded = lambda m: jnp.pad(flat(m), ((0, -n % 128), (0, 0), (0, 0)))
-    solved, inverse = gd._solve_fwd(padded(lower), padded(rhs), True)
+    solved, inverse = compiled.jit(gd._solve_fwd, interpret=True)(padded(lower), padded(rhs))
     eye = jnp.eye(C)
-    np.testing.assert_allclose(inverse[:n], plain(flat(lower), jnp.broadcast_to(eye, (n, C, C))), atol=2e-5)
+    np.testing.assert_allclose(inverse[:n], compiled.jit(plain)(flat(lower), jnp.broadcast_to(eye, (n, C, C))),
+                               atol=2e-5)
     np.testing.assert_array_equal(inverse[n:], jnp.broadcast_to(eye, inverse[n:].shape))    # L 0: T is I
     assert float(jnp.abs(jnp.triu(inverse, 1)).max()) == 0.0
 
@@ -85,9 +91,8 @@ def test_the_forward_kernel_is_the_triangular_solve(regime):
 def test_the_pairs_gradients_are_the_triangular_solves(regime):
     lower, rhs = blocks(regime, lead=(1, 2, 80), seed=1)
     d = jax.random.normal(jax.random.PRNGKey(9), rhs.shape)
-    cost = lambda solve: lambda lower, rhs: jnp.sum(jnp.sin(solve(lower, rhs)) * d)
-    got = jax.grad(cost(pair), argnums=(0, 1))(lower, rhs)
-    want = jax.grad(cost(plain), argnums=(0, 1))(lower, rhs)
+    got = compiled.grad(cost, argnums=(0, 1), solve=pair)(lower, rhs, d)
+    want = compiled.grad(cost, argnums=(0, 1), solve=plain)(lower, rhs, d)
     for name, g, w in zip(("lower", "rhs"), got, want):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5 * float(jnp.abs(w).max()), err_msg=name)
     assert float(jnp.abs(jnp.triu(got[0])).max()) == 0.0            # only the strict lower part is read
@@ -105,26 +110,16 @@ def rule_inputs(t, groups=1, heads=2, decay=(-3.0, 1.0)):
     return q, k, v, g, beta
 
 
-def _rule_and_grads(fn):
-    cost = lambda *a: jnp.sum(jnp.sin(fn(*a)) * jnp.arange(128))
-    return jax.jit(fn), jax.jit(jax.grad(cost, argnums=(0, 1, 2, 3, 4)))
-
-
-# jitted once for the module: the cases of one length share their compiled programs
-RULE, RULE_GRADS = _rule_and_grads(gd.gated_delta_rule)
-RECURRENCE, RECURRENCE_GRADS = _rule_and_grads(recurrence)
-
-
 @pytest.mark.parametrize("t, decay", [(192, (-3.0, 1.0)), (192, (-6.0, 0.5)), (200, (2.0, 0.5))],
                          ids=["three-chunks", "near-0", "padded-near-inf"])
 def test_the_rule_in_the_pallas_form_is_the_recurrence_forward_and_backward(t, decay):
     args = rule_inputs(t, decay=decay)
     assert gd.gated_delta_plan(t, 1, 2, 128, 128) == ("pallas", 64, True)
-    want = RECURRENCE(*args)
-    got = RULE(*args)
+    want = compiled.jit(recurrence)(*args)
+    got = compiled.jit(gd.gated_delta_rule)(*args)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    grads = RULE_GRADS(*args)
-    wants = RECURRENCE_GRADS(*args)
+    grads = compiled.grad(rule_cost, argnums=(0, 1, 2, 3, 4), chunk=gd.CHUNK)(*args)       # the plan's chunk
+    wants = compiled.grad(rule_cost, argnums=(0, 1, 2, 3, 4))(*args)
     for name, g, w in zip("q k v g beta".split(), grads, wants):
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5 * max(1.0, float(jnp.abs(w).max())), err_msg=name)
 

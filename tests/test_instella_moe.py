@@ -4,10 +4,8 @@ router with its selection-only bias, the far-skip residual against the
 standard block, the MTP module's targets and shared leaves, the step that
 finds the objective on the model, and the CLI."""
 
+import functools
 import math
-import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +13,8 @@ import numpy as np
 import optax
 import pytest
 
+import compiled
+import toy_cli
 from pytorch_distributed_training_tpu import models, train
 from pytorch_distributed_training_tpu.models import instella_moe as im, moe
 from pytorch_distributed_training_tpu.ops import pallas_attention as pa
@@ -26,7 +26,10 @@ TOY = dict(vocab_size=512, hidden_size=64, num_hidden_layers=3, num_attention_he
 YARN = dict(im._YARN)
 
 
+@functools.cache
 def toy(**overrides):
+    """The toy model and its parameters, made once a file for each set of
+    overrides (a test that changes a leaf changes a copy)."""
     net = models.create_model("instella_moe_16b_a3b", cfg_overrides={**TOY, **overrides})
     params = net.init(jax.random.PRNGKey(1), jnp.zeros((1, 32), jnp.int32), train=False)["params"]
     return net, params
@@ -153,7 +156,7 @@ def test_mtp_predicts_two_ahead_with_the_trunks_head_and_embedding():
     np.testing.assert_allclose(moved[:, :9], mtp_logits[:, :9], rtol=1e-5, atol=1e-6)
     assert float(jnp.abs(moved[:, 9] - mtp_logits[:, 9]).max()) > 1e-4
     # both heads are the trunk's leaves: the MTP loss reaches them, and the embedding twice over
-    grads = jax.grad(lambda prm: cross_entropy_loss(apply(prm, tokens)[1][:, :-2], tokens[:, 2:]))(params)
+    grads = compiled.grad(lambda prm: cross_entropy_loss(apply(prm, tokens)[1][:, :-2], tokens[:, 2:]))(params)
     assert float(jnp.abs(grads["lm_head"]["kernel"]).max()) > 0 and float(jnp.abs(grads["embed"]).max()) > 0
     # the step's loss: next-token CE + 0.3 x CE two ahead + alpha x balance
     state = train.create_train_state(net, jax.random.PRNGKey(1), tokens, optax.sgd(0.0), init_kwargs={"train": False})
@@ -169,16 +172,26 @@ def test_mtp_predicts_two_ahead_with_the_trunks_head_and_embedding():
     assert float(metrics["moe_held_assignments"]) == 2 * 3 * 32 * 2         # all held: sequences x places x T x k
 
 
-@pytest.mark.parametrize("microbatches", [1, 2])
-def test_the_step_returns_the_loss_parts_and_counters(microbatches):
+@pytest.fixture(scope="module")
+def trained():
+    """The toy's state and its train step for one microbatch and for two,
+    each built and compiled once for the file: AdamW with its rate as state,
+    remat, a batch of (4, 32)."""
     net, _ = toy(experts_held=(2, 4), remat=True)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (4, 32), 0, 512)
-    state = train.create_train_state(net, jax.random.PRNGKey(1), tokens[:1], optax.adamw(1e-3),
+    state = train.create_train_state(net, jax.random.PRNGKey(1), tokens[:1], compiled.adamw(),
                                      init_kwargs={"train": False})
+    return tokens, state, {n: train.make_train_step(kind="lm", num_microbatches=n) for n in (1, 2)}
+
+
+@pytest.mark.parametrize("microbatches", [1, 2])
+def test_the_step_returns_the_loss_parts_and_counters(trained, microbatches):
+    tokens, state, steps = trained
+    state = compiled.at_rate(state, 1e-3)
     bias = np.asarray(state.params["block_1"]["moe"]["router_bias"])      # the step donates its state
     losses = []
     for _ in range(3):
-        state, metrics = train.make_train_step(kind="lm", num_microbatches=microbatches)(state, {"tokens": tokens})
+        state, metrics = steps[microbatches](state, {"tokens": tokens})
         losses.append(float(metrics["loss"]))
         assert set(train.step.STEP_LOSS_PARTS) <= set(metrics) and "moe_load_max" in metrics
         assert 0 < float(metrics["moe_held_assignments"]) < 4 * 3 * 32 * 2
@@ -196,7 +209,7 @@ def test_remat_wraps_the_pair_and_changes_nothing():
         def loss(prm):
             (a, b), _ = net.apply({"params": prm}, tokens, mtp=True, mutable=["losses", "moe_counters"])
             return cross_entropy_loss(a[:, :-1], tokens[:, 1:]) + cross_entropy_loss(b[:, :-2], tokens[:, 2:])
-        return jax.grad(loss)(params)
+        return compiled.grad(loss)(params)
 
     for a, b in zip(jax.tree_util.tree_leaves(grads(plain)), jax.tree_util.tree_leaves(grads(remat))):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
@@ -230,31 +243,20 @@ def test_the_causal_multi_tile_launch_notes_its_visited_share(monkeypatch):
     assert pa.flash_visited_pair_share() == {"flash_fwd": 1.0}
 
 
-def test_compiled_step_names_the_new_phases():
+def test_compiled_step_names_the_new_phases(trained):
     from pytorch_distributed_training_tpu.obs.schema import METRICS
     from pytorch_distributed_training_tpu.obs.trace import PHASES
 
     assert set(train.step.STEP_LOSS_PARTS) <= set(METRICS)
-    net, _ = toy(remat=True)
-    tokens = jnp.zeros((2, 32), jnp.int32)
-    state = train.create_train_state(net, jax.random.PRNGKey(1), tokens, optax.sgd(0.1), init_kwargs={"train": False})
-    text = train.make_train_step(kind="lm").lower(state, {"tokens": tokens}).compile().as_text()
+    tokens, state, steps = trained
+    text = steps[1].lower(state, {"tokens": tokens}).compile().as_text()         # a program the file compiled
     for phase in ("attn/mla", "moe/shared", "train/mtp", "moe/route", "moe/experts", "train/loss"):
         assert phase in PHASES and phase in text, phase
 
 
 def test_cli_trains_the_toy_size(tmp_path):
     overrides = ",".join(f"{k}={v}" for k, v in TOY.items()) + ",experts_held=2:4"
-    out = subprocess.run(
-        [sys.executable, "-m", "pytorch_distributed_training_tpu.cli.main", "--use-cpu", "--model",
-         "instella_moe_16b_a3b", "--dataset", "synthetic-tokens", "--seq-len", "32", "--model-overrides", overrides,
-         "--batch-size", "4", "--accum-steps", "2", "--num-workers", "0", "--steps-per-epoch", "3",
-         "--learning-rate", "1e-3", "--remat", "--metrics-dir", str(tmp_path)],
-        capture_output=True, text=True, timeout=600,
-        # one CPU device: the suite's 8-device XLA_FLAGS would want a batch of 8
-        env={k: v for k, v in {**os.environ, "JAX_PLATFORMS": "cpu"}.items() if k != "XLA_FLAGS"},
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    )
+    out = toy_cli.train("instella_moe_16b_a3b", overrides, "--remat", "--metrics-dir", str(tmp_path))
     assert out.returncode == 0, out.stderr[-3000:] + out.stdout[-2000:]
     assert "training started" in out.stdout
     recorded = "".join(p.read_text() for p in tmp_path.rglob("*") if p.is_file())

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import compiled
 from pytorch_distributed_training_tpu.models import (
     GPT2Config,
     create_model,
@@ -105,7 +106,7 @@ def _remat_parity(build, sample):
         def loss(p):
             return jnp.mean(m.apply({"params": p}, sample, train=True) ** 2)
 
-        results[remat] = jax.value_and_grad(loss)(v["params"])
+        results[remat] = compiled.value_and_grad(loss)(v["params"])
     (l0, g0), (l1, g1) = results[False], results[True]
     np.testing.assert_allclose(float(l1), float(l0), rtol=1e-6)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
@@ -263,8 +264,8 @@ def test_vit_attn_layout_variants_parity():
     def loss(m, p):
         return jnp.sum(m.apply({"params": p}, x, train=False) ** 2)
 
-    g_auto = jax.grad(lambda p: loss(models["auto"], p))(ref)
-    g_bhld2 = jax.grad(lambda p: loss(models["bhld2"], p))(ref)
+    g_auto = compiled.grad(lambda p: loss(models["auto"], p))(ref)
+    g_bhld2 = compiled.grad(lambda p: loss(models["bhld2"], p))(ref)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=2e-3, rtol=2e-3

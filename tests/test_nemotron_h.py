@@ -4,9 +4,7 @@ recurrence, the convolution's causality, ``TopKMoe``'s plain experts against a
 dense loop and the 16-share sum, grouped K/V under the causal mask, the step
 that trains it by plain next-token cross entropy, and the CLI."""
 
-import os
-import subprocess
-import sys
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +12,8 @@ import numpy as np
 import optax
 import pytest
 
+import compiled
+import toy_cli
 from benchmark.reference import nemotron_h as ref
 from pytorch_distributed_training_tpu import models, train
 from pytorch_distributed_training_tpu.models import moe, nemotron_h as nh
@@ -28,7 +28,10 @@ TOY = dict(vocab_size=512, hidden_size=64, hybrid_override_pattern="ME*ME", num_
            num_experts_per_tok=2, moe_intermediate_size=32, moe_shared_expert_intermediate_size=64)
 
 
+@functools.cache
 def toy(**overrides):
+    """The toy model and its parameters, made once a file for each set of
+    overrides (a test that changes a leaf changes a copy)."""
     net = models.create_model("nemotron_h_30b_a3b", cfg_overrides={**TOY, **overrides})
     params = net.init(jax.random.PRNGKey(1), jnp.zeros((1, 32), jnp.int32), train=False)["params"]
     return net, params
@@ -73,17 +76,22 @@ def scan_inputs(t=64, h=4, p=8, g=2, n=16):
             -jnp.arange(1.0, h + 1.0), jax.random.normal(k[2], (2, t, g, n)), jax.random.normal(k[3], (2, t, g, n)))
 
 
+def scan_cost(*inputs, chunk=None):
+    """The chunked scan's output (the recurrence's, without a chunk) under a sine, summed."""
+    return jnp.sum(jnp.sin(recurrence(*inputs) if chunk is None else ssd_chunked(*inputs, chunk=chunk)))
+
+
 @pytest.mark.parametrize("chunk", [16, 32])           # four chunks and two
 def test_chunked_scan_is_the_recurrence_forward_and_backward(chunk):
     args = scan_inputs()
-    want = recurrence(*args)
-    np.testing.assert_allclose(ssd_chunked(*args, chunk=chunk), want, rtol=2e-5, atol=2e-5)
+    want = compiled.jit(recurrence)(*args)
+    scan = compiled.jit(ssd_chunked, chunk=chunk)
+    np.testing.assert_allclose(scan(*args), want, rtol=2e-5, atol=2e-5)
     # the state carried between chunks is not small: the last chunk alone, from a zero state, reads otherwise
-    alone = ssd_chunked(*(m[:, -chunk:] if m.ndim > 1 else m for m in args), chunk=chunk)
+    alone = scan(*(m[:, -chunk:] if m.ndim > 1 else m for m in args))
     assert float(jnp.abs(alone - want[:, -chunk:]).max()) > 0.05 * float(jnp.abs(want).max())
-    cost = lambda fn: lambda *inputs: jnp.sum(jnp.sin(fn(*inputs)))
-    got = jax.grad(cost(lambda *inputs: ssd_chunked(*inputs, chunk=chunk)), argnums=(0, 1, 2, 3, 4))(*args)
-    for name, g, w in zip("x dt a b c".split(), got, jax.grad(cost(recurrence), argnums=(0, 1, 2, 3, 4))(*args)):
+    got = compiled.grad(scan_cost, argnums=(0, 1, 2, 3, 4), chunk=chunk)(*args)
+    for name, g, w in zip("x dt a b c".split(), got, compiled.grad(scan_cost, argnums=(0, 1, 2, 3, 4))(*args)):
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4 * float(jnp.abs(w).max()), err_msg=name)
 
 
@@ -123,11 +131,11 @@ def test_the_model_is_the_reference(held):
                      if "moe_held_assignments" in jax.tree_util.keystr(path))
         return cross_entropy_loss(logits[:, :-1], tokens[:, 1:]), (logits, held_n)
 
-    (want_loss, (want_logits, want_held)), want_grads = jax.value_and_grad(system_loss, has_aux=True)(params)
-    with jax.default_matmul_precision("highest"):
-        for n in range(2):
-            np.testing.assert_allclose(ref.logits_of(params, tokens[n], cfg)[0], want_logits[n], rtol=2e-4, atol=2e-5)
-        got_loss, parts, got_grads, got_held = ref.loss_and_grads(params, tokens, cfg)
+    (want_loss, (want_logits, want_held)), want_grads = compiled.value_and_grad(system_loss, has_aux=True)(params)
+    logits_of = compiled.reference(ref.logits_of, cfg)                  # at the "highest" matmul precision
+    for n in range(2):
+        np.testing.assert_allclose(logits_of(params, tokens[n])[0], want_logits[n], rtol=2e-4, atol=2e-5)
+    got_loss, parts, got_grads, got_held = compiled.reference(ref.loss_and_grads, cfg)(params, tokens)
     assert float(got_loss) == pytest.approx(float(want_loss), rel=1e-5) and parts.shape == (1,)
     assert float(got_held) == float(want_held) and (held is not None or float(got_held) == 2 * 2 * 32 * 2)
     flat_want = dict(jax.tree_util.tree_leaves_with_path(want_grads))
@@ -306,21 +314,31 @@ def test_the_cells_attention_call_notes_its_visited_share(monkeypatch):
     assert pa.flash_visited_pair_share() == {"flash_fwd": (28 + 8 * 36 / 64) / 64, "flash_bwd": (28 + 8 * 10 / 16) / 64}
 
 
-def test_the_step_trains_it_by_next_token_cross_entropy_and_returns_the_counters():
+@pytest.fixture(scope="module")
+def trained():
+    """The toy's train step, built and compiled once for the file: AdamW
+    with its rate as state, remat, two microbatches of (2, 32)."""
     net, _ = toy(experts_held=(2, 4), remat=True)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (4, 32), 0, 512)
-    state = train.create_train_state(net, jax.random.PRNGKey(1), tokens[:1], optax.sgd(0.0), init_kwargs={"train": False})
+    state = train.create_train_state(net, jax.random.PRNGKey(1), tokens[:1], compiled.adamw(),
+                                     init_kwargs={"train": False})
+    return net, tokens, state, train.make_train_step(kind="lm", num_microbatches=2)
+
+
+def test_the_step_trains_it_by_next_token_cross_entropy_and_returns_the_counters(trained):
+    net, tokens, state, step = trained
+    state = compiled.at_rate(state, 0.0)
     assert train.step.lm_objective(state) == ("next_token", None)
-    state, metrics = train.make_train_step(kind="lm", num_microbatches=2)(state, {"tokens": tokens})   # rate 0: same weights
+    state, metrics = step(state, {"tokens": tokens})                             # rate 0: same weights
     logits = net.apply({"params": state.params}, tokens, mutable=["moe_counters"])[0]
     assert float(metrics["loss"]) == pytest.approx(float(cross_entropy_loss(logits[:, :-1], tokens[:, 1:])), rel=1e-5)
     assert set(train.step.STEP_LOSS_PARTS).isdisjoint(metrics) and "moe_load_max" in metrics
     assert 0 < float(metrics["moe_held_assignments"]) < 4 * 2 * 32 * 2           # sequences x E layers x T x k
-    state = state.replace(tx=optax.adamw(1e-3), opt_state=optax.adamw(1e-3).init(state.params))
+    state = compiled.at_rate(state, 1e-3)
     bias = np.asarray(state.params["block_1"]["moe"]["router_bias"])             # the step donates its state
     losses = []
     for _ in range(3):
-        state, metrics = train.make_train_step(kind="lm", num_microbatches=2)(state, {"tokens": tokens})
+        state, metrics = step(state, {"tokens": tokens})
         losses.append(float(metrics["loss"]))
     assert losses[2] < losses[0]
     np.testing.assert_array_equal(state.params["block_1"]["moe"]["router_bias"], bias)   # nothing updates it
@@ -357,7 +375,7 @@ def test_remat_changes_nothing():
         def loss(prm):
             logits, _ = net.apply({"params": prm}, tokens, mutable=["moe_counters"])
             return cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
-        return jax.grad(loss)(params)
+        return compiled.grad(loss)(params)
 
     for a, b in zip(jax.tree_util.tree_leaves(grads(plain)), jax.tree_util.tree_leaves(grads(remat))):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
@@ -375,31 +393,20 @@ def test_eval_and_hidden_states():
         net.apply({"params": params}, tokens[:, :24])
 
 
-def test_compiled_step_names_the_new_phases():
+def test_compiled_step_names_the_new_phases(trained):
     from pytorch_distributed_training_tpu.obs.schema import METRICS
     from pytorch_distributed_training_tpu.obs.trace import PHASES
 
     assert {"moe_held_assignments", "moe_load_max"} <= set(METRICS)
-    net, _ = toy(remat=True)
-    tokens = jnp.zeros((2, 32), jnp.int32)
-    state = train.create_train_state(net, jax.random.PRNGKey(1), tokens, optax.sgd(0.1), init_kwargs={"train": False})
-    text = train.make_train_step(kind="lm").lower(state, {"tokens": tokens}).compile().as_text()
+    _, tokens, state, step = trained
+    text = step.lower(state, {"tokens": tokens}).compile().as_text()             # the program the file compiled
     for phase in ("ssm/conv", "ssm/scan", "ssm/gate", "moe/route", "moe/experts", "moe/shared", "train/loss"):
         assert phase in PHASES and phase in text, phase
 
 
 def test_cli_trains_the_toy_size(tmp_path):
     overrides = ",".join(f"{k}={v}" for k, v in TOY.items()) + ",experts_held=2:4"
-    out = subprocess.run(
-        [sys.executable, "-m", "pytorch_distributed_training_tpu.cli.main", "--use-cpu", "--model",
-         "nemotron_h_30b_a3b", "--dataset", "synthetic-tokens", "--seq-len", "32", "--model-overrides", overrides,
-         "--batch-size", "4", "--accum-steps", "2", "--num-workers", "0", "--steps-per-epoch", "3",
-         "--learning-rate", "1e-3", "--remat", "--metrics-dir", str(tmp_path)],
-        capture_output=True, text=True, timeout=600,
-        # one CPU device: the suite's 8-device XLA_FLAGS would want a batch of 8
-        env={k: v for k, v in {**os.environ, "JAX_PLATFORMS": "cpu"}.items() if k != "XLA_FLAGS"},
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    )
+    out = toy_cli.train("nemotron_h_30b_a3b", overrides, "--remat", "--metrics-dir", str(tmp_path))
     assert out.returncode == 0, out.stderr[-3000:] + out.stdout[-2000:]
     assert "training started" in out.stdout
     recorded = "".join(p.read_text() for p in tmp_path.rglob("*") if p.is_file())

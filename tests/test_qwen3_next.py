@@ -3,16 +3,15 @@ the plain reference (loss, logits and every leaf's gradient), the 3 : 1 layer
 pattern, the zero-centred norms, the gated shared expert, the expert shares
 against the uncut layer, the step that trains it, and the CLI."""
 
-import os
-import subprocess
-import sys
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
+import compiled
+import toy_cli
 from benchmark.reference import qwen3_next as ref
 from pytorch_distributed_training_tpu import models, train
 from pytorch_distributed_training_tpu.models import moe, qwen3_next as qn
@@ -29,7 +28,10 @@ KEYS = ("full_attention_interval", "rms_norm_eps", "num_attention_heads", "num_k
         "linear_key_head_dim", "linear_value_head_dim", "num_experts_per_tok", "norm_topk_prob")
 
 
+@functools.cache
 def toy(**overrides):
+    """The toy model and its parameters, made once a file for each set of
+    overrides (a test that changes a leaf changes a copy)."""
     net = models.create_model("qwen3_next_80b_a3b", cfg_overrides={**TOY, **overrides})
     params = net.init(jax.random.PRNGKey(1), jnp.zeros((1, 32), jnp.int32), train=False)["params"]
     return net, params
@@ -72,12 +74,12 @@ def test_the_model_is_the_reference(held, length):
     net, params = toy(experts_held=held)
     params, cfg = moved(params), reference_config(net)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, length), 0, 512)
-    (want_loss, (want_logits, want_held)), want_grads = jax.value_and_grad(
+    (want_loss, (want_logits, want_held)), want_grads = compiled.value_and_grad(
         system_loss(net, tokens), has_aux=True)(params)
-    with jax.default_matmul_precision("highest"):
-        for n in range(2):
-            np.testing.assert_allclose(ref.logits_of(params, tokens[n], cfg)[0], want_logits[n], rtol=1e-4, atol=2e-5)
-        got_loss, parts, got_grads, got_held = ref.loss_and_grads(params, tokens, cfg)
+    logits_of = compiled.reference(ref.logits_of, cfg)                  # at the "highest" matmul precision
+    for n in range(2):
+        np.testing.assert_allclose(logits_of(params, tokens[n])[0], want_logits[n], rtol=1e-4, atol=2e-5)
+    got_loss, parts, got_grads, got_held = compiled.reference(ref.loss_and_grads, cfg)(params, tokens)
     assert float(got_loss) == pytest.approx(float(want_loss), rel=1e-4) and parts.shape == (1,)
     assert float(got_held) == float(want_held) and (held is not None or float(got_held) == 2 * 4 * length * 4)
     flat_want = dict(jax.tree_util.tree_leaves_with_path(want_grads))
@@ -197,18 +199,27 @@ def test_the_reference_rule_is_the_written_recurrence():
             np.testing.assert_allclose(got[t, h], state[h] @ q[t, h], rtol=1e-5, atol=1e-6)
 
 
-def test_the_step_trains_it_by_next_token_cross_entropy_and_returns_the_counters():
+@pytest.fixture(scope="module")
+def trained():
+    """The toy's train step, built and compiled once for the file: AdamW
+    with its rate as state, remat, two microbatches of (2, 32)."""
     net, _ = toy(experts_held=(4, 8), remat=True)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (4, 32), 0, 512)
-    state = train.create_train_state(net, jax.random.PRNGKey(1), tokens[:1], optax.sgd(0.0), init_kwargs={"train": False})
+    state = train.create_train_state(net, jax.random.PRNGKey(1), tokens[:1], compiled.adamw(),
+                                     init_kwargs={"train": False})
+    return net, tokens, state, train.make_train_step(kind="lm", num_microbatches=2)
+
+
+def test_the_step_trains_it_by_next_token_cross_entropy_and_returns_the_counters(trained):
+    net, tokens, state, step = trained
+    state = compiled.at_rate(state, 0.0)
     assert train.step.lm_objective(state) == ("next_token", None) and state.float32_params == ("A_log", "dt_bias")
-    state, metrics = train.make_train_step(kind="lm", num_microbatches=2)(state, {"tokens": tokens})   # rate 0: same weights
+    state, metrics = step(state, {"tokens": tokens})                             # rate 0: same weights
     logits = net.apply({"params": state.params}, tokens, mutable=["moe_counters"])[0]
     assert float(metrics["loss"]) == pytest.approx(float(cross_entropy_loss(logits[:, :-1], tokens[:, 1:])), rel=1e-5)
     assert set(train.step.STEP_LOSS_PARTS).isdisjoint(metrics) and "moe_load_max" in metrics
     assert 0 < float(metrics["moe_held_assignments"]) < 4 * 4 * 32 * 4           # sequences x layers x T x k
-    state = state.replace(tx=optax.adamw(1e-3), opt_state=optax.adamw(1e-3).init(state.params))
-    step, losses = train.make_train_step(kind="lm", num_microbatches=2), []
+    state, losses = compiled.at_rate(state, 1e-3), []
     for _ in range(3):
         state, metrics = step(state, {"tokens": tokens})
         losses.append(float(metrics["loss"]))
@@ -229,18 +240,16 @@ def test_remat_changes_nothing():
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 32), 0, 512)
     plain, params = toy()
     remat, _ = toy(remat=True)
-    grads = lambda net: jax.grad(lambda prm: system_loss(net, tokens)(prm)[0])(params)
+    grads = lambda net: compiled.grad(system_loss(net, tokens), has_aux=True)(params)[0]
     for a, b in zip(jax.tree_util.tree_leaves(grads(plain)), jax.tree_util.tree_leaves(grads(remat))):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
 
 
-def test_compiled_step_names_the_new_phases():
+def test_compiled_step_names_the_new_phases(trained):
     from pytorch_distributed_training_tpu.obs.trace import PHASES
 
-    net, _ = toy(remat=True)
-    tokens = jnp.zeros((2, 32), jnp.int32)
-    state = train.create_train_state(net, jax.random.PRNGKey(1), tokens, optax.sgd(0.1), init_kwargs={"train": False})
-    text = train.make_train_step(kind="lm").lower(state, {"tokens": tokens}).compile().as_text()
+    _, tokens, state, step = trained
+    text = step.lower(state, {"tokens": tokens}).compile().as_text()             # the program the file compiled
     for phase in ("gdn/proj", "gdn/conv", "gdn/delta", "gdn/gate", "attn/proj", "attn/core", "moe/route",
                   "moe/experts", "moe/shared", "block/norm", "train/head"):
         assert phase in PHASES and phase in text, phase
@@ -248,16 +257,7 @@ def test_compiled_step_names_the_new_phases():
 
 def test_cli_trains_the_toy_size(tmp_path):
     overrides = ",".join(f"{k}={v}" for k, v in TOY.items()) + ",experts_held=4:8"
-    out = subprocess.run(
-        [sys.executable, "-m", "pytorch_distributed_training_tpu.cli.main", "--use-cpu", "--model",
-         "qwen3_next_80b_a3b", "--dataset", "synthetic-tokens", "--seq-len", "32", "--model-overrides", overrides,
-         "--batch-size", "4", "--accum-steps", "2", "--num-workers", "0", "--steps-per-epoch", "3",
-         "--learning-rate", "1e-3", "--remat", "--metrics-dir", str(tmp_path)],
-        capture_output=True, text=True, timeout=600,
-        # one CPU device: the suite's 8-device XLA_FLAGS would want a batch of 8
-        env={k: v for k, v in {**os.environ, "JAX_PLATFORMS": "cpu"}.items() if k != "XLA_FLAGS"},
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    )
+    out = toy_cli.train("qwen3_next_80b_a3b", overrides, "--remat", "--metrics-dir", str(tmp_path))
     assert out.returncode == 0, out.stderr[-3000:] + out.stdout[-2000:]
     assert "training started" in out.stdout
     recorded = "".join(p.read_text() for p in tmp_path.rglob("*") if p.is_file())

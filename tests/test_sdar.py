@@ -11,6 +11,8 @@ import numpy as np
 import optax
 import pytest
 
+import compiled
+import toy_cli
 from pytorch_distributed_training_tpu import models, train
 from pytorch_distributed_training_tpu.models import moe
 from pytorch_distributed_training_tpu.ops import attention, pallas_attention as pa
@@ -282,12 +284,22 @@ def test_noise_masks_the_drawn_share_and_follows_its_key():
     assert abs(float(jnp.mean(p)) - 0.5) < 0.15            # t is uniform
 
 
-def test_the_lm_step_reads_the_objective_from_the_model():
+@pytest.fixture(scope="module")
+def trained():
+    """The toy's state and its block-diffusion train step, built and compiled
+    once for the file: AdamW with its rate as state, remat, two microbatches
+    of (2, 32)."""
     net = models.create_model("sdar_30b_a3b", cfg_overrides=dict(TOY, remat=True))
-    assert models.MODEL_REGISTRY["sdar_30b_a3b"].kind == "lm"
-    sample = jnp.zeros((2, 32), jnp.int32)
-    state = train.create_train_state(net, jax.random.PRNGKey(0), sample, optax.adamw(1e-3),
+    state = train.create_train_state(net, jax.random.PRNGKey(0), jnp.zeros((2, 32), jnp.int32), compiled.adamw(),
                                      init_kwargs={"train": False})
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(2), (4, 32), 0, 511)}
+    return state, batch, train.make_train_step(kind="lm", num_microbatches=2, base_rng=jax.random.PRNGKey(1))
+
+
+def test_the_lm_step_reads_the_objective_from_the_model(trained):
+    assert models.MODEL_REGISTRY["sdar_30b_a3b"].kind == "lm"
+    state, batch, step = trained
+    state = compiled.at_rate(state, 1e-3)
     from pytorch_distributed_training_tpu.train.step import lm_objective
 
     assert lm_objective(state)[0] == "block_diffusion"
@@ -297,8 +309,6 @@ def test_the_lm_step_reads_the_objective_from_the_model():
                                           optax.sgd(0.1), init_kwargs={"train": False})
     assert lm_objective(gpt2_state) == ("next_token", None)
 
-    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(2), (4, 32), 0, 511)}
-    step = train.make_train_step(kind="lm", num_microbatches=2, base_rng=jax.random.PRNGKey(1))
     first = None
     for _ in range(8):
         state, metrics = step(state, batch)
@@ -312,22 +322,9 @@ def test_the_lm_step_reads_the_objective_from_the_model():
         train.make_train_step(kind="lm")(state, batch)
 
 
-def test_cli_trains_the_toy_size(tmp_path):
-    import os
-    import subprocess
-    import sys
-
+def test_cli_trains_the_toy_size():
     overrides = ",".join(f"{k}={v}" for k, v in TOY.items())
-    out = subprocess.run(
-        [sys.executable, "-m", "pytorch_distributed_training_tpu.cli.main", "--use-cpu",
-         "--model", "sdar_30b_a3b", "--dataset", "synthetic-tokens", "--seq-len", "32",
-         "--model-overrides", overrides, "--batch-size", "4", "--accum-steps", "2",
-         "--num-workers", "0", "--steps-per-epoch", "3", "--learning-rate", "1e-3"],
-        capture_output=True, text=True, timeout=600,
-        # one CPU device: the suite's 8-device XLA_FLAGS would want a batch of 8
-        env={k: v for k, v in {**os.environ, "JAX_PLATFORMS": "cpu"}.items() if k != "XLA_FLAGS"},
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    )
+    out = toy_cli.train("sdar_30b_a3b", overrides)
     assert out.returncode == 0, out.stderr[-3000:] + out.stdout[-2000:]
     assert "training started" in out.stdout
 
@@ -349,18 +346,12 @@ def test_masked_kernels_lower_under_their_names():
     # tests/benchmark_tests/test_bench_sdar.py, on the trace's own spelling)
 
 
-def test_compiled_step_names_the_new_phases():
-    import re
-
+def test_compiled_step_names_the_new_phases(trained):
     from pytorch_distributed_training_tpu.obs.schema import METRICS
     from pytorch_distributed_training_tpu.obs.trace import PHASES
     from pytorch_distributed_training_tpu.train.step import STEP_COUNTERS
 
-    net = models.create_model("sdar_30b_a3b", cfg_overrides=TOY)
-    state = train.create_train_state(net, jax.random.PRNGKey(0), jnp.zeros((1, 32), jnp.int32),
-                                     optax.sgd(0.1), init_kwargs={"train": False})
-    step = train.make_train_step(kind="lm", num_microbatches=2, base_rng=jax.random.PRNGKey(1))
-    batch = {"tokens": jnp.zeros((2, 32), jnp.int32)}
+    state, batch, step = trained                                                # the program the file compiled
     op_names = re.findall(r'op_name="([^"]+)"', step.lower(state, batch).compile().as_text())
     for phase in ("train/noise", "attn/block_diffusion", "moe/route", "moe/experts"):
         assert phase in PHASES and any(phase in name for name in op_names), phase

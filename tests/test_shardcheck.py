@@ -9,7 +9,10 @@ live program's collectives and catches a deliberately-broken TP layout
 argument/alias components and tolerance on the peak total — for the
 train step under every --grad-sync mode, the zero1 leg, and all serving
 programs (both pools, tp=1/tp=2), all read from the session-scoped
-lowering cache shared with tests/test_analysis.py.
+lowering cache.  Pass 2's audit of the same programs (donation, host
+callbacks, the DCN crossing census, the recompile guard) reads that cache
+here too: under ``--dist loadfile`` one file is one worker, and one worker
+builds the cache once.
 """
 
 import textwrap
@@ -21,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from pytorch_distributed_training_tpu.analysis import (
     KNOWN_AXES,
+    PROGRAM_REGISTRY,
     check_rules_axes,
     check_tree_coverage,
     lint_source,
@@ -28,7 +32,11 @@ from pytorch_distributed_training_tpu.analysis import (
     validate_memory_records,
 )
 from pytorch_distributed_training_tpu.analysis.hlo_audit import (
+    GRAD_SYNC_MODES,
+    audit_serving_engine,
+    audit_train_program,
     parse_collectives,
+    tp_allreduce_model,
 )
 from pytorch_distributed_training_tpu.analysis.reshard_audit import (
     DEFAULT_HBM_TOL,
@@ -315,6 +323,28 @@ def test_census_max_bytes_guard_rejects_param_sized_gather():
         parse_collectives(_TP_HLO_RESHARD), expected, "fixture"
     )
     assert _rules_of(findings) == ["unexpected-reshard"]
+
+
+_COMBINED_HLO = "\n".join([
+    "HloModule fixture, entry_computation_layout={()->()}",
+    '  %ar = (f32[16]{0}, f32[16,16]{1,0}, f32[]) all-reduce(%a, %b, %c), '
+    'replica_groups={{0,1,2,3}}, op_name="jit(f)/grad/psum"',
+])
+
+
+@pytest.mark.parametrize("want, rules", [(3, []), (4, ["missing-collective"])],
+                         ids=["counts-3", "missing-one-fires"])
+def test_census_counts_each_operand_of_a_combined_all_reduce(want, rules):
+    """XLA's combiner folds three psums into one tuple all-reduce: the
+    census counts the three it carries, and a fourth that is not there
+    is still missing."""
+    expected = [_exp("all-reduce", "f32", want, scope="grad")]
+    findings, report = match_inventory(
+        parse_collectives(_COMBINED_HLO), expected, "fixture"
+    )
+    assert _rules_of(findings) == rules
+    assert report["expected"][0]["found"] == 3
+    assert [c["bytes"] for c in report["collectives"]] == [64, 1024, 4]
 
 
 def test_census_overcount_fires():
@@ -684,6 +714,167 @@ def test_memory_record_schema_roundtrip():
         validate_memory_records([dict(rec2, measured_total="11")])
     with pytest.raises(ValueError):
         validate_memory_records([dict(rec2, total_rel_err="big")])
+
+
+# --------------------------------------------------------------------- #
+# pass 2: the compiled-artifact audit over the real programs (the same
+# session-scoped lowering cache: its readers share one worker's build)
+# --------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize("mode", GRAD_SYNC_MODES)
+def test_train_step_audit_clean(audit_programs, mode):
+    """Donation covers every TrainState leaf, no host callbacks, and the
+    DCN crossing census equals the analytic byte model (crossing >= the
+    best-case bound for flat) — for every --grad-sync mode.  Reads the
+    session-scoped lowering cache (conftest.audit_programs), the same
+    artifacts pass 3's census/memory tests pin."""
+    findings, report = audit_train_program(
+        audit_programs[f"train/step-{mode}"]
+    )
+    assert findings == [], [f.message for f in findings]
+    assert report["alias_entries"] == report["donated_leaves"]
+    if mode != "flat":
+        assert sum(report["dcn_crossing"].values()) == report["dcn_model"]
+    # The compressed wire is visibly compressed: nothing f32 crosses DCN
+    # except int8's per-bucket scales.
+    if mode in ("hier-bf16", "hier-int4", "hier-topk"):
+        assert "f32" not in report["dcn_crossing"], report["dcn_crossing"]
+
+
+def test_bf16_wire_stays_narrow(audit_programs):
+    """Regression pin for the wire-widening find: the hier-bf16 DCN hop
+    crosses as u16 (bitcast bf16), NOT as f32 — XLA's convert motion
+    would otherwise legally widen the payload and double the compressed
+    hop's bytes."""
+    _, report = audit_train_program(
+        audit_programs["train/step-hier-bf16"]
+    )
+    crossing = report["dcn_crossing"]
+    assert set(crossing) == {"u16"}
+    assert crossing["u16"] == report["dcn_model"]
+
+
+@pytest.fixture(scope="module")
+def audit_engines(audit_programs):
+    # The engines behind the cached serving programs — one per pool
+    # layout/TP label, shared with pass 3's tests via the session cache.
+    return {
+        prog.context["label"]: prog.context["engine"]
+        for prog in audit_programs.values()
+        if prog.kind == "serve"
+    }
+
+
+@pytest.mark.parametrize("label", ["contig", "paged"])
+def test_serving_programs_audit_clean(audit_engines, label):
+    """All three AOT serving programs, both pool layouts: donation
+    materialized for every cache leaf, zero host callbacks."""
+    engine = audit_engines[label]
+    findings, report = audit_serving_engine(engine, label)
+    assert findings == [], [f.message for f in findings]
+    assert set(report) == {"prefill", "decode", "verify"}
+    n_cache = len(jax.tree_util.tree_leaves(engine.pool.cache))
+    for entry in report.values():
+        assert entry["alias_entries"] == n_cache
+        assert entry["custom_calls"] == []
+        assert entry["signature"]
+
+
+@pytest.mark.parametrize("label", ["tp2", "tp2-paged"])
+def test_serving_programs_audit_tp(audit_engines, label):
+    """The TP satellite: on the simulated 8-device mesh, donation
+    aliasing holds under NamedShardings and the head-sharded collective
+    census matches the megatron model for all three programs."""
+    engine = audit_engines[label]
+    findings, report = audit_serving_engine(engine, label)
+    assert findings == [], [f.message for f in findings]
+    cfg = engine._decoder.cfg
+    widths = {"prefill": engine.prefill_chunk, "decode": 1,
+              "verify": engine.spec_k + 1}
+    for name, entry in report.items():
+        expect = tp_allreduce_model(
+            num_layers=cfg.num_layers, num_slots=engine.num_slots,
+            width=widths[name], hidden=cfg.hidden_dim,
+        )
+        assert entry["tp_allreduce_model"] == expect
+        got = entry["collectives"]["all-reduce"]["by_dtype"]["f32"]
+        assert got == expect, (name, got, expect)
+
+
+def test_recompile_guard_full_scheduler_trace(audit_engines):
+    """The recompile-count regression: a full ContinuousScheduler trace
+    — admission, speculative decode, mid-decode cancellation, reset, and
+    a second wave after the reset — compiles each AOT engine program
+    exactly once (at construction), pinned via the signature registry."""
+    from pytorch_distributed_training_tpu.serve import (
+        ContinuousScheduler, Request, VirtualClock,
+    )
+
+    engine = audit_engines["paged"]
+    engine.reset()
+
+    # Deterministic drafting (the drafter is an injectable attribute):
+    # every decode tick proposes a repeat of the last token, so the
+    # VERIFY program is exercised on every tick regardless of what the
+    # untrained model happens to emit.
+    class _ScriptedDrafter:
+        index = None
+
+        def observe_prompt(self, prompt):
+            pass
+
+        def draft(self, history, k):
+            return np.full((min(2, max(k, 0)),), history[-1], np.int32)
+
+    real_drafter = engine.drafter
+    engine.drafter = _ScriptedDrafter()
+    base = PROGRAM_REGISTRY.snapshot()
+    sigs = dict(engine.program_signatures)
+    assert set(sigs) == {"prefill", "decode", "verify"}
+    # Construction recorded each signature exactly once.
+    for name, sig in sigs.items():
+        assert PROGRAM_REGISTRY.counts(f"serve/{name}")[
+            (f"serve/{name}", sig)
+        ] == 1, (name, sig)
+
+    clock = VirtualClock()
+    sched = ContinuousScheduler(engine, clock=clock)
+    rng = np.random.default_rng(5)
+    pat = rng.integers(0, 61, (3,)).astype(np.int32)
+    reqs = [
+        Request(0, np.tile(pat, 5)[:12], 10),          # draftable tail
+        # Admitted into the second slot at t=0, budget far beyond its
+        # deadline: expires MID-DECODE (cancelled, not shed).
+        Request(2, rng.integers(0, 61, 5).astype(np.int32), 30,
+                deadline=0.5),
+        Request(1, rng.integers(0, 61, 7).astype(np.int32), 8),
+        Request(3, np.tile(pat, 4)[:9], 6),
+    ]
+    for r in reqs:
+        assert sched.submit(r)
+    for _ in range(100):
+        if sched.idle:
+            break
+        sched.tick()
+        clock.advance(0.2)
+    assert sched.idle
+    reasons = {r["id"]: r["finish_reason"] for r in sched.completed}
+    assert reasons[2] == "cancelled"
+    assert engine.spec_drafted_tokens > 0  # the verify program ran
+    engine.reset()
+    # Second wave on the SAME engine after reset.
+    sched2 = ContinuousScheduler(engine, clock=VirtualClock())
+    assert sched2.submit(Request(10, np.tile(pat, 5)[:12], 8))
+    for _ in range(50):
+        if sched2.idle:
+            break
+        sched2.tick()
+    assert sched2.idle
+    engine.drafter = real_drafter
+    # The whole trace compiled NOTHING new.
+    assert PROGRAM_REGISTRY.compiles_since(base) == {}
+    assert engine.program_signatures == sigs
 
 
 def test_build_audit_programs_filter(devices8):

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import compiled
 from pytorch_distributed_training_tpu import models
 from pytorch_distributed_training_tpu.obs.cost import mosaic_kernels
 from pytorch_distributed_training_tpu.ops import ssd
@@ -35,12 +36,18 @@ def scan_inputs(t, g, r, dtype=jnp.float32, bsz=2, rate=3.0):
             -jnp.arange(1.0, h + 1.0) / h, wide(k[2]), wide(k[3]))
 
 
-def cost(fn):
-    return lambda *inputs: jnp.sum(jnp.sin(fn(*inputs).astype(jnp.float32)))
+def cost(*inputs, fn):
+    return jnp.sum(jnp.sin(fn(*inputs).astype(jnp.float32)))
 
 
-def gradients(fn, args):
-    return jax.grad(cost(fn), argnums=(0, 1, 2, 3, 4))(*args)
+def late(*inputs, fn):
+    """The last chunk's outputs under a sine, summed."""
+    return jnp.sum(jnp.sin(fn(*inputs)[:, -CHUNK:]))
+
+
+def gradients(fn, args, of=cost):
+    """The five gradients of ``of`` at ``fn``, as one compiled program."""
+    return compiled.grad(of, argnums=(0, 1, 2, 3, 4), fn=fn)(*args)
 
 
 pair = lambda *inputs: ssd.ssd_chunked(*inputs, chunk=CHUNK)
@@ -51,10 +58,10 @@ plain = lambda *inputs: ssd._ssd_xla(*inputs, CHUNK)
 def test_the_pair_is_the_recurrence_forward_and_backward(t, g, r):
     args = scan_inputs(t, g, r)
     assert ssd.ssd_plan(t, g * r, g, P, N, CHUNK, 4).kind == "pallas"
-    want = recurrence(*args)
+    want = compiled.jit(recurrence)(*args)
     top = float(jnp.abs(want).max())
-    np.testing.assert_allclose(pair(*args), want, rtol=2e-5, atol=2e-5 * top)
-    np.testing.assert_allclose(pair(*args), plain(*args), rtol=2e-5, atol=2e-5 * top)
+    np.testing.assert_allclose(compiled.jit(pair)(*args), want, rtol=2e-5, atol=2e-5 * top)
+    np.testing.assert_allclose(compiled.jit(pair)(*args), compiled.jit(plain)(*args), rtol=2e-5, atol=2e-5 * top)
     for name, got, want_g, plain_g in zip(INPUTS, gradients(pair, args), gradients(recurrence, args),
                                           gradients(plain, args)):
         atol = 1e-4 * float(jnp.abs(want_g).max())
@@ -67,14 +74,12 @@ def test_the_state_the_pair_carries_over_four_chunks_is_not_small():
     left — the last chunk alone, from a zero state, reads otherwise — and the
     first chunk's inputs take gradient from the last chunk's outputs."""
     args = scan_inputs(512, 2, 2, rate=5.0)
-    want = recurrence(*args)
-    got = pair(*args)
+    want = compiled.jit(recurrence)(*args)
+    got = compiled.jit(pair)(*args)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5 * float(jnp.abs(want).max()))
-    alone = pair(*(m[:, -CHUNK:] if m.ndim > 1 else m for m in args))
+    alone = compiled.jit(pair)(*(m[:, -CHUNK:] if m.ndim > 1 else m for m in args))
     assert float(jnp.abs(alone - want[:, -CHUNK:]).max()) > 0.05 * float(jnp.abs(want).max())
-    late = lambda fn: lambda *inputs: jnp.sum(jnp.sin(fn(*inputs)[:, -CHUNK:]))
-    got_g = jax.grad(late(pair), argnums=(0, 1, 2, 3, 4))(*args)
-    want_g = jax.grad(late(recurrence), argnums=(0, 1, 2, 3, 4))(*args)
+    got_g, want_g = gradients(pair, args, late), gradients(recurrence, args, late)
     for name, g_, w_ in zip(INPUTS, got_g, want_g):
         np.testing.assert_allclose(g_, w_, rtol=1e-4, atol=1e-4 * float(jnp.abs(w_).max()), err_msg=name)
     first = float(jnp.abs(got_g[0][:, :CHUNK]).max())
@@ -84,8 +89,8 @@ def test_the_state_the_pair_carries_over_four_chunks_is_not_small():
 def test_the_pair_in_bf16_keeps_float32_steps_and_rounds_where_the_jnp_form_does():
     args = scan_inputs(512, 2, 2, dtype=jnp.bfloat16)
     assert args[1].dtype == args[2].dtype == jnp.float32
-    exact = recurrence(*(m.astype(jnp.float32) for m in args))
-    got, same = pair(*args), plain(*args)
+    exact = compiled.jit(recurrence)(*(m.astype(jnp.float32) for m in args))
+    got, same = compiled.jit(pair)(*args), compiled.jit(plain)(*args)
     assert got.dtype == jnp.bfloat16
     scale = float(jnp.abs(exact).max())
     # both forms round operands to bf16 in the same places: they differ by an output rounding or two, and
